@@ -75,9 +75,12 @@ def _build_vote_program(num_classes: int):
 #: may materialize in HBM (the pallas path never materializes it at all)
 _MAX_DIST_ELEMS = 64 << 20
 
-# set on the first pallas lowering failure so later transforms skip straight
-# to the XLA path instead of re-tracing the kernel to the same exception
-_pallas_knn_broken = False
+#: test rows per kernel call. The kernel's HBM operands are lane-padded to
+#: 128: a narrow (rows, d) input and the (rows, k) index and distance
+#: outputs each take rows x 512 bytes whatever d and k are — 1.5 GB at
+#: this chunk, where the vendored 10M x 32 predict in ONE call asked for
+#: 14.3 GB of a 16 GB chip (my chip run, PR 21)
+_KERNEL_CHUNK_ROWS = 1 << 20
 
 
 class KnnModel(Model, KnnModelParams):
@@ -96,72 +99,41 @@ class KnnModel(Model, KnnModelParams):
         train = jnp.asarray(self.features, jnp.float32)
         label_idx_d = jnp.asarray(label_idx)
 
-        pred_idx = self._predict_pallas(x, train, label_idx_d, len(classes))
-        # benchmark provenance: which path produced this prediction
-        # (runner.py records it as the row's executionPath)
-        self.last_execution_path = ("pallas" if pred_idx is not None
-                                    else "xla-chunked")
-        if pred_idx is None:
-            # XLA fallback, memory-bounded: test rows in chunks so no
+        from flink_ml_tpu.ops.pallas_kernels import (
+            KNN_VMEM_BUDGET_BYTES,
+            _knn_step_vmem_bytes,
+            knn_topk_indices,
+            pallas_supported,
+        )
+        # n_train is streamed over the kernel's second grid axis, so only
+        # the per-step working set gates (d would have to reach thousands)
+        if (pallas_supported() and _knn_step_vmem_bytes(
+                train.shape[1], self.k) <= KNN_VMEM_BUDGET_BYTES):
+            # fused distance+top-k kernel: the (n, n_train) matrix never
+            # exists, even tile-wise, outside VMEM
+            vote = _build_vote_program(len(classes))
+            chunk = _KERNEL_CHUNK_ROWS
+
+            def predict_chunk(xc):
+                return vote(knn_topk_indices(xc, train, self.k), label_idx_d)
+
+            self.last_execution_path = "pallas"
+        else:
+            # XLA path, memory-bounded: test rows in chunks so no
             # (chunk, n_train) block exceeds _MAX_DIST_ELEMS
             predict = _build_knn_program(self.k, len(classes))
             norms = jnp.sum(train * train, axis=1)
             chunk = max(1, min(n, _MAX_DIST_ELEMS // max(n_train, 1)))
-            parts = []
-            for s in range(0, n, chunk):
-                xc = jnp.asarray(x[s:s + chunk], jnp.float32)
-                parts.append(np.asarray(predict(xc, train, norms,
-                                                label_idx_d)))
-            pred_idx = np.concatenate(parts) if parts else np.zeros(0, int)
+
+            def predict_chunk(xc):
+                return predict(xc, train, norms, label_idx_d)
+
+            self.last_execution_path = "xla-chunked"
+        parts = [np.asarray(predict_chunk(
+            jnp.asarray(x[s:s + chunk], jnp.float32)))
+            for s in range(0, n, chunk)]
+        pred_idx = np.concatenate(parts) if parts else np.zeros(0, int)
         return (table.with_column(self.prediction_col, classes[pred_idx]),)
-
-    def _predict_pallas(self, x, train, label_idx_d, num_classes):
-        """Fused distance+top-k kernel path: the (n, n_train) matrix never
-        exists, even tile-wise, outside VMEM. None = not applicable."""
-        from flink_ml_tpu.ops.pallas_kernels import (
-            KNN_VMEM_BUDGET_BYTES,
-            _knn_step_vmem_bytes,
-            is_surrounding_failure,
-            knn_topk_indices,
-            pallas_supported,
-        )
-        global _pallas_knn_broken
-        nt, d = train.shape
-        # n_train is streamed over the kernel's second grid axis, so only
-        # the per-step working set gates (d would have to reach thousands)
-        if (_pallas_knn_broken or not pallas_supported()
-                or _knn_step_vmem_bytes(d, self.k) > KNN_VMEM_BUDGET_BYTES):
-            return None
-        try:
-            idx = knn_topk_indices(jnp.asarray(x, jnp.float32), train,
-                                   self.k)
-            vote = _build_vote_program(num_classes)
-            return np.asarray(vote(idx, label_idx_d))
-        except Exception as e:
-            # kernel failures fall back to the (correct, slower) XLA path
-            # rather than crashing predict; the process flag stops
-            # re-tracing the same failure each call, and the warning
-            # keeps the cause visible (same policy as the KMeans assign
-            # kernel). An HBM RESOURCE_EXHAUSTED here is ALSO a
-            # kernel-path failure: knn_topk_indices places and pads full
-            # copies of x and train that the chunked XLA fallback never
-            # materializes (it slices numpy and places chunk by chunk),
-            # so the fallback can succeed where the kernel path OOMed —
-            # but it is a size-specific failure, not a broken lowering,
-            # so it does not burn the process-wide flag.
-            import logging
-
-            if is_surrounding_failure(e):
-                logging.getLogger(__name__).warning(
-                    "pallas KNN path exhausted HBM placing its padded "
-                    "inputs; using the memory-bounded XLA path for this "
-                    "call: %s: %s", type(e).__name__, e)
-                return None
-            logging.getLogger(__name__).warning(
-                "pallas KNN kernel failed; using the XLA path for the "
-                "rest of this process: %s: %s", type(e).__name__, e)
-            _pallas_knn_broken = True
-            return None
 
     def set_model_data(self, model_data: Table):
         self.features = model_data.vectors("packedFeatures", np.float64)

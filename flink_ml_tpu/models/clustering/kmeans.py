@@ -42,6 +42,7 @@ from flink_ml_tpu.parallel.mesh import (
     data_pspec,
     data_shard_count,
     default_mesh,
+    local_mesh,
 )
 from flink_ml_tpu.params.param import IntParam, ParamValidators, StringParam
 from flink_ml_tpu.params.shared import (
@@ -68,14 +69,21 @@ class KMeansParams(KMeansModelParams, HasSeed, HasMaxIter):
 
 
 @functools.lru_cache(maxsize=32)
-def _build_assign_program(measure_name: str):
+def _build_assign_program(mesh, measure_name: str, use_kernel: bool = False):
+    """Nearest-centroid index per row, row-parallel over the mesh: each
+    device assigns its own shard of the batch (a pallas call under plain
+    jit would have every device gather and assign the whole batch). With
+    ``use_kernel`` (TPU + euclidean) the shard runs the fused
+    distance+argmin kernel — no (n, k) block in HBM."""
     measure = DistanceMeasure.get_instance(measure_name)
 
-    @jax.jit
     def assign(x, c):
-        return jnp.argmin(measure.pairwise(x, c), axis=1)
+        if use_kernel:
+            from flink_ml_tpu.ops.pallas_kernels import assign_nearest
+            return assign_nearest(x, c)
+        return jnp.argmin(measure.pairwise(x, c), axis=1).astype(jnp.int32)
 
-    return assign
+    return mr.map_rows(assign, mesh, n_extra=1)
 
 
 def _lloyd_round_math(measure, axes, partials_fn=None,
@@ -264,20 +272,6 @@ def _build_lloyd_round_program(mesh, measure_name: str,
         out_specs=(P(), P()), jit=False)
 
 
-# set on the first pallas lowering failure so later transforms skip straight
-# to the XLA path instead of re-tracing the kernel to the same exception
-_pallas_assign_broken = False
-
-# same policy for the fused fit-round kernel (independent lowering)
-_pallas_lloyd_broken = False
-
-
-def _is_pallas_failure(e: Exception) -> bool:
-    from flink_ml_tpu.ops.pallas_kernels import is_pallas_failure
-
-    return is_pallas_failure(e)
-
-
 class KMeansModel(Model, KMeansModelParams):
     def __init__(self, centroids: Optional[np.ndarray] = None,
                  weights: Optional[np.ndarray] = None, **kwargs):
@@ -290,40 +284,23 @@ class KMeansModel(Model, KMeansModelParams):
             raise ValueError("KMeansModel has no model data")
         x = table.vectors(self.features_col)
         from flink_ml_tpu.ops.pallas_kernels import (
-            assign_nearest,
+            lloyd_kernel_fits,
             pallas_supported,
         )
-        global _pallas_assign_broken
-        labels = None
-        if (self.distance_measure == "euclidean" and pallas_supported()
-                and not _pallas_assign_broken):
-            try:
-                # fused distance+argmin pallas kernel: no (n, k) in HBM
-                labels = np.asarray(assign_nearest(
-                    x, np.asarray(self.centroids, np.float32)))
-            except Exception as e:
-                # this try wraps only the kernel call, so an unrecognized
-                # error defaults to fall-back-and-flag (KNN predict's
-                # policy); only a positively identified surrounding
-                # failure — an HBM OOM placing the input — re-raises
-                from flink_ml_tpu.ops.pallas_kernels import (
-                    is_surrounding_failure)
-
-                if is_surrounding_failure(e):
-                    raise
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "pallas assign kernel failed; using the XLA path for "
-                    "the rest of this process: %s: %s", type(e).__name__, e)
-                _pallas_assign_broken = True  # lowering failed; use XLA
+        k, dim = self.centroids.shape
+        # the assign kernel's working set is a subset of the fused Lloyd
+        # round's, so one shape gate serves both
+        use_kernel = (self.distance_measure == "euclidean"
+                      and pallas_supported() and lloyd_kernel_fits(k, dim))
+        mesh = local_mesh()
+        xs, n = ensure_on_mesh(mesh, x, data_axes(mesh), jnp.float32)
+        assign = _build_assign_program(mesh, self.distance_measure,
+                                       use_kernel)
+        labels = np.asarray(assign(
+            xs, jnp.asarray(self.centroids, jnp.float32)))[:n]
         # benchmark provenance (runner.py executionPath)
-        self.last_execution_path = ("pallas-assign" if labels is not None
+        self.last_execution_path = ("pallas-assign" if use_kernel
                                     else "xla-assign")
-        if labels is None:
-            assign = _build_assign_program(self.distance_measure)
-            labels = np.asarray(assign(
-                jnp.asarray(x), jnp.asarray(self.centroids, jnp.float32)))
         return (table.with_column(self.prediction_col,
                                   labels.astype(np.int64)),)
 
@@ -355,7 +332,6 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
         return self._supervised_fit(lambda: self._fit_once(table))
 
     def _fit_once(self, table: Table) -> KMeansModel:
-        global _pallas_lloyd_broken
         x = table.vectors(self.features_col)
         n, dim = x.shape
         k = self.k
@@ -397,53 +373,30 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
             from flink_ml_tpu.ops.pallas_kernels import (
                 lloyd_kernel_fits, pallas_supported)
             unroll = self.max_iter <= _UNROLL_MAX_ROUNDS
+            # the kernel is chosen by the backend and the shape gate,
+            # nothing else; a Mosaic failure propagates
             use_kernel = (self.distance_measure == "euclidean"
                           and pallas_supported()
-                          and not _pallas_lloyd_broken
                           and lloyd_kernel_fits(k, dim))
-
-            def run_fit(use_kernel):
-                fit = _build_lloyd_program(
-                    mesh, self.distance_measure, self.max_iter,
-                    unroll=unroll, use_kernel=use_kernel,
-                    health=health_on, sharded=sharded)
-                # the (c0, counts0) carry is DONATED — copy=True builds
-                # a fresh buffer per attempt even when `init` is itself
-                # a device array (device-resident features: vectors()
-                # returns the jax array, and asarray would ALIAS it —
-                # the first attempt would consume it and the
-                # pallas-fallback retry would pass a deleted buffer);
-                # the split (centroids, counts) outputs fetch once per
-                # fit
-                out = fit(xs, n_valid, jnp.array(init, copy=True),
-                          jnp.zeros((k,), jnp.float32))
-                if health_on:
-                    centroids, counts, shifts = out
-                else:
-                    (centroids, counts), shifts = out, None
-                return np.asarray(centroids), np.asarray(counts), shifts
-
-            try:
-                centroids, counts, shifts = run_fit(use_kernel)
-                # benchmark provenance (runner.py executionPath)
-                self.last_execution_path = (
-                    "pallas-lloyd" if use_kernel else "xla-lloyd")
-            except Exception as e:
-                if not use_kernel or not _is_pallas_failure(e):
-                    raise
-                # kernel lowering/compile failed: fall back to the XLA
-                # partials for the rest of the process, loudly (same
-                # policy as the assign/KNN kernels). Non-kernel failures
-                # (e.g. HBM OOM) re-raise above instead of being
-                # misattributed and silently retried.
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "pallas Lloyd kernel failed; using the XLA fit path "
-                    "for the rest of this process", exc_info=True)
-                _pallas_lloyd_broken = True
-                centroids, counts, shifts = run_fit(False)
-                self.last_execution_path = "xla-lloyd"
+            fit = _build_lloyd_program(
+                mesh, self.distance_measure, self.max_iter,
+                unroll=unroll, use_kernel=use_kernel,
+                health=health_on, sharded=sharded)
+            # the (c0, counts0) carry is DONATED — copy=True builds a
+            # fresh buffer even when `init` is itself a device array
+            # (device-resident features: vectors() returns the jax
+            # array, and asarray would ALIAS it); the split (centroids,
+            # counts) outputs fetch once per fit
+            out = fit(xs, n_valid, jnp.array(init, copy=True),
+                      jnp.zeros((k,), jnp.float32))
+            if health_on:
+                centroids, counts, shifts = out
+            else:
+                centroids, counts = out
+            centroids, counts = np.asarray(centroids), np.asarray(counts)
+            # benchmark provenance (runner.py executionPath)
+            self.last_execution_path = (
+                "pallas-lloyd" if use_kernel else "xla-lloyd")
             if health_on:
                 s = np.asarray(shifts, np.float64)
                 _health.check_fit("KMeans", {"centerShift": s},
@@ -475,50 +428,30 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
             # carry between rounds)
             use_kernel = (seg > 0 and self.distance_measure == "euclidean"
                           and pallas_supported()
-                          and not _pallas_lloyd_broken
                           and lloyd_kernel_fits(k, dim))
+            round_fn = _build_lloyd_round_program(
+                mesh, self.distance_measure, sharded=sharded,
+                use_kernel=use_kernel)
 
-            def run_host_fit(use_k):
-                round_fn = _build_lloyd_round_program(
-                    mesh, self.distance_measure, sharded=sharded,
-                    use_kernel=use_k)
+            def body(carry, epoch):
+                centroids, _ = carry
+                return round_fn(xs, n_valid, centroids)
 
-                def body(carry, epoch):
-                    centroids, _ = carry
-                    return round_fn(xs, n_valid, centroids)
-
-                from jax.sharding import NamedSharding
-                repl = NamedSharding(mesh, P())
-                # fresh carry per attempt: the segmented loop DONATES
-                # the carry into each compiled segment (in-place
-                # update). copy=True — device_put on an already-device
-                # `init` (device-resident features) would SHARE its
-                # buffer, and the kernel-fallback retry would re-pass
-                # the consumed array.
-                return iterate_bounded(
-                    (jax.device_put(jnp.array(init, copy=True), repl),
-                     jax.device_put(jnp.zeros((k,), jnp.float32), repl)),
-                    body, max_iter=self.max_iter,
-                    config=self._iteration_config,
-                    listeners=listeners, donate_carry=True)
-
-            try:
-                centroids, counts = run_host_fit(use_kernel)
-                self.last_execution_path = (
-                    "pallas-lloyd-segments" if use_kernel
-                    else "xla-lloyd-segments" if seg else "host-rounds")
-            except Exception as e:
-                if not use_kernel or not _is_pallas_failure(e):
-                    raise
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "pallas Lloyd kernel failed in the segmented fit; "
-                    "using the XLA round for the rest of this process",
-                    exc_info=True)
-                _pallas_lloyd_broken = True
-                centroids, counts = run_host_fit(False)
-                self.last_execution_path = "xla-lloyd-segments"
+            from jax.sharding import NamedSharding
+            repl = NamedSharding(mesh, P())
+            # the segmented loop DONATES the carry into each compiled
+            # segment (in-place update). copy=True — device_put on an
+            # already-device `init` (device-resident features) would
+            # SHARE its buffer with the input column.
+            centroids, counts = iterate_bounded(
+                (jax.device_put(jnp.array(init, copy=True), repl),
+                 jax.device_put(jnp.zeros((k,), jnp.float32), repl)),
+                body, max_iter=self.max_iter,
+                config=self._iteration_config,
+                listeners=listeners, donate_carry=True)
+            self.last_execution_path = (
+                "pallas-lloyd-segments" if use_kernel
+                else "xla-lloyd-segments" if seg else "host-rounds")
             if not health_on or seg:
                 _health.guard_final_state(
                     "KMeans", np.asarray(centroids, np.float64))

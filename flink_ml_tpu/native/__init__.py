@@ -6,13 +6,15 @@ device compute, and C++ for host-side kernels that are neither XLA-friendly
 nor fast in Python — currently the Swing pairwise-intersection core.
 
 Kernels compile lazily with g++ into a shared library next to the sources
-and bind via ctypes; every caller must handle ``available() == False`` and
+(keyed by a hash of sources, flags and host CPU — see ``_build_key``) and
+bind via ctypes; every caller must handle ``available() == False`` and
 fall back to its Python implementation (no hard native dependency).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,10 +29,56 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = sorted(
     os.path.join(_DIR, f) for f in os.listdir(_DIR) if f.endswith(".cpp"))
 _LIB = os.path.join(_DIR, "_native_kernels.so")
+#: the build key the library on disk was compiled under (see _build_key)
+_KEY_FILE = _LIB + ".key"
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-pthread",
+          "-std=c++17")
 
 _lock = make_lock("native.load")
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+
+
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolves against: the host CPU's model and
+    feature flags. Part of the build key, so a library copied in from
+    another machine (the tree is copied as it stands, ignored files
+    included) is rebuilt instead of loaded."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            lines = f.read().splitlines()
+    except OSError:
+        import platform
+
+        return platform.machine() + platform.processor()
+    keep = {}
+    for line in lines:
+        name = line.split(":", 1)[0].strip()
+        if name in ("model name", "flags", "Features") and name not in keep:
+            keep[name] = line
+    return "\n".join(keep.values())
+
+
+def _build_key() -> str:
+    """Hash of everything the binary depends on: source bytes, compiler
+    flags, and the CPU ``-march=native`` targets. Modification times play
+    no part — a checkout or a copy sets them arbitrarily."""
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        h.update(os.path.basename(src).encode())
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_identity().encode())
+    return h.hexdigest()
+
+
+def _recorded_key() -> Optional[str]:
+    try:
+        with open(_KEY_FILE) as f:
+            return f.read().strip()
+    except OSError:
+        return None
 
 
 def _build() -> Optional[ctypes.CDLL]:
@@ -38,22 +86,26 @@ def _build() -> Optional[ctypes.CDLL]:
     if not _SOURCES:  # sources stripped from the install: no native tier
         _build_failed = True
         return None
+    key = _build_key()
     try:
-        newest_src = max(os.path.getmtime(s) for s in _SOURCES)
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < newest_src):
-            # per-process temp name: concurrent builders never share a file,
-            # and os.replace publishes atomically
+        if not os.path.exists(_LIB) or _recorded_key() != key:
+            # per-process temp names: concurrent builders never share a
+            # file, and os.replace publishes atomically (library first,
+            # then the key that vouches for it)
             tmp = f"{_LIB}.{os.getpid()}.tmp"
+            tmp_key = f"{_KEY_FILE}.{os.getpid()}.tmp"
             try:
                 subprocess.run(
-                    ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-                     "-pthread", "-std=c++17", *_SOURCES, "-o", tmp],
+                    ["g++", *_FLAGS, *_SOURCES, "-o", tmp],
                     check=True, capture_output=True)
+                with open(tmp_key, "w") as f:
+                    f.write(key)
                 os.replace(tmp, _LIB)
+                os.replace(tmp_key, _KEY_FILE)
             finally:
-                if os.path.exists(tmp):
-                    os.remove(tmp)
+                for leftover in (tmp, tmp_key):
+                    if os.path.exists(leftover):
+                        os.remove(leftover)
         lib = ctypes.CDLL(_LIB)
         lib.swing_similarity.restype = ctypes.c_int
         lib.swing_similarity.argtypes = [
@@ -97,12 +149,11 @@ def _build() -> Optional[ctypes.CDLL]:
         return lib
     except (OSError, subprocess.CalledProcessError):
         # a concurrent builder may have published a valid library even if
-        # our own attempt failed — but never load a library older than the
-        # source (a stale kernel is worse than the Python fallback)
+        # our own attempt failed — but never load one built from other
+        # sources, flags or CPU (a stale kernel is worse than the Python
+        # fallback)
         try:
-            if (os.path.exists(_LIB)
-                    and os.path.getmtime(_LIB) >= max(
-                        os.path.getmtime(s) for s in _SOURCES)):
+            if os.path.exists(_LIB) and _recorded_key() == key:
                 return ctypes.CDLL(_LIB)
         except OSError:
             pass

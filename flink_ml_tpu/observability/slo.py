@@ -24,9 +24,9 @@ turns them into verdicts:
   ``slo_violations{slo=...}`` counters in the ``ml.slo`` registry
   group, so the trace artifacts carry the verdict history.
 
-Specs load from JSON (any Python) or TOML (Python 3.11+, stdlib
-``tomllib``) — see docs/observability.md "Live telemetry & SLOs" for
-the format — or fall back to :func:`default_slos`. Evaluation sources:
+Specs load from JSON or TOML (stdlib ``tomllib``) — see
+docs/observability.md "Live telemetry & SLOs" for the format — or fall
+back to :func:`default_slos`. Evaluation sources:
 
 - **live** (the ``/slo`` endpoint, observability/server.py): sliding
   windows straight from the process registry's windowed metrics;
@@ -205,19 +205,15 @@ def default_slos() -> List[SLO]:
 
 
 def load_specs(path: str) -> List[SLO]:
-    """Parse an SLO spec file — JSON anywhere, TOML on Python 3.11+
-    (stdlib ``tomllib``; no new dependency). The document is a
+    """Parse an SLO spec file — JSON or TOML (stdlib ``tomllib``; no
+    new dependency). The document is a
     ``{"slos": [...]}`` mapping (TOML: ``[[slos]]`` tables) or a bare
     JSON list. Raises ValueError on malformed specs."""
     with open(path, "rb") as f:
         raw = f.read()
     if path.endswith(".toml"):
-        try:
-            import tomllib
-        except ImportError as e:  # Python 3.10: no stdlib TOML parser
-            raise ValueError(
-                "TOML SLO specs need Python 3.11+ (tomllib); "
-                "use the JSON spelling instead") from e
+        import tomllib
+
         try:
             doc = tomllib.loads(raw.decode("utf-8"))
         except tomllib.TOMLDecodeError as e:
@@ -804,9 +800,8 @@ def main(argv=None) -> int:
                     "ratios, burn rates).")
     parser.add_argument("trace_dir")
     parser.add_argument("--spec", metavar="FILE",
-                        help="SLO spec file (JSON, or TOML on Python "
-                             "3.11+); default: the built-in serving "
-                             "SLOs")
+                        help="SLO spec file (JSON or TOML); default: the "
+                             "built-in serving SLOs")
     parser.add_argument("--json", action="store_true",
                         help="machine-readable output")
     parser.add_argument("--check", action="store_true",

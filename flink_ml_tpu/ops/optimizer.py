@@ -407,11 +407,6 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
 _UNROLL_MAX_ROUNDS = int(os.environ.get(
     "FLINK_ML_TPU_SGD_UNROLL_MAX", "64"))
 
-# set on the first pallas lowering failure so later fits skip straight to
-# the XLA rounds instead of re-tracing the kernel to the same exception
-_pallas_sgd_broken = False
-
-
 def _static_batch_schedule(local_n: int, lb: int, max_iter: int):
     """The per-shard minibatch schedule as Python ints — valid because the
     reference's slicing (SGD.java:262-284) depends only on (n, batch), not
@@ -525,8 +520,7 @@ def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
         return coeffs, offset[None], opt, mean_loss, epoch, stop
 
     # the (coeffs, offsets, opt) carry donates in EVERY build — the
-    # update happens in place in the donated buffers; callers rebuild
-    # the carry on the pallas-fallback retry (make_init in optimize)
+    # update happens in place in the donated buffers
     return mr.map_shards(
         per_shard, mesh,
         in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
@@ -570,9 +564,7 @@ def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
 def _tp_prepare_program(rem: int, pad_d: int, sharding):
     """Compiled cast+pad for a device-resident feature matrix entering the
     tensor-parallel layout (rows to the data axes, features to the model
-    axis) — no host round-trip. Row-major output layout (see
-    collective.row_major_format)."""
-    from flink_ml_tpu.parallel.collective import row_major_format
+    axis) — no host round-trip."""
 
     def prep(a):
         a = a.astype(jnp.float32)
@@ -580,7 +572,7 @@ def _tp_prepare_program(rem: int, pad_d: int, sharding):
             a = jnp.pad(a, ((0, rem), (0, pad_d)))
         return a
 
-    return jax.jit(prep, out_shardings=row_major_format(sharding, 2))
+    return jax.jit(prep, out_shardings=sharding)
 
 
 def _health_tag(loss_func: LossFunc, tag: Optional[str]) -> str:
@@ -761,23 +753,17 @@ class SGD:
             spec0 = data_pspec(mesh)
             rem = (-n) % data_shard_count(mesh)
             x_sharding = NamedSharding(mesh, P(spec0, MODEL_AXIS))
-            from flink_ml_tpu.parallel.collective import row_major_format
-            x_format = row_major_format(x_sharding, 2)
             if isinstance(features, jax.Array):
                 # device-resident input: cast/pad/reshard on device — the
-                # same residency contract as the DP branch; layout pinned
-                # row-major like every other producer (a bare
-                # NamedSharding put preserves a compiler-chosen
-                # column-major layout and the fit re-pays the relayout)
+                # same residency contract as the DP branch
                 if pad or rem or features.dtype != jnp.float32:
                     features = _tp_prepare_program(
                         rem, pad, x_sharding)(features)
-                xs = jax.device_put(features, x_format)
             else:
                 features = np.asarray(features, np.float32)
                 if pad or rem:
                     features = np.pad(features, ((0, rem), (0, pad)))
-                xs = jax.device_put(features, x_format)
+            xs = jax.device_put(features, x_sharding)
             w_sharding = NamedSharding(mesh, P(MODEL_AXIS))
         else:
             # device-resident features/labels (device datagen or a previous
@@ -799,33 +785,27 @@ class SGD:
         # 1/N under the sharded update) — both for the mapped
         # round/segment and so that checkpoint restore re-places leaves
         # onto the right shardings (a sharded-adam resume puts each
-        # moment slice back on its owning replica). A closure, not a
-        # tuple: the compiled programs DONATE the carry, so the pallas
-        # fallback retry must rebuild it rather than re-pass consumed
-        # buffers. The opt tuple rides at the END of the carry so a
-        # method="sgd" checkpoint keeps the stateless-era leaf order.
-        def make_init():
-            opt_sharding = (NamedSharding(mesh, P(spec0)) if sharded
-                            else w_sharding)
-            opt = tuple(
-                jax.device_put(jnp.zeros(init_coeffs.shape[0], dtype),
-                               opt_sharding)
-                for _ in range(_OPT_VECTORS[self.params.method]))
-            if self.params.method == "adam":
-                opt = opt + (jax.device_put(jnp.asarray(0.0, dtype),
-                                            NamedSharding(mesh, P())),)
-            return (
-                jax.device_put(jnp.asarray(init_coeffs, dtype),
-                               w_sharding),
-                jax.device_put(jnp.zeros((p,), jnp.int32),
-                               NamedSharding(mesh, P(spec0))),
-                jax.device_put(jnp.asarray(jnp.inf, dtype),
-                               NamedSharding(mesh, P())),
-                opt,
-            )
-
+        # moment slice back on its owning replica). The opt tuple rides
+        # at the END of the carry so a method="sgd" checkpoint keeps the
+        # stateless-era leaf order.
         _check_method(self.params)
-        init = make_init()
+        opt_sharding = (NamedSharding(mesh, P(spec0)) if sharded
+                        else w_sharding)
+        opt = tuple(
+            jax.device_put(jnp.zeros(init_coeffs.shape[0], dtype),
+                           opt_sharding)
+            for _ in range(_OPT_VECTORS[self.params.method]))
+        if self.params.method == "adam":
+            opt = opt + (jax.device_put(jnp.asarray(0.0, dtype),
+                                        NamedSharding(mesh, P())),)
+        init = (
+            jax.device_put(jnp.asarray(init_coeffs, dtype), w_sharding),
+            jax.device_put(jnp.zeros((p,), jnp.int32),
+                           NamedSharding(mesh, P(spec0))),
+            jax.device_put(jnp.asarray(jnp.inf, dtype),
+                           NamedSharding(mesh, P())),
+            opt,
+        )
         w0 = init[0]
         # per-replica update-state accounting (benchmark provenance):
         # measured from the carry's real buffers — SGD's coefficients
@@ -854,46 +834,24 @@ class SGD:
             if (not seg_k and self.params.global_batch_size % p == 0
                     and 0 < self.params.max_iter <= _UNROLL_MAX_ROUNDS):
                 from flink_ml_tpu.ops.pallas_kernels import (
-                    is_pallas_failure, pallas_supported)
-                global _pallas_sgd_broken
-                use_kernel = (pallas_supported() and not tp
-                              and not _pallas_sgd_broken)
-                try:
-                    prog = _build_sgd_unrolled_program(
-                        type(loss_func), mesh, self.params,
-                        use_kernel=use_kernel, health=health_on,
-                        sharded=sharded)
-                    # materialize INSIDE the try: async dispatch surfaces
-                    # kernel-execution failures only here
-                    res = prog(xs, ys, ws, init[0], init[1], init[3])
-                    coeffs, _, _, mean_loss, epoch, _ = res[:6]
-                    hist, fin = (res[6:] if health_on else (None, True))
-                    self.last_execution_path = (
-                        "pallas-unrolled" if use_kernel else "xla-unrolled")
-                    out = np.asarray(coeffs, np.float64)[:d]
-                    _finish_fit_health(algo, health_on, hist, fin, epoch,
-                                       mean_loss, out)
-                    return out, float(mean_loss)
-                except Exception as e:
-                    if not use_kernel or not is_pallas_failure(e):
-                        raise
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "pallas SGD kernel failed; using the XLA rounds "
-                        "for the rest of this process", exc_info=True)
-                    _pallas_sgd_broken = True
-                    prog = _build_sgd_unrolled_program(
-                        type(loss_func), mesh, self.params,
-                        use_kernel=False, health=health_on,
-                        sharded=sharded)
-                    # the failed attempt may have consumed the donated
-                    # carry (the programs donate it) — rebuild
-                    init = make_init()
-                    res = prog(xs, ys, ws, init[0], init[1], init[3])
-                    coeffs, _, _, mean_loss, epoch, _ = res[:6]
-                    hist, fin = (res[6:] if health_on else (None, True))
-                self.last_execution_path = "xla-unrolled"
+                    pallas_supported, sgd_round_tile)
+                # the kernel is chosen by the backend and the shape gate,
+                # nothing else; a Mosaic failure propagates
+                local_n = xs.shape[0] // p
+                use_kernel = (
+                    pallas_supported() and not tp
+                    and sgd_round_tile(
+                        min(self.params.global_batch_size // p, local_n),
+                        local_n, xs.shape[1]) > 0)
+                prog = _build_sgd_unrolled_program(
+                    type(loss_func), mesh, self.params,
+                    use_kernel=use_kernel, health=health_on,
+                    sharded=sharded)
+                res = prog(xs, ys, ws, init[0], init[1], init[3])
+                coeffs, _, _, mean_loss, epoch, _ = res[:6]
+                hist, fin = (res[6:] if health_on else (None, True))
+                self.last_execution_path = (
+                    "pallas-unrolled" if use_kernel else "xla-unrolled")
                 out = np.asarray(coeffs, np.float64)[:d]
                 _finish_fit_health(algo, health_on, hist, fin, epoch,
                                    mean_loss, out)

@@ -69,48 +69,11 @@ def assign_nearest(x, centroids, interpret: bool = False):
 
 
 def pallas_supported() -> bool:
-    """True when the default backend can run compiled pallas kernels.
-    FLINK_ML_TPU_DISABLE_PALLAS=1 is the central kill-switch — set by an
-    operator, or by scripts/tpu_kernel_check.py's caller when the
-    on-chip parity check fails (wrong RESULTS can't be caught by the
-    exception-driven fallbacks)."""
-    import os
-
-    if os.environ.get("FLINK_ML_TPU_DISABLE_PALLAS") == "1":
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # dead accelerator plugin raises here (mesh.py
-        return False      # _all_devices) — no backend, no pallas
-
-
-def is_pallas_failure(e: Exception) -> bool:
-    """Heuristic: does this exception come from the pallas/Mosaic stack
-    (lowering, compile, or kernel execution — including a Mosaic VMEM
-    exhaustion) rather than from the surrounding program (e.g. an HBM
-    RESOURCE_EXHAUSTED on a too-large dataset, whose message carries no
-    Mosaic/vmem marker)? Drives the try-kernel-then-XLA fallbacks."""
-    text = f"{type(e).__name__}: {e}"
-    if "RESOURCE_EXHAUSTED" in text and "vmem" not in text.lower():
-        # an HBM OOM can mention the pallas op in its allocation
-        # breakdown without the kernel being at fault — only a VMEM
-        # exhaustion is the kernel's own
-        return False
-    return any(s in text for s in ("Mosaic", "mosaic", "pallas", "Pallas",
-                                   "memory space vmem"))
-
-
-def is_surrounding_failure(e: Exception) -> bool:
-    """Positive identification of a failure in the SURROUNDING program —
-    today an HBM RESOURCE_EXHAUSTED (without a VMEM marker) from placing
-    the inputs. Predict paths whose ``try`` wraps only the kernel call
-    use this as the re-raise test: there, an unrecognized error is far
-    more likely a kernel failure than a program one, so the default is
-    fall-back-and-flag (the inverse of the fit paths, whose ``try``
-    spans the whole program and which re-raise on
-    ``not is_pallas_failure``)."""
-    text = f"{type(e).__name__}: {e}"
-    return "RESOURCE_EXHAUSTED" in text and "vmem" not in text.lower()
+    """True when the default backend compiles pallas kernels (a TPU).
+    Together with each kernel's shape gate this is the whole selection:
+    a kernel that is chosen and then fails to lower, compile or run
+    raises — no call site retries on the XLA twin."""
+    return jax.default_backend() == "tpu"
 
 
 # -- fused Lloyd round: assign + accumulate (KMeans fit) ---------------------
@@ -203,15 +166,21 @@ def lloyd_partial_sums(x, v, centroids, interpret: bool = False):
 # -- fused SGD batch terms (one pass over the minibatch window) --------------
 
 
-def _sgd_terms_kernel(terms, tile_n, scalars_ref, x_ref, y_ref, w_ref,
-                      c_ref, out_ref):
+def _sgd_terms_kernel(terms, tile_n, scalars_ref, x_ref, yw_ref, c_ref,
+                      out_ref):
     """One row tile of the minibatch: forward dots, loss terms and the
     gradient accumulate in VMEM — the batch window is read ONCE (the XLA
     round reads it for the forward matvec and again for the gradient,
     after a dynamic-slice copy). The window's start arrives as a
     prefetched scalar (block units), so ONE compiled kernel serves every
     round of the static schedule; ``scalars_ref[1]`` carries the
-    clip-round cutoff (rows before it weigh 0)."""
+    clip-round cutoff (rows before it weigh 0).
+
+    Per-row quantities (labels, weights, dots, multipliers) live as
+    lane-dense ``(1, tile)`` rows: Mosaic has no rank-1 blocks at tile
+    sizes that are not multiples of 128 (the north-star tile is 1000),
+    and a ``(tile, 1)`` column operand pads 128x in HBM. Both matvecs
+    are then plain MXU forms: ``c @ x.T`` and ``mult @ x``."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -219,17 +188,18 @@ def _sgd_terms_kernel(terms, tile_n, scalars_ref, x_ref, y_ref, w_ref,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     x = x_ref[:]                       # (tile_n, d)
-    y = y_ref[:]
-    w = w_ref[:]
-    c = c_ref[:]                       # (d,)
-    row = jnp.reshape(
-        jax.lax.broadcasted_iota(jnp.int32, (tile_n, 1), 0), (tile_n,))
-    w = jnp.where(i * tile_n + row >= scalars_ref[1], w, 0.0)
-    dots = jnp.dot(x, c, preferred_element_type=jnp.float32)
+    yw = yw_ref[0]                     # (2, tile_n): labels | weights
+    y, w = yw[0:1, :], yw[1:2, :]
+    c = c_ref[:]                       # (1, d)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, tile_n), 1)
+    w = jnp.where(i * tile_n + col >= scalars_ref[1], w, 0.0)
+    dots = jax.lax.dot_general(c, x, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
     loss_sum, mult = terms(dots, y, w)
-    grad = jnp.dot(mult, x, preferred_element_type=jnp.float32)
+    grad = jnp.dot(mult, x, preferred_element_type=jnp.float32)  # (1, d)
     out_ref[:] += jnp.concatenate(
-        [grad, jnp.stack([jnp.sum(w), loss_sum])])
+        [grad, jnp.sum(w, axis=1, keepdims=True),
+         jnp.reshape(loss_sum, (1, 1))], axis=1)
 
 
 #: VMEM budget for the SGD kernel working set: double-buffered (tile, d)
@@ -242,9 +212,8 @@ def sgd_round_tile(lb: int, local_n: int, d: int) -> int:
     local batch and the shard length (the alignment that makes every
     static-schedule window start a whole number of blocks), whose
     working set fits the VMEM budget for feature width ``d``. 0 when no
-    such tile exists (callers fall back to the XLA round) — a shape gate,
-    so predictable wide-feature failures never burn the process-wide
-    broken flag."""
+    such tile exists (callers run the XLA round) — the shape gate of the
+    SGD kernel."""
     import math
 
     g = math.gcd(lb, local_n)
@@ -266,25 +235,28 @@ def _sgd_terms_padded(xl, yl, wl, coeffs, scalars, loss_name, lb, tile,
     from flink_ml_tpu.ops.losses import LossFunc
 
     terms = LossFunc.by_name(loss_name).terms
-    d = xl.shape[1]
+    n, d = xl.shape
+    # labels and weights ride ONE lane-dense (blocks, 2, tile) operand —
+    # a block is then (1, 2, tile) with its last two dims whole
+    yw = jnp.stack([yl.reshape(n // tile, tile),
+                    wl.reshape(n // tile, tile)], axis=1)
     kernel = functools.partial(_sgd_terms_kernel, terms, tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(lb // tile,),
         in_specs=[
             pl.BlockSpec((tile, d), lambda i, s: (s[0] + i, 0)),
-            pl.BlockSpec((tile,), lambda i, s: (s[0] + i,)),
-            pl.BlockSpec((tile,), lambda i, s: (s[0] + i,)),
-            pl.BlockSpec((d,), lambda i, s: (0,)),
+            pl.BlockSpec((1, 2, tile), lambda i, s: (s[0] + i, 0, 0)),
+            pl.BlockSpec((1, d), lambda i, s: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((d + 2,), lambda i, s: (0,)),
+        out_specs=pl.BlockSpec((1, d + 2), lambda i, s: (0, 0)),
     )
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((d + 2,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, d + 2), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(scalars, xl, yl, wl, coeffs)
+    )(scalars, xl, yw, coeffs[None, :])[0]
 
 
 def sgd_batch_terms(xl, yl, wl, coeffs, start, clip, lb: int, tile: int,
@@ -293,12 +265,12 @@ def sgd_batch_terms(xl, yl, wl, coeffs, start, clip, lb: int, tile: int,
     contiguous batch window [start, start+lb) of this shard — fused
     forward+terms+gradient, one pass over the window.
 
-    ``start`` must be a whole number of ``tile`` blocks (the
-    static-schedule gate ``sgd_round_tile`` guarantees it when lb and
-    local_n share the tile); rows whose window-relative index is below
-    ``clip`` weigh 0 (the clip-at-end round). ``start``/``clip`` may be
-    traced scalars — they ride the scalar-prefetch slot, so every round
-    reuses one compiled kernel.
+    ``start`` must be a whole number of ``tile`` blocks and ``tile`` must
+    divide the shard length (the static-schedule gate ``sgd_round_tile``
+    guarantees both when lb and local_n share the tile); rows whose
+    window-relative index is below ``clip`` weigh 0 (the clip-at-end
+    round). ``start``/``clip`` may be traced scalars — they ride the
+    scalar-prefetch slot, so every round reuses one compiled kernel.
     """
     scalars = jnp.stack([jnp.asarray(start, jnp.int32) // tile,
                          jnp.asarray(clip, jnp.int32)])
@@ -411,9 +383,8 @@ def _knn_step_vmem_bytes(d: int, k: int) -> int:
     """Upper estimate of one grid step's VMEM working set (bytes): the
     train/test tiles plus six (KNN_TILE_N, k + KNN_TILE_T)-ish blocks —
     d2, cross, tile_idx, comb_d, comb_i, and the fori_loop's masked
-    comb_d copy. Deliberately generous: admitting a shape whose real
-    footprint overflows VMEM trips _pallas_knn_broken and degrades EVERY
-    later predict in the process to the XLA path."""
+    comb_d copy. Deliberately generous: a shape this gate admits must
+    compile, because a Mosaic VMEM overflow fails the predict."""
     return 4 * (KNN_TILE_T * d + KNN_TILE_N * d
                 + 6 * KNN_TILE_N * (k + KNN_TILE_T))
 
@@ -451,6 +422,7 @@ def _knn_kernel(k: int, x_ref, t_ref, tsq_ref, idx_ref, bd_ref):
     comb_d = jnp.concatenate([bd_ref[:], d2], axis=1)
     comb_i = jnp.concatenate([idx_ref[:], tile_idx], axis=1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (tile_n, k + tile_t), 1)
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (tile_n, k), 1)
 
     def pick(p, carry):
         comb_d, bd, bi = carry
@@ -458,8 +430,10 @@ def _knn_kernel(k: int, x_ref, t_ref, tsq_ref, idx_ref, bd_ref):
         taken = cols == jnp.argmin(comb_d, axis=1).astype(
             jnp.int32)[:, None]
         chosen = jnp.sum(jnp.where(taken, comb_i, 0), axis=1)
-        bd = jax.lax.dynamic_update_slice(bd, m[:, None], (0, p))
-        bi = jax.lax.dynamic_update_slice(bi, chosen[:, None], (0, p))
+        # column p of the carries, written as a select: Mosaic lowers no
+        # dynamic_update_slice at a traced lane offset
+        bd = jnp.where(kcols == p, m[:, None], bd)
+        bi = jnp.where(kcols == p, chosen[:, None], bi)
         return jnp.where(taken, jnp.inf, comb_d), bd, bi
 
     _, bd, bi = jax.lax.fori_loop(

@@ -350,30 +350,6 @@ class _HostOp:
         return False
 
 
-def row_major_format(sharding, ndim: int):
-    """The sharding pinned to a ROW-MAJOR device layout. Every producer of
-    batch-dim-sharded device arrays (datagen, the prepare programs,
-    device_put placements) emits this layout so consumers never pay a
-    relayout: the r3 LR trace showed a 14.4 ms full-input copy
-    (f32[10M,100]{1,0} copy of a {0,1} parameter) purely because the
-    datagen program's compiler-chosen output layout was column-major
-    while the fit wanted row-major. Random generation has no layout
-    preference, so pinning the producer is free.
-
-    API skew: the pair is spelled ``Format(Layout(major_to_minor),
-    sharding)`` on new JAX and ``Layout(DeviceLocalLayout(major_to_minor),
-    sharding)`` on the 0.4.x line — same object either way."""
-    try:
-        from jax.experimental.layout import Format, Layout
-
-        return Format(Layout(major_to_minor=tuple(range(ndim))), sharding)
-    except ImportError:
-        from jax.experimental.layout import DeviceLocalLayout, Layout
-
-        return Layout(DeviceLocalLayout(major_to_minor=tuple(range(ndim))),
-                      sharding)
-
-
 def _dim0_layout(mesh: Mesh, axis_name, ndim: int):
     """The shared dim-0-sharded placement recipe: (shard count, sharding)
     for an ndim-rank array row-sharded over the given data axes."""
@@ -416,8 +392,7 @@ def replicate(mesh: Mesh, tree):
 @functools.lru_cache(maxsize=128)
 def _prepare_program(rem: int, dtype_name: str, sharding, ndim: int):
     """Compiled cast+pad+reshard for device-resident inputs — keyed so
-    repeated fits at the same shapes reuse one program. Output layout
-    pinned row-major (see row_major_format)."""
+    repeated fits at the same shapes reuse one program."""
     dtype = jnp.dtype(dtype_name)
 
     def prep(a):
@@ -426,7 +401,7 @@ def _prepare_program(rem: int, dtype_name: str, sharding, ndim: int):
             a = jnp.pad(a, ((0, rem),) + ((0, 0),) * (a.ndim - 1))
         return a
 
-    return jax.jit(prep, out_shardings=row_major_format(sharding, ndim))
+    return jax.jit(prep, out_shardings=sharding)
 
 
 def ensure_on_mesh(mesh: Mesh, array, axis_name=DATA_AXIS, dtype=None):
@@ -448,11 +423,8 @@ def ensure_on_mesh(mesh: Mesh, array, axis_name=DATA_AXIS, dtype=None):
     with _HostOp("ensure_on_mesh", mesh, array.nbytes):
         if rem == 0 and array.dtype == want:
             # device_put with a matching placement is a no-op; a mismatched
-            # one is a device-to-device reshard/relayout — still no PCIe leg,
-            # and normalizing the layout HERE (once) spares every consumer
-            # program its own full-input relayout copy (r3 trace: 14.4 ms)
-            return jax.device_put(
-                array, row_major_format(sharding, array.ndim)), n
+            # one is a device-to-device reshard — still no PCIe leg
+            return jax.device_put(array, sharding), n
         return _prepare_program(rem, want.name, sharding,
                                 array.ndim)(array), n
 

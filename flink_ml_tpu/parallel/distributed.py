@@ -266,6 +266,10 @@ def launch(argv: Sequence[str], num_processes: int, local_devices: int = 1,
            child_grace_s: float = 30.0) -> List[dict]:
     """Run ``argv`` as ``num_processes`` coordinated CPU processes.
 
+    CPU-only CI harness: a chip belongs to one process at a time, so no
+    entry point that needs the chip goes through here — four chips are
+    ONE process and a four-device mesh.
+
     Each child gets the env mapping (coordinator on a free localhost
     port, its process id, the simulated local device count),
     ``JAX_PLATFORMS=cpu`` and the host-platform XLA flag — the child

@@ -557,7 +557,7 @@ def run_elastic(argv: Sequence[str], num_processes: int,
         signaled = [r for r in failed if r["returncode"] < 0]
         if not signaled:
             # the fleet is intact — this is a fit failure, not a lost
-            # worker: retry at the same N under the ordinary taxonomy
+            # worker: retry at the same N under the ordinary failure classes
             raise RuntimeError(
                 f"elastic attempt {attempt_idx}: {len(failed)} of {n} "
                 f"processes failed (rc={failed[0]['returncode']}) "

@@ -1,20 +1,13 @@
-"""Version-portable ``shard_map`` — THE seam every SPMD program builds on.
+"""``shard_map`` — THE seam every SPMD program builds on.
 
-``jax.shard_map`` moved twice across the JAX line this framework spans:
-it lives at ``jax.experimental.shard_map.shard_map`` (replication check
-spelled ``check_rep``) through 0.4.x/0.5.x and graduates to the
-top-level ``jax.shard_map`` (the check renamed ``check_vma``) in 0.6+.
 Every fit program and test in this repo goes through :func:`shard_map`
-below so the whole multi-device tier runs on either line — on the
-pre-graduation line the 90 shard_map paths used to fail collection-deep
-with ``AttributeError: module 'jax' has no attribute 'shard_map'``; this
-module is what un-froze them.
-
-This is also the mesh-telemetry seam (docs/observability.md
-"Distributed telemetry"): wrapping a program over a mesh is the moment
-the runtime provably commits to a topology, so when tracing is armed the
-mesh snapshot (device count, axis layout, platform) is recorded here —
-once per mesh — as root-span attributes, ``ml.mesh`` gauges and a
+below rather than ``jax.shard_map`` directly: jaxlint JL108 keeps raw
+collectives and direct ``shard_map`` calls inside ``parallel/``, and
+this is the mesh-telemetry seam (docs/observability.md "Distributed
+telemetry"): wrapping a program over a mesh is the moment the runtime
+provably commits to a topology, so when tracing is armed the mesh
+snapshot (device count, axis layout, platform) is recorded here — once
+per mesh — as root-span attributes, ``ml.mesh`` gauges and a
 ``mesh.json`` trace artifact (observability/meshstats.py).
 """
 
@@ -27,29 +20,16 @@ __all__ = ["shard_map", "axis_size"]
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None,
               check_vma: bool = True):
-    """``jax.shard_map`` (0.6+) or ``jax.experimental.shard_map.shard_map``
-    (0.4/0.5, where ``check_vma`` is spelled ``check_rep``) — same
-    semantics either way. All arguments after ``f`` are keyword-style to
-    match the graduated API."""
+    """``jax.shard_map`` plus the mesh-telemetry record. All arguments
+    after ``f`` are keyword-style."""
     _record_mesh(mesh)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, from inside a traced body.
-
-    ``jax.lax.axis_size`` where it exists (0.6+); on older lines
-    ``psum(1, axis)`` constant-folds to the same Python int at trace
-    time — no traced value escapes either way."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a named mesh axis, from inside a traced body."""
+    return jax.lax.axis_size(axis_name)
 
 
 def _record_mesh(mesh) -> None:

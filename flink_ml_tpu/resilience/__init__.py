@@ -1,4 +1,4 @@
-"""Fault-tolerant execution layer: failure taxonomy + retry policy
+"""Fault-tolerant execution layer: failure classes + retry policy
 (:mod:`policy`), the supervised-fit driver (:mod:`supervisor`) and the
 deterministic fault-injection harness (:mod:`faults`).
 

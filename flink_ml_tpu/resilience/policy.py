@@ -1,4 +1,4 @@
-"""Failure taxonomy and retry/backoff policy.
+"""Failure classes and retry/backoff policy.
 
 Classification mirrors the benchmark sweep's exit-code precedent
 (scripts/run_benchmark_sweep.py): exit 2 = transient, RETRYABLE (wrappers
@@ -163,7 +163,7 @@ class RetryPolicy:
 
     ``classify`` precedence: the policy's explicit ``terminal`` types,
     then its explicit ``retryable`` types, then the marker bases and the
-    default taxonomy above. Unrecognized Exception subclasses default to
+    default classes above. Unrecognized Exception subclasses default to
     RETRYABLE — the sweep's precedent (an unexplained failure is recorded
     and retried, never silently promoted to a verdict).
     """
@@ -177,7 +177,7 @@ class RetryPolicy:
     max_backoff_s: float = 30.0
     #: total wall budget across all restarts (None = unbounded)
     deadline_s: Optional[float] = None
-    #: extra exception types, consulted before the default taxonomy
+    #: extra exception types, consulted before the default classes
     retryable: Tuple[Type[BaseException], ...] = ()
     terminal: Tuple[Type[BaseException], ...] = ()
 
@@ -194,7 +194,7 @@ class RetryPolicy:
             return TERMINAL
         if isinstance(exc, self.retryable):
             return RETRYABLE
-        # the marker beats the taxonomy: WorkerTimeout et al. stay
+        # the marker beats the class table: WorkerTimeout et al. stay
         # retryable no matter what else they subclass
         if isinstance(exc, RetryableFailure):
             return RETRYABLE

@@ -530,7 +530,7 @@ class OpsController:
                 self._group.histogram("retrainMs", labels={
                     "model": self.model}).observe(
                     (time.monotonic() - t0) * 1000.0)
-        except Exception as e:  # noqa: BLE001 — terminal taxonomy or
+        except Exception as e:  # noqa: BLE001 — terminal failure class or
             # an exhausted budget: the cycle fails, the active version
             # keeps serving
             self._finish_cycle("failed",

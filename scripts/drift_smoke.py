@@ -1,6 +1,10 @@
 """CI smoke: drift detection end-to-end (docs/observability.md "Drift
 detection").
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 Flow: train an LR model with the FTRL online path under a trace dir
 (the traced-fit seam captures the training-time drift baseline),
 publish it WITH the baseline into a model-registry watch dir, build the

@@ -3,6 +3,10 @@ flight-recorder bundle that ``mltrace incident --check`` exits 4 on —
 and a clean run must exit 0 (docs/observability.md "Causal tracing,
 critical path & incidents").
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 Flow, all in one process:
 
 1. arm a trace dir, serve a small closed-loop run through the

@@ -1,6 +1,10 @@
 """CI smoke: the self-healing ops loop end to end, under seeded chaos
 (docs/ops.md).
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 One scenario run proves the closed loop twice:
 
 1. **drift → retrain → canary → swap**: traffic mean-shifts away from

@@ -1,6 +1,10 @@
 """CI smoke: the continuous evaluation plane end to end
 (docs/observability.md "Continuous evaluation").
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 One scenario proves the quality plane catches what drift cannot:
 
 1. **clean labeled serving**: FTRL-train v1 (the traced fit captures

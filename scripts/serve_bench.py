@@ -1,6 +1,10 @@
 """Serving benchmark: micro-batched vs per-request throughput, SLO-gated
 (docs/serving.md).
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 Flow: train a logistic-regression model with the FTRL online path
 (OnlineLogisticRegression — the train-while-serve producer), publish it
 into a model-registry watch dir (v2 checkpoint manifests), build the
@@ -469,6 +473,9 @@ def main(argv=None) -> int:
                              "causal-tracing ring must stay cheap")
     args = parser.parse_args(argv)
 
+    from flink_ml_tpu.utils import compile_cache
+
+    compile_cache.configure()
     if args.mesh_cell:
         return run_mesh_cell(args)
 
@@ -696,9 +703,7 @@ def main(argv=None) -> int:
         "value": batched["throughput_rps"],
         "unit": "requests/s",
         "vs_per_request": round(ratio, 2),
-        "platform": ("cpu-fallback"
-                     if jax.default_backend() == "cpu"
-                     else jax.default_backend()),
+        "platform": jax.default_backend(),
         "device_count": jax.device_count(),
         # dispatch provenance: the measured runtime above runs the
         # pipelined dispatcher but no mesh (the mesh cells below are

@@ -1,6 +1,10 @@
 """CI smoke: live serving telemetry end-to-end (docs/observability.md
 "Live telemetry & SLOs").
 
+CPU-only CI harness: pins ``JAX_PLATFORMS=cpu`` (and may start child
+processes) — never a chip check. A chip belongs to one process;
+``python chip_smoke.py`` is the check that runs there.
+
 Flow: arm the embedded endpoint (``FLINK_ML_TPU_METRICS_PORT=0`` — an
 ephemeral port read back from the server) and a trace dir, build a
 logistic-regression servable, drive N requests through the serving
@@ -69,6 +73,9 @@ def fetch(port: int, route: str) -> bytes:
 
 
 def main() -> int:
+    from flink_ml_tpu.utils import compile_cache
+
+    compile_cache.configure()
     # a small traced fit first: the stage seam must arm the endpoint
     # and the scraped /metrics must carry fit telemetry beside serving
     from flink_ml_tpu.common.table import Table

@@ -1,16 +1,23 @@
 """On-chip pallas kernel parity check.
 
 The interpret-mode tests prove the kernels' math on CPU; this script
-proves the MOSAIC LOWERING on the real chip before unattended benchmark
-runs trust it: every compiled kernel is run at small scale against its
-numpy oracle. Exit 0 = all kernels agree, 2 = a kernel produced wrong
-results (callers should export FLINK_ML_TPU_DISABLE_PALLAS=1 for
-subsequent runs), 3 = a kernel failed to compile/run (the in-tree
-exception fallbacks already cover that case).
+proves the MOSAIC LOWERING on the real chip: every kernel in
+``ops/pallas_kernels.py`` is compiled — never interpreted — and run at
+small scale against a numpy oracle, then at the shapes the benchmark
+fits use against its XLA twin on the same chip. Each check prints its
+observed maximum error. Exit 0 = all kernels agree, 2 = a kernel
+produced wrong results, 3 = a kernel failed to lower, compile or run.
+Either failure is terminal for the run that selected the kernel: no call
+site retries on the XLA twin, so the fix is in the kernel or in its shape
+gate.
 
-Run on the TPU backend: ``python scripts/tpu_kernel_check.py``.
+Run on the TPU backend: ``python scripts/tpu_kernel_check.py``
+(``python chip_smoke.py`` calls ``main()`` in-process as its kernels
+phase). ``--shrink N`` divides the benchmark-scale shapes so CI can run
+the whole script in interpreter mode; ``--small-only`` skips that phase.
 """
 
+import argparse
 import os
 import sys
 
@@ -19,7 +26,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np  # noqa: E402
 
 
-def main() -> int:
+def main(shrink: int = 1, small_only: bool = False) -> int:
     import jax
 
     if jax.default_backend() == "cpu":
@@ -37,9 +44,14 @@ def main() -> int:
         except Exception as e:  # noqa: BLE001 — record, keep checking
             errors.append(f"{name}: {type(e).__name__}: {e}")
             return
+        oracle = np.asarray(oracle)
+        err = (float(np.max(np.abs(got - oracle)))
+               if got.shape == oracle.shape and got.size else float("nan"))
+        scale = float(np.max(np.abs(oracle))) if oracle.size else 0.0
         try:
             np.testing.assert_allclose(got, oracle, rtol=rtol, atol=atol)
-            print(f"{name}: OK", flush=True)
+            print(f"{name}: OK (max abs err {err:.3g}, oracle max "
+                  f"{scale:.3g})", flush=True)
         except AssertionError as e:
             failures.append(f"{name}: {e}")
 
@@ -65,11 +77,14 @@ def main() -> int:
         idx = np.asarray(pk.knn_topk_indices(x, train, 3))  # (n, 3)
         return dt[np.arange(len(x))[:, None], idx]
 
-    # full top-k machinery (mask + dynamic_update_slice passes), not just
-    # column 0: distances at the chosen k indices must match the k
-    # smallest distances in order
+    # full top-k machinery (mask + select passes), not just column 0:
+    # distances at the chosen k indices must match the k smallest
+    # distances in order. Tolerance from the chip (PR 21): the kernel's
+    # x·tᵀ runs at the TPU's default matmul precision, so among
+    # near-equidistant rows it may rank a neighbour whose exact distance
+    # is up to 0.74 % (0.062 absolute) larger than the oracle's pick.
     check("knn_topk_indices(dists@chosen)", knn_dists,
-          np.sort(dt, axis=1)[:, :3], rtol=1e-3, atol=1e-2)
+          np.sort(dt, axis=1)[:, :3], rtol=2e-2, atol=0.1)
 
     # WELL-SEPARATED clusters so assignment ties are implausible, and
     # generous tolerances: the check hunts wrong lowerings (wrong
@@ -102,21 +117,30 @@ def main() -> int:
                   x, yl, wl, coeffs, start, clip, lb, tile, ln),
               want, rtol=5e-2, atol=0.5)
 
-    # -- benchmark-scale phase (VERDICT r4 next-#2 / weak-#5): kernel
-    # path vs the XLA path at north-star shapes, both ON CHIP.  The
-    # small-shape phase above proves the lowering against numpy; this
-    # phase bounds kernel-vs-XLA drift at the scales the sweep actually
-    # claims (SGD 100k-row batch window at d=100, Lloyd partials at
-    # 1M x 100 k=10, KNN over a multi-tile 200k train set).  Skipped via
-    # FLINK_ML_TPU_KERNEL_CHECK_SMALL_ONLY=1 if a window is short.
-    if not os.environ.get("FLINK_ML_TPU_KERNEL_CHECK_SMALL_ONLY"):
-        import jax.numpy as jnp
+    # segment-reduce: out-of-range ids (negative padding included) must
+    # drop like jax.ops.segment_sum; a ragged final tile; one and two
+    # value columns
+    ns, us = 3 * pk.SEGREDUCE_TILE_N + 77, 37
+    sv = rng.normal(size=(ns, 2)).astype(np.float32)
+    sid = rng.integers(-2, us + 2, ns).astype(np.int32)
+    keep = (sid >= 0) & (sid < us)
+    seg_want = np.zeros((us, 2), np.float64)
+    np.add.at(seg_want, sid[keep], sv[keep].astype(np.float64))
+    check("segment_reduce_sum(2 cols)",
+          lambda: pk.segment_reduce_sum(sv, sid, us), seg_want,
+          rtol=2e-2, atol=0.2)
+    check("segment_reduce_sum(1 col)",
+          lambda: pk.segment_reduce_sum(sv[:, 0], sid, us), seg_want[:, 0],
+          rtol=2e-2, atol=0.2)
 
-        # scale shrink factor (power of two) — lets CI exercise this whole
-        # phase in interpreter mode on tiny shapes, so a chip window is
-        # never burned by a plain bug here
-        shrink = int(os.environ.get(
-            "FLINK_ML_TPU_KERNEL_CHECK_SHRINK", "1"))
+    # -- benchmark-scale phase: kernel path vs the XLA path at the shapes
+    # the fits use, both ON CHIP. The small-shape phase above proves the
+    # lowering against numpy; this phase bounds kernel-vs-XLA drift at
+    # scale (SGD 100k-row batch window at d=100, Lloyd partials at
+    # 1M x 100 k=10, KNN over a multi-tile 200k train set, the FTRL
+    # sparse program's two segment-reduces).
+    if not small_only:
+        import jax.numpy as jnp
 
         # Lloyd partials, north-star KMeans shape (1M x 100, k=10)
         nL, dL, kL = (1 << 20) // shrink, 100, 10
@@ -157,6 +181,24 @@ def main() -> int:
                   lambda: lloyd_got["v"][:, -1], want[:, -1],
                   rtol=0, atol=0)
 
+        # nearest-centroid assignment at the same shape: the exact
+        # distance at the kernel's chosen centroid against the exact
+        # distance at the XLA twin's choice (tie-tolerant, as above)
+        @jax.jit
+        def assign_xla(x, c):
+            d2 = (jnp.sum(x * x, axis=1, keepdims=True)
+                  - 2.0 * (x @ c.T) + jnp.sum(c * c, axis=1)[None, :])
+            return jnp.argmin(d2, axis=1)
+
+        @jax.jit
+        def dist_at(x, c, idx):
+            return jnp.sum(jnp.square(x - c[idx]), axis=1)
+
+        best = np.asarray(dist_at(xd, cd, assign_xla(xd, cd)))
+        check("assign_nearest@1Mx100(dist@chosen)",
+              lambda: dist_at(xd, cd, pk.assign_nearest(xd, cd)), best,
+              rtol=1e-3, atol=1e-2)
+
         # SGD batch terms, north-star LR shape (window 100k of 1M, d=100);
         # the shrunk window stays a multiple of 8 so a valid tile exists
         nS, dS = (1 << 20) // shrink, 100
@@ -165,11 +207,14 @@ def main() -> int:
         ys = (rng.random(nS) > 0.5).astype(np.float32)
         ws = np.ones(nS, np.float32)
         cfs = (rng.normal(size=dS) * 0.1).astype(np.float32)
-        tile = pk.sgd_round_tile(lbS, nS, dS)
+        # the fit's own gate picks the tile; the shard is cut to a whole
+        # number of tiles the way the static schedule guarantees
+        tile = pk.sgd_round_tile(lbS, (nS // lbS) * lbS, dS)
+        nS = (nS // lbS) * lbS
         if tile:
             loss = LossFunc.by_name("logistic")
-            xd2, yd2, wd2 = (jnp.asarray(xs), jnp.asarray(ys),
-                             jnp.asarray(ws))
+            xd2, yd2, wd2 = (jnp.asarray(xs[:nS]), jnp.asarray(ys[:nS]),
+                             jnp.asarray(ws[:nS]))
 
             @jax.jit
             def sgd_xla(x, y, w, c):
@@ -182,10 +227,10 @@ def main() -> int:
                         jax.lax.dynamic_slice_in_dim(w, lbS, lbS)), ls])])
 
             want = np.asarray(sgd_xla(xd2, yd2, wd2, jnp.asarray(cfs)))
-            check("sgd_batch_terms@100kx100",
+            check(f"sgd_batch_terms@100kx100(tile {tile})",
                   lambda: pk.sgd_batch_terms(xd2, yd2, wd2, cfs, lbS, 0,
                                              lbS, tile, "logistic"),
-                  want, rtol=1e-3, atol=np.abs(want).max() * 1e-4)
+                  want, rtol=1e-3, atol=np.abs(want).max() * 1e-3)
         else:
             errors.append("sgd_batch_terms@100kx100: no admissible tile")
 
@@ -212,7 +257,37 @@ def main() -> int:
             return ((xk[:, None, :] - tk[idx][:, :, :]) ** 2).sum(-1)
 
         check("knn_topk_indices@4kx200k", knn_scale_dists, dk_want,
-              rtol=1e-3, atol=1e-2)
+              rtol=1e-2, atol=1.0)
+
+        # segment-reduce at the FTRL sparse program's shapes
+        # (models/online.py _ftrl_sparse_program): a 100k-row batch over
+        # 8 shards pads to rows_s = 16384 > the row gate, so the shapes
+        # that DO reach the kernel are a 2048-row shard block with ~16
+        # non-zeros per row, a (nnz,) forward sum over rows and a
+        # (nnz, 2) grad|weight sum over the d = 100 coordinates
+        rowsF, dF = max(64, 2048 // shrink), 100
+        nnzF = rowsF * 16
+        rowF = np.sort(rng.integers(0, rowsF, nnzF)).astype(np.int32)
+        colF = rng.integers(0, dF, nnzF).astype(np.int32)
+        valF = rng.normal(size=nnzF).astype(np.float32)
+        gwF = np.stack([valF * rng.normal(size=nnzF).astype(np.float32),
+                        np.ones(nnzF, np.float32)], axis=1)
+        if not (pk.segment_reduce_fits(rowsF, 1)
+                and pk.segment_reduce_fits(dF, 2)):
+            errors.append("segment_reduce_sum@ftrl: gate refuses the shape")
+        else:
+            rowFd, colFd = jnp.asarray(rowF), jnp.asarray(colF)
+            valFd, gwFd = jnp.asarray(valF), jnp.asarray(gwF)
+            seg_xla = jax.jit(jax.ops.segment_sum,
+                              static_argnames="num_segments")
+            want = np.asarray(seg_xla(valFd, rowFd, num_segments=rowsF))
+            check(f"segment_reduce_sum@ftrl(rows {rowsF})",
+                  lambda: pk.segment_reduce_sum(valFd, rowFd, rowsF), want,
+                  rtol=2e-2, atol=max(0.05, np.abs(want).max() * 1e-2))
+            want = np.asarray(seg_xla(gwFd, colFd, num_segments=dF))
+            check(f"segment_reduce_sum@ftrl(coords {dF})",
+                  lambda: pk.segment_reduce_sum(gwFd, colFd, dF), want,
+                  rtol=2e-2, atol=max(0.05, np.abs(want).max() * 1e-2))
 
     for f in failures:
         print("PARITY FAILURE:", f, file=sys.stderr)
@@ -226,4 +301,11 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    parser = argparse.ArgumentParser(prog="tpu-kernel-check")
+    parser.add_argument("--shrink", type=int, default=1,
+                        help="divide the benchmark-scale shapes (a power "
+                        "of two; CI runs the script interpreted)")
+    parser.add_argument("--small-only", action="store_true",
+                        help="skip the benchmark-scale phase")
+    cli = parser.parse_args()
+    sys.exit(main(shrink=cli.shrink, small_only=cli.small_only))

@@ -51,7 +51,7 @@ def _clean_elastic_stats():
     elastic.reset_stats()
 
 
-# -- taxonomy -----------------------------------------------------------------
+# -- failure classes ----------------------------------------------------------
 
 def test_worker_lost_is_retryable():
     assert RetryPolicy().classify(WorkerLost(1, "gone")) == "retryable"

@@ -23,50 +23,30 @@ def _load_script():
     return mod
 
 
-def test_kernel_check_main_passes_in_interpret_mode(monkeypatch):
+def test_kernel_check_main_passes_in_interpret_mode(monkeypatch,
+                                                    interpreted_kernels):
     import jax
-
-    from flink_ml_tpu.ops import pallas_kernels as pk
 
     mod = _load_script()
     # the script refuses the cpu backend; CI is exactly where we want it
     # to run anyway (interpret-mode kernels are backend-agnostic)
     monkeypatch.setattr(jax, "default_backend", lambda: "interpret-ci")
-    for name in ("assign_nearest", "knn_topk_indices",
-                 "lloyd_partial_sums", "sgd_batch_terms"):
-        orig = getattr(pk, name)
-        monkeypatch.setattr(
-            pk, name,
-            lambda *a, _orig=orig, **kw: _orig(*a, **{**kw,
-                                                      "interpret": True}))
-    # shrink the scale phase ~64x so interpreter mode finishes in seconds;
-    # clear the skip knob so the scale phase really runs even when the
-    # shell exported the short-window workflow's env
-    monkeypatch.delenv("FLINK_ML_TPU_KERNEL_CHECK_SMALL_ONLY",
-                       raising=False)
-    monkeypatch.setenv("FLINK_ML_TPU_KERNEL_CHECK_SHRINK", "64")
-    assert mod.main() == 0
+    # shrink the scale phase ~64x so interpreter mode finishes in seconds
+    assert mod.main(shrink=64) == 0
 
 
-def test_kernel_check_detects_wrong_results(monkeypatch):
-    """A kernel that returns wrong numbers must drive rc 2 (the parity
-    kill-switch), not rc 0 — the fail-closed contract the sweep trusts."""
+def test_kernel_check_detects_wrong_results(monkeypatch,
+                                            interpreted_kernels):
+    """A kernel that returns wrong numbers must drive rc 2, not rc 0 —
+    the fail-closed contract chip_smoke.py's kernels phase trusts."""
     import jax
 
     from flink_ml_tpu.ops import pallas_kernels as pk
 
     mod = _load_script()
     monkeypatch.setattr(jax, "default_backend", lambda: "interpret-ci")
-    for name in ("knn_topk_indices", "lloyd_partial_sums",
-                 "sgd_batch_terms"):
-        orig = getattr(pk, name)
-        monkeypatch.setattr(
-            pk, name,
-            lambda *a, _orig=orig, **kw: _orig(*a, **{**kw,
-                                                      "interpret": True}))
     # assign_nearest lies: everything lands in cluster 0
     monkeypatch.setattr(
         pk, "assign_nearest",
         lambda x, c, interpret=False: np.zeros(len(x), np.int32))
-    monkeypatch.setenv("FLINK_ML_TPU_KERNEL_CHECK_SMALL_ONLY", "1")
-    assert mod.main() == 2
+    assert mod.main(small_only=True) == 2

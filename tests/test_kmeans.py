@@ -202,11 +202,10 @@ def test_kmeans_fit_emits_no_donation_warnings(rng):
         [str(w.message) for w in caught]
 
 
-def test_kmeans_pallas_fallback_retry_with_device_input(rng, monkeypatch):
-    """The pallas-fallback retry must rebuild a FRESH donated carry even
-    when the features column is device-resident (vectors() returns the
-    jax array and init is a device gather): the first attempt consumes
-    its carry, and the XLA retry must not re-pass deleted buffers."""
+def test_kmeans_kernel_failure_propagates(rng, monkeypatch):
+    """A fit that selected the fused Lloyd kernel and whose kernel then
+    fails must RAISE — no retry on the XLA partials: a run without the
+    kernel it chose must not look like success."""
     import jax.numpy as jnp
 
     from flink_ml_tpu.models.clustering import kmeans as km_mod
@@ -214,29 +213,15 @@ def test_kmeans_pallas_fallback_retry_with_device_input(rng, monkeypatch):
     x = rng.normal(size=(256, 4)).astype(np.float32)
     table = Table.from_columns(features=jnp.asarray(x))
 
-    calls = []
-
     def fake_partials(xl, vl, c, interpret=False):
-        calls.append(True)
         raise RuntimeError("Mosaic lowering failed (synthetic)")
 
-    monkeypatch.setattr(km_mod, "_pallas_lloyd_broken", False)
     from flink_ml_tpu.ops import pallas_kernels as pk
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    monkeypatch.setattr(pk, "lloyd_kernel_fits", lambda k, d: True)
     monkeypatch.setattr(pk, "lloyd_partial_sums", fake_partials)
     km_mod._build_lloyd_program.cache_clear()
-    est = KMeans(k=3, seed=7, max_iter=5)
     try:
-        model = est.fit(table)
+        with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
+            KMeans(k=3, seed=7, max_iter=5).fit(table)
     finally:
         km_mod._build_lloyd_program.cache_clear()
-        km_mod._pallas_lloyd_broken = False
-    assert calls  # the kernel path was really attempted
-    assert model.centroids.shape == (3, 4)
-    assert est.last_execution_path == "xla-lloyd"
-    # the fallback matches a plain XLA fit exactly
-    want = KMeans(k=3, seed=7, max_iter=5).fit(
-        Table.from_columns(features=x))
-    np.testing.assert_allclose(model.centroids, want.centroids,
-                               rtol=1e-6)

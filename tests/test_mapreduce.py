@@ -329,7 +329,6 @@ def test_ftrl_parity_sharded_vs_replicated(monkeypatch, n_dev,
 def test_ftrl_sparse_device_parity(monkeypatch, rng):
     """The device CSR path under sharding: per-coordinate grad/weight
     sums reduce-scattered, z/n slices sharded."""
-    import flink_ml_tpu.models.online as om
     from flink_ml_tpu.iteration.streaming import StreamTable
     from flink_ml_tpu.linalg.vectors import SparseVector
     from flink_ml_tpu.models.online import OnlineLogisticRegression
@@ -345,7 +344,6 @@ def test_ftrl_sparse_device_parity(monkeypatch, rng):
     t = Table.from_columns(features=sv, label=y)
 
     def fit():
-        monkeypatch.setattr(om, "_ftrl_sparse_broken", False)
         est = OnlineLogisticRegression(global_batch_size=100)
         est.set_initial_model_data(
             Table.from_columns(coefficient=np.zeros((1, d))))

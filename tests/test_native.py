@@ -29,6 +29,39 @@ def test_native_builds():
 
 
 @needs_gcc
+def test_native_rebuilds_on_key_mismatch_regardless_of_mtimes(monkeypatch):
+    """What loads must have been compiled on THIS machine from THESE
+    sources: the library is keyed by a hash of sources, flags and host
+    CPU recorded beside it. A library that is newer than every source
+    (the old mtime rule would keep it) but whose recorded key differs —
+    a copy from another machine, an edited flag — is rebuilt."""
+    import os
+    import time
+
+    assert native.available()
+    assert native._recorded_key() == native._build_key()
+    future = time.time() + 3600
+    os.utime(native._LIB, (future, future))  # newer than the sources
+    with open(native._KEY_FILE, "w") as f:
+        f.write("built-somewhere-else")
+    built = []
+    real_run = native.subprocess.run
+
+    def recording_run(cmd, *a, **kw):
+        built.append(cmd[0])
+        return real_run(cmd, *a, **kw)
+
+    monkeypatch.setattr(native.subprocess, "run", recording_run)
+    assert native._build() is not None
+    assert built == ["g++"]
+    assert native._recorded_key() == native._build_key()
+    assert os.path.getmtime(native._LIB) < future
+    # and a matching key is a no-op: nothing compiles
+    assert native._build() is not None
+    assert built == ["g++"]
+
+
+@needs_gcc
 def test_native_matches_python_oracle(rng):
     table = make_purchases(rng)
     op = Swing(min_user_behavior=2, k=5, alpha1=5, beta=0.5)

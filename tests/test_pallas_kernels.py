@@ -217,7 +217,6 @@ def test_kmeans_fit_kernel_path_matches_xla_on_mesh(rng, monkeypatch):
         return est.last_execution_path, model.centroids, model.weights
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    monkeypatch.setattr(km, "_pallas_lloyd_broken", False, raising=True)
     orig = pk.lloyd_partial_sums
     monkeypatch.setattr(pk, "lloyd_partial_sums",
                         lambda *a, **kw: orig(*a, **{**kw,
@@ -252,13 +251,20 @@ def test_knn_predict_kernel_path_matches_xla(rng, monkeypatch):
     t = Table.from_columns(features=x)
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    monkeypatch.setattr(knn_mod, "_pallas_knn_broken", False, raising=True)
+    # the kernel path feeds test rows in bounded chunks (its lane-padded
+    # HBM operands grow 512 bytes a row): 300 rows = 2 chunks + a ragged one
+    monkeypatch.setattr(knn_mod, "_KERNEL_CHUNK_ROWS", 128)
     orig = pk.knn_topk_indices
-    monkeypatch.setattr(pk, "knn_topk_indices",
-                        lambda *a, **kw: orig(*a, **{**kw,
-                                                     "interpret": True}))
+    calls = []
+
+    def interpreted(x, *a, **kw):
+        calls.append(x.shape[0])
+        return orig(x, *a, **{**kw, "interpret": True})
+
+    monkeypatch.setattr(pk, "knn_topk_indices", interpreted)
     pred_k = np.asarray(model.transform(t)[0]["prediction"])
     assert model.last_execution_path == "pallas"
+    assert calls == [128, 128, 44]
     monkeypatch.setattr(pk, "pallas_supported", lambda: False)
     pred_x = np.asarray(model.transform(t)[0]["prediction"])
     assert model.last_execution_path == "xla-chunked"
@@ -298,7 +304,7 @@ def test_sgd_round_tile():
     assert sgd_round_tile(16, 64, 4) == 16
     assert sgd_round_tile(7, 63, 4) == 0  # no multiple-of-8 common tile
     assert sgd_round_tile(8, 8, 4) == 8
-    # wide features shrink the tile instead of burning the broken flag
+    # wide features shrink the tile
     assert 0 < sgd_round_tile(1024, 4096, 100_000) < 1024
     assert sgd_round_tile(8, 8, 10_000_000) == 0
 
@@ -315,8 +321,6 @@ def test_sgd_unrolled_kernel_program_matches_xla(rng, monkeypatch):
     # kernel to interpret mode
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
     orig = pk.sgd_batch_terms
-    monkeypatch.setattr(
-        om, "_pallas_sgd_broken", False, raising=True)
     monkeypatch.setattr(
         pk, "sgd_batch_terms",
         lambda *a, **k: orig(*a, **{**k, "interpret": True}))
@@ -436,3 +440,130 @@ def test_ftrl_sparse_kernel_program_matches_xla(rng):
     want = run(False)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# -- a selected kernel that fails must RAISE at every call site ---------------
+# (selection is by the backend and the shape gates, nothing else; the KMeans
+# fit site is pinned in tests/test_kmeans.py)
+
+def _mosaic_failure(*args, **kwargs):
+    raise NotImplementedError(
+        "Unimplemented primitive in Pallas TPU lowering (synthetic)")
+
+
+def test_sgd_kernel_failure_propagates(rng, monkeypatch):
+    from flink_ml_tpu.ops import optimizer as om
+    from flink_ml_tpu.ops import pallas_kernels as pk
+    from flink_ml_tpu.ops.losses import BinaryLogisticLoss
+
+    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
+    monkeypatch.setattr(pk, "sgd_batch_terms", _mosaic_failure)
+    x = rng.normal(size=(2048, 6))
+    y = (rng.random(2048) > 0.5).astype(np.float64)
+    sgd = om.SGD(om.SGDParams(global_batch_size=512, max_iter=3, tol=0.0))
+    om._build_sgd_unrolled_program.cache_clear()
+    try:
+        with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+            sgd.optimize(BinaryLogisticLoss(), np.zeros(6), x, y)
+    finally:
+        om._build_sgd_unrolled_program.cache_clear()
+
+
+def test_kmeans_transform_kernel_failure_propagates(rng, monkeypatch):
+    from flink_ml_tpu.common.table import Table
+    from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.models.clustering.kmeans import KMeansModel
+    from flink_ml_tpu.ops import pallas_kernels as pk
+
+    model = KMeansModel(centroids=rng.normal(size=(3, 4)),
+                        weights=np.ones(3))
+    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
+    monkeypatch.setattr(pk, "assign_nearest", _mosaic_failure)
+    km._build_assign_program.cache_clear()
+    try:
+        with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+            model.transform(Table.from_columns(
+                features=rng.normal(size=(64, 4))))
+    finally:
+        km._build_assign_program.cache_clear()
+
+
+def test_kmeans_transform_gate_refuses_oversized_centroids(rng, monkeypatch):
+    """The assign kernel shares the Lloyd shape gate: centroids whose
+    working set overflows VMEM take the XLA program instead of failing."""
+    from flink_ml_tpu.common.table import Table
+    from flink_ml_tpu.models.clustering.kmeans import KMeansModel
+    from flink_ml_tpu.ops import pallas_kernels as pk
+
+    model = KMeansModel(centroids=rng.normal(size=(3, 4)),
+                        weights=np.ones(3))
+    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
+    monkeypatch.setattr(pk, "lloyd_kernel_fits", lambda k, d: False)
+    monkeypatch.setattr(pk, "assign_nearest", _mosaic_failure)
+    model.transform(Table.from_columns(features=rng.normal(size=(64, 4))))
+    assert model.last_execution_path == "xla-assign"
+
+
+def test_knn_kernel_failure_propagates(rng, monkeypatch):
+    from flink_ml_tpu.common.table import Table
+    from flink_ml_tpu.models.classification.knn import Knn
+    from flink_ml_tpu.ops import pallas_kernels as pk
+
+    model = Knn(k=3).fit(Table.from_columns(
+        features=rng.normal(size=(50, 4)),
+        label=rng.integers(0, 2, 50).astype(np.float64)))
+    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
+    monkeypatch.setattr(pk, "knn_topk_indices", _mosaic_failure)
+    with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+        model.transform(Table.from_columns(features=rng.normal(size=(8, 4))))
+
+
+def _sparse_ftrl_fit(rng, monkeypatch):
+    from flink_ml_tpu.common.table import Table
+    from flink_ml_tpu.linalg.vectors import DenseVector, SparseVector
+    from flink_ml_tpu.models.online import OnlineLogisticRegression
+
+    monkeypatch.setenv("FLINK_ML_TPU_FTRL_SPARSE_MIN_NNZ", "1")
+    n, d = 200, 9
+    x = rng.normal(size=(n, d))
+    col = np.empty(n, object)
+    for i in range(n):
+        idx = np.nonzero(rng.random(d) < 0.6)[0]
+        col[i] = SparseVector(d, idx, x[i, idx])
+    est = OnlineLogisticRegression(global_batch_size=100)
+    est.set_initial_model_data(Table.from_columns(
+        coefficient=[DenseVector(np.zeros(d))]))
+    table = Table.from_columns(
+        features=col, label=(rng.random(n) > 0.5).astype(np.float64))
+    return est, table
+
+
+def test_ftrl_sparse_kernel_failure_propagates(rng, monkeypatch):
+    from flink_ml_tpu.models import online as om
+    from flink_ml_tpu.ops import pallas_kernels as pk
+
+    est, table = _sparse_ftrl_fit(rng, monkeypatch)
+    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
+    monkeypatch.setattr(pk, "segment_reduce_sum", _mosaic_failure)
+    om._ftrl_sparse_program.cache_clear()
+    try:
+        with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+            est.fit(table)
+    finally:
+        om._ftrl_sparse_program.cache_clear()
+
+
+def test_ftrl_sparse_device_failure_is_not_demoted_to_host(rng,
+                                                           monkeypatch):
+    """A failed device sparse step raises; the nnz gate alone chooses
+    between the device and the host CSR engines."""
+    from flink_ml_tpu.models import online as om
+
+    est, table = _sparse_ftrl_fit(rng, monkeypatch)
+
+    def dead_program(*args, **kwargs):
+        raise RuntimeError("device lost (synthetic)")
+
+    monkeypatch.setattr(om, "_ftrl_sparse_program", dead_program)
+    with pytest.raises(RuntimeError, match="device lost"):
+        est.fit(table)

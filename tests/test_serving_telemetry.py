@@ -354,15 +354,9 @@ def test_slo_spec_toml(tmp_path):
     spec = tmp_path / "slo.toml"
     spec.write_text('[[slos]]\nname = "lat"\nkind = "latency"\n'
                     'threshold_ms = 50.0\n')
-    try:
-        import tomllib  # noqa: F401 — availability probe (3.11+)
-    except ImportError:
-        with pytest.raises(ValueError, match="tomllib"):
-            slo.load_specs(str(spec))
-    else:
-        specs = slo.load_specs(str(spec))
-        assert specs[0].name == "lat"
-        assert specs[0].threshold_ms == 50.0
+    specs = slo.load_specs(str(spec))
+    assert specs[0].name == "lat"
+    assert specs[0].threshold_ms == 50.0
 
 
 def test_slo_latency_violation_and_burn_rate():

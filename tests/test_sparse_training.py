@@ -309,7 +309,6 @@ def test_ftrl_sparse_device_path_matches_host(rng, monkeypatch):
     segment-sum SPMD program; the result must match the float64 host CSR
     engine within float32 tolerance, with executionPath provenance."""
     from flink_ml_tpu.models.online import OnlineLogisticRegression
-    import flink_ml_tpu.models.online as online_mod
 
     n, d = 600, 40
     x = rng.normal(size=(n, d))
@@ -325,7 +324,6 @@ def test_ftrl_sparse_device_path_matches_host(rng, monkeypatch):
         m = est.fit(Table.from_columns(f=col, l=y))
         return est.last_execution_path, m
 
-    monkeypatch.setattr(online_mod, "_ftrl_sparse_broken", False)
     monkeypatch.setenv("FLINK_ML_TPU_FTRL_SPARSE_MIN_NNZ", "1")
     path_dev, m_dev = fit()
     assert path_dev == "device-csr-batches"
@@ -344,7 +342,6 @@ def test_ftrl_sparse_device_path_matches_host(rng, monkeypatch):
 def test_ftrl_sparse_device_weighted_rows(rng, monkeypatch):
     """weightCol flows into the device path's per-coordinate weight sums."""
     from flink_ml_tpu.models.online import OnlineLogisticRegression
-    import flink_ml_tpu.models.online as online_mod
 
     n, d = 300, 12
     x = rng.normal(size=(n, d))
@@ -361,7 +358,6 @@ def test_ftrl_sparse_device_weighted_rows(rng, monkeypatch):
         est.set_initial_model_data(init)
         return est.fit(Table.from_columns(f=col, l=y, w=w))
 
-    monkeypatch.setattr(online_mod, "_ftrl_sparse_broken", False)
     monkeypatch.setenv("FLINK_ML_TPU_FTRL_SPARSE_MIN_NNZ", "1")
     m_dev = fit()
     monkeypatch.setenv("FLINK_ML_TPU_FTRL_SPARSE_MIN_NNZ", str(1 << 60))

@@ -51,7 +51,7 @@ def test_unexpected_success_exits_3_and_is_recorded(tmp_path, capsys):
 def test_unmeasured_rows_stay_retryable_exit_2(tmp_path):
     resume = {"ok": _measured(),
               "dead": {"configFile": "c.json",
-                       "exception": "RuntimeError: tunnel died"},
+                       "exception": "RuntimeError: device lost"},
               "Undefined-Parameter": dict(_measured(),
                                           unexpectedSuccess=True)}
     rc, data = _run_main(tmp_path, resume)
@@ -70,11 +70,3 @@ def test_clean_sweep_exits_0_and_drops_stale_meta(tmp_path):
     rc, data = _run_main(tmp_path, resume)
     assert rc == 0
     assert "_meta" not in data
-
-
-def test_wrapper_treats_exit_3_as_terminal():
-    """tpu_wait_and_sweep must not retry (or fold into BASELINE.md) on a
-    validation regression; source-level check keeps this jax-free."""
-    src = open(os.path.join(REPO, "scripts",
-                            "tpu_wait_and_sweep.py")).read()
-    assert "rc == 3" in src and "return 3" in src
