@@ -28,17 +28,22 @@ def _profiled(method, kind: str):
     """Wrap a fit/transform implementation with the observability hooks
     (SURVEY.md §5: run visibility is the reference's gap we close).
     Two independent, composing arms — ``FLINK_ML_TPU_PROFILE_DIR``
-    records a jax.profiler trace (device/XLA internals),
-    ``FLINK_ML_TPU_TRACE_DIR`` opens a tracer span (host-side structure:
-    fit→epoch→checkpoint nesting, docs/observability.md). Two env checks
-    of overhead when both are off. Traces nest safely: a Pipeline's
-    stages inside the pipeline trace record wall-time gauges only.
+    records a jax.profiler trace (device/XLA internals), and the tracer
+    opens the root span (host-side structure: fit→optimize→launch/fetch
+    nesting, docs/observability.md) whenever it is active: a trace dir,
+    the live endpoint's ring, or any running ``jax.profiler`` capture,
+    whose ``.xplane.pb`` then holds the program's spans on the device
+    trace's clock. Two env checks and the profiler's flag of overhead
+    when all are off. Traces nest safely: a Pipeline's stages inside the
+    pipeline trace record wall-time gauges only.
 
-    A traced fit also arms compile telemetry: the jax.monitoring
-    subscription (compile counts/durations land in ``ml.compile``), a
-    recompile-storm window scoped to the outermost stage call, and a
-    device-memory watermark sampled as the ROOT span closes (no-op on
-    CPU) — so peak HBM per fit is on the root span itself."""
+    A fit traced into a trace DIR also arms compile telemetry: the
+    jax.monitoring subscription (compile counts/durations land in
+    ``ml.compile``), a recompile-storm window scoped to the outermost
+    stage call, and a device-memory watermark sampled as the ROOT span
+    closes (no-op on CPU) — so peak HBM per fit is on the root span
+    itself. Under a capture or the ring alone none of these run: such a
+    fit is perturbed by its spans only."""
 
     @functools.wraps(method)
     def wrapper(self, *args, **kwargs):
@@ -63,6 +68,11 @@ def _profiled(method, kind: str):
         from flink_ml_tpu.common.locks import install_thread_excepthook
 
         install_thread_excepthook()
+        if not trace_dir and not tracer.enabled:
+            # the ring or a profiler capture alone: the root span and
+            # nothing heavier, so the fit is perturbed by its spans only
+            with tracer.span(region, kind=kind, stage=type(self).__name__):
+                return method(self, *args, **kwargs)
         try:
             with contextlib.ExitStack() as stack:
                 sp = None
@@ -85,7 +95,8 @@ def _profiled(method, kind: str):
                     stack.enter_context(profile(
                         os.path.join(trace_dir, region), name=region))
                 result = method(self, *args, **kwargs)
-                if sp is not None and sp.parent_id is None:
+                if (tracer.enabled and sp is not None
+                        and sp.parent_id is None):
                     compilestats.sample_memory(f"root:{kind}", span=sp)
                 return result
         finally:

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from flink_ml_tpu.api.stage import Estimator, Model
 from flink_ml_tpu.common.table import Table, as_dense_vector_column
 from flink_ml_tpu.linalg.vectors import DenseVector
+from flink_ml_tpu.observability.tracing import tracer
 from flink_ml_tpu.ops.losses import LossFunc
 from flink_ml_tpu.ops.optimizer import SGD, SGDParams
 from flink_ml_tpu.params.shared import (
@@ -256,7 +257,8 @@ class LinearEstimatorBase(Estimator, LinearTrainParams,
 
     def _fit_once(self, table: Table):
         from flink_ml_tpu.linalg import sparse
-        x, y, w = extract_labeled_points(self, table)
+        with tracer.span("fit.extract"):
+            x, y, w = extract_labeled_points(self, table)
         params = SGDParams(
             learning_rate=self.learning_rate,
             global_batch_size=self.global_batch_size,
@@ -288,10 +290,11 @@ class LinearEstimatorBase(Estimator, LinearTrainParams,
         # program shape actually trained this model
         self.last_execution_path = getattr(sgd, "last_execution_path",
                                            None)
-        model = self.model_class(coefficients=coeffs)
-        model = self.copy_params_to(model)
-        _capture_drift_baseline(self, model, x, coeffs)
-        _capture_quality_baseline(self, model, x, y, coeffs)
+        with tracer.span("fit.model"):
+            model = self.model_class(coefficients=coeffs)
+            model = self.copy_params_to(model)
+            _capture_drift_baseline(self, model, x, coeffs)
+            _capture_quality_baseline(self, model, x, y, coeffs)
         return model
 
 

@@ -32,14 +32,23 @@ pair:
   join that trace, so the merged ``spans-p<k>-*.jsonl`` artifacts of a
   multi-process run stitch into ONE trace.
 
-When no trace dir is armed (env or :meth:`Tracer.configure`), ``span``
-returns a shared no-op context manager — one dict lookup of overhead —
-so the instrumentation stays compiled into production paths, same policy
-as resilience.faults.
+Two sinks, one span system. An armed span is a JSON line in the trace
+dir (when one is set) and a record in the in-memory ring
+(:attr:`Tracer.recent`), AND a ``jax.profiler.TraceAnnotation`` for its
+lifetime: it lies in the profiler's ``.xplane.pb`` on the host thread's
+line, on the device trace's own clock, nested as the Python nests — so a
+device idle gap can be put down to the program span that covers it.
+Spans are armed by a trace dir, by the live endpoint's ring
+(``keep_recent``) or by the profiler itself: while ANY ``jax.profiler``
+capture runs (``TraceAnnotation.is_enabled()``: a benchmark's traced
+window, ``FLINK_ML_TPU_PROFILE_DIR``, ``/profilez``, an incident
+capture) the program's spans are taken too, into that capture and into
+the ring (ids, parents, attributes: what the xplane cannot carry).
 
-This composes with (does not replace) the ``FLINK_ML_TPU_PROFILE_DIR``
-jax.profiler hook: the profiler captures device/XLA internals, the
-tracer captures the host-side structure around them.
+With none of the three, ``span`` returns a shared no-op context manager
+— one profiler-flag read and one environment look-up — so the
+instrumentation stays compiled into production paths, same policy as
+resilience.faults.
 """
 
 from __future__ import annotations
@@ -51,6 +60,8 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: env var holding a directory; when set, instrumented seams emit spans
 #: as ``spans-<pid>.jsonl`` files there (docs/observability.md)
@@ -64,10 +75,11 @@ TRACE_DIR_ENV = "FLINK_ML_TPU_TRACE_DIR"
 TRACE_PARENT_ENV = "FLINK_ML_TPU_TRACE_PARENT"
 
 #: default capacity of the recent-span ring (the live ``/spans/recent``
-#: endpoint and the flight recorder's span evidence —
-#: observability/flightrecorder.py); override with
-#: ``FLINK_ML_TPU_TRACE_RING``
-RECENT_SPANS = 256
+#: endpoint, the flight recorder's span evidence —
+#: observability/flightrecorder.py — and what a reader takes after a
+#: profiler capture: 2 s of back-to-back fits at a dozen spans each are
+#: held whole); override with ``FLINK_ML_TPU_TRACE_RING``
+RECENT_SPANS = 2048
 
 #: env var overriding the ring capacity (a bigger ring = more incident
 #: evidence, more resident memory); read once per Tracer construction /
@@ -237,21 +249,28 @@ _NOOP = _NoopSpan()
 
 
 class _ActiveSpan:
-    """Context manager pairing a real Span with its tracer."""
+    """Context manager pairing a real Span with its tracer, and with
+    the profiler's annotation of the same name: the span's second sink,
+    the ``.xplane.pb`` of whatever capture is running (free when none
+    is)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_stack", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, stack: List[Span]):
         self._tracer = tracer
         self.span = span
+        self._stack = stack  # the opening thread's: where the span sits
+        self._annotation = TraceAnnotation(span.name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.span.set_attribute("error", exc_type.__name__)
-        self._tracer._end(self.span)
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._tracer._end(self.span, self._stack)
         return False
 
 
@@ -296,9 +315,12 @@ class Tracer:
     @property
     def active(self) -> bool:
         """Spans are being recorded somewhere: to the trace dir
-        (``enabled``) and/or to the in-memory recent ring for the live
-        telemetry endpoint (``keep_recent``)."""
-        return self.enabled or self.keep_recent
+        (``enabled``), to the in-memory recent ring for the live
+        telemetry endpoint (``keep_recent``), or — while any
+        ``jax.profiler`` capture runs, the profiler's own flag — into
+        that capture and the ring."""
+        return (self.keep_recent or TraceAnnotation.is_enabled()
+                or self.enabled)
 
     def configure(self, trace_dir: Optional[str]) -> None:
         """Programmatic arming (tests, embedding); ``None`` reverts to
@@ -391,7 +413,7 @@ class Tracer:
         sp = Span(name, trace_id, _new_id(), parent_id, attrs,
                   links=links)
         stack.append(sp)
-        return _ActiveSpan(self, sp)
+        return _ActiveSpan(self, sp, stack)
 
     def event(self, name: str, **attrs) -> None:
         """Record an instant event on the current span; with no span
@@ -407,9 +429,8 @@ class Tracer:
         with self.span(f"event:{name}") as sp:
             sp.add_event(name, **attrs)
 
-    def _end(self, sp: Span) -> None:
+    def _end(self, sp: Span, stack: List[Span]) -> None:
         sp.finish()
-        stack = self._stack()
         if stack and stack[-1] is sp:
             stack.pop()
         else:  # out-of-order exit: drop it from wherever it sits
@@ -418,14 +439,6 @@ class Tracer:
             except ValueError:
                 pass
         record = sp.to_record(os.getpid(), threading.get_ident())
-        from flink_ml_tpu.observability.exporters import (
-            safe_process_label)
-
-        proc = safe_process_label()
-        if proc is not None:
-            # attribution for multi-process trace merges: same-pid span
-            # records from different hosts must not fold into one process
-            record["process"] = proc
         # the ring fills whenever spans are recorded at all (not just
         # under keep_recent): it is the flight recorder's evidence of
         # "what ran before the incident", which must exist BEFORE the
@@ -439,7 +452,8 @@ class Tracer:
                 and len(self.recent) >= self.recent.maxlen):
             self.dropped_spans += 1
         self.recent.append(record)
-        self._write(record)
+        if self.trace_dir:  # ring or capture alone: no sink work at all
+            self._write(record)
 
     def mirror_dropped(self) -> int:
         """Fold ring evictions tallied since the last call into the
@@ -475,6 +489,15 @@ class Tracer:
         path = self.span_file()
         if path is None:
             return
+        from flink_ml_tpu.observability.exporters import (
+            safe_process_label)
+
+        proc = safe_process_label()
+        if proc is not None:
+            # attribution for multi-process trace merges: same-pid span
+            # records from different hosts must not fold into one process
+            # (a record that stays in the ring alone is this process's)
+            record["process"] = proc
         line = json.dumps(record, default=str) + "\n"
         with self._sink_lock:
             if self._sink is not None and self._sink_pid != os.getpid():

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_ml_tpu.observability import health as _health
+from flink_ml_tpu.observability.tracing import tracer
 from flink_ml_tpu.ops.losses import LossFunc
 from flink_ml_tpu.ops.regularization import regularize
 from flink_ml_tpu.parallel.mesh import (
@@ -182,7 +183,8 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
 
     def apply_packed(coeffs, opt, packed_local):
         if sharded:
-            tail = mr.reduce_sum(packed_local[-2:], axes)
+            with jax.named_scope("sgd.grad_allreduce"):
+                tail = mr.reduce_sum(packed_local[-2:], axes)
             total_w, total_loss = tail[0], tail[1]
             grad_pad = _upd.pad_leading(packed_local[:-2], coeffs.shape[0])
 
@@ -195,7 +197,8 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
             updated, new_opt = _upd.sharded_apply(axes, grad_pad, coeffs,
                                                   opt, apply_fn)
         else:
-            packed = mr.reduce_sum(packed_local, axes)
+            with jax.named_scope("sgd.grad_allreduce"):
+                packed = mr.reduce_sum(packed_local, axes)
             grad, total_w, total_loss = packed[:-2], packed[-2], packed[-1]
 
             # ref updateModel (SGD.java:231-243); skip when no weight
@@ -210,14 +213,17 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
         return coeffs_out, opt_out, mean_loss
 
     def update(coeffs, opt, xb, yb, wb):
-        if model_axis is None:
-            d = xb.shape[1]  # == coeffs length unless sharded padding
-            loss_sum, grad_sum = loss_func.loss_and_gradient(coeffs[:d],
-                                                             xb, yb, wb)
-        else:
-            dots = mr.reduce_sum(xb @ coeffs, model_axis)
-            loss_sum, multipliers = loss_func.terms(dots, yb, wb)
-            grad_sum = xb.T @ multipliers  # local feature shard
+        # LossFunc.loss_and_gradient, spelled out so that the two
+        # products carry their names into the device trace
+        with jax.named_scope("sgd.margins"):
+            if model_axis is None:
+                d = xb.shape[1]  # == coeffs length unless sharded padding
+                dots = xb @ coeffs[:d]
+            else:
+                dots = mr.reduce_sum(xb @ coeffs, model_axis)
+        loss_sum, multipliers = loss_func.terms(dots, yb, wb)
+        with jax.named_scope("sgd.gradient"):
+            grad_sum = xb.T @ multipliers  # local feature shard under TP
         packed = jnp.concatenate([
             grad_sum, jnp.sum(wb)[None].astype(grad_sum.dtype),
             loss_sum[None]])
@@ -249,6 +255,7 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
     update, _ = _sgd_update_math(loss_func, prm, axes, model_axis,
                                  sharded=sharded)
 
+    @jax.named_scope("sgd.round")
     def round_step(xl, yl, wl, coeffs, opt, offset):
         local_n = xl.shape[0]  # static at trace time
         lb_max = min(lb_base + (1 if lb_rem else 0), local_n)
@@ -360,8 +367,8 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
                 fin)
 
     if health:
-        def per_shard(xl, yl, wl, coeffs, offsets, opt, epoch0, limit,
-                      hist, fin):
+        def sgd_segment(xl, yl, wl, coeffs, offsets, opt, epoch0, limit,
+                        hist, fin):
             out = run(xl, yl, wl, coeffs, offsets, opt, epoch0, limit,
                       hist, fin)
             if not fused:
@@ -375,7 +382,7 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
         extra_out = (P(),) if fused else (P(), P())
         donate = (3, 4, 5, 8)
     else:
-        def per_shard(xl, yl, wl, coeffs, offsets, opt, epoch0, limit):
+        def sgd_segment(xl, yl, wl, coeffs, offsets, opt, epoch0, limit):
             out = run(xl, yl, wl, coeffs, offsets, opt, epoch0, limit,
                       jnp.zeros((0, 3), jnp.float32),
                       jnp.asarray(True))[:6]
@@ -390,7 +397,7 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
 
     scalar_out = (P(),) if fused else (P(), P())
     return mr.map_shards(
-        per_shard, mesh,
+        sgd_segment, mesh,
         in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
                   P(spec0), opt_specs, P(), P()) + extra_in,
         out_specs=(wspec, P(spec0), opt_specs, P()) + scalar_out
@@ -459,12 +466,13 @@ def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                             model_axis, sharded=sharded)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
-    def per_shard(xl, yl, wl, coeffs, offsets, opt):
+    def sgd_unrolled(xl, yl, wl, coeffs, offsets, opt):
         local_n = xl.shape[0]
         lb = min(lb_base, local_n)
         tile = 0
         if use_kernel and model_axis is None:
-            from flink_ml_tpu.ops.pallas_kernels import sgd_round_tile
+            from flink_ml_tpu.ops.pallas_kernels import (
+                sgd_batch_terms, sgd_round_tile)
             tile = sgd_round_tile(lb, local_n, xl.shape[1])
         sched = _static_batch_schedule(local_n, lb, prm.max_iter)
         offset = offsets[0]
@@ -474,46 +482,46 @@ def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
         rows = []
         fin = jnp.asarray(True)
         for start, clip in sched:
-            if tile:
-                from flink_ml_tpu.ops.pallas_kernels import sgd_batch_terms
-                # the kernel sees the TRUE feature dim — coeffs may be
-                # padded for the sharded update; apply_packed re-pads
-                # the local [grad | w | loss] partials it returns
-                packed = sgd_batch_terms(xl, yl, wl,
-                                         coeffs[:xl.shape[1]], start,
-                                         clip, lb, tile, loss_cls.NAME)
-                updated, new_opt, new_loss = apply_packed(coeffs, opt,
-                                                          packed)
-            else:
-                xb = jax.lax.slice_in_dim(xl, start, start + lb, axis=0)
-                yb = jax.lax.slice_in_dim(yl, start, start + lb, axis=0)
-                wb = jax.lax.slice_in_dim(wl, start, start + lb, axis=0)
-                if clip:  # short batch at the end: clipped rows weigh 0
-                    wb = wb * (np.arange(lb) >= clip).astype(xl.dtype)
-                updated, new_opt, new_loss = update(coeffs, opt, xb, yb,
-                                                    wb)
-            new_off = jnp.int32(0 if start + clip + lb >= local_n
-                                else start + clip + lb)
-            active = jnp.logical_not(stop)
-            if health:
-                # first-class numeric telemetry: the round's convergence
-                # row + ONE isfinite fold over loss and every parameter
-                # element; rounds past the tol stop record NaN rows and
-                # never poison the sentinel (they are masked out anyway)
-                row, row_fin = _health.convergence_row(
-                    new_loss, coeffs, updated, model_axis)
-                rows.append(jnp.where(
-                    active, row, jnp.full((3,), jnp.nan, jnp.float32)))
-                fin = jnp.logical_and(fin, jnp.logical_or(
-                    jnp.logical_not(active), row_fin))
-            coeffs = jnp.where(active, updated, coeffs)
-            opt = jax.tree_util.tree_map(
-                lambda n, o: jnp.where(active, n, o), new_opt, opt)
-            offset = jnp.where(active, new_off, offset)
-            mean_loss = jnp.where(active, new_loss, mean_loss)
-            epoch = epoch + active.astype(jnp.int32)
-            stop = jnp.logical_or(stop, jnp.logical_and(
-                active, new_loss < prm.tol))
+            with jax.named_scope("sgd.round"):
+                if tile:
+                    # the kernel sees the TRUE feature dim — coeffs may be
+                    # padded for the sharded update; apply_packed re-pads
+                    # the local [grad | w | loss] partials it returns
+                    packed = sgd_batch_terms(xl, yl, wl,
+                                             coeffs[:xl.shape[1]], start,
+                                             clip, lb, tile, loss_cls.NAME)
+                    updated, new_opt, new_loss = apply_packed(coeffs, opt,
+                                                              packed)
+                else:
+                    xb = jax.lax.slice_in_dim(xl, start, start + lb, axis=0)
+                    yb = jax.lax.slice_in_dim(yl, start, start + lb, axis=0)
+                    wb = jax.lax.slice_in_dim(wl, start, start + lb, axis=0)
+                    if clip:  # short batch at the end: clipped rows weigh 0
+                        wb = wb * (np.arange(lb) >= clip).astype(xl.dtype)
+                    updated, new_opt, new_loss = update(coeffs, opt, xb, yb,
+                                                        wb)
+                new_off = jnp.int32(0 if start + clip + lb >= local_n
+                                    else start + clip + lb)
+                active = jnp.logical_not(stop)
+                if health:
+                    # first-class numeric telemetry: the round's convergence
+                    # row + ONE isfinite fold over loss and every parameter
+                    # element; rounds past the tol stop record NaN rows and
+                    # never poison the sentinel (they are masked out anyway)
+                    row, row_fin = _health.convergence_row(
+                        new_loss, coeffs, updated, model_axis)
+                    rows.append(jnp.where(
+                        active, row, jnp.full((3,), jnp.nan, jnp.float32)))
+                    fin = jnp.logical_and(fin, jnp.logical_or(
+                        jnp.logical_not(active), row_fin))
+                coeffs = jnp.where(active, updated, coeffs)
+                opt = jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(active, n, o), new_opt, opt)
+                offset = jnp.where(active, new_off, offset)
+                mean_loss = jnp.where(active, new_loss, mean_loss)
+                epoch = epoch + active.astype(jnp.int32)
+                stop = jnp.logical_or(stop, jnp.logical_and(
+                    active, new_loss < prm.tol))
         if health:
             return (coeffs, offset[None], opt, mean_loss, epoch, stop,
                     jnp.stack(rows), fin)
@@ -522,7 +530,7 @@ def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
     # the (coeffs, offsets, opt) carry donates in EVERY build — the
     # update happens in place in the donated buffers
     return mr.map_shards(
-        per_shard, mesh,
+        sgd_unrolled, mesh,
         in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
                   P(spec0), opt_specs),
         out_specs=(wspec, P(spec0), opt_specs, P(), P(), P())
@@ -548,13 +556,13 @@ def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                  sharded=sharded)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
-    def per_shard(xl, yl, wl, coeffs, offsets, opt):
+    def sgd_round(xl, yl, wl, coeffs, offsets, opt):
         coeffs, opt, new_offset, mean_loss = round_step(
             xl, yl, wl, coeffs, opt, offsets[0])
         return coeffs, new_offset[None], mean_loss, opt
 
     return mr.map_shards(
-        per_shard, mesh,
+        sgd_round, mesh,
         in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
                   P(spec0), opt_specs),
         out_specs=(wspec, P(spec0), P(), opt_specs), jit=False)
@@ -566,13 +574,27 @@ def _tp_prepare_program(rem: int, pad_d: int, sharding):
     tensor-parallel layout (rows to the data axes, features to the model
     axis) — no host round-trip."""
 
-    def prep(a):
+    def prepare_rows(a):
         a = a.astype(jnp.float32)
         if rem or pad_d:
             a = jnp.pad(a, ((0, rem), (0, pad_d)))
         return a
 
-    return jax.jit(prep, out_shardings=sharding)
+    return jax.jit(prepare_rows, out_shardings=sharding)
+
+
+def _fresh_health_hist(rows: int, sharding):
+    """The NaN-filled ``(rows, 3)`` convergence history a segmented fit
+    starts from. Built under jit, not device_put: putting a host NaN
+    array onto a multi-process sharding trips jax's cross-process value
+    check (NaN != NaN in multihost_utils.assert_equal). The jitted
+    function is new in every call and dies with it, cache entries and
+    all, so the whole cost falls where it is called."""
+
+    def sgd_health_hist():
+        return jnp.full((rows, 3), jnp.nan, jnp.float32)
+
+    return jax.jit(sgd_health_hist, out_shardings=sharding)()
 
 
 def _health_tag(loss_func: LossFunc, tag: Optional[str]) -> str:
@@ -630,12 +652,21 @@ class SGD:
         reference's state persistence is representation-agnostic
         (SGD.java:308-360) and so is ours.
         """
-        prm = self.params
         # the mesh fixes the simulated task count p: a PURE function of the
         # mesh configuration, never of process state — sparse and dense
         # fits must slice batches identically (the parity contract below)
         # and a checkpointed carry must resume under the same p
         mesh = mesh or default_mesh()
+        with tracer.span("sgd.optimize", path="csr-host",
+                         rounds=self.params.max_iter,
+                         shards=data_shard_count(mesh)):
+            return self._optimize_csr(loss_func, init_coeffs, features_csr,
+                                      labels, weights, mesh, config,
+                                      listeners, tag)
+
+    def _optimize_csr(self, loss_func, init_coeffs, features_csr, labels,
+                      weights, mesh, config, listeners, tag):
+        prm = self.params
         p = data_shard_count(mesh)
         n, d = features_csr.shape
         ls = -(-n // p) if n else 1  # padded local length (shard_batch)
@@ -692,14 +723,20 @@ class SGD:
             opt0 = opt0 + (np.float64(0.0),)
         init = (np.asarray(init_coeffs, np.float64).copy(),
                 np.zeros(p, np.int64), np.float64(np.inf), opt0)
-        coeffs, _, mean_loss, _ = iterate_bounded(
-            init, round_body, max_iter=prm.max_iter,
-            terminate=lambda carry, epoch: carry[2] < prm.tol,
-            config=config, listeners=listeners, jit_round=False)
+        # host rounds, one ``epoch`` span each: nothing is enqueued and
+        # nothing awaited, the names are the dense paths' for one tree
+        with tracer.span("sgd.launch"):
+            coeffs, _, mean_loss, _ = iterate_bounded(
+                init, round_body, max_iter=prm.max_iter,
+                terminate=lambda carry, epoch: carry[2] < prm.tol,
+                config=config, listeners=listeners, jit_round=False)
         self.last_execution_path = "csr-host"
-        if not health_on:
-            _health.guard_final_state(algo, coeffs, loss=mean_loss)
-        return coeffs, float(mean_loss)
+        with tracer.span("sgd.fetch"):
+            mean_loss = float(mean_loss)
+        with tracer.span("sgd.health"):
+            if not health_on:
+                _health.guard_final_state(algo, coeffs, loss=mean_loss)
+        return coeffs, mean_loss
 
     def optimize(self, loss_func: LossFunc, init_coeffs: np.ndarray,
                  features: np.ndarray, labels: np.ndarray,
@@ -721,10 +758,33 @@ class SGD:
         (observability/health.py) the compiled programs return per-epoch
         convergence rows + a non-finite sentinel, and every path raises
         the terminal ``NonFiniteState`` on a NaN/Inf state instead of
-        returning garbage coefficients."""
+        returning garbage coefficients.
+
+        One ``sgd.optimize`` span a fit, with a child at each boundary
+        the host crosses (docs/observability.md, span catalogue):
+        ``sgd.place_inputs``, ``sgd.init_carry``, ``sgd.build_program``,
+        ``sgd.launch`` (the enqueue, never a wait), ``sgd.fetch`` (the
+        blocking device→host reads) and ``sgd.health``."""
+        mesh = mesh or default_mesh()
+        with tracer.span("sgd.optimize", rounds=self.params.max_iter,
+                         shards=data_shard_count(mesh)) as sp:
+            out = self._optimize(loss_func, init_coeffs, features, labels,
+                                 weights, mesh, dtype, config, listeners,
+                                 tag)
+            sp.set_attribute("path", self.last_execution_path)
+            return out
+
+    @staticmethod
+    def _fetch_result(coeffs, d: int, mean_loss):
+        """The blocking reads of the fitted state every dense path ends
+        in: where the wait for the enqueued rounds falls."""
+        with tracer.span("sgd.fetch"):
+            return np.asarray(coeffs, np.float64)[:d], float(mean_loss)
+
+    def _optimize(self, loss_func, init_coeffs, features, labels, weights,
+                  mesh, dtype, config, listeners, tag):
         algo = _health_tag(loss_func, tag)
         health_on = _health.armed()
-        mesh = mesh or default_mesh()
         n = features.shape[0]
         d = features.shape[1]
 
@@ -742,39 +802,41 @@ class SGD:
             if pad:
                 init_coeffs = np.pad(init_coeffs, (0, pad))
         from jax.sharding import NamedSharding
-        if tp:
-            # tensor parallelism: feature dim padded to the model-axis size
-            # and sharded over it (padded coords stay exactly zero: zero
-            # features → zero grad → soft-threshold(0) = 0)
-            tp_size = int(mesh.shape[MODEL_AXIS])
-            pad = (-d) % tp_size
-            if pad:
-                init_coeffs = np.pad(init_coeffs, (0, pad))
-            spec0 = data_pspec(mesh)
-            rem = (-n) % data_shard_count(mesh)
-            x_sharding = NamedSharding(mesh, P(spec0, MODEL_AXIS))
-            if isinstance(features, jax.Array):
-                # device-resident input: cast/pad/reshard on device — the
-                # same residency contract as the DP branch
-                if pad or rem or features.dtype != jnp.float32:
-                    features = _tp_prepare_program(
-                        rem, pad, x_sharding)(features)
+        with tracer.span("sgd.place_inputs"):
+            if tp:
+                # tensor parallelism: feature dim padded to the model-axis
+                # size and sharded over it (padded coords stay exactly
+                # zero: zero features → zero grad → soft-threshold(0) = 0)
+                tp_size = int(mesh.shape[MODEL_AXIS])
+                pad = (-d) % tp_size
+                if pad:
+                    init_coeffs = np.pad(init_coeffs, (0, pad))
+                spec0 = data_pspec(mesh)
+                rem = (-n) % data_shard_count(mesh)
+                x_sharding = NamedSharding(mesh, P(spec0, MODEL_AXIS))
+                if isinstance(features, jax.Array):
+                    # device-resident input: cast/pad/reshard on device —
+                    # the same residency contract as the DP branch
+                    if pad or rem or features.dtype != jnp.float32:
+                        features = _tp_prepare_program(
+                            rem, pad, x_sharding)(features)
+                else:
+                    features = np.asarray(features, np.float32)
+                    if pad or rem:
+                        features = np.pad(features, ((0, rem), (0, pad)))
+                xs = jax.device_put(features, x_sharding)
+                w_sharding = NamedSharding(mesh, P(MODEL_AXIS))
             else:
-                features = np.asarray(features, np.float32)
-                if pad or rem:
-                    features = np.pad(features, ((0, rem), (0, pad)))
-            xs = jax.device_put(features, x_sharding)
-            w_sharding = NamedSharding(mesh, P(MODEL_AXIS))
-        else:
-            # device-resident features/labels (device datagen or a previous
-            # device stage) stay on device end-to-end — no host round-trip
-            xs, _ = ensure_on_mesh(mesh, features, axes, jnp.float32)
-            w_sharding = NamedSharding(mesh, P())
-        ys, _ = ensure_on_mesh(mesh, labels, axes, jnp.float32)
-        if weights is None:
-            ws = ones_on_mesh(mesh, n, axes, jnp.float32)
-        else:
-            ws, _ = ensure_on_mesh(mesh, weights, axes, jnp.float32)
+                # device-resident features/labels (device datagen or a
+                # previous device stage) stay on device end-to-end — no
+                # host round-trip
+                xs, _ = ensure_on_mesh(mesh, features, axes, jnp.float32)
+                w_sharding = NamedSharding(mesh, P())
+            ys, _ = ensure_on_mesh(mesh, labels, axes, jnp.float32)
+            if weights is None:
+                ws = ones_on_mesh(mesh, n, axes, jnp.float32)
+            else:
+                ws, _ = ensure_on_mesh(mesh, weights, axes, jnp.float32)
         from flink_ml_tpu.iteration.iteration import (
             device_checkpoint_segment, needs_host_loop, run_segmented)
         p = data_shard_count(mesh)
@@ -788,38 +850,39 @@ class SGD:
         # moment slice back on its owning replica). The opt tuple rides
         # at the END of the carry so a method="sgd" checkpoint keeps the
         # stateless-era leaf order.
-        _check_method(self.params)
-        opt_sharding = (NamedSharding(mesh, P(spec0)) if sharded
-                        else w_sharding)
-        opt = tuple(
-            jax.device_put(jnp.zeros(init_coeffs.shape[0], dtype),
-                           opt_sharding)
-            for _ in range(_OPT_VECTORS[self.params.method]))
-        if self.params.method == "adam":
-            opt = opt + (jax.device_put(jnp.asarray(0.0, dtype),
-                                        NamedSharding(mesh, P())),)
-        init = (
-            jax.device_put(jnp.asarray(init_coeffs, dtype), w_sharding),
-            jax.device_put(jnp.zeros((p,), jnp.int32),
-                           NamedSharding(mesh, P(spec0))),
-            jax.device_put(jnp.asarray(jnp.inf, dtype),
-                           NamedSharding(mesh, P())),
-            opt,
-        )
-        w0 = init[0]
-        # per-replica update-state accounting (benchmark provenance):
-        # measured from the carry's real buffers — SGD's coefficients
-        # all-gather back to replicated every round, so this honestly
-        # reports full size even under the sharded update; the moment
-        # vectors are the state that genuinely shrinks 1/N (their
-        # slices never all-gather), recorded both folded into the algo
-        # total and as a standalone ".moments" record so the multihost
-        # bench can gate on the moment bytes alone
-        opt_leaves = list(jax.tree_util.tree_leaves(init[3]))
-        if opt_leaves:
-            _upd.record_state_bytes(f"{algo}.moments", opt_leaves, p,
-                                    sharded)
-        _upd.record_state_bytes(algo, [w0] + opt_leaves, p, sharded)
+        with tracer.span("sgd.init_carry"):
+            _check_method(self.params)
+            opt_sharding = (NamedSharding(mesh, P(spec0)) if sharded
+                            else w_sharding)
+            opt = tuple(
+                jax.device_put(jnp.zeros(init_coeffs.shape[0], dtype),
+                               opt_sharding)
+                for _ in range(_OPT_VECTORS[self.params.method]))
+            if self.params.method == "adam":
+                opt = opt + (jax.device_put(jnp.asarray(0.0, dtype),
+                                            NamedSharding(mesh, P())),)
+            init = (
+                jax.device_put(jnp.asarray(init_coeffs, dtype), w_sharding),
+                jax.device_put(jnp.zeros((p,), jnp.int32),
+                               NamedSharding(mesh, P(spec0))),
+                jax.device_put(jnp.asarray(jnp.inf, dtype),
+                               NamedSharding(mesh, P())),
+                opt,
+            )
+            w0 = init[0]
+            # per-replica update-state accounting (benchmark provenance):
+            # measured from the carry's real buffers — SGD's coefficients
+            # all-gather back to replicated every round, so this honestly
+            # reports full size even under the sharded update; the moment
+            # vectors are the state that genuinely shrinks 1/N (their
+            # slices never all-gather), recorded both folded into the algo
+            # total and as a standalone ".moments" record so the multihost
+            # bench can gate on the moment bytes alone
+            opt_leaves = list(jax.tree_util.tree_leaves(init[3]))
+            if opt_leaves:
+                _upd.record_state_bytes(f"{algo}.moments", opt_leaves, p,
+                                        sharded)
+            _upd.record_state_bytes(algo, [w0] + opt_leaves, p, sharded)
 
         seg_k = device_checkpoint_segment(config, listeners)
         if seg_k or not needs_host_loop(config, listeners):
@@ -843,67 +906,66 @@ class SGD:
                     and sgd_round_tile(
                         min(self.params.global_batch_size // p, local_n),
                         local_n, xs.shape[1]) > 0)
-                prog = _build_sgd_unrolled_program(
-                    type(loss_func), mesh, self.params,
-                    use_kernel=use_kernel, health=health_on,
-                    sharded=sharded)
-                res = prog(xs, ys, ws, init[0], init[1], init[3])
+                with tracer.span("sgd.build_program"):
+                    prog = _build_sgd_unrolled_program(
+                        type(loss_func), mesh, self.params,
+                        use_kernel=use_kernel, health=health_on,
+                        sharded=sharded)
+                with tracer.span("sgd.launch"):
+                    res = prog(xs, ys, ws, init[0], init[1], init[3])
                 coeffs, _, _, mean_loss, epoch, _ = res[:6]
                 hist, fin = (res[6:] if health_on else (None, True))
                 self.last_execution_path = (
                     "pallas-unrolled" if use_kernel else "xla-unrolled")
-                out = np.asarray(coeffs, np.float64)[:d]
-                _finish_fit_health(algo, health_on, hist, fin, epoch,
-                                   mean_loss, out)
-                return out, float(mean_loss)
+                out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
+                with tracer.span("sgd.health"):
+                    _finish_fit_health(algo, health_on, hist, fin, epoch,
+                                       mean_loss, out)
+                return out, mean_loss
             from flink_ml_tpu.iteration.iteration import (
                 read_boundary, segment_fusion_enabled)
             fused = segment_fusion_enabled()
-            seg_prog = _build_sgd_segment_program(type(loss_func), mesh,
-                                                  self.params,
-                                                  health=health_on,
-                                                  sharded=sharded,
-                                                  fused=fused)
-            # health carry lives OUTSIDE the checkpointed carry so the
-            # snapshot format is identical with telemetry on or off; a
-            # restore simply resumes the series at its epoch (earlier
-            # rows stay NaN and are sliced off by `first`)
-            repl = NamedSharding(mesh, P())
-            # built under jit, not device_put: putting a host NaN array
-            # onto a multi-process sharding trips jax's cross-process
-            # value check (NaN != NaN in multihost_utils.assert_equal)
-            hist_rows = self.params.max_iter if health_on else 0
-            hstate = {
-                "hist": jax.jit(
-                    functools.partial(jnp.full, (hist_rows, 3),
-                                      jnp.nan, jnp.float32),
-                    out_shardings=repl)(),
-                "fin": True, "first": None, "epoch": 0,
-            }
+            with tracer.span("sgd.build_program"):
+                seg_prog = _build_sgd_segment_program(type(loss_func), mesh,
+                                                      self.params,
+                                                      health=health_on,
+                                                      sharded=sharded,
+                                                      fused=fused)
+                # health carry lives OUTSIDE the checkpointed carry so the
+                # snapshot format is identical with telemetry on or off; a
+                # restore simply resumes the series at its epoch (earlier
+                # rows stay NaN and are sliced off by `first`)
+                hstate = {
+                    "hist": _fresh_health_hist(
+                        self.params.max_iter if health_on else 0,
+                        NamedSharding(mesh, P())),
+                    "fin": True, "first": None, "epoch": 0,
+                }
 
             def run_segment(carry, epoch0, limit):
                 coeffs, offsets, _, opt = carry
                 if hstate["first"] is None:
                     hstate["first"] = int(epoch0)
                 if health_on:
-                    out = seg_prog(
-                        xs, ys, ws, coeffs, offsets, opt,
-                        jnp.int32(epoch0), jnp.int32(limit),
-                        hstate["hist"], jnp.asarray(bool(hstate["fin"])))
+                    with tracer.span("sgd.launch"):
+                        out = seg_prog(
+                            xs, ys, ws, coeffs, offsets, opt,
+                            jnp.int32(epoch0), jnp.int32(limit),
+                            hstate["hist"],
+                            jnp.asarray(bool(hstate["fin"])))
                     if fused:
                         # ONE stacked [epoch, stop, fin] transfer per
                         # boundary instead of three scalar fetches
-                        (coeffs, offsets, opt, mean_loss, bundle,
+                        (coeffs, offsets, opt, mean_loss, boundary,
                          hstate["hist"]) = out
-                        vals = read_boundary(bundle)
-                        epoch, stop = int(vals[0]), bool(vals[1])
-                        hstate["fin"] = bool(vals[2])
                     else:
                         (coeffs, offsets, opt, mean_loss, epoch, stop,
                          hstate["hist"], fin) = out
-                        vals = read_boundary((epoch, stop, fin))
-                        epoch, stop = int(vals[0]), bool(vals[1])
-                        hstate["fin"] = bool(vals[2])
+                        boundary = (epoch, stop, fin)
+                    with tracer.span("sgd.fetch"):
+                        vals = read_boundary(boundary)
+                    epoch, stop = int(vals[0]), bool(vals[1])
+                    hstate["fin"] = bool(vals[2])
                     # epoch-boundary health check: the segment boundary
                     # is this mode's host sync point, so reading the
                     # sentinel costs no extra round-trip (it rides the
@@ -916,16 +978,18 @@ class SGD:
                             hstate["epoch"], mean_loss, None,
                             epoch0=hstate["first"])
                 else:
-                    out = seg_prog(
-                        xs, ys, ws, coeffs, offsets, opt,
-                        jnp.int32(epoch0), jnp.int32(limit))
+                    with tracer.span("sgd.launch"):
+                        out = seg_prog(
+                            xs, ys, ws, coeffs, offsets, opt,
+                            jnp.int32(epoch0), jnp.int32(limit))
                     if fused:
-                        coeffs, offsets, opt, mean_loss, bundle = out
-                        vals = read_boundary(bundle)
+                        coeffs, offsets, opt, mean_loss, boundary = out
                     else:
                         (coeffs, offsets, opt, mean_loss, epoch,
                          stop) = out
-                        vals = read_boundary((epoch, stop))
+                        boundary = (epoch, stop)
+                    with tracer.span("sgd.fetch"):
+                        vals = read_boundary(boundary)
                     epoch, stop = int(vals[0]), bool(vals[1])
                 return (coeffs, offsets, mean_loss, opt), epoch, stop
 
@@ -938,17 +1002,19 @@ class SGD:
                     init, 0, self.params.max_iter)
             self.last_execution_path = ("xla-while-segments" if seg_k
                                         else "xla-while")
-            out = np.asarray(coeffs, np.float64)[:d]
-            _finish_fit_health(
-                algo, health_on, hstate["hist"] if health_on else None,
-                hstate["fin"], hstate["epoch"], mean_loss, out,
-                epoch0=hstate["first"] or 0)
-            return out, float(mean_loss)
+            out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
+            with tracer.span("sgd.health"):
+                _finish_fit_health(
+                    algo, health_on, hstate["hist"] if health_on else None,
+                    hstate["fin"], hstate["epoch"], mean_loss, out,
+                    epoch0=hstate["first"] or 0)
+            return out, mean_loss
 
         from flink_ml_tpu.iteration.iteration import iterate_bounded
 
-        round_fn = _build_sgd_round_program(type(loss_func), mesh,
-                                            self.params, sharded=sharded)
+        with tracer.span("sgd.build_program"):
+            round_fn = _build_sgd_round_program(
+                type(loss_func), mesh, self.params, sharded=sharded)
 
         def body(carry, epoch):
             coeffs, offsets, _, opt = carry
@@ -966,13 +1032,17 @@ class SGD:
                 _health.ConvergenceListener.for_params(
                     algo, np.asarray(w0)),)
 
-        final = iterate_bounded(
-            init, body, max_iter=self.params.max_iter,
-            terminate=lambda carry, epoch: carry[2] < self.params.tol,
-            config=config, listeners=listeners)
+        # the rounds are enqueued (and their stop bits awaited) by the
+        # iteration runtime, one ``epoch`` span each under this one
+        with tracer.span("sgd.launch"):
+            final = iterate_bounded(
+                init, body, max_iter=self.params.max_iter,
+                terminate=lambda carry, epoch: carry[2] < self.params.tol,
+                config=config, listeners=listeners)
         coeffs, _, mean_loss, _ = final
         self.last_execution_path = "host-rounds"
-        out = np.asarray(coeffs, np.float64)[:d]
-        if not health_on:
-            _health.guard_final_state(algo, out, loss=mean_loss)
-        return out, float(mean_loss)
+        out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
+        with tracer.span("sgd.health"):
+            if not health_on:
+                _health.guard_final_state(algo, out, loss=mean_loss)
+        return out, mean_loss

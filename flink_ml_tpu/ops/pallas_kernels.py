@@ -40,6 +40,7 @@ def _assign_padded(x, centroids, interpret=False):
     grid = (n // TILE_N,)
     return pl.pallas_call(
         _assign_kernel,
+        name="assign_nearest",
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         grid=grid,
         in_specs=[
@@ -127,6 +128,7 @@ def _lloyd_padded(x, v, centroids, interpret=False):
     csq = jnp.sum(centroids * centroids, axis=1)
     return pl.pallas_call(
         _lloyd_accum_kernel,
+        name="lloyd_partial_sums",
         out_shape=jax.ShapeDtypeStruct((k, d + 1), jnp.float32),
         grid=(n // TILE_N,),
         in_specs=[
@@ -253,6 +255,7 @@ def _sgd_terms_padded(xl, yl, wl, coeffs, scalars, loss_name, lb, tile,
     )
     return pl.pallas_call(
         kernel,
+        name="sgd_batch_terms",
         out_shape=jax.ShapeDtypeStruct((1, d + 2), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -330,6 +333,7 @@ def _segreduce_padded(x, ids, num_segments, interpret=False):
     n, d = x.shape
     return pl.pallas_call(
         _segreduce_kernel,
+        name="segment_reduce_sum",
         out_shape=jax.ShapeDtypeStruct((num_segments, d), jnp.float32),
         grid=(n // SEGREDUCE_TILE_N,),
         in_specs=[
@@ -455,6 +459,7 @@ def _knn_padded(x, train, k, interpret=False):
     kernel = functools.partial(_knn_kernel, k)
     idx, _ = pl.pallas_call(
         kernel,
+        name="knn_topk_indices",
         out_shape=(jax.ShapeDtypeStruct((n, k), jnp.int32),
                    jax.ShapeDtypeStruct((n, k), jnp.float32)),
         grid=(n // KNN_TILE_N, (nt + pad_t) // KNN_TILE_T),

@@ -23,7 +23,7 @@ JL107's whole point), which is exactly the right meaning here: it
 answers "what collectives does this program issue, over which axes, at
 what sizes". Runtime timing comes from the host-level helpers below,
 which ARE host boundaries: each records an ``ml.collective
-opMs{op=,devices=}`` histogram and, when tracing is armed, a
+opMs{op=,devices=}`` histogram and, whenever the tracer is active, a
 ``collective.host`` span.
 """
 
@@ -308,18 +308,17 @@ def local_valid_mask(axes, local_n: int, n_valid, dtype=jnp.float32):
 class _HostOp:
     """Time one host-boundary collective/placement op into
     ``ml.collective opMs{op=,devices=}`` (+ payload bytes), with a
-    ``collective.host`` span when tracing is armed. Also the seam that
-    records the mesh topology: a host placement op is proof the mesh is
-    in use."""
+    ``collective.host`` span whenever the tracer is active. Also the
+    seam that records the mesh topology: a host placement op is proof
+    the mesh is in use."""
 
-    __slots__ = ("op", "mesh", "nbytes", "_t0", "_span_cm", "_span")
+    __slots__ = ("op", "mesh", "nbytes", "_t0", "_span_cm")
 
     def __init__(self, op: str, mesh: Mesh, nbytes: int = 0):
         self.op = op
         self.mesh = mesh
         self.nbytes = int(nbytes)
         self._span_cm = None
-        self._span = None
 
     def __enter__(self):
         from flink_ml_tpu.observability import meshstats, tracing
@@ -328,12 +327,11 @@ class _HostOp:
             meshstats.ensure_mesh_recorded(self.mesh)
         except Exception:
             pass
-        if tracing.tracer.enabled:
-            self._span_cm = tracing.tracer.span(
-                "collective.host", op=self.op,
-                devices=self.mesh.devices.size,
-                payload_bytes=self.nbytes)
-            self._span = self._span_cm.__enter__()
+        # the shared no-op unless the tracer is active
+        self._span_cm = tracing.tracer.span(
+            "collective.host", op=self.op, devices=self.mesh.devices.size,
+            payload_bytes=self.nbytes)
+        self._span_cm.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -345,8 +343,7 @@ class _HostOp:
         if self.nbytes:
             group.histogram("payloadBytes", buckets=PAYLOAD_BUCKETS,
                             labels=labels).observe(self.nbytes)
-        if self._span_cm is not None:
-            self._span_cm.__exit__(*exc)
+        self._span_cm.__exit__(*exc)
         return False
 
 
@@ -395,13 +392,13 @@ def _prepare_program(rem: int, dtype_name: str, sharding, ndim: int):
     repeated fits at the same shapes reuse one program."""
     dtype = jnp.dtype(dtype_name)
 
-    def prep(a):
+    def prepare_rows(a):
         a = a.astype(dtype)
         if rem:
             a = jnp.pad(a, ((0, rem),) + ((0, 0),) * (a.ndim - 1))
         return a
 
-    return jax.jit(prep, out_shardings=sharding)
+    return jax.jit(prepare_rows, out_shardings=sharding)
 
 
 def ensure_on_mesh(mesh: Mesh, array, axis_name=DATA_AXIS, dtype=None):
@@ -433,10 +430,10 @@ def ensure_on_mesh(mesh: Mesh, array, axis_name=DATA_AXIS, dtype=None):
 def _ones_program(padded: int, dtype_name: str, sharding):
     dtype = jnp.dtype(dtype_name)
 
-    def make(n):
+    def ones_rows(n):
         return (jnp.arange(padded) < n).astype(dtype)
 
-    return jax.jit(make, out_shardings=sharding)
+    return jax.jit(ones_rows, out_shardings=sharding)
 
 
 def ones_on_mesh(mesh: Mesh, n: int, axis_name=DATA_AXIS,

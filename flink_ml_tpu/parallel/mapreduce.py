@@ -142,7 +142,13 @@ def map_shards(fn, mesh, in_specs, out_specs, *, check_vma: bool = False,
     (replicated FTRL, unsharded SGD) donate without paying a Python
     signature lookup per call. ``jit=False`` returns the bare mapped
     callable for host loops that jit the round themselves
-    (iteration.iterate_bounded)."""
+    (iteration.iterate_bounded).
+
+    The program's name on the device trace (``XLA Modules``:
+    ``jit_<name>``) is ``fn.__name__`` — ``shard_map`` and ``jit`` carry
+    it through — so fit bodies are given stable names (``sgd_segment``,
+    ``sgd_unrolled``, ``sgd_round``), not ``per_shard``. ``name=`` is the
+    compile-accounting label, not that name, and changes the dispatch."""
     mapped = _shard_map(fn, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs, check_vma=check_vma)
     if not jit:

@@ -181,7 +181,6 @@ def record_state_bytes(algo: str, leaves, n_shards: int,
         grp = metrics.group(ML_GROUP, "update")
         labels = {"algo": algo, "sharded": str(int(sharded))}
         grp.gauge("stateBytesPerReplica", per_replica, labels=labels)
-        grp.gauge("stateShards", n_shards if sharded else 1, labels=labels)
     except Exception:
         pass
     return per_replica
