@@ -134,7 +134,9 @@ def iterate_bounded(initial_carry: Carry,
 
     ``jit_round=False`` runs the body as plain host code per round (no
     tracing) — for bodies whose math lives on host (the CSR sparse trainer:
-    scipy matvecs have no XLA form). Such bodies always use the host loop.
+    scipy matvecs have no XLA form) and for bodies that call a compiled
+    round program of their own, built once and found again in every fit
+    (SGD's host rounds). Such bodies always use the host loop.
 
     ``donate_carry=True`` donates the carry buffers through the compiled
     device/segment loops (the update happens in place — no fresh
@@ -389,11 +391,13 @@ def _host_loop(initial_carry, body, max_iter, terminate, config, listeners,
 
         round_fn = jax.jit(round_impl)
     else:
-        # plain host rounds: no jnp anywhere, so a pure-host iteration
-        # (CSR math) runs without ever initializing a device backend
+        # the body runs as it is: plain host rounds (CSR math: no jnp
+        # anywhere, so no device backend is ever initialized), or a round
+        # that calls a compiled program of its own, whose stop bit then
+        # stays on the device until the guarded fetch below
         def round_fn(carry, epoch):
             new_carry = body(carry, epoch)
-            stop = (bool(terminate(new_carry, epoch))
+            stop = (terminate(new_carry, epoch)
                     if terminate is not None else False)
             return new_carry, stop
 
@@ -422,7 +426,7 @@ def _host_loop(initial_carry, body, max_iter, terminate, config, listeners,
             if config.per_round_init is not None:
                 carry = config.per_round_init(carry, epoch)
             carry, stop = round_fn(
-                carry, jnp.int32(epoch) if jit_round else epoch)
+                carry, np.int32(epoch) if jit_round else epoch)
             faults.inject("epoch-boundary", epoch=epoch)
             from flink_ml_tpu.parallel import elastic
             elastic.on_boundary(epoch)
@@ -435,7 +439,7 @@ def _host_loop(initial_carry, body, max_iter, terminate, config, listeners,
                     (epoch + 1) % config.checkpoint_interval == 0:
                 mgr.save(carry, epoch + 1)
             host_ms = (_time.perf_counter() - host_start) * 1000.0
-            if jit_round and tracing.tracer.enabled:
+            if tracing.tracer.enabled:
                 # per-shard time-to-ready while the async round drains:
                 # per-replica epoch attribution + straggler detection
                 # (ml.shard readyMs{shard=,device=}, ml.skew events)

@@ -542,11 +542,11 @@ def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
 @functools.lru_cache(maxsize=128)
 def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
                              sharded: bool = False):
-    """ONE training round as a mapped (un-jitted) callable — the
-    building block of the checkpointable host loop (iterate_bounded jits
-    the round itself). Wraps the same _sgd_round_math as the all-device
-    program, so device and host modes are numerically identical by
-    construction."""
+    """ONE training round as a compiled mapped program — the building
+    block of the checkpointable host loop (iterate_bounded calls it as it
+    is: nothing is jitted per fit). Wraps the same _sgd_round_math as the
+    all-device program, so device and host modes are numerically
+    identical by construction."""
     axes = data_axes(mesh)
     spec0 = data_pspec(mesh)
     p = data_shard_count(mesh)
@@ -565,7 +565,7 @@ def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
         sgd_round, mesh,
         in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
                   P(spec0), opt_specs),
-        out_specs=(wspec, P(spec0), P(), opt_specs), jit=False)
+        out_specs=(wspec, P(spec0), P(), opt_specs))
 
 
 @functools.lru_cache(maxsize=128)
@@ -583,18 +583,19 @@ def _tp_prepare_program(rem: int, pad_d: int, sharding):
     return jax.jit(prepare_rows, out_shardings=sharding)
 
 
-def _fresh_health_hist(rows: int, sharding):
-    """The NaN-filled ``(rows, 3)`` convergence history a segmented fit
-    starts from. Built under jit, not device_put: putting a host NaN
-    array onto a multi-process sharding trips jax's cross-process value
-    check (NaN != NaN in multihost_utils.assert_equal). The jitted
-    function is new in every call and dies with it, cache entries and
-    all, so the whole cost falls where it is called."""
+@functools.lru_cache(maxsize=128)
+def _health_hist_program(rows: int, sharding):
+    """Compiled maker of the NaN-filled ``(rows, 3)`` convergence history
+    a health-armed segmented fit starts from (each call a fresh buffer:
+    the segment program donates it). Built under jit, not device_put:
+    putting a host NaN array onto a multi-process sharding trips jax's
+    cross-process value check (NaN != NaN in
+    multihost_utils.assert_equal)."""
 
     def sgd_health_hist():
         return jnp.full((rows, 3), jnp.nan, jnp.float32)
 
-    return jax.jit(sgd_health_hist, out_shardings=sharding)()
+    return jax.jit(sgd_health_hist, out_shardings=sharding)
 
 
 def _health_tag(loss_func: LossFunc, tag: Optional[str]) -> str:
@@ -849,26 +850,23 @@ class SGD:
         # onto the right shardings (a sharded-adam resume puts each
         # moment slice back on its owning replica). The opt tuple rides
         # at the END of the carry so a method="sgd" checkpoint keeps the
-        # stateless-era leaf order.
+        # stateless-era leaf order. Host arrays, placed by ONE call: a
+        # leaf made on the default device first costs a program and a
+        # transfer of its own, once a device.
         with tracer.span("sgd.init_carry"):
             _check_method(self.params)
-            opt_sharding = (NamedSharding(mesh, P(spec0)) if sharded
-                            else w_sharding)
-            opt = tuple(
-                jax.device_put(jnp.zeros(init_coeffs.shape[0], dtype),
-                               opt_sharding)
-                for _ in range(_OPT_VECTORS[self.params.method]))
-            if self.params.method == "adam":
-                opt = opt + (jax.device_put(jnp.asarray(0.0, dtype),
-                                            NamedSharding(mesh, P())),)
-            init = (
-                jax.device_put(jnp.asarray(init_coeffs, dtype), w_sharding),
-                jax.device_put(jnp.zeros((p,), jnp.int32),
-                               NamedSharding(mesh, P(spec0))),
-                jax.device_put(jnp.asarray(jnp.inf, dtype),
-                               NamedSharding(mesh, P())),
-                opt,
-            )
+            row_sharding = NamedSharding(mesh, P(spec0))
+            scalar = NamedSharding(mesh, P())
+            moments = _OPT_VECTORS[self.params.method]
+            step = self.params.method == "adam"
+            init = jax.device_put(
+                (np.asarray(init_coeffs, dtype), np.zeros((p,), np.int32),
+                 np.asarray(np.inf, dtype),
+                 (np.zeros(init_coeffs.shape[0], dtype),) * moments
+                 + (np.zeros((), dtype),) * step),
+                (w_sharding, row_sharding, scalar,
+                 (row_sharding if sharded else w_sharding,) * moments
+                 + (scalar,) * step))
             w0 = init[0]
             # per-replica update-state accounting (benchmark provenance):
             # measured from the carry's real buffers — SGD's coefficients
@@ -936,9 +934,9 @@ class SGD:
                 # restore simply resumes the series at its epoch (earlier
                 # rows stay NaN and are sliced off by `first`)
                 hstate = {
-                    "hist": _fresh_health_hist(
-                        self.params.max_iter if health_on else 0,
-                        NamedSharding(mesh, P())),
+                    "hist": _health_hist_program(
+                        self.params.max_iter,
+                        NamedSharding(mesh, P()))() if health_on else None,
                     "fin": True, "first": None, "epoch": 0,
                 }
 
@@ -950,9 +948,8 @@ class SGD:
                     with tracer.span("sgd.launch"):
                         out = seg_prog(
                             xs, ys, ws, coeffs, offsets, opt,
-                            jnp.int32(epoch0), jnp.int32(limit),
-                            hstate["hist"],
-                            jnp.asarray(bool(hstate["fin"])))
+                            np.int32(epoch0), np.int32(limit),
+                            hstate["hist"], np.bool_(hstate["fin"]))
                     if fused:
                         # ONE stacked [epoch, stop, fin] transfer per
                         # boundary instead of three scalar fetches
@@ -981,7 +978,7 @@ class SGD:
                     with tracer.span("sgd.launch"):
                         out = seg_prog(
                             xs, ys, ws, coeffs, offsets, opt,
-                            jnp.int32(epoch0), jnp.int32(limit))
+                            np.int32(epoch0), np.int32(limit))
                     if fused:
                         coeffs, offsets, opt, mean_loss, boundary = out
                     else:
@@ -1005,8 +1002,8 @@ class SGD:
             out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
             with tracer.span("sgd.health"):
                 _finish_fit_health(
-                    algo, health_on, hstate["hist"] if health_on else None,
-                    hstate["fin"], hstate["epoch"], mean_loss, out,
+                    algo, health_on, hstate["hist"], hstate["fin"],
+                    hstate["epoch"], mean_loss, out,
                     epoch0=hstate["first"] or 0)
             return out, mean_loss
 
@@ -1038,7 +1035,7 @@ class SGD:
             final = iterate_bounded(
                 init, body, max_iter=self.params.max_iter,
                 terminate=lambda carry, epoch: carry[2] < self.params.tol,
-                config=config, listeners=listeners)
+                config=config, listeners=listeners, jit_round=False)
         coeffs, _, mean_loss, _ = final
         self.last_execution_path = "host-rounds"
         out, mean_loss = self._fetch_result(coeffs, d, mean_loss)

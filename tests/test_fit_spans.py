@@ -305,10 +305,14 @@ def test_programs_lower_under_stable_names_with_the_round_scoped(
 
 def test_the_small_programs_of_a_fit_are_named(table, tmp_path, monkeypatch):
     """``ones_rows`` and ``sgd_health_hist`` are what the device trace
-    showed as ``jit_make`` and ``jit__unknown``."""
+    showed as ``jit_make`` and ``jit__unknown``. The history exists with
+    health armed alone, and its program is built once a process."""
+    from flink_ml_tpu.observability import health
     from flink_ml_tpu.parallel import collective
 
     monkeypatch.setattr(opt_mod, "_UNROLL_MAX_ROUNDS", 0)
+    monkeypatch.setenv(health.HEALTH_ENV, "1")
+    opt_mod._health_hist_program.cache_clear()
     seen = []
     real_jit = jax.jit
     monkeypatch.setattr(
