@@ -29,7 +29,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from flink_ml_tpu.api.stage import Estimator, Model
 from flink_ml_tpu.common.table import Table, as_dense_vector_column
@@ -53,6 +53,7 @@ from flink_ml_tpu.params.shared import (
     HasSeed,
 )
 from flink_ml_tpu.models.common import IterationRuntimeMixin
+from flink_ml_tpu.observability.tracing import tracer
 from flink_ml_tpu.utils import io as rw
 
 
@@ -86,6 +87,13 @@ def _build_assign_program(mesh, measure_name: str, use_kernel: bool = False):
     return mr.map_rows(assign, mesh, n_extra=1)
 
 
+def _local_valid_count(axes, local_n: int, n_valid):
+    """How many of this shard's ``local_n`` rows are real: the shard holds
+    the global rows ``[shard * local_n, (shard + 1) * local_n)`` and the
+    rows from ``n_valid`` on are ``shard_batch``'s zero padding."""
+    return jnp.clip(n_valid - mr.shard_index(axes) * local_n, 0, local_n)
+
+
 def _lloyd_round_math(measure, axes, partials_fn=None,
                       sharded: bool = False):
     """The per-shard math of ONE Lloyd round — shared verbatim by the
@@ -94,15 +102,17 @@ def _lloyd_round_math(measure, axes, partials_fn=None,
     a ``mapreduce.map_shards`` body over the mesh's data axes (flat or
     dcn-hybrid).
 
-    ``partials_fn(xl, vl, centroids) -> (k, d+1)`` overrides how the
-    local [weighted sums | counts] partials are computed (the fused
-    pallas kernel); the cross-shard reduction and the empty-cluster-
-    preserving renormalization stay shared either way. Caveat scoping
-    the identity claim: the kernel's csq − 2·x·cᵀ assignment can differ
-    from ``measure.pairwise`` in float rounding for near-tie points, so
-    a kernel-partialed fit matches the XLA programs up to tie-breaks
-    (the same asymmetry the predict path accepts for ``assign_nearest``)
-    — modes sharing ``partials_fn=None`` remain bit-identical.
+    ``partials_fn(xl, nl, centroids) -> (k, d+1)`` overrides how the
+    local [sums | counts] partials over the shard's first ``nl`` rows are
+    computed (the fused pallas kernel); the cross-shard reduction and the
+    empty-cluster-preserving renormalization stay shared either way. Both
+    forms multiply in float32 (``HIGHEST``). Caveat scoping the identity
+    claim: the kernel's csq − 2·c·xᵀ assignment and its tile-by-tile sums
+    differ from ``measure.pairwise`` and the one ``one_hot.T @ x`` in
+    float32 rounding, so a kernel-partialed fit matches the XLA programs
+    up to near-tie rows (the same asymmetry the predict path accepts for
+    ``assign_nearest``) — modes sharing ``partials_fn=None`` remain
+    bit-identical.
 
     With ``sharded`` (update_sharding.py) the centroid update is
     cross-replica sharded: the (k, d+1) partials reduce-scatter over
@@ -111,13 +121,17 @@ def _lloyd_round_math(measure, axes, partials_fn=None,
     the fresh centroids all-gather. Per-replica update FLOPs scale
     1/N; the carry stays (k, d), so every caller is unchanged."""
 
-    def local_partials(xl, vl, centroids):
+    def local_partials(xl, nl, centroids):
         k = centroids.shape[0]
+        # the validity mask from the scalar and an iota: XLA fuses it
+        # into the one-hot, no (n,) operand
+        vl = (jnp.arange(xl.shape[0]) < nl).astype(xl.dtype)
         dists = measure.pairwise(xl, centroids)
         one_hot = jax.nn.one_hot(jnp.argmin(dists, axis=1), k,
                                  dtype=xl.dtype) * vl[:, None]
         return jnp.concatenate(
-            [one_hot.T @ xl, jnp.sum(one_hot, axis=0)[:, None]], axis=1)
+            [jnp.dot(one_hot.T, xl, precision=jax.lax.Precision.HIGHEST),
+             jnp.sum(one_hot, axis=0)[:, None]], axis=1)
 
     def renormalize(sums, counts, centroids):
         # ref CentroidsUpdateAccumulator; empty clusters keep position
@@ -125,8 +139,8 @@ def _lloyd_round_math(measure, axes, partials_fn=None,
             counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1),
             centroids)
 
-    def round_step(xl, vl, centroids):
-        packed = (partials_fn or local_partials)(xl, vl, centroids)
+    def round_step(xl, nl, centroids):
+        packed = (partials_fn or local_partials)(xl, nl, centroids)
         if sharded:
             k = centroids.shape[0]
             kp = _upd.padded_len(k, mr.shard_count(axes))
@@ -158,8 +172,9 @@ def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
     round_step, one builder), but XLA may pipeline across rounds. With
     ``use_kernel`` (TPU + euclidean) the per-shard partials come from the
     fused pallas assign+accumulate kernel: each round reads the shard
-    once instead of once per sub-op; the shard is zero-weight-padded to
-    the kernel tile ONCE, outside the rounds.
+    once instead of once per sub-op, where it lies: the kernel masks the
+    ragged last tile itself, so the program keeps no copy of the shard
+    and its temporaries do not grow with ``n``.
 
     Signature: ``fit(xs, n_valid, c0, counts0) -> (centroids, counts)``
     (``(..., shifts)`` with health). The ``(c0, counts0)`` carry is
@@ -185,19 +200,13 @@ def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
         DistanceMeasure.get_instance(measure_name), axes, partials_fn,
         sharded=sharded)
 
-    def per_shard(xl, n_valid, c0, counts0):
-        vl = mr.local_valid_mask(axes, xl.shape[0], n_valid, xl.dtype)
-        if use_kernel:
-            from flink_ml_tpu.ops.pallas_kernels import TILE_N
-            pad = (-xl.shape[0]) % TILE_N
-            if pad:  # once per fit, not per round (loop-invariant)
-                xl = jnp.pad(xl, ((0, pad), (0, 0)))
-                vl = jnp.pad(vl, (0, pad))
+    def lloyd_fit(xl, n_valid, c0, counts0):
+        nl = _local_valid_count(axes, xl.shape[0], n_valid)
         centroids, counts = c0, counts0
         shifts = jnp.zeros((max_iter if health else 0,), jnp.float32)
         if unroll:
             for epoch in range(max_iter):
-                new_centroids, counts = round_step(xl, vl, centroids)
+                new_centroids, counts = round_step(xl, nl, centroids)
                 if health:
                     shift = jnp.sqrt(jnp.sum(jnp.square(
                         new_centroids - centroids))).astype(jnp.float32)
@@ -210,7 +219,7 @@ def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
 
             def step(state):
                 centroids, counts, epoch, shifts = state
-                new_centroids, counts = round_step(xl, vl, centroids)
+                new_centroids, counts = round_step(xl, nl, centroids)
                 if health:
                     shift = jnp.sqrt(jnp.sum(jnp.square(
                         new_centroids - centroids))).astype(jnp.float32)
@@ -224,11 +233,39 @@ def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
                 else (centroids, counts))
 
     return mr.map_shards(
-        per_shard, mesh,
+        lloyd_fit, mesh,
         in_specs=(P(spec0, None), P(), P(), P()),
         out_specs=((P(), P(), P()) if health else (P(), P())),
         donate_argnums=(2, 3),
         name="kmeans.lloyd" if sharded else None)
+
+
+@functools.lru_cache(maxsize=32)
+def _build_init_rows_program(mesh, k: int):
+    """``rows(xs, index) -> (k, d)`` replicated: the chosen rows of the
+    row-sharded column, each taken by the shard that holds it and summed
+    over the shards (the others add zeros: exact). One cached program a
+    mesh and ``k`` in place of a fancy index traced in every fit; its
+    output is a fresh buffer, so the fit program may take it as its
+    donated carry. The rows are ``k`` dynamic slices, not a gather: a
+    resident ``(n, 100)`` float32 column lies column-major on the TPU, a
+    gather wants it row-major, and XLA then copies the whole column first
+    (6.1 GB and 17 ms a fit at 12M rows; a ``lax.map`` over the indices
+    does the same)."""
+    axes = data_axes(mesh)
+
+    def lloyd_init_rows(xl, index):
+        local_n = xl.shape[0]
+        local = index - mr.shard_index(axes) * local_n
+        mine = (local >= 0) & (local < local_n)
+        rows = jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(xl, local[j], 1, axis=0)
+             for j in range(k)], axis=0)  # (an index off the shard clamps)
+        return mr.reduce_sum(jnp.where(mine[:, None], rows, 0.0), axes)
+
+    return mr.map_shards(
+        lloyd_init_rows, mesh,
+        in_specs=(P(data_pspec(mesh), None), P()), out_specs=P())
 
 
 #: fits with at most this many rounds compile fully unrolled — Lloyd's has
@@ -242,18 +279,44 @@ _UNROLL_MAX_ROUNDS = int(os.environ.get(
 
 @functools.lru_cache(maxsize=32)
 def _build_lloyd_round_program(mesh, measure_name: str,
-                               sharded: bool = False,
-                               use_kernel: bool = False):
-    """ONE Lloyd round — the building block of the checkpointable host
-    loop; wraps the same _lloyd_round_math as the all-device program
-    (iterate_bounded jits the round, hence ``jit=False``). With
-    ``use_kernel`` (TPU + euclidean, segment-mode fits) the per-shard
-    partials come from the fused pallas assign+accumulate kernel —
-    lloyd_partial_sums pads the shard internally, and inside the
-    segmented while_loop the pad of the loop-invariant shard hoists out
-    of the rounds."""
+                               sharded: bool = False):
+    """ONE Lloyd round, compiled once and found again in every fit — the
+    building block of the host-driven rounds (``iterate_bounded`` calls
+    it as it is, ``jit_round=False``); wraps the same _lloyd_round_math
+    as the all-device program. Never the kernel: per-round dispatch is
+    host-bound already, and listeners may hold the carry between rounds,
+    so nothing is donated either."""
     axes = data_axes(mesh)
-    spec0 = data_pspec(mesh)
+    round_step = _lloyd_round_math(
+        DistanceMeasure.get_instance(measure_name), axes, sharded=sharded)
+
+    def lloyd_round(xl, n_valid, centroids):
+        return round_step(
+            xl, _local_valid_count(axes, xl.shape[0], n_valid), centroids)
+
+    return mr.map_shards(
+        lloyd_round, mesh,
+        in_specs=(P(data_pspec(mesh), None), P(), P()),
+        out_specs=(P(), P()))
+
+
+@functools.lru_cache(maxsize=32)
+def _build_lloyd_segment_program(mesh, measure_name: str,
+                                 sharded: bool = False,
+                                 use_kernel: bool = False,
+                                 fused: bool = True):
+    """The rounds ``[epoch0, limit)`` as one compiled while_loop — the
+    checkpointed fit's segment, the same program for every segment of
+    every fit (the bounds are arguments). ``seg(xs, n_valid, centroids,
+    counts, epoch0, limit) -> (centroids, counts, boundary)``; the
+    ``(centroids, counts)`` carry is DONATED. ``boundary`` is what
+    ``iteration.read_boundary`` fetches: ``[epoch, stop]`` stacked into
+    one int32 vector when ``fused`` (one transfer a boundary), the two
+    scalars otherwise; Lloyd has no early stop, so ``stop`` is 0. With
+    ``use_kernel`` (TPU + euclidean) the partials come from the fused
+    pallas assign+accumulate kernel, which reads the shard where it lies
+    (no pad, no copy)."""
+    axes = data_axes(mesh)
     partials_fn = None
     if use_kernel:
         from flink_ml_tpu.ops.pallas_kernels import lloyd_partial_sums
@@ -262,14 +325,25 @@ def _build_lloyd_round_program(mesh, measure_name: str,
         DistanceMeasure.get_instance(measure_name), axes, partials_fn,
         sharded=sharded)
 
-    def per_shard(xl, n_valid, centroids):
-        vl = mr.local_valid_mask(axes, xl.shape[0], n_valid, xl.dtype)
-        return round_step(xl, vl, centroids)
+    def lloyd_segment(xl, n_valid, centroids, counts, epoch0, limit):
+        nl = _local_valid_count(axes, xl.shape[0], n_valid)
+
+        def step(state):
+            centroids, _, epoch = state
+            return round_step(xl, nl, centroids) + (epoch + 1,)
+
+        centroids, counts, epoch = jax.lax.while_loop(
+            lambda state: state[2] < limit, step,
+            (centroids, counts, epoch0))
+        stop = jnp.zeros((), jnp.int32)
+        return centroids, counts, (jnp.stack([epoch, stop]) if fused
+                                   else (epoch, stop))
 
     return mr.map_shards(
-        per_shard, mesh,
-        in_specs=(P(spec0, None), P(), P()),
-        out_specs=(P(), P()), jit=False)
+        lloyd_segment, mesh,
+        in_specs=(P(data_pspec(mesh), None),) + (P(),) * 5,
+        out_specs=(P(), P(), P() if fused else (P(), P())),
+        donate_argnums=(2, 3))
 
 
 class KMeansModel(Model, KMeansModelParams):
@@ -335,24 +409,15 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
         x = table.vectors(self.features_col)
         n, dim = x.shape
         k = self.k
-
-        # init: k distinct random input points (ref selectRandomCentroids)
-        rng = np.random.default_rng(self.get_seed_or_default())
-        init = x[rng.choice(n, size=min(k, n), replace=False)].astype(np.float32)
-        if len(init) < k:  # fewer points than clusters: repeat cyclically
-            init = np.resize(init, (k, init.shape[1]))
-
         mesh = default_mesh()
         axes = data_axes(mesh)
-        # device-resident input (device datagen / upstream device stage)
-        # never leaves HBM; host input is cast+placed once
-        xs, _ = ensure_on_mesh(mesh, x, axes, jnp.float32)
-        # padded rows must not join any cluster: the validity mask is
-        # derived on-device from the scalar n (no (n,) mask transfer)
-        n_valid = jnp.int32(n)
+        repl = NamedSharding(mesh, P())
+        with tracer.span("lloyd.place_inputs"):
+            # device-resident input (device datagen / upstream device
+            # stage) never leaves HBM; host input is cast+placed once
+            xs, _ = ensure_on_mesh(mesh, x, axes, jnp.float32)
 
-        from flink_ml_tpu.observability import tracing as _tracing
-        if _tracing.tracer.enabled:
+        if tracer.enabled:
             # mesh telemetry at the fit boundary: per-shard row counts
             # (imbalance/skew) and per-shard non-finite input counts, so
             # a bad replica is identifiable before the fit consumes it
@@ -360,111 +425,123 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
             meshstats.record_shard_rows(mesh, n, axes)
             meshstats.record_input_health("KMeans", mesh, xs)
 
-        from flink_ml_tpu.iteration.iteration import (iterate_bounded,
-                                                      needs_host_loop)
+        from flink_ml_tpu.iteration.iteration import (
+            device_checkpoint_segment, iterate_bounded, needs_host_loop,
+            read_boundary, run_segmented, segment_fusion_enabled)
         from flink_ml_tpu.observability import health as _health
+        from flink_ml_tpu.ops.pallas_kernels import (
+            lloyd_kernel_fits, pallas_supported)
         health_on = _health.armed()
         # cross-replica sharded centroid update (update_sharding.py):
         # per-replica update FLOPs scale 1/N; carry shape unchanged
         sharded = _upd.enabled()
-        shifts = None
-        if not needs_host_loop(self._iteration_config,
-                               self._iteration_listeners):
-            from flink_ml_tpu.ops.pallas_kernels import (
-                lloyd_kernel_fits, pallas_supported)
-            unroll = self.max_iter <= _UNROLL_MAX_ROUNDS
-            # the kernel is chosen by the backend and the shape gate,
-            # nothing else; a Mosaic failure propagates
-            use_kernel = (self.distance_measure == "euclidean"
-                          and pallas_supported()
-                          and lloyd_kernel_fits(k, dim))
-            fit = _build_lloyd_program(
-                mesh, self.distance_measure, self.max_iter,
-                unroll=unroll, use_kernel=use_kernel,
-                health=health_on, sharded=sharded)
-            # the (c0, counts0) carry is DONATED — copy=True builds a
-            # fresh buffer even when `init` is itself a device array
-            # (device-resident features: vectors() returns the jax
-            # array, and asarray would ALIAS it); the split (centroids,
-            # counts) outputs fetch once per fit
-            out = fit(xs, n_valid, jnp.array(init, copy=True),
-                      jnp.zeros((k,), jnp.float32))
-            if health_on:
-                centroids, counts, shifts = out
-            else:
-                centroids, counts = out
-            centroids, counts = np.asarray(centroids), np.asarray(counts)
-            # benchmark provenance (runner.py executionPath)
-            self.last_execution_path = (
-                "pallas-lloyd" if use_kernel else "xla-lloyd")
-            if health_on:
-                s = np.asarray(shifts, np.float64)
-                _health.check_fit("KMeans", {"centerShift": s},
-                                  finite=bool(np.isfinite(s).all()))
-            else:
-                _health.guard_final_state("KMeans", centroids)
+        listeners = self._iteration_listeners
+        host_loop = needs_host_loop(self._iteration_config, listeners)
+        seg = device_checkpoint_segment(self._iteration_config, listeners)
+        # the kernel is chosen by the backend and the shape gate, nothing
+        # else; a Mosaic failure propagates. Segment-mode fits (compiled
+        # K-round while_loop slices) use it like the all-device path;
+        # true host rounds keep the XLA partials (per-round dispatch is
+        # already host-bound there, and listeners may inspect the carry
+        # between rounds)
+        use_kernel = ((seg > 0 or not host_loop)
+                      and self.distance_measure == "euclidean"
+                      and pallas_supported() and lloyd_kernel_fits(k, dim))
+        path = ("host-rounds" if host_loop and not seg else
+                ("pallas-lloyd" if use_kernel else "xla-lloyd")
+                + ("-segments" if seg else ""))
+
+        with tracer.span("lloyd.init", rounds=self.max_iter, k=k,
+                         path=path):
+            # init: k distinct random input points (ref
+            # selectRandomCentroids); fewer points than clusters repeat
+            # cyclically. The indices, the zero counts and the row count
+            # (padded rows must not join any cluster: the validity mask
+            # is derived on-device from the scalar n) go up as host arrays
+            # in ONE call; the rows leave the column by one cached program,
+            # into a fresh buffer that the fit may take as donated
+            rng = np.random.default_rng(self.get_seed_or_default())
+            index = np.resize(
+                rng.choice(n, size=min(k, n), replace=False), k)
+            index, counts0, n_valid = jax.device_put(
+                (index.astype(np.int32), np.zeros((k,), np.float32),
+                 np.int32(n)), repl)
+            c0 = _build_init_rows_program(mesh, k)(xs, index)
+            # per-replica update-state accounting (benchmark provenance)
+            # from the carry's real buffers, honestly full-size: the
+            # centroid carry all-gathers back to replicated every round
+            # even when the sharded update ran (only persistent sharded
+            # state like FTRL's z/n shrinks 1/N)
+            _upd.record_state_bytes("KMeans", (c0, counts0),
+                                    data_shard_count(mesh), sharded)
+
+        shifts = []  # the health-armed device fit's center-shift series
+        if not host_loop:
+            with tracer.span("lloyd.build_program"):
+                fit = _build_lloyd_program(
+                    mesh, self.distance_measure, self.max_iter,
+                    unroll=self.max_iter <= _UNROLL_MAX_ROUNDS,
+                    use_kernel=use_kernel, health=health_on,
+                    sharded=sharded)
+            with tracer.span("lloyd.launch"):
+                # the (c0, counts0) carry is DONATED: the loop updates
+                # the centroid state in place
+                centroids, counts, *shifts = fit(xs, n_valid, c0, counts0)
+        elif seg:
+            with tracer.span("lloyd.build_program"):
+                segment = _build_lloyd_segment_program(
+                    mesh, self.distance_measure, sharded=sharded,
+                    use_kernel=use_kernel, fused=segment_fusion_enabled())
+
+            def run_segment(carry, epoch0, limit):
+                centroids, counts, boundary = segment(
+                    xs, n_valid, *carry, np.int32(epoch0), np.int32(limit))
+                epoch, stop = read_boundary(boundary)
+                return (centroids, counts), int(epoch), bool(stop)
+
+            # the segments are enqueued, and their boundaries awaited, by
+            # the iteration runtime: one ``segment`` span each under this
+            # one. A segmented fit gains no health listener (that would
+            # demote it to per-round host dispatch); it keeps the cheap
+            # final-state guard
+            with tracer.span("lloyd.launch"):
+                centroids, counts = run_segmented(
+                    run_segment, (c0, counts0), self.max_iter, seg,
+                    self._iteration_config.checkpoint_manager)
         else:
-            from flink_ml_tpu.iteration.iteration import (
-                device_checkpoint_segment)
-            listeners = self._iteration_listeners
-            seg = device_checkpoint_segment(self._iteration_config,
-                                            listeners)
-            if health_on and not seg:
+            if health_on:
                 # true host-driven rounds: the center-shift series rides
-                # a listener at the epoch boundary. A segmented device
-                # fit (seg > 0) must NOT gain a listener — that would
-                # demote it to per-round host dispatch; it keeps the
-                # cheap final-state guard instead.
+                # a listener at the epoch boundary
                 listeners = tuple(listeners) + (
                     _health.ConvergenceListener.for_centroids(
-                        "KMeans", init),)
-
-            from flink_ml_tpu.ops.pallas_kernels import (
-                lloyd_kernel_fits, pallas_supported)
-            # segment-mode fits (compiled K-round while_loop slices) use
-            # the fused pallas partials like the all-device path; true
-            # host rounds keep the XLA partials (per-round dispatch is
-            # already host-bound there, and listeners may inspect the
-            # carry between rounds)
-            use_kernel = (seg > 0 and self.distance_measure == "euclidean"
-                          and pallas_supported()
-                          and lloyd_kernel_fits(k, dim))
-            round_fn = _build_lloyd_round_program(
-                mesh, self.distance_measure, sharded=sharded,
-                use_kernel=use_kernel)
-
-            def body(carry, epoch):
-                centroids, _ = carry
-                return round_fn(xs, n_valid, centroids)
-
-            from jax.sharding import NamedSharding
-            repl = NamedSharding(mesh, P())
-            # the segmented loop DONATES the carry into each compiled
-            # segment (in-place update). copy=True — device_put on an
-            # already-device `init` (device-resident features) would
-            # SHARE its buffer with the input column.
-            centroids, counts = iterate_bounded(
-                (jax.device_put(jnp.array(init, copy=True), repl),
-                 jax.device_put(jnp.zeros((k,), jnp.float32), repl)),
-                body, max_iter=self.max_iter,
-                config=self._iteration_config,
-                listeners=listeners, donate_carry=True)
-            self.last_execution_path = (
-                "pallas-lloyd-segments" if use_kernel
-                else "xla-lloyd-segments" if seg else "host-rounds")
-            if not health_on or seg:
-                _health.guard_final_state(
-                    "KMeans", np.asarray(centroids, np.float64))
-
-        # per-replica update-state accounting (benchmark provenance),
-        # from the fit's REAL state buffers — the fetched packed output
-        # on the compiled path, the replicated device carry on the
-        # host-rounds path — honestly full-size: the centroid carry
-        # all-gathers back to replicated every round even when the
-        # sharded update ran (only persistent sharded state like FTRL's
-        # z/n shrinks 1/N)
-        _upd.record_state_bytes("KMeans", (centroids, counts),
-                                data_shard_count(mesh), sharded)
-        model = KMeansModel(centroids=np.asarray(centroids, np.float64),
-                            weights=np.asarray(counts, np.float64))
-        return self.copy_params_to(model)
+                        "KMeans", np.asarray(c0)),)
+            with tracer.span("lloyd.build_program"):
+                one_round = _build_lloyd_round_program(
+                    mesh, self.distance_measure, sharded=sharded)
+            # one ``epoch`` span a round under this one
+            with tracer.span("lloyd.launch"):
+                centroids, counts = iterate_bounded(
+                    (c0, counts0),
+                    lambda carry, epoch: one_round(xs, n_valid, carry[0]),
+                    max_iter=self.max_iter, config=self._iteration_config,
+                    listeners=listeners, jit_round=False)
+        with tracer.span("lloyd.fetch"):
+            # the blocking reads, where the wait for the rounds falls:
+            # counted, and under the collective deadline where one is armed
+            centroids, counts, *shifts = read_boundary(
+                (centroids, counts, *shifts))
+        # benchmark provenance (runner.py executionPath)
+        self.last_execution_path = path
+        with tracer.span("lloyd.health"):
+            if shifts:
+                series = np.asarray(shifts[0], np.float64)
+                _health.check_fit("KMeans", {"centerShift": series},
+                                  finite=bool(np.isfinite(series).all()))
+            elif seg or not health_on:
+                # (health-armed host rounds: the listener has checked)
+                _health.guard_final_state("KMeans", centroids)
+        with tracer.span("fit.model"):
+            model = KMeansModel(
+                centroids=np.asarray(centroids, np.float64),
+                weights=np.asarray(counts, np.float64))
+            return self.copy_params_to(model)

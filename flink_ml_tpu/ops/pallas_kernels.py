@@ -20,53 +20,90 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_N = 1024
+#: row tiles the Lloyd and assign kernels may take, widest first: a fit
+#: at 12M x 100, k 10 on a v5e took 144.7 / 112.8 / 98.2 / 95.3 ms at
+#: 512 / 1024 / 2048 / 4096 rows a tile (PERF.md section 6, PR 29)
+TILES_N = (2048, 1024, 512, 256)
+TILE_N = TILES_N[0]
+
+#: float32 matmuls inside the kernels: at Mosaic's default an MXU product
+#: rounds its float32 operands to bfloat16, and in ``csq - 2 c.x`` that
+#: error (0.1 on terms of 25-35) is larger than the gap between a row's
+#: two nearest centroids
+_F32 = jax.lax.Precision.HIGHEST
 
 
-def _assign_kernel(x_ref, c_ref, csq_ref, out_ref):
-    x = x_ref[:]                       # (tile_n, d)
-    c = c_ref[:]                       # (k, d)
-    # ‖x−c‖² up to the per-point constant ‖x‖² (irrelevant to the argmin)
-    cross = jnp.dot(x, c.T, preferred_element_type=jnp.float32)
-    d2 = csq_ref[:][None, :] - 2.0 * cross
-    out_ref[:, 0] = jnp.argmin(d2, axis=1).astype(jnp.int32)
+def _nearest(xt, c):
+    """``(idx, first)``: ``idx`` the ``(k, tile)`` centroid index of every
+    entry and ``first`` the ``(1, tile)`` index of each row's nearest
+    centroid, the first smallest on ties. ``xt`` is ``(d, tile)`` — rows
+    in lanes, so every per-row quantity is lane-dense."""
+    k = c.shape[0]
+    # ‖x−c‖² up to the per-row constant ‖x‖² (irrelevant to the argmin)
+    d2 = jnp.sum(c * c, axis=1, keepdims=True) - 2.0 * jnp.dot(
+        c, xt, preferred_element_type=jnp.float32, precision=_F32)
+    idx = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    first = jnp.min(jnp.where(d2 == jnp.min(d2, axis=0, keepdims=True),
+                              idx, k), axis=0, keepdims=True)
+    return idx, first
+
+
+def _assign_kernel(xt_ref, c_ref, out_ref):
+    # what the ragged last tile reads past the array gives indices past
+    # the output, which are never written back
+    out_ref[:] = _nearest(xt_ref[:], c_ref[:])[1]
+
+
+def _row_tiles(x, centroids, out_specs, prefetch: int = 0):
+    """``(the (d, n) view the kernels read, grid spec)`` over row tiles of
+    the widest width the shapes allow; ``out_specs(tile)`` gives the
+    outputs' block specs. A resident ``(n, d)`` float32 table
+    lies column-major tiled on the TPU (d = 100: 416 B a row), so inside a
+    program the transpose is a relabelling and every per-row quantity is
+    lane-dense; ``(tile, d)`` row blocks instead cost a relayout copy of
+    the whole table in every fit (6.1 GB at 12M rows). The last tile is
+    ragged: nothing is padded."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = x.shape
+    k = centroids.shape[0]
+    tile = lloyd_tile(k, d) or TILES_N[-1]
+    return x.T, pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=prefetch,
+        grid=(pl.cdiv(n, tile),),
+        in_specs=[
+            pl.BlockSpec((d, tile), lambda i, *s: (0, i)),
+            pl.BlockSpec((k, d), lambda i, *s: (0, 0)),
+        ],
+        out_specs=out_specs(tile))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _assign_padded(x, centroids, interpret=False):
-    n, d = x.shape
-    k = centroids.shape[0]
-    csq = jnp.sum(centroids * centroids, axis=1)
-    grid = (n // TILE_N,)
+def _assign_tiles(x, centroids, interpret=False):
+    xt, grid_spec = _row_tiles(
+        x, centroids,
+        lambda tile: pl.BlockSpec((1, tile), lambda i: (0, i)))
     return pl.pallas_call(
         _assign_kernel,
         name="assign_nearest",
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_N, d), lambda i: (i, 0)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((TILE_N, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, x.shape[0]), jnp.int32),
+        grid_spec=grid_spec,
         interpret=interpret,
-    )(x, centroids, csq)
+    )(xt, centroids)[0]
 
 
 def assign_nearest(x, centroids, interpret: bool = False):
-    """Nearest-centroid index per row of x — fused distance+argmin.
+    """Nearest-centroid index per row of x — fused distance+argmin, in
+    float32, the first smallest index on ties.
 
-    x: (n, d) float32; centroids: (k, d) float32 → (n,) int32.
-    Pads n up to the tile size; callers slice with the true n.
+    x: (n, d) float32; centroids: (k, d) float32 → (n,) int32. Any n: the
+    last tile is ragged, nothing is padded.
     """
     x = jnp.asarray(x, jnp.float32)
     centroids = jnp.asarray(centroids, jnp.float32)
-    n = x.shape[0]
-    pad = (-n) % TILE_N
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    out = _assign_padded(x, centroids, interpret=interpret)
-    return out[:n, 0]
+    if x.shape[0] == 0:
+        return jnp.zeros((0,), jnp.int32)
+    return _assign_tiles(x, centroids, interpret=interpret)
 
 
 def pallas_supported() -> bool:
@@ -79,90 +116,103 @@ def pallas_supported() -> bool:
 
 # -- fused Lloyd round: assign + accumulate (KMeans fit) ---------------------
 
-#: VMEM the kernel's working set may claim: double-buffered (TILE_N, d)
-#: x tiles, the (TILE_N, k) distance/one-hot blocks, the (k, d) centroids
-#: and the (k, d+1) accumulator that persists across grid steps
-LLOYD_VMEM_BUDGET_BYTES = 8 << 20
+#: VMEM the kernel's working set may claim, under Mosaic's 16 MiB scoped
+#: limit: the double-buffered (d, tile) x tiles, the masked tile and
+#: its three bfloat16 parts (a float32 product on the MXU is six passes
+#: over operands split in three), the (k, tile) distance, index and
+#: one-hot blocks with their parts, and the (k, d) + (k, 128)
+#: accumulators that persist across grid steps. The compiler's own count
+#: at d 100, k 10 and a tile of 8192: 20.1 MB, against 21.0 MB by this sum.
+LLOYD_VMEM_BUDGET_BYTES = 12 << 20
+
+
+def lloyd_tile(k: int, d: int) -> int:
+    """The widest row tile whose working set fits the VMEM budget for
+    these shapes, 0 when none does (callers run the XLA round) — the shape
+    gate of the Lloyd and assign kernels."""
+    for tile in TILES_N:
+        working = (6 * tile * d + 8 * tile * k + k * d
+                   + 2 * k * (d + 128)) * 4
+        if working <= LLOYD_VMEM_BUDGET_BYTES:
+            return tile
+    return 0
 
 
 def lloyd_kernel_fits(k: int, d: int) -> bool:
-    """True when the fused Lloyd kernel's working set fits the VMEM
-    budget for these shapes — the gate kmeans.fit applies."""
-    working = (2 * TILE_N * d + 3 * TILE_N * k + k * d
-               + 2 * k * (d + 1)) * 4
-    return working <= LLOYD_VMEM_BUDGET_BYTES
+    """True when the fused Lloyd kernel has a tile for these shapes —
+    the gate kmeans.fit applies."""
+    return lloyd_tile(k, d) > 0
 
 
-def _lloyd_accum_kernel(x_ref, v_ref, c_ref, csq_ref, out_ref):
+def _lloyd_accum_kernel(nv_ref, xt_ref, c_ref, sums_ref, counts_ref):
     """One row tile of a Lloyd round, entirely in VMEM: nearest-centroid
-    assignment and the weighted (sums, counts) accumulation read the tile
-    ONCE — the XLA round reads the shard for the pairwise matmul, again
-    for the row norms, and a third time for the one_hot.T @ x sums. The
-    TPU grid iterates sequentially per core, so out_ref accumulates
-    across tiles (init at step 0)."""
+    assignment and the (sums, counts) accumulation read the tile ONCE —
+    the XLA round reads the shard for the pairwise matmul, again for the
+    row norms, and a third time for the one_hot.T @ x sums. The TPU grid
+    iterates sequentially per core, so the outputs accumulate across
+    tiles (init at step 0). Counts accumulate per lane, ``(k, 128)``: the
+    caller adds the lanes up."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        sums_ref[:] = jnp.zeros_like(sums_ref)
+        counts_ref[:] = jnp.zeros_like(counts_ref)
 
-    x = x_ref[:]                       # (tile_n, d)
-    v = v_ref[:]                       # (tile_n, 1) validity weight
-    c = c_ref[:]                       # (k, d)
-    cross = jnp.dot(x, c.T, preferred_element_type=jnp.float32)
-    # ‖x−c‖² up to the per-point constant ‖x‖² (irrelevant to the argmin)
-    d2 = csq_ref[:][None, :] - 2.0 * cross
-    a = jnp.argmin(d2, axis=1)
-    k = c.shape[0]
-    one_hot = (a[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, k), 1)).astype(jnp.float32) * v
-    sums = jnp.dot(one_hot.T, x, preferred_element_type=jnp.float32)
-    counts = jnp.sum(one_hot, axis=0)
-    out_ref[:] += jnp.concatenate([sums, counts[:, None]], axis=1)
+    # the tile's rows whose index is under n_valid: what the ragged last
+    # tile reads past the array is masked here, never padded in HBM
+    tile = xt_ref.shape[1]
+    valid = i * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (1, tile), 1) < nv_ref[0]
+    xt = jnp.where(valid, xt_ref[:], 0.0)          # (d, tile)
+    idx, first = _nearest(xt, c_ref[:])
+    one_hot = ((idx == first) & valid).astype(jnp.float32)   # (k, tile)
+    sums_ref[:] += jax.lax.dot_general(
+        one_hot, xt, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_F32)
+    counts_ref[:] += sum(one_hot[:, lane:lane + 128]
+                         for lane in range(0, tile, 128))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _lloyd_padded(x, v, centroids, interpret=False):
-    n, d = x.shape
-    k = centroids.shape[0]
-    csq = jnp.sum(centroids * centroids, axis=1)
-    return pl.pallas_call(
+def _lloyd_tiles(x, n_valid, centroids, interpret=False):
+    k, d = centroids.shape
+    xt, grid_spec = _row_tiles(
+        x, centroids,
+        lambda tile: (pl.BlockSpec((k, d), lambda i, s: (0, 0)),
+                      pl.BlockSpec((k, 128), lambda i, s: (0, 0))),
+        prefetch=1)
+    sums, counts = pl.pallas_call(
         _lloyd_accum_kernel,
         name="lloyd_partial_sums",
-        out_shape=jax.ShapeDtypeStruct((k, d + 1), jnp.float32),
-        grid=(n // TILE_N,),
-        in_specs=[
-            pl.BlockSpec((TILE_N, d), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_N, 1), lambda i: (i, 0)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((k, d + 1), lambda i: (0, 0)),
+        out_shape=(jax.ShapeDtypeStruct((k, d), jnp.float32),
+                   jax.ShapeDtypeStruct((k, 128), jnp.float32)),
+        grid_spec=grid_spec,
         interpret=interpret,
-    )(x, v, centroids, csq)
+    )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), xt, centroids)
+    return jnp.concatenate(
+        [sums, jnp.sum(counts, axis=1, keepdims=True)], axis=1)
 
 
-def lloyd_partial_sums(x, v, centroids, interpret: bool = False):
-    """Per-shard Lloyd partials — fused assign+accumulate, one pass over x.
+def lloyd_partial_sums(x, n_valid, centroids, interpret: bool = False):
+    """Per-shard Lloyd partials — fused assign+accumulate, one pass over
+    x, in float32.
 
-    x: (n, d) float32; v: (n,) float32 validity/weight (0 for padding);
-    centroids: (k, d) float32 → (k, d+1) float32 = [weighted sums | counts].
-    Pads n up to the tile size with zero-weight rows; euclidean only
-    (assignment by the same csq − 2·x·cᵀ argmin as ``assign_nearest``).
-    Callers psum the result across data shards and renormalize.
+    x: (n, d) float32; n_valid: scalar, rows ``[0, n_valid)`` count and the
+    rest (a shard's zero padding) do not; centroids: (k, d) float32 →
+    (k, d+1) float32 = [sums | counts]. Any n: the mask comes from
+    ``n_valid`` and an iota inside the kernel and the last tile is ragged,
+    so nothing is padded or copied. Euclidean only (assignment by the same
+    csq − 2·c·xᵀ argmin as ``assign_nearest``). Callers psum the result
+    across data shards and renormalize.
     """
     x = jnp.asarray(x, jnp.float32)
-    v = jnp.asarray(v, jnp.float32)
     centroids = jnp.asarray(centroids, jnp.float32)
-    n = x.shape[0]
-    if n == 0:  # empty grid would skip the step-0 init and return garbage
+    if x.shape[0] == 0:  # an empty grid would skip the step-0 init
         k, d = centroids.shape
         return jnp.zeros((k, d + 1), jnp.float32)
-    pad = (-n) % TILE_N
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        v = jnp.pad(v, (0, pad))
-    return _lloyd_padded(x, v[:, None], centroids, interpret=interpret)
+    return _lloyd_tiles(x, jnp.asarray(n_valid, jnp.int32), centroids,
+                        interpret=interpret)
 
 
 # -- fused SGD batch terms (one pass over the minibatch window) --------------

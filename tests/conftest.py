@@ -58,6 +58,7 @@ def interpreted_kernels(monkeypatch):
     def clear():
         om._build_sgd_unrolled_program.cache_clear()
         km._build_lloyd_program.cache_clear()
+        km._build_lloyd_segment_program.cache_clear()
         km._build_assign_program.cache_clear()
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)

@@ -524,7 +524,10 @@ def test_kmeans_supervised_traced_fit_golden(tmp_path, monkeypatch, rng):
     epochs = [s for s in spans if s["name"] == "epoch"]
     saves = [s for s in spans if s["name"] == "checkpoint.save"]
     assert epochs and saves
-    assert all(e["parent"] == fit["id"] for e in epochs)
+    # the rounds are driven under the fit's ``lloyd.launch`` span (PR 29)
+    launches = {e["parent"] for e in epochs}
+    assert all(by_id[l]["name"] == "lloyd.launch"
+               and by_id[l]["parent"] == fit["id"] for l in launches)
     assert all(by_id[s["parent"]]["name"] == "epoch" for s in saves)
     assert any(ev["name"] == "supervisor.restart"
                for s in spans for ev in s["events"])

@@ -155,12 +155,14 @@ class _NoSpan:
 class Watch:
     """What a fit builds and places while armed: the ``jax.jit`` objects
     it makes, the compile requests it issues (the ``jax.monitoring``
-    channel ``benchmarks/harness/compiles.py`` counts), and each
-    ``jax.device_put`` call with the optimizer's span it fell under."""
+    channel ``benchmarks/harness/compiles.py`` counts; ``events`` may name
+    more channels), and each ``jax.device_put`` call with the span of
+    ``module``'s tracer it fell under."""
 
     REQUEST = "/jax/core/compile/backend_compile_duration"
 
-    def __init__(self, monkeypatch):
+    def __init__(self, monkeypatch, module=opt_mod, events=(REQUEST,)):
+        self.events = events
         self.jits, self.requests, self.puts = [], 0, []
         self.armed = False
         self._stack = []
@@ -180,6 +182,8 @@ class Watch:
             return out
 
         class Spans:
+            enabled = False
+
             @contextlib.contextmanager
             def span(self, name, **attrs):
                 watch._stack.append(name)
@@ -190,12 +194,12 @@ class Watch:
 
         monkeypatch.setattr(jax, "jit", jit)
         monkeypatch.setattr(jax, "device_put", device_put)
-        monkeypatch.setattr(opt_mod, "tracer", Spans())
+        monkeypatch.setattr(module, "tracer", Spans())
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(self._duration)
 
     def _duration(self, event, duration_secs, **kw):
-        if self.armed and event == self.REQUEST:
+        if self.armed and event in self.events:
             self.requests += 1
 
     @contextlib.contextmanager

@@ -102,9 +102,11 @@ def test_lloyd_partial_sums_matches_xla(rng):
     centers = rng.normal(size=(k, d)).astype(np.float32) * 10
     assign = rng.integers(0, k, n)
     x = (centers[assign] + rng.normal(size=(n, d)) * 0.1).astype(np.float32)
-    v = (rng.random(n) > 0.1).astype(np.float32)  # some zero-weight rows
+    n_valid = 270  # the rows from here on are a shard's padding
+    v = (np.arange(n) < n_valid).astype(np.float32)
 
-    got = np.asarray(lloyd_partial_sums(x, v, centers, interpret=True))
+    got = np.asarray(lloyd_partial_sums(x, n_valid, centers,
+                                        interpret=True))
 
     d2 = ((x[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
     a = d2.argmin(1)
@@ -113,20 +115,18 @@ def test_lloyd_partial_sums_matches_xla(rng):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
-def test_lloyd_partial_sums_pads_zero_weight(rng):
-    """Rows added by tile padding must contribute nothing."""
+def test_lloyd_partial_sums_masks_the_ragged_tile(rng):
+    """What the ragged last tile reads past the array, and the rows from
+    ``n_valid`` on, must contribute nothing — whatever lies there."""
     from flink_ml_tpu.ops.pallas_kernels import TILE_N, lloyd_partial_sums
 
     k, d = 3, 4
     c = rng.normal(size=(k, d)).astype(np.float32)
     x = rng.normal(size=(10, d)).astype(np.float32)  # far from TILE_N
-    v = np.ones(10, np.float32)
-    got = np.asarray(lloyd_partial_sums(x, v, c, interpret=True))
-    xp = np.zeros((TILE_N, d), np.float32)
+    got = np.asarray(lloyd_partial_sums(x, 10, c, interpret=True))
+    xp = np.full((TILE_N + 7, d), np.nan, np.float32)  # NaN past the rows
     xp[:10] = x
-    vp = np.zeros(TILE_N, np.float32)
-    vp[:10] = 1.0
-    got_pre = np.asarray(lloyd_partial_sums(xp, vp, c, interpret=True))
+    got_pre = np.asarray(lloyd_partial_sums(xp, 10, c, interpret=True))
     np.testing.assert_allclose(got, got_pre, rtol=1e-5)
     assert got[:, -1].sum() == 10.0
 
@@ -151,21 +151,19 @@ def test_lloyd_fit_program_with_kernel_partials(rng):
 
     partials = km._lloyd_round_math(
         None, data_axes(mesh),
-        lambda xl, vl, c: pk.lloyd_partial_sums(xl, vl, c, interpret=True))
+        lambda xl, nl, c: pk.lloyd_partial_sums(xl, nl, c, interpret=True))
     # build a one-off interpret-mode fit mirroring _build_lloyd_program
     import jax
     from jax.sharding import PartitionSpec as P
-    from flink_ml_tpu.parallel.collective import local_valid_mask
     from flink_ml_tpu.parallel.mesh import data_pspec
 
     spec0 = data_pspec(mesh)
 
     def per_shard(xl, n_valid, c0):
-        vl = local_valid_mask(data_axes(mesh), xl.shape[0], n_valid,
-                              xl.dtype)
+        nl = km._local_valid_count(data_axes(mesh), xl.shape[0], n_valid)
         centroids = c0
         for _ in range(3):
-            centroids, counts = partials(xl, vl, centroids)
+            centroids, counts = partials(xl, nl, centroids)
         return jnp.concatenate([centroids, counts[:, None]], axis=1)
 
     from flink_ml_tpu.parallel.shardmap import shard_map
@@ -189,8 +187,7 @@ def test_lloyd_partial_sums_empty_input(rng):
 
     c = rng.normal(size=(3, 4)).astype(np.float32)
     got = np.asarray(lloyd_partial_sums(
-        np.zeros((0, 4), np.float32), np.zeros(0, np.float32), c,
-        interpret=True))
+        np.zeros((0, 4), np.float32), 0, c, interpret=True))
     np.testing.assert_array_equal(got, np.zeros((3, 5), np.float32))
 
 
