@@ -106,13 +106,14 @@ def _lloyd_round_math(measure, axes, partials_fn=None,
     local [sums | counts] partials over the shard's first ``nl`` rows are
     computed (the fused pallas kernel); the cross-shard reduction and the
     empty-cluster-preserving renormalization stay shared either way. Both
-    forms multiply in float32 (``HIGHEST``). Caveat scoping the identity
-    claim: the kernel's csq − 2·c·xᵀ assignment and its tile-by-tile sums
-    differ from ``measure.pairwise`` and the one ``one_hot.T @ x`` in
-    float32 rounding, so a kernel-partialed fit matches the XLA programs
-    up to near-tie rows (the same asymmetry the predict path accepts for
-    ``assign_nearest``) — modes sharing ``partials_fn=None`` remain
-    bit-identical.
+    forms multiply in float32 (here at ``HIGHEST``; the kernel adds the
+    same part-products itself, ``pallas_kernels._split3``). Caveat scoping
+    the identity claim: the kernel's csq − 2·c·xᵀ assignment and its
+    tile-by-tile sums differ from ``measure.pairwise`` and the one
+    ``one_hot.T @ x`` in float32 rounding, so a kernel-partialed fit
+    matches the XLA programs up to near-tie rows (the same asymmetry the
+    predict path accepts for ``assign_nearest``) — modes sharing
+    ``partials_fn=None`` remain bit-identical.
 
     With ``sharded`` (update_sharding.py) the centroid update is
     cross-replica sharded: the (k, d+1) partials reduce-scatter over
@@ -451,8 +452,11 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
                 ("pallas-lloyd" if use_kernel else "xla-lloyd")
                 + ("-segments" if seg else ""))
 
+        # what the kernels' float32 products are made of (pallas_kernels.
+        # _split3): said where the path is said
+        products = {"products": "split3"} if use_kernel else {}
         with tracer.span("lloyd.init", rounds=self.max_iter, k=k,
-                         path=path):
+                         path=path, **products):
             # init: k distinct random input points (ref
             # selectRandomCentroids); fewer points than clusters repeat
             # cyclically. The indices, the zero counts and the row count

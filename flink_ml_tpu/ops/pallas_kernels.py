@@ -21,27 +21,75 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 #: row tiles the Lloyd and assign kernels may take, widest first: a fit
-#: at 12M x 100, k 10 on a v5e took 144.7 / 112.8 / 98.2 / 95.3 ms at
-#: 512 / 1024 / 2048 / 4096 rows a tile (PERF.md section 6, PR 29)
-TILES_N = (2048, 1024, 512, 256)
+#: at 12M x 100, k 10 on a v5e took 99.6 / 82.1 / 74.5 ms at 1024 / 2048 /
+#: 4096 rows a tile (PERF.md section 6, PR 30)
+TILES_N = (4096, 2048, 1024, 512, 256)
 TILE_N = TILES_N[0]
 
-#: float32 matmuls inside the kernels: at Mosaic's default an MXU product
-#: rounds its float32 operands to bfloat16, and in ``csq - 2 c.x`` that
-#: error (0.1 on terms of 25-35) is larger than the gap between a row's
-#: two nearest centroids
-_F32 = jax.lax.Precision.HIGHEST
+
+def _split3(v):
+    """``(hi, mid, lo)``, three bfloat16 arrays whose float32 sum is ``v``
+    bit for bit: the three parts the MXU's own float32 product
+    (``Precision.HIGHEST``) makes of an operand,
+    made here once so that both products of a tile share them and so that
+    the parts that are zero, or that fit one pass together, are not
+    multiplied one by one. ``hi`` is ``v`` rounded to bfloat16's eight
+    bits, ``mid`` the same of what is left, ``lo`` the rest, which has
+    eight bits or fewer. The rounding is Veltkamp's, in float32 arithmetic
+    (``g = 65537 v; hi = g - (g - v)``): a ``v - f32(bf16(v))`` is 3 ms a
+    fit slower in the kernel, and in an XLA program it is 0 — XLA on the
+    TPU drops a float32 -> bfloat16 -> float32 round trip, and the middle
+    and low parts with it (PERF.md section 6, PR 30).
+
+    The range is ``|v| < 2**128 / 65537`` (5.19e33): above it ``g``
+    overflows and the parts are NaN where ``HIGHEST`` stayed finite (a
+    squared distance overflows from 1.8e19 already). Under ``2**-103``
+    (1e-31) the low part's last bits are subnormal, and a device that
+    flushes them loses 1e-38, absolute; a CPU keeps them."""
+    def top(u):
+        g = u * jnp.float32(65537.0)
+        return g - (g - u)
+
+    hi = top(v)
+    rest = v - hi
+    mid = top(rest)
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            (rest - mid).astype(jnp.bfloat16))
 
 
-def _nearest(xt, c):
+def _dot(a, b, contract=((1,), (0,))):
+    """One MXU pass over bfloat16 parts, accumulated in float32."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _cx(x3, c):
+    """``c @ x``, ``(k, tile)``: the float32 product as ``HIGHEST`` forms
+    it, the six largest of the nine part-products, in three MXU passes
+    where it takes six. ``x3`` is the three parts of the ``(d, tile)``
+    tile, ``c`` the ``(k, d)`` float32 centroids, whose parts are ``3k``
+    rows of a 128-column array: stacked they ride one pass per part of
+    ``x``."""
+    k = c.shape[0]
+    x_hi, x_mid, x_lo = x3
+    c_hi, c_mid, c_lo = _split3(c)
+    by_hi = _dot(jnp.concatenate([c_hi, c_mid, c_lo], axis=0), x_hi)
+    by_mid = _dot(jnp.concatenate([c_hi, c_mid], axis=0), x_mid)
+    by_lo = _dot(c_hi, x_lo)
+    # smallest terms first: lo.hi + mid.mid + hi.lo, mid.hi + hi.mid, hi.hi
+    return ((by_hi[2 * k:] + by_mid[k:] + by_lo)
+            + (by_hi[k:2 * k] + by_mid[:k])) + by_hi[:k]
+
+
+def _nearest(x3, c):
     """``(idx, first)``: ``idx`` the ``(k, tile)`` centroid index of every
     entry and ``first`` the ``(1, tile)`` index of each row's nearest
-    centroid, the first smallest on ties. ``xt`` is ``(d, tile)`` — rows
-    in lanes, so every per-row quantity is lane-dense."""
+    centroid, the first smallest on ties. ``x3`` is the three parts of the
+    ``(d, tile)`` tile — rows in lanes, so every per-row quantity is
+    lane-dense."""
     k = c.shape[0]
     # ‖x−c‖² up to the per-row constant ‖x‖² (irrelevant to the argmin)
-    d2 = jnp.sum(c * c, axis=1, keepdims=True) - 2.0 * jnp.dot(
-        c, xt, preferred_element_type=jnp.float32, precision=_F32)
+    d2 = jnp.sum(c * c, axis=1, keepdims=True) - 2.0 * _cx(x3, c)
     idx = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
     first = jnp.min(jnp.where(d2 == jnp.min(d2, axis=0, keepdims=True),
                               idx, k), axis=0, keepdims=True)
@@ -51,7 +99,7 @@ def _nearest(xt, c):
 def _assign_kernel(xt_ref, c_ref, out_ref):
     # what the ragged last tile reads past the array gives indices past
     # the output, which are never written back
-    out_ref[:] = _nearest(xt_ref[:], c_ref[:])[1]
+    out_ref[:] = _nearest(_split3(xt_ref[:]), c_ref[:])[1]
 
 
 def _row_tiles(x, centroids, out_specs, prefetch: int = 0):
@@ -117,23 +165,40 @@ def pallas_supported() -> bool:
 # -- fused Lloyd round: assign + accumulate (KMeans fit) ---------------------
 
 #: VMEM the kernel's working set may claim, under Mosaic's 16 MiB scoped
-#: limit: the double-buffered (d, tile) x tiles, the masked tile and
-#: its three bfloat16 parts (a float32 product on the MXU is six passes
-#: over operands split in three), the (k, tile) distance, index and
-#: one-hot blocks with their parts, and the (k, d) + (k, 128)
-#: accumulators that persist across grid steps. The compiler's own count
-#: at d 100, k 10 and a tile of 8192: 20.1 MB, against 21.0 MB by this sum.
+#: limit, by ``_lloyd_working_bytes``' count. The compiler's own count
+#: (the least ``vmem_limit_bytes`` it compiles at: ``python
+#: scripts/lloyd_vmem_bisect.py``, which needs no chip) is 2-24 % under
+#: that sum at every ``(k, d)`` tried from (4, 6) to (1000, 64) and
+#: (10, 1000), and 46 % under at the gate's widest k (1280 at d 100); at
+#: d 100, k 10: 9.2 MiB at 4096 rows a tile (10.9 by the sum), 18.4 at
+#: 8192. ``tests/test_lloyd_gate_compiles.py`` compiles both
+#: kernels for the chip at the gate's edges, the largest k of every tile.
 LLOYD_VMEM_BUDGET_BYTES = 12 << 20
+
+
+def _lloyd_working_bytes(k: int, d: int, tile: int) -> int:
+    """Bytes of VMEM one grid step of the Lloyd kernel is counted at. A
+    row of the tile: the double-buffered float32 ``(d, tile)`` block, the
+    masked tile, one float32 remainder of the split and the three bfloat16
+    parts, made once (22 d); the ``(3k + 2k + k, tile)`` product blocks as
+    far as they live together, the distances, the index and the one-hot
+    (20 k). Beside the tile: the centroids with their stacked parts and
+    the ``(k, d)`` + ``(k, 128)`` accumulators, double-buffered. ``d`` and
+    ``k`` in whole bfloat16 sublane tiles."""
+    dp, kp = -(-d // 16) * 16, -(-k // 16) * 16
+    return tile * (22 * dp + 20 * kp) + kp * (28 * dp + 1024)
 
 
 def lloyd_tile(k: int, d: int) -> int:
     """The widest row tile whose working set fits the VMEM budget for
     these shapes, 0 when none does (callers run the XLA round) — the shape
-    gate of the Lloyd and assign kernels."""
+    gate of the Lloyd and assign kernels. One feature has none: Mosaic
+    does not lower a bfloat16 product with one output column (the sums at
+    d 1 fail its verifier)."""
+    if d < 2:
+        return 0
     for tile in TILES_N:
-        working = (6 * tile * d + 8 * tile * k + k * d
-                   + 2 * k * (d + 128)) * 4
-        if working <= LLOYD_VMEM_BUDGET_BYTES:
+        if _lloyd_working_bytes(k, d, tile) <= LLOYD_VMEM_BUDGET_BYTES:
             return tile
     return 0
 
@@ -164,13 +229,18 @@ def _lloyd_accum_kernel(nv_ref, xt_ref, c_ref, sums_ref, counts_ref):
     tile = xt_ref.shape[1]
     valid = i * tile + jax.lax.broadcasted_iota(
         jnp.int32, (1, tile), 1) < nv_ref[0]
-    xt = jnp.where(valid, xt_ref[:], 0.0)          # (d, tile)
-    idx, first = _nearest(xt, c_ref[:])
-    one_hot = ((idx == first) & valid).astype(jnp.float32)   # (k, tile)
-    sums_ref[:] += jax.lax.dot_general(
-        one_hot, xt, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_F32)
-    counts_ref[:] += sum(one_hot[:, lane:lane + 128]
+    x_hi, x_mid, x_lo = x3 = _split3(
+        jnp.where(valid, xt_ref[:], 0.0))          # 3 x (d, tile)
+    idx, first = _nearest(x3, c_ref[:])
+    hit = (idx == first) & valid                   # (k, tile)
+    # 0 and 1 are whole in bfloat16: the one-hot has no middle or low part,
+    # and the three passes that would multiply by them are left out
+    one_hot = hit.astype(jnp.bfloat16)
+    rows = ((1,), (1,))                            # one_hot @ part.T
+    sums_ref[:] += (_dot(one_hot, x_lo, rows) + _dot(one_hot, x_mid, rows)
+                    ) + _dot(one_hot, x_hi, rows)
+    counted = hit.astype(jnp.float32)
+    counts_ref[:] += sum(counted[:, lane:lane + 128]
                          for lane in range(0, tile, 128))
 
 

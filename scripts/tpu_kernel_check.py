@@ -103,6 +103,55 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
           lambda: pk.lloyd_partial_sums(xw, n_valid, cw),
           lloyd_want, rtol=5e-2, atol=0.5)
 
+    # the two KMeans kernels' float32 products are three bfloat16 parts an
+    # operand (pk._split3), multiplied on the MXU and added in float32: on
+    # the benchmark's own kind of data (uniform, so rows do lie near ties)
+    # the chosen centroid is the float64-nearest but for four float32
+    # roundings of the terms csq - 2 c.x cancels, predict's assignment is
+    # the fit kernel's row for row, and the sums are the float64 sums of
+    # those rows. At the benchmark's (k, d), at 3k over the MXU's 128
+    # columns, at k not a whole number of sublane tiles, at a narrower tile
+    # (a generator of its own: the checks below keep the data they had)
+    uniform = np.random.default_rng(30)
+    for k, d in ((10, 100), (50, 100), (4, 6), (200, 64)):
+        tile = pk.lloyd_tile(k, d)
+        rows = 2 * tile + 77                     # a ragged last tile
+        counted = rows - 50                      # and a shard's padding
+        xs, cs = (uniform.random(shape, np.float32)
+                  for shape in ((rows, d), (k, d)))
+        x64, c64 = xs.astype(np.float64), cs.astype(np.float64)
+        exact = ((x64[:, None, :] - c64[None]) ** 2).sum(-1)
+        terms = (c64 ** 2).sum(1).max() + 2 * (x64 @ c64.T).max(1)
+        chosen = {}
+
+        def regret(k=k, d=d, xs=xs, cs=cs, exact=exact, terms=terms,
+                   chosen=chosen):
+            chosen["v"] = np.asarray(pk.assign_nearest(xs, cs))
+            return (exact[np.arange(len(xs)), chosen["v"]]
+                    - exact.min(1)) / terms
+
+        check(f"assign_nearest[k {k}, d {d}, tile {tile}](regret)", regret,
+              np.zeros(rows), rtol=0, atol=4 * 2.0 ** -24)
+        if "v" not in chosen:
+            continue
+        one_hot = (chosen["v"][:counted, None]
+                   == np.arange(k)[None]).astype(np.float64)
+        packed = {}
+
+        def counts(xs=xs, cs=cs, counted=counted, packed=packed):
+            packed["v"] = np.asarray(
+                pk.lloyd_partial_sums(xs, counted, cs), np.float64)
+            return packed["v"][:, -1]
+
+        check(f"lloyd_partial_sums[k {k}, d {d}](counts = assign's)",
+              counts, one_hot.sum(0), rtol=0, atol=0)
+        if "v" in packed:
+            want = one_hot.T @ x64[:counted]
+            check(f"lloyd_partial_sums[k {k}, d {d}](sums, relative)",
+                  lambda packed=packed, want=want:
+                  (packed["v"][:, :-1] - want) / np.maximum(want, 1.0),
+                  np.zeros_like(want), rtol=0, atol=1e-6)
+
     yl = (rng.random(2048) > 0.5).astype(np.float32)
     wl = (rng.random(2048) + 0.5).astype(np.float32)
     coeffs = rng.normal(size=16).astype(np.float32)

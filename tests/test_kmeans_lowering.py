@@ -1,9 +1,13 @@
 """What the suite can hold of the Lloyd programs without a chip, from the
-traced programs themselves: every matrix product is float32 at ``HIGHEST``
-precision (the CPU multiplies float32 exactly whatever the precision says,
-so no answer computed here could show a bfloat16 product), and a fit at a
-ragged row count neither pads the table nor hands the kernel a per-row
-mask.
+traced programs themselves: every matrix product of an XLA program is
+float32 at ``HIGHEST`` precision; inside the two kernels every product
+multiplies bfloat16 parts that ``_split3`` made of a float32 operand (or
+the one-hot, whose 0 and 1 are whole in bfloat16) and accumulates in
+float32, the distance holding the six part-products of the float32 product
+and the sums the three that are not zero, and no other bfloat16 value
+exists in any program; a fit at a ragged row count neither pads the table
+nor hands the kernel a per-row mask. What the parts add up to is computed,
+on the CPU, in ``tests/test_lloyd_split_products.py``.
 """
 
 import jax
@@ -89,19 +93,83 @@ def programs():
         km._build_assign_program.cache_clear()
 
 
+def kernel_eqns(jaxpr):
+    """``(equations outside any kernel, [each kernel's equations])``."""
+    kernels = [list(eqns(eqn.params["jaxpr"])) for eqn in eqns(jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    inside = {id(e) for kernel in kernels for e in kernel}
+    outside = [e for e in eqns(jaxpr) if id(e) not in inside]
+    return outside, kernels
+
+
+def has_bfloat16(eqn):
+    return any(getattr(v.aval, "dtype", None) == jnp.bfloat16
+               for v in (*eqn.invars, *eqn.outvars))
+
+
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("name", PROGRAMS)
 def test_every_product_is_float32_at_highest_precision(name, devices,
                                                        programs):
-    dots = [e for e in eqns(programs[devices][name].jaxpr)
-            if e.primitive.name == "dot_general"]
-    assert dots, "a Lloyd round multiplies: distances, then the sums"
+    """Outside the kernels: float32 operands at ``HIGHEST`` and no
+    bfloat16 value anywhere. Inside: see ``assert_split_products``."""
+    outside, kernels = kernel_eqns(programs[devices][name].jaxpr)
+    assert bool(kernels) == name.startswith("pallas")
+    dots = [e for e in outside if e.primitive.name == "dot_general"]
+    assert dots or kernels, "a Lloyd round multiplies: distances, the sums"
     highest = jax.lax.Precision.HIGHEST
     for dot in dots:
         assert [v.aval.dtype for v in dot.invars] == [jnp.float32] * 2
         assert dot.params["precision"] in (highest, (highest, highest)), (
             name, dot.params["precision"])
         assert dot.params["preferred_element_type"] in (None, jnp.float32)
+    assert not [e for e in outside if has_bfloat16(e)]
+    for kernel in kernels:
+        assert_split_products(kernel, sums="lloyd" in name)
+
+
+def assert_split_products(kernel, sums: bool):
+    """One kernel's products: bfloat16 parts in, float32 out. The distance
+    is three products whose left operands are the centroids' parts stacked
+    ``3k``, ``2k`` and ``k`` rows deep against the tile's high, middle and
+    low part: six ``(k, tile)`` part-products. The sums are three, the
+    one-hot against each part of the tile. Every bfloat16 value is a part
+    (a float32 difference converted: ``_split3`` converts nothing else),
+    the one-hot (a boolean converted) or parts stacked."""
+    dots = [e for e in kernel if e.primitive.name == "dot_general"]
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+        assert dot.params["preferred_element_type"] == jnp.float32
+    producer = {id(v): e for e in kernel for v in e.outvars}
+    distance = [e for e in dots
+                if e.params["dimension_numbers"][0] == ((1,), (0,))]
+    summed = [e for e in dots if e not in distance]
+    assert [e.invars[0].aval.shape[0] for e in distance] == [
+        3 * K, 2 * K, K]
+    x_parts = [e.invars[1] for e in distance]
+    assert len({id(v) for v in x_parts}) == 3
+    assert {v.aval.shape[0] for v in x_parts} == {D}
+    if sums:
+        # low, middle, high: each part of the same split, the one one-hot
+        assert [id(e.invars[1]) for e in summed] == [
+            id(v) for v in reversed(x_parts)]
+        one_hot, = {id(e.invars[0]): e.invars[0] for e in summed}.values()
+        made = producer[id(one_hot)]
+        assert made.primitive.name == "convert_element_type"
+        assert made.invars[0].aval.dtype == jnp.bool_
+    else:
+        assert not summed
+    converts = [e for e in kernel if has_bfloat16(e)
+                and e.primitive.name == "convert_element_type"]
+    parts = [e for e in converts if e.invars[0].aval.dtype == jnp.float32]
+    # two operands split (the tile, the centroids), three parts each
+    assert len(parts) == 6 and len(converts) == 6 + sums
+    for part in parts:
+        assert producer[id(part.invars[0])].primitive.name == "sub"
+    for eqn in kernel:
+        if has_bfloat16(eqn):
+            assert eqn.primitive.name in (
+                "convert_element_type", "concatenate", "dot_general"), eqn
 
 
 @pytest.mark.parametrize("devices", [1, 4])

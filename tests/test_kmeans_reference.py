@@ -4,12 +4,18 @@ copy: direct squared differences in float32, sums in float64 on the host),
 at the benchmark configuration's shapes (d 100, k 10, 10 rounds) and a row
 count that divides over four devices and not into a kernel tile.
 
-The seed is one at which no row lies within float32 rounding of a tie
+The seeds are ones at which no row lies within float32 rounding of a tie
 between two centroids on any path: at 4,100 rows ONE row that goes the
-other way moves a centroid by 1e-3 at once and by 2e-2 ten rounds later
-(seed 5 has such a row on the four-device kernel paths; 1, 2, 3, 7 and 11
-have none). The paths are float32 by different sums, so they may break a
-tie differently; on the chip, at 12M rows, that is 60 rows in a fit.
+other way moves a centroid by 1e-3 at once and by 2e-2 ten rounds later.
+Seed 5 has such a row on the four-device kernel paths, and seed 11 (used
+here until the kernel summed its part-products itself) on the one-device
+kernel paths: after two rounds its row 833 has two centroids whose
+distances differ by 7e-9 of the terms ``csq - 2 c.x`` cancels, an eighth
+of one float32 rounding, so that which of them is nearer is not a
+float32 question at all (the last test holds that number, and that the
+seeds used have no row under three roundings). The paths are float32 by
+different sums, so they may break such a tie differently; on the chip, at
+12M rows, that is 60 rows in a fit.
 """
 
 import jax
@@ -24,7 +30,9 @@ from flink_ml_tpu.models.clustering import kmeans as km
 from flink_ml_tpu.models.clustering.kmeans import KMeans
 from flink_ml_tpu.parallel import create_mesh
 
-N, D, K, ROUNDS, SEED = 4100, 100, 10, 10, 11
+N, D, K, ROUNDS, SEEDS = 4100, 100, 10, 10, (7, 1)
+#: the seed with the tied row, and one float32 rounding
+TIED_SEED, EPS32 = 11, 2.0 ** -24
 #: path -> (rounds unrolled, kernels interpreted, iteration config)
 PATHS = {
     "xla-lloyd/while": (False, False, None),
@@ -37,23 +45,23 @@ PATHS = {
 }
 
 
-def resident_table(mesh):
+def resident_table(mesh, seed):
     """The benchmark generator's table: uniform [0, 1) float32, resident
     and row-sharded over ``mesh``."""
     x = jax.jit(
         lambda key: jax.random.uniform(key, (N, D), jax.numpy.float32),
         out_shardings=NamedSharding(mesh, P("data", None)))(
-            jax.random.key(SEED))
+            jax.random.key(seed))
     return jax.block_until_ready(x)
 
 
-def fit_path(path, x, mesh, monkeypatch, tmp_path, request):
+def fit_path(path, x, seed, mesh, monkeypatch, tmp_path, request):
     unroll, kernels, mode = PATHS[path]
     if kernels:
         request.getfixturevalue("interpreted_kernels")
     monkeypatch.setattr(km, "default_mesh", lambda: mesh)
     monkeypatch.setattr(km, "_UNROLL_MAX_ROUNDS", 64 if unroll else 0)
-    est = KMeans(k=K, max_iter=ROUNDS, seed=SEED)
+    est = KMeans(k=K, max_iter=ROUNDS, seed=seed)
     if mode == "segments":
         est.set_iteration_config(IterationConfig(
             mode="device", checkpoint_interval=4,
@@ -64,17 +72,18 @@ def fit_path(path, x, mesh, monkeypatch, tmp_path, request):
     return model, est.last_execution_path
 
 
+@pytest.mark.parametrize("seed", SEEDS)
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("path", PATHS)
 def test_every_lloyd_path_agrees_with_the_plain_reference(
-        path, devices, monkeypatch, tmp_path, request):
+        path, devices, seed, monkeypatch, tmp_path, request):
     mesh = create_mesh(devices=jax.devices()[:devices])
-    x = resident_table(mesh)
-    model, reported = fit_path(path, x, mesh, monkeypatch, tmp_path,
+    x = resident_table(mesh, seed)
+    model, reported = fit_path(path, x, seed, mesh, monkeypatch, tmp_path,
                                request)
     assert reported == path.split("/")[0]
     reference = lloyd_kmeans.run(
-        {"features": x}, {"k": K, "maxIter": ROUNDS, "seed": SEED}, devices)
+        {"features": x}, {"k": K, "maxIter": ROUNDS, "seed": seed}, devices)
     assert reference["_rounds"] == ROUNDS
     np.testing.assert_allclose(model.centroids, reference["centroid"],
                                rtol=0, atol=1e-5)
@@ -83,3 +92,32 @@ def test_every_lloyd_path_agrees_with_the_plain_reference(
     gaps = lloyd_kmeans.compare(
         {"centroid": model.centroids, "weight": model.weights}, reference)
     assert gaps["centroid_gap"] < 1e-5 and gaps["weight_gap"] == 0.0
+
+
+def tie_margins(seed):
+    """Round by round along the reference's own fit: the least distance,
+    over the rows, between a row's two nearest centroids, in float64 and
+    as a share of the terms that ``csq - 2 c.x`` cancels -> [(share, row)]."""
+    x = resident_table(create_mesh(devices=jax.devices()[:1]), seed)
+    x64 = np.asarray(x, np.float64)
+    out = []
+    for rounds in range(ROUNDS):
+        c64 = np.asarray(lloyd_kmeans.run(
+            {"features": x}, {"k": K, "maxIter": rounds, "seed": seed},
+            1)["centroid"], np.float32).astype(np.float64)
+        d2 = np.sort(((x64[:, None, :] - c64[None]) ** 2).sum(-1), axis=1)
+        terms = (c64 ** 2).sum(1).max() + 2 * (abs(x64) @ abs(c64).T).max(1)
+        share = (d2[:, 1] - d2[:, 0]) / terms
+        out.append((float(share.min()), int(share.argmin())))
+    return out
+
+
+@pytest.mark.parametrize("seed", SEEDS + (TIED_SEED,))
+def test_the_seeds_used_have_no_float32_tie_and_the_one_left_has(seed):
+    margins = tie_margins(seed)
+    if seed == TIED_SEED:
+        # row 833, going into the third round: an eighth of a rounding
+        assert min(margins) == margins[2] and margins[2][1] == 833
+        assert margins[2][0] < EPS32 / 4
+    else:
+        assert min(margins)[0] > 3 * EPS32

@@ -159,13 +159,17 @@ TREE = {"lloyd.place_inputs": "KMeans.fit", "lloyd.init": "KMeans.fit",
         "fit.model": "KMeans.fit"}
 #: what the iteration runtime records under ``lloyd.launch``, and how often
 UNDER_LAUNCH = {"xla-lloyd": {}, "xla-lloyd-segments": {"segment": 3},
-                "host-rounds": {"epoch": ROUNDS}}
+                "host-rounds": {"epoch": ROUNDS}, "pallas-lloyd": {},
+                "pallas-lloyd-segments": {"segment": 3}}
 
 
-@pytest.mark.parametrize("case", [XLA_CASES[1], XLA_CASES[4], XLA_CASES[7]],
+@pytest.mark.parametrize("case", [XLA_CASES[1], XLA_CASES[4], XLA_CASES[7],
+                                  KERNEL_CASES[0], KERNEL_CASES[3]],
                          ids=case_id)
 def test_the_spans_of_a_fit_are_one_tree_that_sums_to_its_root(
-        case, tmp_path, monkeypatch):
+        case, tmp_path, monkeypatch, request):
+    if case in KERNEL_CASES:
+        request.getfixturevalue("interpreted_kernels")
     fit(case, tmp_path)                      # warm, and nobody looking:
     assert len(tracer.recent) == 0           # nothing recorded
     fetches = metrics.group(ML_GROUP, "iteration").snapshot()[
@@ -185,6 +189,9 @@ def test_the_spans_of_a_fit_are_one_tree_that_sums_to_its_root(
     init = next(r for r in records if r["name"] == "lloyd.init")
     assert init["attrs"] == {**init["attrs"], "rounds": ROUNDS, "k": K,
                              "path": case[0]}
+    # the kernel paths say what their float32 products are made of
+    assert init["attrs"].get("products") == (
+        "split3" if case in KERNEL_CASES else None)
     launch = next(r for r in records if r["name"] == "lloyd.launch")
     inner = [r["name"] for r in records if r["parent"] == launch["id"]
              and r["name"] in ("segment", "epoch")]

@@ -1,0 +1,84 @@
+"""The Lloyd and assign kernels, compiled for the chip at the edges of their
+gate. A kernel that ``lloyd_tile`` admits and Mosaic then refuses raises in
+the fit that chose it (``pallas_supported``: no call site retries on the XLA
+round), so what the gate admits has to compile: at every feature width here,
+the largest k of every tile, where ``_lloyd_working_bytes`` comes nearest
+its budget, and the smallest shapes. Ahead of time, for a v5e, by the TPU
+compiler the installation brings: no chip is needed, and where there is no
+such compiler the cases are skipped. What Mosaic itself counts at a shape is
+``python scripts/lloyd_vmem_bisect.py k,d,tile``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from flink_ml_tpu.ops import pallas_kernels as pk
+
+#: feature widths: under a sublane tile, the benchmark's, whole lanes, wide
+WIDTHS = (6, 100, 128, 512, 1000, 2048)
+
+
+@functools.lru_cache(maxsize=None)
+def chip():
+    """One device of a v5e as the compiler sees it, or None."""
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices[0]
+    except Exception:  # noqa: BLE001 — no TPU compiler here
+        return None
+
+
+def compile_both(k, d, rows):
+    """Both kernels at ``(k, d)`` over ``rows`` rows, a ragged last tile."""
+    one = SingleDeviceSharding(chip())
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pk._lloyd_tiles.lower(of((rows, d)), of((), jnp.int32),
+                          of((k, d))).compile()
+    pk._assign_tiles.lower(of((rows, d)), of((k, d))).compile()
+
+
+def edges(d):
+    """``[(largest k the gate gives this tile, tile)]`` at width ``d``."""
+    tiles = {}
+    for k in range(1, 4097):
+        tiles[pk.lloyd_tile(k, d)] = k
+    return [(k, tile) for tile, k in tiles.items() if tile]
+
+
+@pytest.mark.parametrize("d", WIDTHS)
+def test_the_largest_k_of_every_tile_compiles_for_the_chip(d):
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    found = edges(d)
+    assert found and {tile for _, tile in found} <= set(pk.TILES_N)
+    for k, tile in found:
+        assert pk.lloyd_tile(k + 16, d) < tile    # an edge: the next k has
+        compile_both(k, d, 2 * tile + 77)         # a narrower tile, or none
+
+
+@pytest.mark.parametrize("k,d", [(1, 2), (2, 2), (3, 3), (10, 7), (17, 9),
+                                 (10, 100), (50, 100), (129, 100)],
+                         ids=lambda v: str(v))
+def test_small_and_unaligned_shapes_compile_for_the_chip(k, d):
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    assert pk.lloyd_tile(k, d)
+    compile_both(k, d, 20_000)
+
+
+def test_one_feature_is_gated_off_because_its_sums_do_not_lower():
+    """Why ``lloyd_tile(k, 1)`` is 0: when this stops raising, the gate's
+    ``d < 2`` can go."""
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    assert pk.lloyd_tile(10, 1) == 0
+    with pytest.raises(Exception, match="vector.broadcast|verif"):
+        compile_both(10, 1, 20_000)
