@@ -272,8 +272,7 @@ def _build_init_rows_program(mesh, k: int):
 #: fits with at most this many rounds compile fully unrolled — Lloyd's has
 #: no data-dependent exit (TerminateOnMaxIter only, ref KMeans.java:150),
 #: so the unrolled body is just max_iter repetitions XLA can pipeline
-#: (same rationale and escape hatch as optimizer._UNROLL_MAX_ROUNDS:
-#: compile time scales with the unroll; 0 disables unrolling)
+#: (compile time scales with the unroll; 0 disables unrolling)
 _UNROLL_MAX_ROUNDS = int(os.environ.get(
     "FLINK_ML_TPU_LLOYD_UNROLL_MAX", "64"))
 

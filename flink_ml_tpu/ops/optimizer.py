@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Optional
 
 import numpy as np
@@ -154,20 +153,16 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     """The post-slice math of one round — loss/gradient on the minibatch,
     the fused [grad, weight, loss] reduction (the reference's
     feedbackArray layout, SGD.java:190), the model update +
-    regularization (SGD.java:231-243) — shared by the while-loop,
-    unrolled and host-driven programs so a change here propagates to
-    every fit path.
+    regularization (SGD.java:231-243) — shared by the while-loop and
+    host-driven programs so a change here propagates to every fit path.
 
-    Returns ``(update, apply_packed)``: ``update(coeffs, opt, xb, yb,
-    wb) -> (new_coeffs, new_opt, mean_loss)`` for the slice-based
-    rounds, and ``apply_packed(coeffs, opt, packed_local) ->
-    (new_coeffs, new_opt, mean_loss)`` for rounds whose local
-    [grad | weight | loss] partials come from the fused pallas kernel —
-    the cross-shard reduction and the model update are this one shared
-    tail either way. ``opt`` is the stateful rule's moment tuple
-    (:func:`_update_rule`): ``()`` for plain sgd, so the stateless
-    programs carry nothing. Must be called inside a
-    ``mapreduce.map_shards`` body over the mesh's data ``axes``.
+    Returns ``update(coeffs, opt, xb, yb, wb) -> (new_coeffs, new_opt,
+    mean_loss)``: the local [grad | weight | loss] partials of the
+    minibatch, their cross-shard reduction and the model update.
+    ``opt`` is the stateful rule's moment tuple (:func:`_update_rule`):
+    ``()`` for plain sgd, so the stateless programs carry nothing. Must
+    be called inside a ``mapreduce.map_shards`` body over the mesh's
+    data ``axes``.
 
     With ``sharded`` (update_sharding.py, DP meshes only) the tail is
     the cross-replica sharded update: the gradient reduce-scatters so
@@ -181,7 +176,21 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     to float reassociation in the reduction order."""
     rule = _update_rule(prm)
 
-    def apply_packed(coeffs, opt, packed_local):
+    def update(coeffs, opt, xb, yb, wb):
+        # LossFunc.loss_and_gradient, spelled out so that the two
+        # products carry their names into the device trace
+        with jax.named_scope("sgd.margins"):
+            if model_axis is None:
+                d = xb.shape[1]  # == coeffs length unless sharded padding
+                dots = xb @ coeffs[:d]
+            else:
+                dots = mr.reduce_sum(xb @ coeffs, model_axis)
+        loss_sum, multipliers = loss_func.terms(dots, yb, wb)
+        with jax.named_scope("sgd.gradient"):
+            grad_sum = xb.T @ multipliers  # local feature shard under TP
+        packed_local = jnp.concatenate([
+            grad_sum, jnp.sum(wb)[None].astype(grad_sum.dtype),
+            loss_sum[None]])
         if sharded:
             with jax.named_scope("sgd.grad_allreduce"):
                 tail = mr.reduce_sum(packed_local[-2:], axes)
@@ -212,24 +221,7 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
         mean_loss = total_loss / jnp.maximum(total_w, 1e-30)
         return coeffs_out, opt_out, mean_loss
 
-    def update(coeffs, opt, xb, yb, wb):
-        # LossFunc.loss_and_gradient, spelled out so that the two
-        # products carry their names into the device trace
-        with jax.named_scope("sgd.margins"):
-            if model_axis is None:
-                d = xb.shape[1]  # == coeffs length unless sharded padding
-                dots = xb @ coeffs[:d]
-            else:
-                dots = mr.reduce_sum(xb @ coeffs, model_axis)
-        loss_sum, multipliers = loss_func.terms(dots, yb, wb)
-        with jax.named_scope("sgd.gradient"):
-            grad_sum = xb.T @ multipliers  # local feature shard under TP
-        packed = jnp.concatenate([
-            grad_sum, jnp.sum(wb)[None].astype(grad_sum.dtype),
-            loss_sum[None]])
-        return apply_packed(coeffs, opt, packed)
-
-    return update, apply_packed
+    return update
 
 
 def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
@@ -252,8 +244,8 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
     shard, and the loss/weight reduction crosses the data axes only."""
     gb = prm.global_batch_size
     lb_base, lb_rem = gb // p, gb % p
-    update, _ = _sgd_update_math(loss_func, prm, axes, model_axis,
-                                 sharded=sharded)
+    update = _sgd_update_math(loss_func, prm, axes, model_axis,
+                              sharded=sharded)
 
     @jax.named_scope("sgd.round")
     def round_step(xl, yl, wl, coeffs, opt, offset):
@@ -404,139 +396,6 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
         + extra_out,
         donate_argnums=donate,
         name="sgd.segment" if sharded else None)
-
-
-#: plain fits with at most this many rounds compile fully unrolled with
-#: STATIC slice starts (the offset schedule is data-independent) — no
-#: dynamic-slice machinery, no while-loop: XLA sees max_iter static-offset
-#: windows and can pipeline their HBM reads. Large max_iter keeps the
-#: while program (compile time scales with the unroll).
-_UNROLL_MAX_ROUNDS = int(os.environ.get(
-    "FLINK_ML_TPU_SGD_UNROLL_MAX", "64"))
-
-def _static_batch_schedule(local_n: int, lb: int, max_iter: int):
-    """The per-shard minibatch schedule as Python ints — valid because the
-    reference's slicing (SGD.java:262-284) depends only on (n, batch), not
-    on data: round r slices [start, start+lb) with clip-at-end and
-    wrap-to-zero. Returns [(start, first_valid)] per round; rows before
-    ``first_valid`` (clip overlap) weigh 0. Requires offset 0 at entry and
-    a uniform lb (gb % p == 0)."""
-    sched, offset = [], 0
-    for _ in range(max_iter):
-        start = min(offset, local_n - lb)
-        sched.append((start, offset - start))  # offset-start == 0 unless clipped
-        offset = 0 if offset + lb >= local_n else offset + lb
-    return sched
-
-
-@functools.lru_cache(maxsize=128)
-def _build_sgd_unrolled_program(loss_cls, mesh: Mesh, prm: SGDParams,
-                                use_kernel: bool = False,
-                                health: bool = False,
-                                sharded: bool = False):
-    """The plain (uncheckpointed, fresh-offset) fit as ONE fully-unrolled
-    SPMD program: ``fit(xs, ys, ws, coeffs, offsets, opt) -> (coeffs,
-    offsets, opt, mean_loss, epoch, stop)`` — the same carry as the
-    segment program (``opt`` = the stateful rule's moment tuple, ``()``
-    for plain sgd). The tol early-exit becomes masking (rounds after
-    the stop compute and are discarded by ``where`` — moments
-    included), so the result — coeffs, final offsets, the loss AT the
-    stopping round, the executed-round count — is identical to the
-    while program's by construction. Only valid for offsets == 0 and
-    gb %% p == 0 (the dispatch in ``optimize`` guarantees both). With
-    ``health`` the outputs grow ``(..., hist, fin)``: the stacked
-    per-round ``(max_iter, 3)`` convergence rows (NaN past the stopping
-    round) and the single non-finite sentinel folded over the executed
-    rounds (observability/health.py).
-
-    With ``use_kernel`` (TPU, DP-only mesh), rounds whose window aligns
-    to a shared tile run the fused pallas batch-terms kernel — one pass
-    over the window instead of a slice copy plus two reads; the psum and
-    the model update stay in the one shared tail
-    (``_sgd_update_math.apply_packed``), so results agree with the XLA
-    rounds up to float reassociation in the per-tile partial sums."""
-    axes = data_axes(mesh)
-    spec0 = data_pspec(mesh)
-    p = data_shard_count(mesh)
-    model_axis = model_axis_of(mesh)
-    wspec = P(model_axis) if model_axis else P()
-    lb_base = prm.global_batch_size // p
-    assert prm.global_batch_size % p == 0
-    update, apply_packed = _sgd_update_math(loss_cls(), prm, axes,
-                                            model_axis, sharded=sharded)
-    opt_specs = _opt_specs(prm, wspec, spec0, sharded)
-
-    def sgd_unrolled(xl, yl, wl, coeffs, offsets, opt):
-        local_n = xl.shape[0]
-        lb = min(lb_base, local_n)
-        tile = 0
-        if use_kernel and model_axis is None:
-            from flink_ml_tpu.ops.pallas_kernels import (
-                sgd_batch_terms, sgd_round_tile)
-            tile = sgd_round_tile(lb, local_n, xl.shape[1])
-        sched = _static_batch_schedule(local_n, lb, prm.max_iter)
-        offset = offsets[0]
-        mean_loss = jnp.asarray(jnp.inf, coeffs.dtype)
-        epoch = jnp.int32(0)
-        stop = jnp.asarray(False)
-        rows = []
-        fin = jnp.asarray(True)
-        for start, clip in sched:
-            with jax.named_scope("sgd.round"):
-                if tile:
-                    # the kernel sees the TRUE feature dim — coeffs may be
-                    # padded for the sharded update; apply_packed re-pads
-                    # the local [grad | w | loss] partials it returns
-                    packed = sgd_batch_terms(xl, yl, wl,
-                                             coeffs[:xl.shape[1]], start,
-                                             clip, lb, tile, loss_cls.NAME)
-                    updated, new_opt, new_loss = apply_packed(coeffs, opt,
-                                                              packed)
-                else:
-                    xb = jax.lax.slice_in_dim(xl, start, start + lb, axis=0)
-                    yb = jax.lax.slice_in_dim(yl, start, start + lb, axis=0)
-                    wb = jax.lax.slice_in_dim(wl, start, start + lb, axis=0)
-                    if clip:  # short batch at the end: clipped rows weigh 0
-                        wb = wb * (np.arange(lb) >= clip).astype(xl.dtype)
-                    updated, new_opt, new_loss = update(coeffs, opt, xb, yb,
-                                                        wb)
-                new_off = jnp.int32(0 if start + clip + lb >= local_n
-                                    else start + clip + lb)
-                active = jnp.logical_not(stop)
-                if health:
-                    # first-class numeric telemetry: the round's convergence
-                    # row + ONE isfinite fold over loss and every parameter
-                    # element; rounds past the tol stop record NaN rows and
-                    # never poison the sentinel (they are masked out anyway)
-                    row, row_fin = _health.convergence_row(
-                        new_loss, coeffs, updated, model_axis)
-                    rows.append(jnp.where(
-                        active, row, jnp.full((3,), jnp.nan, jnp.float32)))
-                    fin = jnp.logical_and(fin, jnp.logical_or(
-                        jnp.logical_not(active), row_fin))
-                coeffs = jnp.where(active, updated, coeffs)
-                opt = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(active, n, o), new_opt, opt)
-                offset = jnp.where(active, new_off, offset)
-                mean_loss = jnp.where(active, new_loss, mean_loss)
-                epoch = epoch + active.astype(jnp.int32)
-                stop = jnp.logical_or(stop, jnp.logical_and(
-                    active, new_loss < prm.tol))
-        if health:
-            return (coeffs, offset[None], opt, mean_loss, epoch, stop,
-                    jnp.stack(rows), fin)
-        return coeffs, offset[None], opt, mean_loss, epoch, stop
-
-    # the (coeffs, offsets, opt) carry donates in EVERY build — the
-    # update happens in place in the donated buffers
-    return mr.map_shards(
-        sgd_unrolled, mesh,
-        in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
-                  P(spec0), opt_specs),
-        out_specs=(wspec, P(spec0), opt_specs, P(), P(), P())
-        + ((P(), P()) if health else ()),
-        donate_argnums=(3, 4, 5),
-        name="sgd.unrolled" if sharded else None)
 
 
 @functools.lru_cache(maxsize=128)
@@ -886,40 +745,7 @@ class SGD:
         if seg_k or not needs_host_loop(config, listeners):
             # the compiled fast path: a plain fit is one max_iter segment;
             # a checkpointed fit runs K-round segments with the carry
-            # snapshotted between them (same single program either way).
-            # A plain fit with a uniform batch share and a bounded round
-            # count compiles fully UNROLLED instead: the offset schedule
-            # is data-independent, so every slice start is static — no
-            # dynamic-slice machinery, no while-loop (results identical
-            # by construction; see _build_sgd_unrolled_program).
-            if (not seg_k and self.params.global_batch_size % p == 0
-                    and 0 < self.params.max_iter <= _UNROLL_MAX_ROUNDS):
-                from flink_ml_tpu.ops.pallas_kernels import (
-                    pallas_supported, sgd_round_tile)
-                # the kernel is chosen by the backend and the shape gate,
-                # nothing else; a Mosaic failure propagates
-                local_n = xs.shape[0] // p
-                use_kernel = (
-                    pallas_supported() and not tp
-                    and sgd_round_tile(
-                        min(self.params.global_batch_size // p, local_n),
-                        local_n, xs.shape[1]) > 0)
-                with tracer.span("sgd.build_program"):
-                    prog = _build_sgd_unrolled_program(
-                        type(loss_func), mesh, self.params,
-                        use_kernel=use_kernel, health=health_on,
-                        sharded=sharded)
-                with tracer.span("sgd.launch"):
-                    res = prog(xs, ys, ws, init[0], init[1], init[3])
-                coeffs, _, _, mean_loss, epoch, _ = res[:6]
-                hist, fin = (res[6:] if health_on else (None, True))
-                self.last_execution_path = (
-                    "pallas-unrolled" if use_kernel else "xla-unrolled")
-                out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
-                with tracer.span("sgd.health"):
-                    _finish_fit_health(algo, health_on, hist, fin, epoch,
-                                       mean_loss, out)
-                return out, mean_loss
+            # snapshotted between them (same single program either way)
             from flink_ml_tpu.iteration.iteration import (
                 read_boundary, segment_fusion_enabled)
             fused = segment_fusion_enabled()

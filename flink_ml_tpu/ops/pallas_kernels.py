@@ -8,8 +8,11 @@ between the matmul and the argmin; this kernel keeps each (tile_n, k)
 distance block in VMEM and writes only the argmin — HBM traffic drops from
 O(n·k) to O(n·d + k·d + n).
 
-Used by KMeans/KNN paths when running on a real TPU backend; elsewhere the
-plain XLA path runs. Tests exercise the kernel in interpreter mode on CPU.
+Four kernels: ``assign_nearest`` (KMeans predict), ``lloyd_partial_sums``
+(KMeans fit), ``segment_reduce_sum`` (scatter-add by segment id) and
+``knn_topk_indices`` (KNN). Each runs on a real TPU backend where its shape
+gate admits the input; elsewhere the plain XLA path runs. Tests exercise
+the kernels in interpreter mode on CPU.
 """
 
 from __future__ import annotations
@@ -283,123 +286,6 @@ def lloyd_partial_sums(x, n_valid, centroids, interpret: bool = False):
         return jnp.zeros((k, d + 1), jnp.float32)
     return _lloyd_tiles(x, jnp.asarray(n_valid, jnp.int32), centroids,
                         interpret=interpret)
-
-
-# -- fused SGD batch terms (one pass over the minibatch window) --------------
-
-
-def _sgd_terms_kernel(terms, tile_n, scalars_ref, x_ref, yw_ref, c_ref,
-                      out_ref):
-    """One row tile of the minibatch: forward dots, loss terms and the
-    gradient accumulate in VMEM — the batch window is read ONCE (the XLA
-    round reads it for the forward matvec and again for the gradient,
-    after a dynamic-slice copy). The window's start arrives as a
-    prefetched scalar (block units), so ONE compiled kernel serves every
-    round of the static schedule; ``scalars_ref[1]`` carries the
-    clip-round cutoff (rows before it weigh 0).
-
-    Per-row quantities (labels, weights, dots, multipliers) live as
-    lane-dense ``(1, tile)`` rows: Mosaic has no rank-1 blocks at tile
-    sizes that are not multiples of 128 (the north-star tile is 1000),
-    and a ``(tile, 1)`` column operand pads 128x in HBM. Both matvecs
-    are then plain MXU forms: ``c @ x.T`` and ``mult @ x``."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    x = x_ref[:]                       # (tile_n, d)
-    yw = yw_ref[0]                     # (2, tile_n): labels | weights
-    y, w = yw[0:1, :], yw[1:2, :]
-    c = c_ref[:]                       # (1, d)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, tile_n), 1)
-    w = jnp.where(i * tile_n + col >= scalars_ref[1], w, 0.0)
-    dots = jax.lax.dot_general(c, x, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    loss_sum, mult = terms(dots, y, w)
-    grad = jnp.dot(mult, x, preferred_element_type=jnp.float32)  # (1, d)
-    out_ref[:] += jnp.concatenate(
-        [grad, jnp.sum(w, axis=1, keepdims=True),
-         jnp.reshape(loss_sum, (1, 1))], axis=1)
-
-
-#: VMEM budget for the SGD kernel working set: double-buffered (tile, d)
-#: x blocks + the y/w vectors + coeffs + the (d+2,) accumulator
-SGD_VMEM_BUDGET_BYTES = 8 << 20
-
-
-def sgd_round_tile(lb: int, local_n: int, d: int) -> int:
-    """The largest row tile ≤ 1024, a multiple of 8, dividing both the
-    local batch and the shard length (the alignment that makes every
-    static-schedule window start a whole number of blocks), whose
-    working set fits the VMEM budget for feature width ``d``. 0 when no
-    such tile exists (callers run the XLA round) — the shape gate of the
-    SGD kernel."""
-    import math
-
-    g = math.gcd(lb, local_n)
-    for t in range(min(1024, g) - min(1024, g) % 8, 7, -8):
-        if g % t != 0:
-            continue
-        working = (2 * t * d + 4 * t + 2 * d + (d + 2)) * 4
-        if working <= SGD_VMEM_BUDGET_BYTES:
-            return t
-    return 0
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("loss_name", "lb", "tile", "interpret"))
-def _sgd_terms_padded(xl, yl, wl, coeffs, scalars, loss_name, lb, tile,
-                      interpret=False):
-    from jax.experimental.pallas import tpu as pltpu
-
-    from flink_ml_tpu.ops.losses import LossFunc
-
-    terms = LossFunc.by_name(loss_name).terms
-    n, d = xl.shape
-    # labels and weights ride ONE lane-dense (blocks, 2, tile) operand —
-    # a block is then (1, 2, tile) with its last two dims whole
-    yw = jnp.stack([yl.reshape(n // tile, tile),
-                    wl.reshape(n // tile, tile)], axis=1)
-    kernel = functools.partial(_sgd_terms_kernel, terms, tile)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(lb // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i, s: (s[0] + i, 0)),
-            pl.BlockSpec((1, 2, tile), lambda i, s: (s[0] + i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i, s: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d + 2), lambda i, s: (0, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        name="sgd_batch_terms",
-        out_shape=jax.ShapeDtypeStruct((1, d + 2), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(scalars, xl, yw, coeffs[None, :])[0]
-
-
-def sgd_batch_terms(xl, yl, wl, coeffs, start, clip, lb: int, tile: int,
-                    loss_name: str, interpret: bool = False):
-    """Packed [grad sums | weight sum | loss sum] (d+2,) over the
-    contiguous batch window [start, start+lb) of this shard — fused
-    forward+terms+gradient, one pass over the window.
-
-    ``start`` must be a whole number of ``tile`` blocks and ``tile`` must
-    divide the shard length (the static-schedule gate ``sgd_round_tile``
-    guarantees both when lb and local_n share the tile); rows whose
-    window-relative index is below ``clip`` weigh 0 (the clip-at-end
-    round). ``start``/``clip`` may be traced scalars — they ride the
-    scalar-prefetch slot, so every round reuses one compiled kernel.
-    """
-    scalars = jnp.stack([jnp.asarray(start, jnp.int32) // tile,
-                         jnp.asarray(clip, jnp.int32)])
-    return _sgd_terms_padded(xl, yl, wl, jnp.asarray(coeffs, jnp.float32),
-                             scalars, loss_name, lb, tile,
-                             interpret=interpret)
 
 
 # -- fused segment-reduce (scatter-add by segment id) ------------------------

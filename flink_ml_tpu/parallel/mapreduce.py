@@ -147,7 +147,7 @@ def map_shards(fn, mesh, in_specs, out_specs, *, check_vma: bool = False,
     The program's name on the device trace (``XLA Modules``:
     ``jit_<name>``) is ``fn.__name__`` — ``shard_map`` and ``jit`` carry
     it through — so fit bodies are given stable names (``sgd_segment``,
-    ``sgd_unrolled``, ``sgd_round``), not ``per_shard``. ``name=`` is the
+    ``sgd_round``), not ``per_shard``. ``name=`` is the
     compile-accounting label, not that name, and changes the dispatch."""
     mapped = _shard_map(fn, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs, check_vma=check_vma)

@@ -1,7 +1,7 @@
 """Where XLA's persistent compilation cache lives.
 
 A chip call starts from a clean machine and the first compile of the
-unrolled SGD program or of each serving bucket costs tens of seconds;
+SGD program or of each serving bucket costs tens of seconds;
 the persistent cache lets a second process on the same machine reuse
 them. The cache directory is part of nothing's key but must not MOVE
 between processes that want to share it, so it is either where the

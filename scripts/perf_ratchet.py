@@ -49,7 +49,7 @@ MLTRACE = os.path.join(REPO, "scripts", "mltrace.py")
 
 #: the shared traced workload — run from BOTH worktrees, so it may only
 #: use APIs that exist at the merge-base (the PR<=10 public surface):
-#: a plain LogisticRegression fit (unrolled SGD program), a checkpointed
+#: a plain LogisticRegression fit, a checkpointed
 #: segment-mode fit, KMeans device + segment-mode fits, and an FTRL
 #: stream fit. Prints per-fit wall ms as JSON; tracing/metrics land in
 #: FLINK_ML_TPU_TRACE_DIR.

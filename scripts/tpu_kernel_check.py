@@ -33,7 +33,6 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
         print("kernel check needs the TPU backend", file=sys.stderr)
         return 1
     from flink_ml_tpu.ops import pallas_kernels as pk
-    from flink_ml_tpu.ops.losses import LossFunc
 
     rng = np.random.default_rng(7)
     failures, errors = [], []
@@ -152,22 +151,6 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
                   (packed["v"][:, :-1] - want) / np.maximum(want, 1.0),
                   np.zeros_like(want), rtol=0, atol=1e-6)
 
-    yl = (rng.random(2048) > 0.5).astype(np.float32)
-    wl = (rng.random(2048) + 0.5).astype(np.float32)
-    coeffs = rng.normal(size=16).astype(np.float32)
-    for loss_name in ("logistic", "hinge", "least_square"):
-        loss = LossFunc.by_name(loss_name)
-        lb, tile, start, clip = 512, 64, 1024, 3
-        wb = wl[start:start + lb] * (np.arange(lb) >= clip)
-        ls, grad = loss.loss_and_gradient(
-            coeffs, x[start:start + lb], yl[start:start + lb],
-            wb.astype(np.float32))
-        want = np.concatenate([np.asarray(grad), [wb.sum(), float(ls)]])
-        check(f"sgd_batch_terms[{loss_name}]",
-              lambda ln=loss_name: pk.sgd_batch_terms(
-                  x, yl, wl, coeffs, start, clip, lb, tile, ln),
-              want, rtol=5e-2, atol=0.5)
-
     # segment-reduce: out-of-range ids (negative padding included) must
     # drop like jax.ops.segment_sum; a ragged final tile; one and two
     # value columns
@@ -187,9 +170,8 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
     # -- benchmark-scale phase: kernel path vs the XLA path at the shapes
     # the fits use, both ON CHIP. The small-shape phase above proves the
     # lowering against numpy; this phase bounds kernel-vs-XLA drift at
-    # scale (SGD 100k-row batch window at d=100, Lloyd partials at
-    # 1M x 100 k=10, KNN over a multi-tile 200k train set, the FTRL
-    # sparse program's two segment-reduces).
+    # scale (Lloyd partials at 1M x 100 k=10, KNN over a multi-tile 200k
+    # train set, the FTRL sparse program's two segment-reduces).
     if not small_only:
         import jax.numpy as jnp
 
@@ -247,41 +229,6 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
         check("assign_nearest@1Mx100(dist@chosen)",
               lambda: dist_at(xd, cd, pk.assign_nearest(xd, cd)), best,
               rtol=1e-3, atol=1e-2)
-
-        # SGD batch terms, north-star LR shape (window 100k of 1M, d=100);
-        # the shrunk window stays a multiple of 8 so a valid tile exists
-        nS, dS = (1 << 20) // shrink, 100
-        lbS = max(64, (100_000 // shrink) & ~7)
-        xs = rng.normal(size=(nS, dS)).astype(np.float32)
-        ys = (rng.random(nS) > 0.5).astype(np.float32)
-        ws = np.ones(nS, np.float32)
-        cfs = (rng.normal(size=dS) * 0.1).astype(np.float32)
-        # the fit's own gate picks the tile; the shard is cut to a whole
-        # number of tiles the way the static schedule guarantees
-        tile = pk.sgd_round_tile(lbS, (nS // lbS) * lbS, dS)
-        nS = (nS // lbS) * lbS
-        if tile:
-            loss = LossFunc.by_name("logistic")
-            xd2, yd2, wd2 = (jnp.asarray(xs[:nS]), jnp.asarray(ys[:nS]),
-                             jnp.asarray(ws[:nS]))
-
-            @jax.jit
-            def sgd_xla(x, y, w, c):
-                ls, grad = loss.loss_and_gradient(
-                    c, jax.lax.dynamic_slice_in_dim(x, lbS, lbS),
-                    jax.lax.dynamic_slice_in_dim(y, lbS, lbS),
-                    jax.lax.dynamic_slice_in_dim(w, lbS, lbS))
-                return jnp.concatenate(
-                    [grad, jnp.stack([jnp.sum(
-                        jax.lax.dynamic_slice_in_dim(w, lbS, lbS)), ls])])
-
-            want = np.asarray(sgd_xla(xd2, yd2, wd2, jnp.asarray(cfs)))
-            check(f"sgd_batch_terms@100kx100(tile {tile})",
-                  lambda: pk.sgd_batch_terms(xd2, yd2, wd2, cfs, lbS, 0,
-                                             lbS, tile, "logistic"),
-                  want, rtol=1e-3, atol=np.abs(want).max() * 1e-3)
-        else:
-            errors.append("sgd_batch_terms@100kx100: no admissible tile")
 
         # KNN streamed top-k over a multi-tile train set vs lax.top_k
         nK, dK, ntK, kK = (max(256, 4096 // shrink), 100,

@@ -52,18 +52,16 @@ def interpreted_kernels(monkeypatch):
     cleared around the test so no other test sees a program built under
     the patch."""
     from flink_ml_tpu.models.clustering import kmeans as km
-    from flink_ml_tpu.ops import optimizer as om
     from flink_ml_tpu.ops import pallas_kernels as pk
 
     def clear():
-        om._build_sgd_unrolled_program.cache_clear()
         km._build_lloyd_program.cache_clear()
         km._build_lloyd_segment_program.cache_clear()
         km._build_assign_program.cache_clear()
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
     for name in ("assign_nearest", "knn_topk_indices", "lloyd_partial_sums",
-                 "sgd_batch_terms", "segment_reduce_sum"):
+                 "segment_reduce_sum"):
         orig = getattr(pk, name)
         monkeypatch.setattr(
             pk, name,

@@ -20,7 +20,7 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402
 
 KERNELS = ("assign_nearest", "knn_topk_indices", "lloyd_partial_sums",
-           "sgd_batch_terms", "segment_reduce_sum")
+           "segment_reduce_sum")
 
 
 def test_main_refuses_a_non_tpu_platform():
@@ -52,33 +52,29 @@ def test_smoke_alone_in_a_directory_fails(tmp_path):
     assert '"ok": true' not in proc.stdout
 
 
-def test_lr_fit_phase_tiny(mesh8, interpreted_kernels):
+def test_lr_fit_phase_tiny(mesh8):
     # 32768 x 100 keeps the device datagen path (>= 8 MB) and gives each
-    # of the 8 shards 4096 rows, a 512-row batch share and a 512 tile
+    # of the 8 shards 4096 rows and a 512-row batch share
     out = chip_smoke.lr_fit_phase(
         mesh8, stage={"maxIter": 4, "globalBatchSize": 4096},
         data={"numValues": 32768}, ckpt_rounds=4, ckpt_interval=2,
         sample_rows=4096, min_accuracy=0.5, max_loss=float(np.log(2.0)),
         ref_tol=1e-3, seg_tol=1e-3)
-    assert out["vendored"]["executionPath"] == "pallas-unrolled"
+    assert out["vendored"]["executionPath"] == "xla-while"
+    assert out["learnablePath"] == "xla-while"
     assert out["segmentPath"] == "xla-while-segments"
     assert out["input"] == {"shards": 8, "rowsPerShard": 4096}
     assert out["refRelErr"] < 1e-3
 
 
 def test_lr_fit_phase_fails_on_a_wrong_path(mesh8, monkeypatch):
-    """Without the kernel gate patched open the fit takes the XLA path;
-    a phase told to expect the kernel path must FAIL, not say ok."""
-    from flink_ml_tpu.ops import pallas_kernels as pk
-
-    real = pk.pallas_supported
-    calls = {"n": 0}
-
-    def flip():  # True for the phase's expectation, real for the fit
-        calls["n"] += 1
-        return True if calls["n"] == 1 else real()
-
-    monkeypatch.setattr(pk, "pallas_supported", flip)
+    """A fit whose row reports another path than the plain fit's one
+    program must FAIL the phase, not say ok."""
+    real = chip_smoke.run_row
+    monkeypatch.setattr(
+        chip_smoke, "run_row",
+        lambda name, spec: {**real(name, spec),
+                            "executionPath": "host-rounds"})
     with pytest.raises(chip_smoke.SmokeFailure, match="expected"):
         chip_smoke.lr_fit_phase(
             mesh8, stage={"maxIter": 2, "globalBatchSize": 4096},

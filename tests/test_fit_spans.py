@@ -169,12 +169,12 @@ def test_fit_under_capture_is_one_trace_in_the_ring_and_in_the_xplane(
     with Capture(tmp_path) as cap:
         est = estimator()
         est.fit(table)
-    assert est.last_execution_path == "xla-unrolled"
+    assert est.last_execution_path == "xla-while"
     by_name, by_id = one_trace("LogisticRegression.fit")
     assert set(by_name) == set(TREE) | {"LogisticRegression.fit"}
     assert_tree(by_name, by_id, TREE)
     optimize = by_name["sgd.optimize"][0]
-    assert optimize["attrs"]["path"] == "xla-unrolled"
+    assert optimize["attrs"]["path"] == "xla-while"
     assert optimize["attrs"]["rounds"] == 4
     assert optimize["attrs"]["shards"] == 8
 
@@ -194,8 +194,7 @@ def test_fit_under_capture_is_one_trace_in_the_ring_and_in_the_xplane(
 
 
 def _while(monkeypatch, est, tmp_path):
-    monkeypatch.setattr(opt_mod, "_UNROLL_MAX_ROUNDS", 0)
-    return {}
+    return {}  # the plain fit: nothing to arrange
 
 
 def _segments(monkeypatch, est, tmp_path):
@@ -287,8 +286,6 @@ def _program_args(mesh, n=1600, d=6):
     (lambda mesh, prm: opt_mod._build_sgd_segment_program(
         BinaryLogisticLoss, mesh, prm), "jit_sgd_segment",
      (jnp.int32(0), jnp.int32(4))),
-    (lambda mesh, prm: opt_mod._build_sgd_unrolled_program(
-        BinaryLogisticLoss, mesh, prm), "jit_sgd_unrolled", ()),
     (lambda mesh, prm: jax.jit(opt_mod._build_sgd_round_program(
         BinaryLogisticLoss, mesh, prm)), "jit_sgd_round", ()),
 ])
@@ -310,7 +307,6 @@ def test_the_small_programs_of_a_fit_are_named(table, tmp_path, monkeypatch):
     from flink_ml_tpu.observability import health
     from flink_ml_tpu.parallel import collective
 
-    monkeypatch.setattr(opt_mod, "_UNROLL_MAX_ROUNDS", 0)
     monkeypatch.setenv(health.HEALTH_ENV, "1")
     opt_mod._health_hist_program.cache_clear()
     seen = []

@@ -414,7 +414,7 @@ def test_health_cli_check_empty_dir(tmp_path):
 
 # -- compiled program variants (shard_map-gated, run in CI) -------------------
 
-def test_dense_unrolled_fit_records_series(tmp_path, monkeypatch, rng):
+def test_dense_plain_fit_records_series(tmp_path, monkeypatch, rng):
     trace_dir = tmp_path / "trace"
     monkeypatch.setenv(TRACE_DIR_ENV, str(trace_dir))
     table = dense_regression_table(rng)

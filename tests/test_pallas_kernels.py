@@ -275,77 +275,6 @@ def test_knn_predict_kernel_path_matches_xla(rng, monkeypatch):
     np.testing.assert_array_equal(pred_k, pred_x)
 
 
-@pytest.mark.parametrize("loss_name", ["logistic", "hinge", "least_square"])
-def test_sgd_batch_terms_matches_xla(rng, loss_name):
-    """The fused batch-terms kernel must equal loss_and_gradient on the
-    same window — including a dynamic start and a clip mask."""
-    from flink_ml_tpu.ops.losses import LossFunc
-    from flink_ml_tpu.ops.pallas_kernels import sgd_batch_terms
-
-    n, d, lb, tile = 64, 5, 16, 8
-    xl = rng.normal(size=(n, d)).astype(np.float32)
-    yl = (rng.random(n) > 0.5).astype(np.float32)
-    wl = (rng.random(n) + 0.5).astype(np.float32)
-    coeffs = rng.normal(size=d).astype(np.float32)
-    loss = LossFunc.by_name(loss_name)
-    for start, clip in ((0, 0), (16, 0), (48, 5)):
-        got = np.asarray(sgd_batch_terms(
-            xl, yl, wl, coeffs, start, clip, lb, tile, loss_name,
-            interpret=True))
-        wb = wl[start:start + lb] * (np.arange(lb) >= clip)
-        loss_sum, grad = loss.loss_and_gradient(
-            coeffs, xl[start:start + lb], yl[start:start + lb],
-            wb.astype(np.float32))
-        want = np.concatenate([np.asarray(grad),
-                               [wb.sum(), float(loss_sum)]])
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-5)
-
-
-def test_sgd_round_tile():
-    from flink_ml_tpu.ops.pallas_kernels import sgd_round_tile
-
-    assert sgd_round_tile(100_000, 10_000_000, 100) == 1000
-    assert sgd_round_tile(16, 64, 4) == 16
-    assert sgd_round_tile(7, 63, 4) == 0  # no multiple-of-8 common tile
-    assert sgd_round_tile(8, 8, 4) == 8
-    # wide features shrink the tile
-    assert 0 < sgd_round_tile(1024, 4096, 100_000) < 1024
-    assert sgd_round_tile(8, 8, 10_000_000) == 0
-
-
-def test_sgd_unrolled_kernel_program_matches_xla(rng, monkeypatch):
-    """The unrolled fit with kernel rounds (interpret-mode pallas inside
-    shard_map) must match the plain unrolled fit."""
-    from flink_ml_tpu.ops import optimizer as om
-    from flink_ml_tpu.ops import pallas_kernels as pk
-    from flink_ml_tpu.ops.losses import BinaryLogisticLoss
-    from flink_ml_tpu.parallel.mesh import create_mesh
-
-    # interpret-mode kernels run anywhere: patch the gate open and the
-    # kernel to interpret mode
-    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    orig = pk.sgd_batch_terms
-    monkeypatch.setattr(
-        pk, "sgd_batch_terms",
-        lambda *a, **k: orig(*a, **{**k, "interpret": True}))
-
-    mesh = create_mesh()
-    x = rng.normal(size=(2048, 6)).astype(np.float64)
-    y = (rng.random(2048) > 0.5).astype(np.float64)
-    prm = om.SGDParams(learning_rate=0.1, global_batch_size=512,
-                       max_iter=5, tol=0.0)
-    sgd = om.SGD(prm)
-    om._build_sgd_unrolled_program.cache_clear()
-    c_kernel, l_kernel = sgd.optimize(BinaryLogisticLoss(), np.zeros(6),
-                                      x, y)
-    om._build_sgd_unrolled_program.cache_clear()
-    monkeypatch.setattr(pk, "pallas_supported", lambda: False)
-    c_xla, l_xla = sgd.optimize(BinaryLogisticLoss(), np.zeros(6), x, y)
-    om._build_sgd_unrolled_program.cache_clear()
-    np.testing.assert_allclose(c_kernel, c_xla, rtol=1e-5, atol=1e-7)
-    np.testing.assert_allclose(l_kernel, l_xla, rtol=1e-5)
-
-
 def test_segment_reduce_sum_matches_segment_sum(rng):
     """The fused segment-reduce kernel must equal jax.ops.segment_sum —
     1-D and 2-D values, out-of-range ids dropped, padding inert."""
@@ -453,24 +382,6 @@ def test_ftrl_sparse_kernel_program_matches_xla(rng):
 def _mosaic_failure(*args, **kwargs):
     raise NotImplementedError(
         "Unimplemented primitive in Pallas TPU lowering (synthetic)")
-
-
-def test_sgd_kernel_failure_propagates(rng, monkeypatch):
-    from flink_ml_tpu.ops import optimizer as om
-    from flink_ml_tpu.ops import pallas_kernels as pk
-    from flink_ml_tpu.ops.losses import BinaryLogisticLoss
-
-    monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    monkeypatch.setattr(pk, "sgd_batch_terms", _mosaic_failure)
-    x = rng.normal(size=(2048, 6))
-    y = (rng.random(2048) > 0.5).astype(np.float64)
-    sgd = om.SGD(om.SGDParams(global_batch_size=512, max_iter=3, tol=0.0))
-    om._build_sgd_unrolled_program.cache_clear()
-    try:
-        with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
-            sgd.optimize(BinaryLogisticLoss(), np.zeros(6), x, y)
-    finally:
-        om._build_sgd_unrolled_program.cache_clear()
 
 
 def test_kmeans_transform_kernel_failure_propagates(rng, monkeypatch):
