@@ -53,10 +53,7 @@ from flink_ml_tpu.parallel.mesh import (
 )
 from flink_ml_tpu.parallel import mapreduce as mr
 from flink_ml_tpu.parallel import update_sharding as _upd
-from flink_ml_tpu.parallel.collective import (
-    ensure_on_mesh,
-    ones_on_mesh,
-)
+from flink_ml_tpu.parallel.collective import ensure_on_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,7 +222,8 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
 
 
 def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
-                    model_axis=None, sharded: bool = False):
+                    model_axis=None, sharded: bool = False,
+                    weighted: bool = True, n_valid: Optional[int] = None):
     """The per-shard math of ONE training round — shared verbatim by the
     all-device while_loop program and the host-driven round program so the
     two modes stay numerically identical by construction.
@@ -234,6 +232,13 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
     (coeffs, opt, new_offset, mean_loss)`` operating on this shard's
     slice; must be called inside shard_map over the mesh's data axes
     (``axes`` — a flat ("data",) mesh or a ("dcn", "data") hybrid).
+
+    Without ``weighted`` (a fit with no weight column) ``wl`` is ``None``
+    and every row of the batch weighs 1: the batch weight is the round's
+    own validity mask. ``n_valid`` is then the true row count when the
+    inputs were zero-padded to divide over the data axes (``None`` when
+    nothing was padded): the padded rows weigh 0, as a padded weight
+    column's would.
 
     With ``model_axis`` (tensor parallelism for wide models — a TPU-native
     capability beyond the reference's DP-only design), the feature
@@ -268,10 +273,18 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
         start = jnp.minimum(offset, local_n - lb_max)
         xb = jax.lax.dynamic_slice_in_dim(xl, start, lb_max, axis=0)
         yb = jax.lax.dynamic_slice_in_dim(yl, start, lb_max, axis=0)
-        ws = jax.lax.dynamic_slice_in_dim(wl, start, lb_max, axis=0)
+        ws = (jax.lax.dynamic_slice_in_dim(wl, start, lb_max, axis=0)
+              if weighted else None)
         src = start + jnp.arange(lb_max)
         valid = jnp.logical_and(src >= offset, src < offset + lb)
-        wb = ws * valid.astype(xl.dtype)
+        if weighted:
+            wb = ws * valid.astype(xl.dtype)
+        else:
+            if n_valid is not None:
+                # the rows ensure_on_mesh padded on weigh nothing
+                valid = jnp.logical_and(
+                    valid, task_id * local_n + src < n_valid)
+            wb = valid.astype(xl.dtype)
 
         coeffs, opt, mean_loss = update(coeffs, opt, xb, yb, wb)
         new_offset = jnp.where(offset + lb >= local_n, 0, offset + lb)
@@ -284,7 +297,9 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
 def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                health: bool = False,
                                sharded: bool = False,
-                               fused: bool = False):
+                               fused: bool = False,
+                               weighted: bool = True,
+                               n_valid: Optional[int] = None):
     """A K-round slice of the training loop as ONE compiled SPMD program:
     ``segment(xs, ys, ws, coeffs, offsets, opt, epoch0, limit, hist,
     fin) -> (coeffs, offsets, opt, mean_loss, epoch, stop, hist, fin)``.
@@ -304,6 +319,11 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     The plain (uncheckpointed) fit is the degenerate call
     ``segment(..., epoch0=0, limit=max_iter)`` — ONE program serves both,
     so the two paths cannot drift numerically.
+
+    Without ``weighted`` the fit has no weight column: ``ws`` is ``None``,
+    the program takes no weight operand and a row's weight is its
+    validity in the round's batch (:func:`_sgd_round_math`, where
+    ``n_valid`` is explained too).
 
     With ``health`` (observability/health.py), the signature grows two
     trailing carries and each round writes its ``(loss, update norm,
@@ -329,7 +349,8 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     model_axis = model_axis_of(mesh)
     wspec = P(model_axis) if model_axis else P()
     round_step = _sgd_round_math(loss_cls(), prm, p, axes, model_axis,
-                                 sharded=sharded)
+                                 sharded=sharded, weighted=weighted,
+                                 n_valid=n_valid)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
     def run(xl, yl, wl, coeffs, offsets, opt, epoch0, limit, hist, fin):
@@ -400,19 +421,22 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
 
 @functools.lru_cache(maxsize=128)
 def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
-                             sharded: bool = False):
+                             sharded: bool = False,
+                             weighted: bool = True,
+                             n_valid: Optional[int] = None):
     """ONE training round as a compiled mapped program — the building
     block of the checkpointable host loop (iterate_bounded calls it as it
     is: nothing is jitted per fit). Wraps the same _sgd_round_math as the
     all-device program, so device and host modes are numerically
-    identical by construction."""
+    identical by construction (``weighted`` and ``n_valid`` as there)."""
     axes = data_axes(mesh)
     spec0 = data_pspec(mesh)
     p = data_shard_count(mesh)
     model_axis = model_axis_of(mesh)
     wspec = P(model_axis) if model_axis else P()
     round_step = _sgd_round_math(loss_cls(), prm, p, axes, model_axis,
-                                 sharded=sharded)
+                                 sharded=sharded, weighted=weighted,
+                                 n_valid=n_valid)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
     def sgd_round(xl, yl, wl, coeffs, offsets, opt):
@@ -627,7 +651,9 @@ class SGD:
         blocking device→host reads) and ``sgd.health``."""
         mesh = mesh or default_mesh()
         with tracer.span("sgd.optimize", rounds=self.params.max_iter,
-                         shards=data_shard_count(mesh)) as sp:
+                         shards=data_shard_count(mesh),
+                         weights="unit" if weights is None
+                         else "column") as sp:
             out = self._optimize(loss_func, init_coeffs, features, labels,
                                  weights, mesh, dtype, config, listeners,
                                  tag)
@@ -649,6 +675,8 @@ class SGD:
         d = features.shape[1]
 
         axes = data_axes(mesh)
+        p = data_shard_count(mesh)
+        spec0 = data_pspec(mesh)
         init_coeffs = np.asarray(init_coeffs)
         tp = model_axis_of(mesh) is not None
         # cross-replica sharded update (update_sharding.py; DP meshes
@@ -658,7 +686,7 @@ class SGD:
         # coords stay exactly zero: zero grad → soft-threshold(0) = 0)
         sharded = _upd.enabled() and not tp
         if sharded:
-            pad = (-d) % data_shard_count(mesh)
+            pad = (-d) % p
             if pad:
                 init_coeffs = np.pad(init_coeffs, (0, pad))
         from jax.sharding import NamedSharding
@@ -671,8 +699,7 @@ class SGD:
                 pad = (-d) % tp_size
                 if pad:
                     init_coeffs = np.pad(init_coeffs, (0, pad))
-                spec0 = data_pspec(mesh)
-                rem = (-n) % data_shard_count(mesh)
+                rem = (-n) % p
                 x_sharding = NamedSharding(mesh, P(spec0, MODEL_AXIS))
                 if isinstance(features, jax.Array):
                     # device-resident input: cast/pad/reshard on device —
@@ -693,14 +720,17 @@ class SGD:
                 xs, _ = ensure_on_mesh(mesh, features, axes, jnp.float32)
                 w_sharding = NamedSharding(mesh, P())
             ys, _ = ensure_on_mesh(mesh, labels, axes, jnp.float32)
-            if weights is None:
-                ws = ones_on_mesh(mesh, n, axes, jnp.float32)
-            else:
+            # no weight column: none is built. The programs take no
+            # weight operand and a row weighs 1 where its round's batch
+            # holds it; rows padded on above weigh 0 by the row count
+            weighted = weights is not None
+            ws = n_valid = None
+            if weighted:
                 ws, _ = ensure_on_mesh(mesh, weights, axes, jnp.float32)
+            elif n % p:
+                n_valid = n
         from flink_ml_tpu.iteration.iteration import (
             device_checkpoint_segment, needs_host_loop, run_segmented)
-        p = data_shard_count(mesh)
-        spec0 = data_pspec(mesh)
 
         # carry leaves must live on the full mesh (replicated or
         # model-sharded coeffs, per-task offsets, moment vectors sharded
@@ -754,7 +784,9 @@ class SGD:
                                                       self.params,
                                                       health=health_on,
                                                       sharded=sharded,
-                                                      fused=fused)
+                                                      fused=fused,
+                                                      weighted=weighted,
+                                                      n_valid=n_valid)
                 # health carry lives OUTSIDE the checkpointed carry so the
                 # snapshot format is identical with telemetry on or off; a
                 # restore simply resumes the series at its epoch (earlier
@@ -837,7 +869,8 @@ class SGD:
 
         with tracer.span("sgd.build_program"):
             round_fn = _build_sgd_round_program(
-                type(loss_func), mesh, self.params, sharded=sharded)
+                type(loss_func), mesh, self.params, sharded=sharded,
+                weighted=weighted, n_valid=n_valid)
 
         def body(carry, epoch):
             coeffs, offsets, _, opt = carry

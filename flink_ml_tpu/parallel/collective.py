@@ -424,25 +424,3 @@ def ensure_on_mesh(mesh: Mesh, array, axis_name=DATA_AXIS, dtype=None):
             return jax.device_put(array, sharding), n
         return _prepare_program(rem, want.name, sharding,
                                 array.ndim)(array), n
-
-
-@functools.lru_cache(maxsize=128)
-def _ones_program(padded: int, dtype_name: str, sharding):
-    dtype = jnp.dtype(dtype_name)
-
-    def ones_rows(n):
-        return (jnp.arange(padded) < n).astype(dtype)
-
-    return jax.jit(ones_rows, out_shardings=sharding)
-
-
-def ones_on_mesh(mesh: Mesh, n: int, axis_name=DATA_AXIS,
-                 dtype=jnp.float32):
-    """A length-``n`` ones vector (zero-padded to the shard multiple),
-    generated directly sharded ON device — the default sample-weight column
-    without a host allocation or transfer. ``n`` is a traced argument, so
-    one compiled program per padded length serves all true counts."""
-    n_shards, sharding = _dim0_layout(mesh, axis_name, 1)
-    padded = n + ((-n) % n_shards)
-    return _ones_program(padded, jnp.dtype(dtype).name, sharding)(
-        jnp.int32(n))

@@ -71,18 +71,21 @@ class Capture:
         jax.profiler.stop_trace()
         return False
 
-    def host_events(self, names):
-        """``[(name, start, end)]`` of the capture's host events so named,
-        sorted outer before inner."""
+    def events(self):
+        """Every event of the capture's host planes."""
         from jax.profiler import ProfileData
 
         path, = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
                           recursive=True)
+        return [ev for plane in ProfileData.from_file(path).planes
+                if not plane.name.startswith("/device:")
+                for line in plane.lines for ev in line.events]
+
+    def host_events(self, names):
+        """``[(name, start, end)]`` of the capture's host events so named,
+        sorted outer before inner."""
         events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
-                  for plane in ProfileData.from_file(path).planes
-                  if not plane.name.startswith("/device:")
-                  for line in plane.lines for ev in line.events
-                  if ev.name in names]
+                  for ev in self.events() if ev.name in names]
         return sorted(events, key=lambda e: (e[1], -e[2]))
 
 
@@ -177,6 +180,7 @@ def test_fit_under_capture_is_one_trace_in_the_ring_and_in_the_xplane(
     assert optimize["attrs"]["path"] == "xla-while"
     assert optimize["attrs"]["rounds"] == 4
     assert optimize["attrs"]["shards"] == 8
+    assert optimize["attrs"]["weights"] == "unit"
 
     # the same spans in the profiler's own file, nested the same way
     events = cap.host_events(set(by_name))
@@ -300,21 +304,27 @@ def test_programs_lower_under_stable_names_with_the_round_scoped(
         assert scope in text, scope
 
 
-def test_the_small_programs_of_a_fit_are_named(table, tmp_path, monkeypatch):
-    """``ones_rows`` and ``sgd_health_hist`` are what the device trace
-    showed as ``jit_make`` and ``jit__unknown``. The history exists with
-    health armed alone, and its program is built once a process."""
+@pytest.fixture
+def built(monkeypatch):
+    """The names of the functions ``jax.jit`` is given, as they come."""
+    names = []
+    real_jit = jax.jit
+    monkeypatch.setattr(
+        jax, "jit", lambda fn, *a, **k: (names.append(fn.__name__),
+                                         real_jit(fn, *a, **k))[1])
+    return names
+
+
+def test_the_small_programs_of_a_fit_are_named(table, built, monkeypatch):
+    """``sgd_health_hist`` is what the device trace showed as
+    ``jit__unknown``. The history exists with health armed alone, and its
+    program is built once a process. A fit with no weight column has no
+    third small program: it builds no column."""
     from flink_ml_tpu.observability import health
     from flink_ml_tpu.parallel import collective
 
     monkeypatch.setenv(health.HEALTH_ENV, "1")
     opt_mod._health_hist_program.cache_clear()
-    seen = []
-    real_jit = jax.jit
-    monkeypatch.setattr(
-        jax, "jit", lambda fn, *a, **k: (seen.append(fn.__name__),
-                                         real_jit(fn, *a, **k))[1])
-    collective._ones_program.cache_clear()
     collective._prepare_program.cache_clear()
     # a device-resident table whose rows do not divide over the mesh is
     # padded on the device
@@ -322,6 +332,62 @@ def test_the_small_programs_of_a_fit_are_named(table, tmp_path, monkeypatch):
     y = np.asarray(table.column("label"))[:1999]
     opt_mod.SGD(SGDParams(max_iter=4, global_batch_size=160)).optimize(
         BinaryLogisticLoss(), np.zeros(6), x, y, None)
-    collective._ones_program.cache_clear()
     collective._prepare_program.cache_clear()
-    assert {"ones_rows", "sgd_health_hist", "prepare_rows"} <= set(seen)
+    assert {"sgd_health_hist", "prepare_rows"} <= set(built)
+    assert set(built) <= {"sgd_health_hist", "prepare_rows", "sgd_segment"}
+
+
+# -- a fit with no weight column builds none -----------------------------------
+
+def _resident(mesh, table, weights):
+    """The table's columns where a resident table holds them: on the mesh,
+    rows over the data axis (a placement ``ensure_on_mesh`` leaves be)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    rows = NamedSharding(mesh, P("data"))
+    x = jax.device_put(np.asarray(table.column("features")), rows)
+    y = jax.device_put(np.asarray(table.column("label")), rows)
+    w = (jax.device_put(np.ones(x.shape[0], np.float32), rows)
+         if weights == "column" else None)
+    return x, y, w
+
+
+@pytest.mark.parametrize("weights", ["unit", "column"])
+def test_a_fit_builds_and_runs_its_segment_program_and_no_other(
+        table, tmp_path, mesh8, built, weights):
+    """Cold, a plain fit builds one program, ``sgd_segment``; warm it
+    builds none and dispatches that one: with no weight column nothing
+    writes one, and ``sgd.optimize`` says which of the two the fit was."""
+    x, y, w = _resident(mesh8, table, weights)
+    opt_mod._build_sgd_segment_program.cache_clear()
+
+    def fit():
+        sgd = opt_mod.SGD(SGDParams(max_iter=4, global_batch_size=160))
+        sgd.optimize(BinaryLogisticLoss(), np.zeros(6), x, y, w, mesh=mesh8)
+        assert sgd.last_execution_path == "xla-while"
+
+    fit()
+    assert built == ["sgd_segment"]
+    with Capture(tmp_path) as cap:
+        fit()
+    assert built == ["sgd_segment"]
+    optimize, = (r for r in tracer.recent if r["name"] == "sgd.optimize")
+    assert optimize["attrs"]["weights"] == weights
+
+    # what the capture saw the runtime do: every jitted call and every
+    # XLA program the fit's thread ran (jax traces both by these names)
+    events = [ev.name for ev in cap.events()]
+    assert {e for e in events if e.startswith("PjitFunction(")} == {
+        "PjitFunction(sgd_segment)"}
+    assert events.count("PjRtCpuExecutable::Execute") == 1
+
+
+def test_a_fit_with_a_weight_column_says_so(table, tmp_path):
+    x = np.asarray(table.column("features"))
+    weighted = Table.from_columns(
+        features=x, label=np.asarray(table.column("label")),
+        weight=np.linspace(0.5, 1.5, x.shape[0]).astype(np.float32))
+    with Capture(tmp_path):
+        estimator().set_weight_col("weight").fit(weighted)
+    by_name, _ = one_trace("LogisticRegression.fit")
+    assert by_name["sgd.optimize"][0]["attrs"]["weights"] == "column"

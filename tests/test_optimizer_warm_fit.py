@@ -7,7 +7,13 @@ answers are the parent tree's to the last bit
 (``tests/fixtures/optimizer_warm_fit/golden.json``, written from commit
 6708c0f by running this file as a script there; the four cases under its
 ``later`` key by the same ``fit`` at 1297d45, the while-loop program
-chosen as the benchmark chose it).
+chosen as the benchmark chose it). The parent's fits had a weight column,
+ones it wrote anew in every fit; since PR 32 a fit with no column builds
+none, and XLA's CPU backend orders the loss's sum otherwise where it fuses
+it without the multiply by 1.0: the coefficients are the parent's to the
+last bit, the loss to 1e-6 (2e-7 at most: four of the eight-device fits
+and one health series move at all), and the same fit given a column of
+ones answers the parent's loss to the last bit too.
 """
 
 import contextlib
@@ -95,7 +101,7 @@ def make_data(est: str):
     return x, y.astype(np.float32)
 
 
-def fit(case, ckpt_dir, manager=None):
+def fit(case, ckpt_dir, manager=None, weights=None):
     """One fit of ``case`` -> (coefficients, loss, the path it reported)."""
     est, method, devices, path = case
     config = None
@@ -109,7 +115,8 @@ def fit(case, ckpt_dir, manager=None):
                         method=method))
     x, y = make_data(est)
     coeffs, loss = sgd.optimize(ESTIMATORS[est].loss, np.zeros(D), x, y,
-                                None, mesh=make_mesh(devices), config=config)
+                                weights, mesh=make_mesh(devices),
+                                config=config)
     return coeffs, loss, sgd.last_execution_path
 
 
@@ -240,10 +247,16 @@ def test_a_warm_fit_builds_nothing_and_answers_as_the_parent_did(
     # the inputs and the fetch
     assert [span for span, _ in watch.puts
             if span != "sgd.place_inputs"] == ["sgd.init_carry"]
+    assert loss_again == loss
     for got_c, got_l in ((coeffs, loss), (again, loss_again)):
         assert got_c.dtype == np.float64
         assert got_c.tolist() == want["coefficients"]
-        assert got_l == want["loss"]
+        assert got_l == pytest.approx(want["loss"], rel=1e-6, abs=0)
+    # given the column the parent's fits wrote, the parent's last bit
+    column, column_loss, _ = fit(case, tmp_path,
+                                 weights=np.ones(N, np.float32))
+    assert column.tolist() == want["coefficients"]
+    assert column_loss == want["loss"]
     parents = golden["fits"][case_id(case)]
     assert coeffs.tolist() == parents["coefficients"]
     assert loss == pytest.approx(parents["loss"], rel=1e-6, abs=0)
@@ -309,7 +322,12 @@ def test_health_armed_the_history_comes_from_one_cached_program(
         ROUNDS, jax.sharding.NamedSharding(make_mesh(case[2]), P()))
     assert prog.__name__ == "sgd_health_hist"
     assert np.isnan(np.asarray(prog())).all()
-    assert first == second == golden["health"][case_id(case)]
+    want = golden["health"][case_id(case)]
+    assert first == second and set(first) == set(want)
+    for key, series in want.items():
+        # the norms are the parent's; the loss as the header says
+        assert first[key] == pytest.approx(
+            series, rel=1e-6 if key == "loss" else 0, abs=0)
 
 
 def test_health_off_no_history_is_built(tmp_path, monkeypatch):
@@ -351,7 +369,7 @@ def test_a_checkpointed_fit_restored_midway_resumes_to_the_same_answer(
     coeffs, loss, _ = fit(case, tmp_path, manager=manager)
     assert restored[0] is not None and restored[0][1] == 2 * SEGMENT
     assert coeffs.tolist() == want["coefficients"]
-    assert loss == want["loss"]
+    assert loss == pytest.approx(want["loss"], rel=1e-6, abs=0)
 
 
 @pytest.mark.parametrize("method,want", [

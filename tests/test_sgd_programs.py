@@ -5,21 +5,47 @@ to a float64 NumPy reference of the reference implementation's round
 per task of the data axis a contiguous batch share with clip at the shard's
 end and wrap to zero, the all-reduced [grad | weight | loss], the step and
 the elastic-net shrink, and the stop at ``loss < tol``.
+
+A fit with no weight column builds none: its programs take no weight
+operand and a row weighs 1 where its round's batch holds it (0 where
+``ensure_on_mesh`` padded it on). It is held to the same fit given a device
+column of ones on every dense path, and the program of a fit that has a
+column to the parent's text (``fixtures/sgd_programs/weighted_lowered.json``,
+written from commit fe1a259 by running this file as a script there).
 """
 
 import dataclasses
+import hashlib
+import itertools
+import json
+import os
+import sys
+
+if __name__ == "__main__":  # the fixture writer: the mesh conftest.py gives
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=8")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, os.getcwd())
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from flink_ml_tpu.iteration import CheckpointManager, IterationConfig
+from flink_ml_tpu.observability import health
+from flink_ml_tpu.ops import optimizer as opt_mod
 from flink_ml_tpu.ops.losses import (
     BinaryLogisticLoss,
     HingeLoss,
     LeastSquareLoss,
 )
 from flink_ml_tpu.ops.optimizer import SGD, SGDParams
-from flink_ml_tpu.parallel import create_mesh
+from flink_ml_tpu.parallel import create_mesh, update_sharding
+
+WEIGHTED_LOWERED = os.path.join(os.path.dirname(__file__), "fixtures",
+                                "sgd_programs", "weighted_lowered.json")
 
 
 def _reference_terms(name, dots, y, w):
@@ -173,3 +199,203 @@ def test_every_plain_fit_runs_the_while_program(rng, monkeypatch, rounds,
     if unroll_max is not None:
         monkeypatch.setenv("FLINK_ML_TPU_SGD_UNROLL_MAX", unroll_max)
         assert fit() == unset
+
+
+# -- a fit with no weight column builds none ----------------------------------
+
+#: mesh name -> (shape, axis names)
+UNIT_MESHES = {"one-device": ((1,), ("data",)),
+               "data-4": ((4,), ("data",)),
+               "tensor-parallel": ((4, 2), ("data", "model"))}
+UNIT_PATHS = ("xla-while", "xla-while-segments", "host-rounds")
+#: 400 rows divide over the data axis of every mesh; 397 leave the last of
+#: four shards 97 rows and 3 of padding, which the second round's batch
+#: (rows 50-99 of each shard) reaches
+UNIT_ROWS = {"rows-divide": 400, "rows-padded": 397}
+#: the sharded update is a data-parallel mesh's: a tensor-parallel mesh
+#: never takes it, so that pairing would be the "replicated" case again
+UNIT_CASES = [
+    c for c in itertools.product(
+        UNIT_PATHS, UNIT_MESHES, ("sgd", "momentum", "adam"),
+        ("replicated", "sharded-update"), UNIT_ROWS,
+        ("health-off", "health-on"))
+    if not (c[1] == "tensor-parallel" and c[3] == "sharded-update")]
+
+
+UNIT_PRM = SGDParams(learning_rate=0.1, global_batch_size=200, max_iter=5,
+                     tol=0.0)
+
+
+def _unit_mesh(name):
+    shape, names = UNIT_MESHES[name]
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8-device CPU mesh")
+    return create_mesh(shape, names,
+                       devices=jax.devices()[:int(np.prod(shape))])
+
+
+def _unit_data(rng, rows):
+    x = rng.normal(size=(UNIT_ROWS[rows], 5)).astype(np.float32)
+    return x, (x @ rng.normal(size=5) > 0).astype(np.float32)
+
+
+def _unit_fit(path, mesh, method, x, y, w, ckpt_dir):
+    """``(coefficients, loss)`` of one 5-round fit on ``path``."""
+    config = None
+    if path == "xla-while-segments":
+        config = IterationConfig(
+            checkpoint_interval=2,
+            checkpoint_manager=CheckpointManager(str(ckpt_dir)))
+    elif path == "host-rounds":
+        config = IterationConfig(mode="host")
+    sgd = SGD(dataclasses.replace(UNIT_PRM, method=method))
+    out = sgd.optimize(BinaryLogisticLoss(), np.zeros(5), x, y, w,
+                       mesh=mesh, config=config)
+    assert sgd.last_execution_path == path
+    return out
+
+
+@pytest.mark.parametrize("path,mesh_name,method,update,rows,telemetry",
+                         UNIT_CASES, ids=map("-".join, UNIT_CASES))
+def test_no_weight_column_answers_as_a_column_of_ones(
+        rng, tmp_path, monkeypatch, path, mesh_name, method, update, rows,
+        telemetry):
+    monkeypatch.setenv(update_sharding.ENV,
+                       "1" if update == "sharded-update" else "0")
+    monkeypatch.setenv(health.HEALTH_ENV,
+                       "1" if telemetry == "health-on" else "0")
+    series, real = [], health.check_fit
+    monkeypatch.setattr(
+        health, "check_fit", lambda algo, rows, **kw: (
+            series.append({k: np.asarray(v, np.float64)
+                           for k, v in rows.items()}),
+            real(algo, rows, **kw))[1])
+    mesh = _unit_mesh(mesh_name)
+    x, y = _unit_data(rng, rows)
+    ones = jnp.ones(y.shape, jnp.float32)  # a device column, as a table's
+
+    unit = _unit_fit(path, mesh, method, x, y, None, tmp_path / "unit")
+    unit_series = series[:]
+    del series[:]
+    column = _unit_fit(path, mesh, method, x, y, ones, tmp_path / "column")
+    # multiplying by 1.0 is exact; what may differ is how the compiler
+    # orders a sum it fuses without the multiply: the cases read 0 on the
+    # coefficients and 2e-7 of the loss at most
+    assert unit[0].tolist() == column[0].tolist()
+    assert unit[1] == pytest.approx(column[1], rel=1e-6, abs=0)
+    assert len(unit_series) == len(series)
+    for got, want in zip(unit_series, series):
+        for key in want:
+            np.testing.assert_allclose(got[key], want[key], rtol=1e-6)
+
+
+def test_the_padded_rows_weigh_nothing_by_the_row_count_alone(rng):
+    """The case above is one the padding decides: the same program built
+    without the row count counts the 3 padded rows and answers otherwise."""
+    mesh = _unit_mesh("data-4")
+    x, y = _unit_data(rng, "rows-padded")
+    n = y.shape[0]
+    rows = NamedSharding(mesh, P("data"))
+    pad = [(0, 400 - n)]
+
+    def run(n_valid):
+        prog = opt_mod._build_sgd_segment_program(
+            BinaryLogisticLoss, mesh, UNIT_PRM, weighted=False,
+            n_valid=n_valid)
+        out = prog(jax.device_put(np.pad(x, pad + [(0, 0)]), rows),
+                   jax.device_put(np.pad(y, pad), rows), None,
+                   jnp.zeros((5,), jnp.float32),
+                   jax.device_put(np.zeros((4,), np.int32), rows), (),
+                   np.int32(0), np.int32(5))
+        return np.asarray(out[0], np.float64)
+
+    want, _ = _unit_fit("xla-while", mesh, "sgd", x, y,
+                        jnp.ones((n,), jnp.float32), None)
+    assert run(n).tolist() == want.tolist()
+    assert np.abs(run(None) - want).max() > 1e-5
+
+
+# -- a fit with a weight column runs the parent's program ----------------------
+
+#: program name -> (builder, its keywords, trailing operands)
+LOWERED = {
+    "segment": ("_build_sgd_segment_program", {}, ("epoch0", "limit")),
+    "segment-fused": ("_build_sgd_segment_program", {"fused": True},
+                      ("epoch0", "limit")),
+    "segment-fused-health": ("_build_sgd_segment_program",
+                             {"fused": True, "health": True},
+                             ("epoch0", "limit", "hist", "fin")),
+    "segment-fused-sharded": ("_build_sgd_segment_program",
+                              {"fused": True, "sharded": True},
+                              ("epoch0", "limit")),
+    "round": ("_build_sgd_round_program", {}, ()),
+}
+
+
+def weighted_lowered_text(program, mesh_name, method="sgd", n=400, d=8):
+    """The lowered text of ``program`` for a fit that has a weight column,
+    at ``n`` rows of ``d`` features on ``mesh_name``."""
+    builder, keywords, trailing = LOWERED[program]
+    mesh = _unit_mesh(mesh_name)
+    tp = "model" in mesh.axis_names
+    prm = dataclasses.replace(UNIT_PRM, method=method)
+    f32 = jnp.float32
+    wspec = P("model") if tp else P()
+    mspec = P("data") if keywords.get("sharded") else wspec
+
+    def shape(dims, spec, dtype=f32):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    opt = {"sgd": (), "momentum": (shape((d,), mspec),),
+           "adam": (shape((d,), mspec),) * 2 + (shape((), P()),)}[method]
+    operands = {"epoch0": np.int32(0), "limit": np.int32(5),
+                "hist": shape((5, 3), P()), "fin": np.bool_(True)}
+    prog = getattr(opt_mod, builder)(BinaryLogisticLoss, mesh, prm,
+                                     **keywords)
+    if builder == "_build_sgd_round_program":
+        prog = jax.jit(prog)
+    # the sharded build is an ``instrumented_jit``, which keeps its jit
+    return getattr(prog, "_jitted", prog).lower(
+        shape((n, d), P("data", "model") if tp else P("data")),
+        shape((n,), P("data")), shape((n,), P("data")), shape((d,), wspec),
+        shape((mesh.shape["data"],), P("data"), jnp.int32), opt,
+        *(operands[name] for name in trailing)).as_text()
+
+
+LOWERED_CASES = [
+    ("segment", "one-device", "sgd"), ("segment-fused", "one-device", "sgd"),
+    ("segment-fused", "data-4", "sgd"), ("segment-fused", "data-4", "adam"),
+    ("segment-fused", "tensor-parallel", "momentum"),
+    ("segment-fused-health", "data-4", "sgd"),
+    ("segment-fused-sharded", "data-4", "adam"),
+    ("round", "one-device", "sgd"), ("round", "data-4", "momentum"),
+]
+
+
+@pytest.mark.parametrize("program,mesh_name,method", LOWERED_CASES,
+                         ids=map("-".join, LOWERED_CASES))
+def test_a_weighted_fit_lowers_to_the_parents_text(program, mesh_name,
+                                                   method):
+    with open(WEIGHTED_LOWERED) as f:
+        want = json.load(f)["programs"]["-".join((program, mesh_name,
+                                                  method))]
+    text = weighted_lowered_text(program, mesh_name, method)
+    assert len(text) == want["characters"]
+    assert hashlib.sha256(text.encode()).hexdigest() == want["sha256"]
+
+
+def write_weighted_lowered(path, commit):
+    out = {"commit": commit, "programs": {}}
+    for case in LOWERED_CASES:
+        text = weighted_lowered_text(*case)
+        out["programs"]["-".join(case)] = {
+            "characters": len(text),
+            "sha256": hashlib.sha256(text.encode()).hexdigest()}
+    with open(path, "w") as f:
+        json.dump(out, f, indent=1)
+        f.write("\n")
+
+
+if __name__ == "__main__":
+    write_weighted_lowered(sys.argv[1], sys.argv[2])
