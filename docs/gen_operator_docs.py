@@ -21,6 +21,14 @@ before use. Snake-case setters exist for every param
 (`stage.set_max_iter(5)`, `stage.max_iter`).
 
 """
+#: what the generated tables cannot say: model data whose columns are not
+#: upstream's (docs/deviations.md has the reasons)
+FOOTER = """## Model data that departs from upstream's
+
+| Model | `get_model_data()` columns (one row) | Upstream |
+|---|---|---|
+| `NaiveBayesModel` | `theta` (L, d, V) float64 log-probabilities; `values` (d, V) each feature's values in ascending order, NaN where a feature has fewer than V (and `theta` holds the floor there); `piArray` (L,); `labels` (L,); `floors` (L, d) what a value unseen at fit time scores | `theta` as `Map<Double, Double>[][]`, `piArray`, `labels` |
+"""
 
 
 def main():
@@ -64,6 +72,7 @@ def main():
                 params.append(f"`{p.name}` {mark}")
             lines.append(f"| `{name}` | {kind(cls)} | {', '.join(params)} |")
         lines.append("")
+    lines.append(FOOTER)
     out_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "operators.md")
     with open(out_path, "w") as f:

@@ -8,9 +8,9 @@ between the matmul and the argmin; this kernel keeps each (tile_n, k)
 distance block in VMEM and writes only the argmin — HBM traffic drops from
 O(n·k) to O(n·d + k·d + n).
 
-Four kernels: ``assign_nearest`` (KMeans predict), ``lloyd_partial_sums``
-(KMeans fit), ``segment_reduce_sum`` (scatter-add by segment id) and
-``knn_topk_indices`` (KNN). Each runs on a real TPU backend where its shape
+Five kernels: ``assign_nearest`` (KMeans predict), ``lloyd_partial_sums``
+(KMeans fit), ``category_counts`` (NaiveBayes fit), ``segment_reduce_sum``
+(scatter-add by segment id) and ``knn_topk_indices`` (KNN). Each runs on a real TPU backend where its shape
 gate admits the input; elsewhere the plain XLA path runs. Tests exercise
 the kernels in interpreter mode on CPU.
 """
@@ -286,6 +286,155 @@ def lloyd_partial_sums(x, n_valid, centroids, interpret: bool = False):
         return jnp.zeros((k, d + 1), jnp.float32)
     return _lloyd_tiles(x, jnp.asarray(n_valid, jnp.int32), centroids,
                         interpret=interpret)
+
+
+# -- one-hot contingency counts (NaiveBayes fit) ------------------------------
+
+#: row tiles the counting kernel may take, widest first. 2048 is the widest
+#: at which a tile's packed sums are exact (``_counts_kernel``); a pass over
+#: 12M x 100 with 20 values and 10 labels on a v5e took 11.8 / 13.2 ms at
+#: 2048 / 1024 rows a tile (PERF.md section 6, PR 33)
+COUNTS_TILES_N = (2048, 1024, 512, 256)
+#: what the odd value of a pair weighs in the packed one-hot: a tile has at
+#: most 2048 rows, so the even value's count stays under it, and the sum
+#: ``even + 4096 * odd <= 4096 * 2048 = 2**23`` is whole in float32
+COUNTS_ODD_WEIGHT = 4096
+#: VMEM the counting kernel's working set may claim, by
+#: ``_counts_working_bytes``' count, under Mosaic's 16 MiB scoped limit
+COUNTS_VMEM_BUDGET_BYTES = 12 << 20
+#: pairs of values up to which the kernel's loop over them is unrolled
+COUNTS_UNROLL_MAX = 16
+
+
+def _counts_working_bytes(d: int, labels: int, values: int,
+                          tile: int) -> int:
+    """Bytes of VMEM one grid step of the counting kernel is counted at.
+    A row of the tile: the double-buffered float32 ``(d, tile)`` block, the
+    half, the weight, one pair's select and its bfloat16 one-hot (22 d),
+    the label block and the labels' one-hot (10 L). Beside the tile: the
+    ``(V, L, d)`` int32 counts, double-buffered, and one pair's float32
+    product and its two int32 halves."""
+    dp, lp = -(-d // 16) * 16, -(-labels // 16) * 16
+    out = lp * (-(-d // 128) * 128) * 4
+    return tile * (22 * dp + 10 * lp) + (2 * values + 6) * out
+
+
+def counts_tile(d: int, labels: int, values: int) -> int:
+    """The widest row tile whose working set fits the VMEM budget, 0 when
+    none does (callers run the XLA form) — the counting kernel's shape
+    gate."""
+    if d < 2:       # see ``lloyd_tile``: no product with one output column
+        return 0
+    for tile in COUNTS_TILES_N:
+        if (_counts_working_bytes(d, labels, values, tile)
+                <= COUNTS_VMEM_BUDGET_BYTES):
+            return tile
+    return 0
+
+
+def counts_kernel_fits(d: int, labels: int, values: int) -> bool:
+    """True when the counting kernel has a tile for these shapes — the
+    gate ``ops/contingency.py`` applies."""
+    return counts_tile(d, labels, values) > 0
+
+
+def _counts_kernel(nv_ref, xt_ref, y_ref, out_ref):
+    """One row tile of the ``(value, label, feature)`` contingency counts,
+    entirely in VMEM. ``A = onehot(y)`` is ``(L, tile)``; for the pair of
+    values ``(2g, 2g + 1)`` ``B`` is ``(d, tile)`` and holds 1 where
+    ``x == 2g``, 4096 where ``x == 2g + 1`` and 0 elsewhere: 0, 1 and a
+    power of two are whole in bfloat16, so ``A @ B.T`` on the MXU with
+    float32 accumulation is ``even + 4096 * odd``, the two exact counts of
+    the tile's rows with label ``l``, packed: ``even <= 2048`` fits under
+    the odd one's weight and the sum is at most 2**23, whole in float32.
+    Two values ride one pass of the MXU, and one compare and select on the
+    VPU, where a plain one-hot takes two (19.2 ms against 11.8 a pass over
+    12M x 100, PERF.md section 6, PR 33). Across tiles the two halves add
+    up in int32. Nothing is scattered and no integer key is ever formed.
+
+    The rows whose index is not under ``n_valid`` — a shard's zero padding,
+    and what the ragged last tile reads past the array — are masked out of
+    ``A``, so whatever ``B`` holds there multiplies a zero. An entry that
+    is not a whole number in ``[0, V)``, or a label not one in ``[0, L)``,
+    matches nothing and is not counted: the counts of a table of ``n``
+    rows add up to ``n * d`` exactly when every entry was in range."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    slots, labels, _ = out_ref.shape            # slots: values, made even
+    tile = xt_ref.shape[1]
+    valid = i * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (1, tile), 1) < nv_ref[0]
+    label = jax.lax.broadcasted_iota(
+        jnp.int32, (labels, tile), 0).astype(jnp.float32)
+    a = ((label == y_ref[:]) & valid).astype(jnp.bfloat16)   # (L, tile)
+    x = xt_ref[:]                                            # (d, tile)
+    half = jnp.floor(x * 0.5)
+    odd = x - 2.0 * half        # 0 or 1 for a whole number, exactly
+    weight = jnp.where(odd == 0.0, 1.0, jnp.where(
+        odd == 1.0, float(COUNTS_ODD_WEIGHT), 0.0))
+
+    def one_pair(g, carry):
+        b = jnp.where(half == g.astype(jnp.float32), weight,
+                      0.0).astype(jnp.bfloat16)
+        packed = _dot(a, b, ((1,), (1,))).astype(jnp.int32)  # (L, d)
+        out_ref[pl.ds(2 * g, 2)] += jnp.stack(
+            [packed & (COUNTS_ODD_WEIGHT - 1),
+             packed >> (COUNTS_ODD_WEIGHT.bit_length() - 1)])
+        return carry
+
+    jax.lax.fori_loop(0, slots // 2, one_pair, 0,
+                      unroll=slots // 2 <= COUNTS_UNROLL_MAX)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("labels", "values", "interpret"))
+def _counts_tiles(x, y, n_valid, labels, values, interpret=False):
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = x.shape
+    tile = counts_tile(d, labels, values) or COUNTS_TILES_N[-1]
+    slots = values + values % 2
+    return pl.pallas_call(
+        _counts_kernel,
+        name="category_counts",
+        out_shape=jax.ShapeDtypeStruct((slots, labels, d), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n, tile),),
+            in_specs=[pl.BlockSpec((d, tile), lambda i, s: (0, i)),
+                      pl.BlockSpec((1, tile), lambda i, s: (0, i))],
+            out_specs=pl.BlockSpec((slots, labels, d),
+                                   lambda i, s: (0, 0, 0))),
+        interpret=interpret,
+    )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), x.T,
+      y[None, :])[:values]
+
+
+def category_counts(x, y, n_valid, labels: int, values: int,
+                    interpret: bool = False):
+    """``counts[v, l, j]``: how many of the rows ``[0, n_valid)`` have
+    label ``l`` and ``x[:, j] == v`` — one pass over ``x`` where it lies,
+    exact, in int32.
+
+    x: (n, d) float32; y: (n,) float32; → (values, labels, d) int32. An
+    entry that is not a whole number in ``[0, values)`` (a label: in
+    ``[0, labels)``) is not counted, so the counts add up to ``n_valid *
+    d`` exactly when every entry was in range: the caller's check. Any n:
+    the mask comes from ``n_valid`` and an iota inside the kernel and the
+    last tile is ragged, so nothing is padded or copied (the ``(d, n)``
+    view is a relabelling of the resident table: see ``_row_tiles``).
+    Callers psum the result across data shards.
+    """
+    x = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(y, jnp.float32)
+    if x.shape[0] == 0:  # an empty grid would skip the step-0 init
+        return jnp.zeros((values, labels, x.shape[1]), jnp.int32)
+    return _counts_tiles(x, y, jnp.asarray(n_valid, jnp.int32),
+                         labels=labels, values=values, interpret=interpret)
 
 
 # -- fused segment-reduce (scatter-add by segment id) ------------------------

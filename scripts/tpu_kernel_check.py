@@ -167,11 +167,37 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
           lambda: pk.segment_reduce_sum(sv[:, 0], sid, us), seg_want[:, 0],
           rtol=2e-2, atol=0.2)
 
+    # contingency counts: exact. A ragged last tile, rows past n_valid,
+    # entries that are not whole numbers in range (counted nowhere); an
+    # even arity, an odd one, and one past the kernel's unrolled loop
+    def counts_oracle(xc, yc, n_valid, labels, values):
+        y_ok = (yc == np.floor(yc)) & (yc >= 0) & (yc < labels)
+        y_ok &= np.arange(len(yc)) < n_valid
+        with np.errstate(invalid="ignore"):
+            ok = y_ok[:, None] & (xc == np.floor(xc)) & (xc >= 0) & (
+                xc < values)
+        rows, cols = np.nonzero(ok)
+        want = np.zeros((values, labels, xc.shape[1]), np.int64)
+        np.add.at(want, (xc[rows, cols].astype(np.int64),
+                         yc[rows].astype(np.int64), cols), 1)
+        return want
+
+    for nc, dc, lc, vc in ((3 * pk.COUNTS_TILES_N[0] + 77, 100, 10, 20),
+                           (5000, 7, 3, 41)):
+        xc = np.floor(rng.random((nc, dc)) * vc).astype(np.float32)
+        yc = np.floor(rng.random(nc) * lc).astype(np.float32)
+        xc[3, 1], xc[4, 0], xc[5, 1], xc[6, 1] = 0.5, -1.0, vc, np.nan
+        yc[7], yc[8] = lc, 0.25
+        check(f"category_counts(n {nc}, d {dc}, L {lc}, V {vc})",
+              lambda: pk.category_counts(xc, yc, nc - 5, lc, vc),
+              counts_oracle(xc, yc, nc - 5, lc, vc), rtol=0, atol=0)
+
     # -- benchmark-scale phase: kernel path vs the XLA path at the shapes
     # the fits use, both ON CHIP. The small-shape phase above proves the
     # lowering against numpy; this phase bounds kernel-vs-XLA drift at
     # scale (Lloyd partials at 1M x 100 k=10, KNN over a multi-tile 200k
-    # train set, the FTRL sparse program's two segment-reduces).
+    # train set, the FTRL sparse program's two segment-reduces, the
+    # contingency counts at 1M x 100).
     if not small_only:
         import jax.numpy as jnp
 
@@ -284,6 +310,23 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
             check(f"segment_reduce_sum@ftrl(coords {dF})",
                   lambda: pk.segment_reduce_sum(gwFd, colFd, dF), want,
                   rtol=2e-2, atol=max(0.05, np.abs(want).max() * 1e-2))
+
+        # contingency counts at the NaiveBayes benchmark's shapes (d 100,
+        # 20 values, 10 labels) against the XLA loop of one-hot products:
+        # both exact, so equal
+        from flink_ml_tpu.ops.contingency import category_counts_xla
+
+        nN, dN, lN, vN = (1 << 20) // shrink + 13, 100, 10, 20
+        xN = jnp.asarray(np.floor(rng.random((nN, dN)) * vN), jnp.float32)
+        yN = jnp.asarray(np.floor(rng.random(nN) * lN), jnp.float32)
+        one_hot = (jnp.float32 if jax.devices()[0].platform == "cpu"
+                   else jnp.bfloat16)   # XLA's CPU multiplies no bfloat16
+        want = np.asarray(jax.jit(
+            category_counts_xla, static_argnums=(3, 4, 5))(
+                xN, yN, nN - 5, lN, vN, one_hot))
+        check(f"category_counts@nb(n {nN})",
+              lambda: pk.category_counts(xN, yN, nN - 5, lN, vN), want,
+              rtol=0, atol=0)
 
     for f in failures:
         print("PARITY FAILURE:", f, file=sys.stderr)

@@ -52,16 +52,18 @@ def interpreted_kernels(monkeypatch):
     cleared around the test so no other test sees a program built under
     the patch."""
     from flink_ml_tpu.models.clustering import kmeans as km
+    from flink_ml_tpu.ops import contingency
     from flink_ml_tpu.ops import pallas_kernels as pk
 
     def clear():
         km._build_lloyd_program.cache_clear()
         km._build_lloyd_segment_program.cache_clear()
         km._build_assign_program.cache_clear()
+        contingency.counts_program.cache_clear()
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    for name in ("assign_nearest", "knn_topk_indices", "lloyd_partial_sums",
-                 "segment_reduce_sum"):
+    for name in ("assign_nearest", "category_counts", "knn_topk_indices",
+                 "lloyd_partial_sums", "segment_reduce_sum"):
         orig = getattr(pk, name)
         monkeypatch.setattr(
             pk, name,

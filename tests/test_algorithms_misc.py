@@ -77,6 +77,44 @@ def test_naive_bayes_categorical(tmp_path):
     t2 = Table.from_columns(features=np.array([[7.0, 1.0]]))
     assert model.transform(t2)[0]["prediction"].shape == (1,)
 
+    # the model data is numeric arrays and round-trips
+    (md,) = model.get_model_data()
+    assert all(np.asarray(md.column(c)).dtype == np.float64
+               for c in md.column_names)
+    fresh = NaiveBayesModel().set_model_data(md)
+    np.testing.assert_array_equal(fresh.transform(t)[0]["prediction"], pred)
+
+
+def test_naive_bayes_model_saved_as_dicts_still_loads(tmp_path):
+    """Before the model data was arrays a saved model held ``theta`` as
+    ``L x d`` dicts in ``data/model.json``: such a directory loads to the
+    same model."""
+    import json
+    import os
+
+    x = np.array([[0.0, 1.0], [0.0, 0.0], [1.0, 1.0], [1.0, 0.0],
+                  [0.0, 1.0], [1.0, 3.0]])
+    y = np.array([0.0, 0.0, 1.0, 1.0, 0.0, 1.0])
+    t = Table.from_columns(features=x, label=y)
+    model = NaiveBayes(smoothing=1.0).fit(t)
+    path = str(tmp_path / "nb")
+    model.save(path)
+    os.remove(os.path.join(path, "data", "model.npz"))
+    as_dicts = [[{str(v): float(model.theta[li, j, k])
+                  for k, v in enumerate(model.values[j]) if v == v}
+                 for j in range(2)] for li in range(2)]
+    with open(os.path.join(path, "data", "model.json"), "w") as f:
+        json.dump({"theta": as_dicts, "pi": model.pi.tolist(),
+                   "labels": model.labels.tolist(),
+                   "floors": model.floors.tolist()}, f)
+    old = NaiveBayesModel.load(path)
+    for name in ("theta", "values", "pi", "labels", "floors"):
+        np.testing.assert_array_equal(getattr(old, name),
+                                      getattr(model, name), err_msg=name)
+    t2 = Table.from_columns(features=np.array([[7.0, 1.0], [1.0, 3.0]]))
+    np.testing.assert_array_equal(old.transform(t2)[0]["prediction"],
+                                  model.transform(t2)[0]["prediction"])
+
 
 def test_naive_bayes_matches_sklearn_categorical(rng):
     from sklearn.naive_bayes import CategoricalNB
@@ -310,12 +348,9 @@ def test_naive_bayes_device_fit_parity(rng):
     np.testing.assert_array_equal(m_d.labels, m_h.labels)
     np.testing.assert_allclose(m_d.pi, m_h.pi, rtol=1e-12)
     np.testing.assert_allclose(m_d.floors, m_h.floors, rtol=1e-12)
-    for li in range(len(m_h.labels)):
-        for j in range(6):
-            assert m_d.theta[li][j].keys() == m_h.theta[li][j].keys()
-            for v in m_h.theta[li][j]:
-                assert m_d.theta[li][j][v] == pytest.approx(
-                    m_h.theta[li][j][v], rel=1e-12)
+    np.testing.assert_array_equal(m_d.values, m_h.values)
+    assert m_d.theta.shape == m_h.theta.shape == (3, 6, 5)
+    np.testing.assert_allclose(m_d.theta, m_h.theta, rtol=1e-12)
     # identical predictions end to end
     t = Table.from_columns(f=x, l=y)
     np.testing.assert_array_equal(

@@ -6,7 +6,10 @@ the largest k of every tile, where ``_lloyd_working_bytes`` comes nearest
 its budget, and the smallest shapes. Ahead of time, for a v5e, by the TPU
 compiler the installation brings: no chip is needed, and where there is no
 such compiler the cases are skipped. What Mosaic itself counts at a shape is
-``python scripts/lloyd_vmem_bisect.py k,d,tile``.
+``python scripts/lloyd_vmem_bisect.py k,d,tile``. The counting kernel of the
+NaiveBayes fit (``category_counts``, gate ``counts_tile``) is held the same
+way, here and not in a file of its own: one file, one worker, one load of
+the TPU compiler.
 """
 
 import functools
@@ -82,3 +85,49 @@ def test_one_feature_is_gated_off_because_its_sums_do_not_lower():
     assert pk.lloyd_tile(10, 1) == 0
     with pytest.raises(Exception, match="vector.broadcast|verif"):
         compile_both(10, 1, 20_000)
+
+
+# -- the counting kernel (NaiveBayes fit) --------------------------------------
+
+def compile_counts(d, labels, values, rows):
+    one = SingleDeviceSharding(chip())
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pk._counts_tiles.lower(of((rows, d)), of((rows,)), of((), jnp.int32),
+                           labels=labels, values=values).compile()
+
+
+def count_edges(d, labels):
+    """``[(largest V the gate gives this tile, tile)]`` at ``(d, L)``."""
+    tiles = {}
+    for values in range(1, 4097):
+        tiles[pk.counts_tile(d, labels, values)] = values
+    return [(values, tile) for tile, values in tiles.items() if tile]
+
+
+@pytest.mark.parametrize("d,labels", [(6, 3), (100, 10), (100, 300),
+                                      (512, 10), (1000, 2)],
+                         ids=lambda v: str(v))
+def test_the_most_values_of_every_counting_tile_compile_for_the_chip(
+        d, labels):
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    found = count_edges(d, labels)
+    assert found and {tile for _, tile in found} <= set(pk.COUNTS_TILES_N)
+    for values, tile in found:
+        compile_counts(d, labels, values, 2 * tile + 77)
+
+
+@pytest.mark.parametrize("d,labels,values", [
+    (2, 1, 1), (2, 2, 2), (7, 3, 41), (100, 10, 20), (100, 10, 33),
+    (100, 10, 300), (128, 16, 64), (100, 100, 20)], ids=lambda v: str(v))
+def test_small_and_unaligned_counting_shapes_compile_for_the_chip(
+        d, labels, values):
+    """Odd and even arities, one value, one label, the benchmark's shape,
+    and an arity past ``COUNTS_UNROLL_MAX`` pairs (a loop, not unrolled)."""
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    assert pk.counts_tile(d, labels, values)
+    compile_counts(d, labels, values, 20_000)
