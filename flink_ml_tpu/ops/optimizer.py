@@ -661,11 +661,18 @@ class SGD:
             return out
 
     @staticmethod
-    def _fetch_result(coeffs, d: int, mean_loss):
-        """The blocking reads of the fitted state every dense path ends
-        in: where the wait for the enqueued rounds falls."""
+    def _fetch_result(coeffs, d: int, mean_loss, boundary=()):
+        """The blocking read of the fitted state every dense path ends
+        in, where the wait for the enqueued rounds falls: ONE wait,
+        under which a plain fit's ``boundary`` leaves cross too (their
+        host values come back third)."""
+        from flink_ml_tpu.iteration.iteration import read_boundary
+
         with tracer.span("sgd.fetch"):
-            return np.asarray(coeffs, np.float64)[:d], float(mean_loss)
+            *vals, coeffs, mean_loss = read_boundary(
+                (*boundary, coeffs, mean_loss))
+            return (np.asarray(coeffs, np.float64)[:d], float(mean_loss),
+                    vals)
 
     def _optimize(self, loss_func, init_coeffs, features, labels, weights,
                   mesh, dtype, config, listeners, tag):
@@ -798,66 +805,72 @@ class SGD:
                     "fin": True, "first": None, "epoch": 0,
                 }
 
-            def run_segment(carry, epoch0, limit):
+            def launch_segment(carry, epoch0, limit):
+                """Enqueue one segment, no wait: the new carry and the
+                boundary's leaves, all still on the device."""
                 coeffs, offsets, _, opt = carry
                 if hstate["first"] is None:
                     hstate["first"] = int(epoch0)
+                health_in = ((hstate["hist"], np.bool_(hstate["fin"]))
+                             if health_on else ())
+                with tracer.span("sgd.launch"):
+                    coeffs, offsets, opt, mean_loss, *tail = seg_prog(
+                        xs, ys, ws, coeffs, offsets, opt,
+                        np.int32(epoch0), np.int32(limit), *health_in)
+                # fused, the boundary is ONE stacked [epoch, stop(, fin)]
+                # vector instead of a transfer a scalar
+                if not health_on:
+                    boundary = tuple(tail)
+                elif fused:
+                    boundary, hstate["hist"] = (tail[0],), tail[1]
+                else:
+                    epoch, stop, hstate["hist"], fin = tail
+                    boundary = (epoch, stop, fin)
+                return (coeffs, offsets, mean_loss, opt), boundary
+
+            def crossed(vals):
+                """A boundary's values, on the host: where the fit
+                stands."""
+                if fused:
+                    vals, = vals
+                epoch, stop = int(vals[0]), bool(vals[1])
                 if health_on:
-                    with tracer.span("sgd.launch"):
-                        out = seg_prog(
-                            xs, ys, ws, coeffs, offsets, opt,
-                            np.int32(epoch0), np.int32(limit),
-                            hstate["hist"], np.bool_(hstate["fin"]))
-                    if fused:
-                        # ONE stacked [epoch, stop, fin] transfer per
-                        # boundary instead of three scalar fetches
-                        (coeffs, offsets, opt, mean_loss, boundary,
-                         hstate["hist"]) = out
-                    else:
-                        (coeffs, offsets, opt, mean_loss, epoch, stop,
-                         hstate["hist"], fin) = out
-                        boundary = (epoch, stop, fin)
-                    with tracer.span("sgd.fetch"):
-                        vals = read_boundary(boundary)
-                    epoch, stop = int(vals[0]), bool(vals[1])
-                    hstate["fin"] = bool(vals[2])
-                    # epoch-boundary health check: the segment boundary
-                    # is this mode's host sync point, so reading the
-                    # sentinel costs no extra round-trip (it rides the
-                    # fused bundle) — and a NaN state fails the fit NOW
-                    # instead of burning the remaining segments
-                    hstate["epoch"] = epoch
+                    # epoch-boundary health check: the boundary is this
+                    # mode's host sync point, so reading the sentinel
+                    # costs no extra round-trip (it rides the bundle) —
+                    # and a NaN state fails the fit NOW instead of
+                    # burning the remaining segments
+                    hstate["fin"], hstate["epoch"] = bool(vals[2]), epoch
                     if not hstate["fin"]:
                         _finish_fit_health(
-                            algo, True, hstate["hist"], False,
-                            hstate["epoch"], mean_loss, None,
-                            epoch0=hstate["first"])
-                else:
-                    with tracer.span("sgd.launch"):
-                        out = seg_prog(
-                            xs, ys, ws, coeffs, offsets, opt,
-                            np.int32(epoch0), np.int32(limit))
-                    if fused:
-                        coeffs, offsets, opt, mean_loss, boundary = out
-                    else:
-                        (coeffs, offsets, opt, mean_loss, epoch,
-                         stop) = out
-                        boundary = (epoch, stop)
-                    with tracer.span("sgd.fetch"):
-                        vals = read_boundary(boundary)
-                    epoch, stop = int(vals[0]), bool(vals[1])
-                return (coeffs, offsets, mean_loss, opt), epoch, stop
+                            algo, True, hstate["hist"], False, epoch,
+                            None, None, epoch0=hstate["first"])
+                return epoch, stop
+
+            def run_segment(carry, epoch0, limit):
+                carry, boundary = launch_segment(carry, epoch0, limit)
+                with tracer.span("sgd.fetch"):
+                    vals = read_boundary(boundary)
+                return (carry, *crossed(vals))
 
             if seg_k:
+                # the driver needs epoch and stop to go on: one read a
+                # segment boundary, the fitted state's pair at the end
                 coeffs, _, mean_loss, _ = run_segmented(
                     run_segment, init, self.params.max_iter, seg_k,
                     config.checkpoint_manager)
+                out, mean_loss, _ = self._fetch_result(coeffs, d,
+                                                       mean_loss)
             else:
-                (coeffs, _, mean_loss, _), _, _ = run_segment(
+                # a plain fit is one segment, and everything it ends in
+                # crosses to the host under one wait
+                (coeffs, _, mean_loss, _), boundary = launch_segment(
                     init, 0, self.params.max_iter)
+                out, mean_loss, vals = self._fetch_result(
+                    coeffs, d, mean_loss, boundary)
+                crossed(vals)
             self.last_execution_path = ("xla-while-segments" if seg_k
                                         else "xla-while")
-            out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
             with tracer.span("sgd.health"):
                 _finish_fit_health(
                     algo, health_on, hstate["hist"], hstate["fin"],
@@ -897,7 +910,7 @@ class SGD:
                 config=config, listeners=listeners, jit_round=False)
         coeffs, _, mean_loss, _ = final
         self.last_execution_path = "host-rounds"
-        out, mean_loss = self._fetch_result(coeffs, d, mean_loss)
+        out, mean_loss, _ = self._fetch_result(coeffs, d, mean_loss)
         with tracer.span("sgd.health"):
             if not health_on:
                 _health.guard_final_state(algo, out, loss=mean_loss)

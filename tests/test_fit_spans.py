@@ -176,6 +176,9 @@ def test_fit_under_capture_is_one_trace_in_the_ring_and_in_the_xplane(
     by_name, by_id = one_trace("LogisticRegression.fit")
     assert set(by_name) == set(TREE) | {"LogisticRegression.fit"}
     assert_tree(by_name, by_id, TREE)
+    # a plain fit waits for its results once: boundary, coefficients and
+    # loss cross under one read
+    assert len(by_name["sgd.fetch"]) == 1
     optimize = by_name["sgd.optimize"][0]
     assert optimize["attrs"]["path"] == "xla-while"
     assert optimize["attrs"]["rounds"] == 4
@@ -234,13 +237,15 @@ def test_the_tree_is_the_same_on_every_execution_path(
         "LogisticRegression.fit"}
     tree = dict(TREE, **extra)
     if path == "xla-while-segments":
-        # the boundary fetches sit under their segment, the final one
-        # under the optimizer
-        parents = {by_id[r["parent"]]["name"]
-                   for r in by_name.pop("sgd.fetch")}
-        assert parents == {"segment", "sgd.optimize"}
+        # the boundary fetches sit under their segment, one each, the
+        # final state's one read under the optimizer
+        parents = sorted(by_id[r["parent"]]["name"]
+                         for r in by_name.pop("sgd.fetch"))
+        assert parents == ["segment", "segment", "sgd.optimize"]
         del tree["sgd.fetch"]
         assert len(by_name["sgd.launch"]) == len(by_name["segment"]) == 2
+    else:
+        assert len(by_name["sgd.fetch"]) == 1
     assert_tree(by_name, by_id, tree)
     assert by_name["sgd.optimize"][0]["attrs"]["path"] == path
 
@@ -253,6 +258,7 @@ def test_the_other_linear_estimators_leave_the_same_tree(
     by_name, by_id = one_trace(f"{cls.__name__}.fit")
     assert set(by_name) == set(TREE) | {f"{cls.__name__}.fit"}
     assert_tree(by_name, by_id, TREE)
+    assert len(by_name["sgd.fetch"]) == 1
 
 
 def test_sparse_fit_opens_launch_and_fetch_too(rng, tmp_path):

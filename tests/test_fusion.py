@@ -41,10 +41,17 @@ def _sgd_data(rng, n=640, d=6):
     return x, y
 
 
-def _boundary_counts():
+def _counter(name):
     snap = metrics.snapshot().get("ml.iteration", {}).get("counters", {})
-    return (int(snap.get("boundaryFetches", 0)),
-            int(snap.get("boundaries", 0)))
+    return int(snap.get(name, 0))
+
+
+def _boundary_counts():
+    return _counter("boundaryFetches"), _counter("boundaries")
+
+
+def _boundary_waits():
+    return _counter("boundaryWaits")
 
 
 def test_fusion_env_gate(monkeypatch):
@@ -129,10 +136,14 @@ def test_kmeans_segment_fusion_bit_identical(monkeypatch, rng, n_dev,
 
 def test_fused_boundary_is_one_transfer(monkeypatch, rng, tmp_path):
     """The acceptance bar: segment-mode device→host transfers per
-    boundary == 1 fused, > 1 on the pre-fusion path."""
+    boundary == 1 fused, > 1 on the pre-fusion path. The fitted state's
+    two leaves (coefficients, loss) are counted too, once a fit and
+    apart from the boundaries': they cross under one wait of their own,
+    so a fit waits once a boundary and once more."""
     x, y = _sgd_data(rng)
     prm = SGDParams(learning_rate=0.05, global_batch_size=64,
                     max_iter=8, tol=0.0)
+    final_leaves = 2
 
     def fetches_per_boundary(fused, sub):
         monkeypatch.setenv(FUSION_ENV, "1" if fused else "0")
@@ -140,14 +151,34 @@ def test_fused_boundary_is_one_transfer(monkeypatch, rng, tmp_path):
             mode="device", checkpoint_interval=2,
             checkpoint_manager=CheckpointManager(str(tmp_path / sub)))
         f0, b0 = _boundary_counts()
+        w0 = _boundary_waits()
         SGD(prm).optimize(BinaryLogisticLoss(), np.zeros(6), x, y,
                           config=cfg)
         f1, b1 = _boundary_counts()
         assert b1 - b0 == 4  # 8 rounds / K=2
-        return (f1 - f0) / (b1 - b0)
+        assert _boundary_waits() - w0 == 4 + 1
+        return (f1 - f0 - final_leaves) / (b1 - b0)
 
     assert fetches_per_boundary(True, "fused") == 1.0
     assert fetches_per_boundary(False, "plain") == 2.0
+
+
+@pytest.mark.parametrize("fused, leaves", [(True, 3), (False, 4)])
+def test_a_plain_fit_waits_for_its_results_once(monkeypatch, rng, fused,
+                                                leaves):
+    """No checkpoint, no listener: the boundary nobody needs before the
+    final state crosses with it — bundle (or epoch and stop),
+    coefficients and loss under ONE wait."""
+    monkeypatch.setenv(FUSION_ENV, "1" if fused else "0")
+    x, y = _sgd_data(rng)
+    prm = SGDParams(learning_rate=0.05, global_batch_size=64,
+                    max_iter=8, tol=0.0)
+    (f0, b0), w0 = _boundary_counts(), _boundary_waits()
+    sgd = SGD(prm)
+    sgd.optimize(BinaryLogisticLoss(), np.zeros(6), x, y)
+    assert sgd.last_execution_path == "xla-while"
+    (f1, b1), w1 = _boundary_counts(), _boundary_waits()
+    assert (f1 - f0, b1 - b0, w1 - w0) == (leaves, 0, 1)
 
 
 def test_sgd_fusion_chaos_restart_parity(monkeypatch, rng, tmp_path):

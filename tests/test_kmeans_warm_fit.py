@@ -172,8 +172,7 @@ def test_the_spans_of_a_fit_are_one_tree_that_sums_to_its_root(
         request.getfixturevalue("interpreted_kernels")
     fit(case, tmp_path)                      # warm, and nobody looking:
     assert len(tracer.recent) == 0           # nothing recorded
-    fetches = metrics.group(ML_GROUP, "iteration").snapshot()[
-        "counters"].get("boundaryFetches", 0)
+    before = metrics.group(ML_GROUP, "iteration").snapshot()["counters"]
     monkeypatch.setattr(tracer, "keep_recent", True)
     fit(case, tmp_path)
     records = list(tracer.recent)
@@ -202,10 +201,12 @@ def test_the_spans_of_a_fit_are_one_tree_that_sums_to_its_root(
     children = [r for r in records if r["parent"] == root["id"]]
     assert sum(r["dur_us"] for r in children) <= root["dur_us"]
     # the blocking reads are counted as the SGD fit's are: the final state
-    # (two leaves), and one fused bundle a segment boundary
-    counted = metrics.group(ML_GROUP, "iteration").snapshot()[
-        "counters"]["boundaryFetches"] - fetches
-    assert counted == 2 + UNDER_LAUNCH[case[0]].get("segment", 0)
+    # (two leaves under one wait), and one fused bundle a segment boundary
+    after = metrics.group(ML_GROUP, "iteration").snapshot()["counters"]
+    segments = UNDER_LAUNCH[case[0]].get("segment", 0)
+    assert {name: after[name] - before.get(name, 0)
+            for name in ("boundaryFetches", "boundaryWaits")} == {
+        "boundaryFetches": 2 + segments, "boundaryWaits": 1 + segments}
 
 
 def write_golden(path):

@@ -308,8 +308,7 @@ def test_the_spans_of_a_fit_are_one_tree_under_its_root(devices, path,
     est = NaiveBayes()
     est.fit(table)                          # warm, and nobody looking:
     assert len(tracer.recent) == 0          # nothing recorded
-    fetches = metrics.group(ML_GROUP, "iteration").snapshot()[
-        "counters"].get("boundaryFetches", 0)
+    before = metrics.group(ML_GROUP, "iteration").snapshot()["counters"]
     monkeypatch.setattr(tracer, "keep_recent", True)
     est.fit(table)
     assert est.last_execution_path == path
@@ -327,9 +326,11 @@ def test_the_spans_of_a_fit_are_one_tree_under_its_root(devices, path,
         assert launch["attrs"] == {
             "path": "mxu-counts", "rows": 403, "d": 6, "labels": 3,
             "values": 5, "passes": 1, "program": "xla"}
-        counted = metrics.group(ML_GROUP, "iteration").snapshot()[
-            "counters"]["boundaryFetches"] - fetches
-        assert counted == 2         # the look's four numbers, the counts
+        after = metrics.group(ML_GROUP, "iteration").snapshot()["counters"]
+        # the look's four numbers, then the counts: a leaf a wait, the
+        # second program's shape waits for the first read
+        assert [after[name] - before.get(name, 0) for name in
+                ("boundaryFetches", "boundaryWaits")] == [2, 2]
         state = metrics.group(ML_GROUP, "update").snapshot()["gauges"]
         assert any("NaiveBayes" in key and value == 5 * 3 * 6 * 4
                    for key, value in state.items()), state

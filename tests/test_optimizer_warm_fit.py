@@ -262,6 +262,30 @@ def test_a_warm_fit_builds_nothing_and_answers_as_the_parent_did(
     assert loss == pytest.approx(parents["loss"], rel=1e-6, abs=0)
 
 
+def serial_reads(boundary):
+    """``read_boundary`` as it was until PR 34: each leaf waited on before
+    the next one's copy starts."""
+    if isinstance(boundary, (tuple, list)):
+        return [np.asarray(v) for v in boundary]
+    return list(np.asarray(boundary))
+
+
+@pytest.mark.parametrize("case", CASES, ids=case_id)
+def test_one_wait_answers_as_serial_reads_did(case, tmp_path, monkeypatch):
+    """The same buffers are copied, only the order of start and wait
+    changes: coefficients and loss are those of one read after another,
+    bit for bit, on every path."""
+    from flink_ml_tpu.iteration import iteration
+
+    coeffs, loss, path = fit(case, tmp_path)
+    assert path == case[3]
+    monkeypatch.setattr(iteration, "read_boundary", serial_reads)
+    serial, serial_loss, _ = fit(case, tmp_path)
+    assert coeffs.dtype == serial.dtype == np.float64
+    assert coeffs.tolist() == serial.tolist()
+    assert loss == serial_loss and type(loss) is float
+
+
 #: the carry's leaves in order — coefficients, per-task offsets, loss, then
 #: the rule's moments and Adam's step — as (dtype, spec, shape); "w" is the
 #: coefficient spec, "m" the moments'
