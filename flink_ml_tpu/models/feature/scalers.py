@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from flink_ml_tpu.api.stage import Estimator, Model
 from flink_ml_tpu.common.table import Table
+from flink_ml_tpu.observability.tracing import tracer
 from flink_ml_tpu.ops import columnar
 from flink_ml_tpu.params.param import BooleanParam, FloatParam, ParamValidators
 from flink_ml_tpu.params.shared import (
@@ -338,22 +339,40 @@ class RobustScalerModel(_VectorStatModelBase, RobustScalerParams):
 
 
 class RobustScaler(Estimator, RobustScalerParams):
+    """``medians`` and ``ranges = upper - lower`` per dimension, each an
+    element of the column: the one of 0-based rank ``floor(q (n - 1))``
+    (docs/deviations.md). A device-resident column is selected from where
+    it lies, exactly, by counting passes (``ops/quantile.select_on_device``:
+    path ``select-device``); a host column by ``np.quantile``
+    (``host-quantiles``), to the same model."""
+
     def fit(self, table: Table) -> RobustScalerModel:
         x, xp = columnar.fit_vectors(table, self.input_col)
+        probs = [self.lower, 0.5, self.upper]
         if xp is jnp:
-            # device-resident input: rank-exact order statistics via the
-            # sort-free bisection kernel (ops/quantile.rank_select_device)
-            # — element-of-dataset semantics matching the reference's GK
-            # summary, at streaming-pass cost instead of a (n, d) sort
-            from flink_ml_tpu.ops.quantile import rank_select_device
+            from flink_ml_tpu.ops.quantile import select_on_device
+            from flink_ml_tpu.parallel import update_sharding as _upd
+            from flink_ml_tpu.parallel.mesh import (
+                data_shard_count, default_mesh)
 
-            qs = np.asarray(rank_select_device(
-                x, [self.lower, 0.5, self.upper]), np.float64)
+            qs, _ = select_on_device(x, probs)
+            # (the fit's state is its answers: the brackets that led to
+            # them are the programs')
+            _upd.record_state_bytes("RobustScaler", [qs],
+                                    data_shard_count(default_mesh()), False)
+            self.last_execution_path = "select-device"
         else:
             from flink_ml_tpu.ops.quantile import approx_quantiles
-            qs = approx_quantiles(
-                x, [self.lower, 0.5, self.upper],
-                relative_error=self.relative_error)
-        lo, med, hi = qs[0], qs[1], qs[2]
-        model = RobustScalerModel(medians=med, ranges=hi - lo)
-        return self.copy_params_to(model)
+
+            # (no program to enqueue: the host's one read of the column is
+            # the blocking part, and ``select.fetch`` is what names passes)
+            with tracer.span("select.fetch", path="host-quantiles",
+                             rows=x.shape[0], d=x.shape[1], probs=probs,
+                             passes=1):
+                qs = approx_quantiles(x, probs,
+                                      relative_error=self.relative_error)
+            self.last_execution_path = "host-quantiles"
+        with tracer.span("fit.model"):
+            lo, med, hi = np.asarray(qs, np.float64)
+            model = RobustScalerModel(medians=med, ranges=hi - lo)
+            return self.copy_params_to(model)

@@ -5,20 +5,29 @@ Greenwald-Khanna summary (insert buffer, compress threshold 10000, merge,
 query) backing the ``relativeError`` param of RobustScaler, Imputer and
 KBinsDiscretizer.
 
-Two tiers:
+Three tiers:
 - :class:`QuantileSummary` — a faithful GK sketch for streaming/merge use
   (online pipelines, bounded memory).
-- :func:`approx_quantiles` — the batch path: exact numpy quantiles over the
-  materialized column (an exact answer trivially satisfies any ε bound; the
-  reference only sketches because its input is an unbounded stream).
+- :func:`approx_quantiles` — the batch path on the host: exact numpy
+  quantiles over the materialized column (an exact answer trivially
+  satisfies any ε bound; the reference only sketches because its input is an
+  unbounded stream).
+- :func:`select_on_device` — the batch path on the device: the same exact
+  order statistics of a resident column, by counting passes over it where
+  it lies (:func:`select_programs`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from flink_ml_tpu.observability.tracing import tracer
 
 
 @dataclasses.dataclass
@@ -147,67 +156,493 @@ def approx_quantiles(x: np.ndarray, probs: Sequence[float],
     return np.quantile(x, np.asarray(probs), axis=0, method="lower")
 
 
-def rank_select_device(x, probs: Sequence[float]):
-    """Per-column order statistics of a DEVICE (n, d) float32 array →
-    (m, d) device array, WITHOUT a device sort.
+# ---------------------------------------------------------------------------
+# exact order statistics of a device-resident column
+# ---------------------------------------------------------------------------
+#
+# A median is a sort on every other platform; the chip has no fast sort, and
+# a sort of an (n, d) column is a second copy of it. The selection below
+# never moves the table: every float32 has an order-preserving 32-bit
+# integer key (the sign-magnitude flip a radix sort uses), the element of
+# 0-based rank r is the smallest key t with count(key <= t) >= r + 1, and a
+# count is one fused compare-and-sum pass over the table where it lies, the
+# keys made in registers as each tile is read. Every bracket [lo, hi] a
+# probability and a column is PROVEN by counts (count(key < lo) < r + 1 <=
+# count(key <= hi)); what is left to choose is where to count next:
+#
+# - a first guess: the order statistics of a small sample of rows (a few
+#   contiguous runs spread over every shard) at the sample ranks a safety
+#   margin under the wanted one, at it, and the margin over. The first pass
+#   over the table counts at those three: it proves the bracket or, on a
+#   sorted table, proves which side of it the element lies. Nothing is taken
+#   on trust;
+# - then three counts a probability a pass, placed by interpolating the
+#   wanted rank between the bracket's two proven ends, in VALUES (a float's
+#   key is piecewise logarithmic in it), with the same margin either side:
+#   on a smooth column the K elements of a bracket become some 2 sqrt(K), so
+#   12M become 1e5 (the sample), 600, 50, a dozen;
+# - a bracket that TWO passes running did not cut to a quarter of its keys
+#   is cut at its quarters by the next, which does: the loop's own fallback
+#   (ties, outliers, a first guess proven wrong), three passes a two bits at
+#   the worst. One such pass is not enough to give the ranks up: a count
+#   that fell outside its margin (one bracket in some 5,000 a pass) leaves
+#   the wide outer part of its bracket with the wanted rank at its very
+#   end, and interpolated there the next pass leaves fewer elements than a
+#   hit would have: the table's pass count does not move. So does a bracket
+#   that spans zero, whose values interpolate well and whose keys (all the
+#   exponents between) stay wide. Cut at its quarters at once, as this was
+#   first written, such a bracket lagged one pass to the end: one uniform
+#   table in some fifty read the whole table an eighth time for one bracket
+#   of 300, and a normal table nearly always (PERF.md section 6, PR 35);
+# - a bracket of a few dozen elements lying dense in its last keys is cut
+#   at its quarters too, which then beats its ranks (a factor of 4 a pass
+#   for certain, where some 2 sqrt(K) elements with a margin around them
+#   are about as many as there were);
+# - where a bracket is a run of keys with a handful of elements far apart
+#   in it (a column centred on zero, where floats lie dense; a short
+#   table), or holds many elements that three spread counts did not part
+#   (one value many times over: whole numbers, categories, a clipped or
+#   zero-inflated column), a pass of another kind also pulls its ends in to
+#   the elements themselves (the smallest key >= lo, the largest <= hi):
+#   one value left is then the answer at once, not after a bisection of its
+#   mantissa (12M whole numbers 0..999: 6 passes with it, 18 without).
+#
+# The answers are exact by construction: the loop ends when every bracket is
+# one key wide, wherever the counts were taken. How many passes that takes
+# is the TABLE's, not the shape's: PERF.md section 6 (PR 35) lists what
+# uniform, normal, zero-inflated, whole-number and sorted tables of 12M and
+# 10M rows took on the chip, and tests/test_order_stats.py holds a bound a
+# distribution.
 
-    ``jnp.quantile`` sorts every column — the whole fit cost of
-    RobustScaler at benchmark scale (a (10M, 100) sort made it 22x
-    slower than its sibling scalers, r3 sweep).  Instead: 32 rounds of
-    bisection on the ORDER-PRESERVING uint32 bit image of float32 (the
-    sign-magnitude flip radix-sort uses), each one fused compare-count
-    pass over x inside a jitted ``fori_loop``.  XLA fuses the
-    broadcast-compare into the (d, m) count reduction — nothing of shape
-    (n, d, m) materializes.  Integer bisection converges EXACTLY to the
-    bit pattern of the floor(q*(n-1))-th smallest element — the same
-    element-of-dataset semantics as numpy's method='lower' and the
-    reference's GK summary (QuantileSummary.java:42) — independent of
-    the column's value range: outliers, denormals and infinities cost
-    nothing (keys are just 32-bit integers; no midpoint overflow, no
-    lost resolution).  NaN bit patterns sort outside the finite band
-    (negative-payload NaNs below -inf, positive above +inf), matching a
-    sort-based quantile's endpoint behavior.
-    """
-    from flink_ml_tpu.ops import columnar
+#: rows of every shard the first guess is taken from, in SAMPLE_RUNS
+#: contiguous runs spread evenly over the shard (32 small rounds over them
+#: take 2 ms at d 100; the brackets the third pass leaves shrink with the
+#: fourth root of it)
+SAMPLE_ROWS = 1 << 17
+SAMPLE_RUNS = 16
+#: standard deviations of the rank between two proven counts that a
+#: bracket keeps either side of its guess
+SAFETY = 4.0
+#: keys that two passes at two bits finish: a bracket of a few dozen
+#: elements with no more keys than that for each of them is dense (the
+#: rule under ``EVEN_KEYS``)
+SNAP_KEYS = 16
+#: keys that three passes at two bits finish: a bracket wider than that for
+#: each of its elements, where it holds no more of them than one pass's
+#: counts tell apart (``PIVOTS + 1``), or wider than that and holding more,
+#: none of which its last pass took off, has its ends pulled in to its
+#: elements (:func:`_wants_ends`). Not sooner: that pass costs two and a
+#: half counting passes as XLA compiles it (22.7 ms against 8.3 at 12M x
+#: 100), and it is every bracket's pass once one asks for it. At 16 keys
+#: an element 4 uniform tables in 40 took one, for brackets their counts
+#: finished in as many passes anyway (81 ms a fit for 68.5); at 64 none in
+#: 40 (PERF.md section 6, PR 36)
+ENDS_KEYS = 64
+#: keys that four passes at two bits finish: a bracket of no more than that,
+#: dense in them (at most SNAP_KEYS keys an element) and of so few elements
+#: that its ranks cut it no faster than its quarters do (some SAFETY sqrt(K)
+#: / 2 left of K against K / 4: K under 4 SAFETY^2), is cut at its quarters
+EVEN_KEYS = 255
+#: counts a probability a pass (the programs' pivots are written as three:
+#: a lower guess, the guess, an upper guess)
+PIVOTS = 3
+#: passes the head program makes, one after another, before the driver
+#: first looks: no table longer than its sample took fewer than five.
+#: Timed on a v5e at 12M x 100 with 1 | 2 | 3 | 4 | 5 of them (PERF.md
+#: section 6, PR 36): a uniform table's fit 72.8 | 71.5 | 70.0 | 68.7 | 67.2
+#: ms (a host trip between two passes is 1.4 ms), a normal one's 146 | 144
+#: | 142 | 128 | 116, whole numbers' 103 | 101 | 87 | 105 | 126 (the head's
+#: passes only count; at five a tied table reads the table nine times)
+HEAD_PASSES = 4
 
-    n = int(x.shape[0])
-    ranks = np.floor(np.asarray(probs, np.float64) * (n - 1)) \
-        .astype(np.int32)
-    return columnar.apply(_rank_select_kernel, x, (ranks,))
+_TOP = 0x80000000
 
 
-def _rank_select_kernel(x, ranks):
-    import jax
-    import jax.numpy as jnp
+def float_keys(x):
+    """The order-preserving int32 key of float32: signed order == IEEE
+    order, -0 under +0, NaNs at the two ends by their sign. The one copy
+    of the mapping: the programs make their keys by it, and the checks
+    their oracles."""
+    s = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return s ^ ((s >> 31) & jnp.int32(0x7FFFFFFF))
 
-    m = ranks.shape[0]
-    # order-preserving uint32 image: non-negative floats map above
-    # 0x80000000 keeping magnitude order; negative floats flip so larger
-    # magnitude sorts lower. Total order == IEEE float order.
-    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    keys = jnp.where(u >= jnp.uint32(0x80000000),
-                     jnp.uint32(0xFFFFFFFF) - u,
-                     u + jnp.uint32(0x80000000))
-    target = (ranks + 1)[:, None]                  # (m, 1)
-    d = x.shape[1]
-    LO = jnp.zeros((m, d), jnp.uint32)
-    HI = jnp.full((m, d), jnp.uint32(0xFFFFFFFF))
 
-    def step(_, state):
-        LO, HI = state
-        mid = LO + (HI - LO) // jnp.uint32(2)
-        # (n, d, m) broadcast-compare fused into the count reduction
-        cnt = jnp.sum(
-            (keys[:, :, None] <= mid.T[None, :, :]).astype(jnp.int32),
-            axis=0)
-        ok = cnt.T >= target                       # (m, d)
-        HI = jnp.where(ok, mid, HI)
-        LO = jnp.where(ok, LO, mid + jnp.uint32(1))
-        return LO, HI
+def keys_to_float(keys):
+    """The float32 of an int32 key (the mapping is its own inverse)."""
+    return jax.lax.bitcast_convert_type(
+        keys ^ ((keys >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
 
-    # 32 halvings of a 2^32 bracket: LO == HI == the answer's bit image
-    _, HI = jax.lax.fori_loop(0, 32, step, (LO, HI))
-    back = jnp.where(HI >= jnp.uint32(0x80000000),
-                     HI - jnp.uint32(0x80000000),
-                     jnp.uint32(0xFFFFFFFF) - HI)
-    return jax.lax.bitcast_convert_type(back, jnp.float32)
+
+def _signed(keys):
+    """uint32 keys (what the brackets are kept in: a width never
+    overflows) as the int32 keys of the same order."""
+    return jax.lax.bitcast_convert_type(keys ^ jnp.uint32(_TOP), jnp.int32)
+
+
+def _unsigned(signed):
+    return jax.lax.bitcast_convert_type(signed, jnp.uint32) ^ jnp.uint32(
+        _TOP)
+
+
+def _float_to_key(x):
+    return _unsigned(float_keys(x))
+
+
+def _key_to_float(keys):
+    return keys_to_float(_signed(keys))
+
+
+def count_le(x, piv):
+    """``(P, d)`` int32: how many rows of ``x (rows, d)`` have a key at or
+    under each of ``piv (P, d)`` int32. One sum a pivot over the same keys:
+    XLA makes them one fusion with ``P`` results that reads the table once
+    where it lies and makes the keys in registers (a broadcast compare
+    ``(rows, P, d)`` makes it write the keys out first: 4.99 GB at 12M x
+    100)."""
+    keys = float_keys(x.T)                           # (d, rows)
+    return jnp.stack([jnp.sum(keys <= piv[i][:, None], axis=1,
+                              dtype=jnp.int32)
+                      for i in range(piv.shape[0])])
+
+
+def _ends_within(x, lo, hi, rows_valid):
+    """``(smallest key >= lo, largest key <= hi)`` over the first
+    ``rows_valid`` rows of ``x``, as signed keys, each ``(P, d)`` like
+    ``lo`` and ``hi`` (``hi`` and ``lo`` themselves where no row lies
+    there). One reduction a bracket over the same keys, as in
+    :func:`count_le` and for the same reason."""
+    keys = float_keys(x.T)                           # (d, rows)
+    valid = jnp.arange(x.shape[0]) < rows_valid
+    s_lo, s_hi = _signed(lo)[:, :, None], _signed(hi)[:, :, None]
+    a = [jnp.min(jnp.where(valid & (keys >= s_lo[i]), keys, s_hi[i]),
+                 axis=1) for i in range(lo.shape[0])]
+    b = [jnp.max(jnp.where(valid & (keys <= s_hi[i]), keys, s_lo[i]),
+                 axis=1) for i in range(lo.shape[0])]
+    return jnp.stack(a), jnp.stack(b)
+
+
+def _wants_ends(held, width, stuck):
+    """Which brackets (``held`` elements in ``width`` keys; ``stuck``: the
+    last pass took no element off) the next pass should pull in to their
+    elements: a handful of elements in a run of keys far wider than they
+    (a column centred on zero, where floats lie dense; a short table), or
+    many elements that three counts spread over the bracket did not part
+    (one value many times over: whole numbers, categories, a clipped or
+    zero-inflated column), while counts alone would still take more than
+    three passes over the keys that are left."""
+    u32 = jnp.uint32
+    return jnp.where(held <= PIVOTS + 1,
+                     width // u32(ENDS_KEYS) > held,
+                     stuck & (width > u32(ENDS_KEYS)))
+
+
+def _pack(lo, hi, c_lo, c_hi, piv, stuck, was_slow):
+    """A fit's brackets between two programs, as one ``(5 + PIVOTS, m, d)``
+    uint32 array (one operand a launch, not eight): the bracket's two
+    proven ends and the two counts that prove them, the two marks of its
+    last pass, the next pass's pivots."""
+    u32 = jnp.uint32
+    marks = stuck.astype(u32) | (was_slow.astype(u32) << u32(1))
+    as_bits = functools.partial(jax.lax.bitcast_convert_type,
+                                new_dtype=u32)
+    return jnp.concatenate([
+        jnp.stack([lo, hi, as_bits(c_lo), as_bits(c_hi), marks]), piv])
+
+
+def _unpack(state):
+    as_count = functools.partial(jax.lax.bitcast_convert_type,
+                                 new_dtype=jnp.int32)
+    marks = state[4]
+    return (state[0], state[1], as_count(state[2]), as_count(state[3]),
+            state[5:], (marks & jnp.uint32(1)) > 0,
+            (marks & jnp.uint32(2)) > 0)
+
+
+@functools.lru_cache(maxsize=32)
+def select_programs(mesh, m: int):
+    """``(head, step, step_ends)``: the three programs of a selection of
+    ``m`` order statistics a column over ``mesh``, each held (a dictionary
+    hit a warm fit) and each traced only when first called.
+
+    - ``head(xs, spec) -> (state, report)``: the first guess from the
+      sample and the table's first ``HEAD_PASSES`` passes, one after
+      another with nothing around them (one pass where the table is its
+      own sample: that pass proves every answer);
+    - ``step(xs, spec, state) -> (state, report)``: one more pass;
+    - ``step_ends``: one more pass that also pulls every bracket's ends in
+      to its outermost elements.
+
+    ``xs`` is the row-sharded table, ``spec`` the replicated int32 vector
+    ``[n_valid, rank_0, .., rank_{m-1}]`` (0-based ranks over the rows
+    ``[0, n_valid)``: the rows past them are ``ensure_on_mesh``'s zero
+    padding, at the table's tail), ``state`` the brackets (:func:`_pack`)
+    and ``report`` the int32 vector a driver reads between two programs
+    (:func:`read_report`): the bits of the ``(m, d)`` float32 elements at
+    the brackets' upper ends (the answers once no bracket is open), whether
+    any bracket is still open, whether the next pass should be
+    ``step_ends``, and the passes this program made. Under ``shard_map``
+    each shard reads its own rows and the counts cross by one ``psum`` a
+    pass.
+
+    Why three programs and a driver between them, and not one loop: a
+    reduction over the rows fuses into ONE read of the table where it lies
+    (9.5 ms at 12M x 100 on a v5e) only while no control flow surrounds
+    it; inside a ``while_loop`` or behind a ``cond`` XLA first copies the
+    table row-major (6.1 GB and 98 ms a fit; PERF.md section 6, PR 35 and
+    36). Straight-line passes keep nothing of the table's size."""
+    from jax.sharding import PartitionSpec as P
+
+    from flink_ml_tpu.parallel import mapreduce as mr
+    from flink_ml_tpu.parallel.mesh import data_axes, data_pspec
+
+    axes = data_axes(mesh)
+    u32 = jnp.uint32
+    full = u32(0xFFFFFFFF)
+
+    def table(xl, spec):
+        """What every program knows of the table before it reads it."""
+        local_n = xl.shape[0]
+        n_valid = spec[0]
+        pad = local_n * mr.shard_count(axes) - n_valid
+        local_valid = jnp.clip(n_valid - mr.shard_index(axes) * local_n,
+                               0, local_n)
+        return n_valid, (spec[1:] + 1)[:, None], pad, local_valid
+
+    def count(rows, piv, zeros_in):
+        """Rows of the whole table's ``rows`` with a key <= ``piv (..., m,
+        d)``, less the ``zeros_in`` padding rows (+0.0) among them."""
+        flat = piv.reshape(-1, piv.shape[-1])
+        c = mr.reduce_sum(count_le(rows, _signed(flat)), axes)
+        c = c - zeros_in * (flat >= u32(_TOP)).astype(jnp.int32)
+        return c.reshape(piv.shape)
+
+    def first_guess(xl, spec):
+        """The ``(PIVOTS, m, d)`` keys the first pass counts at: the order
+        statistics of a sample at the sample ranks a safety margin under
+        the wanted one, at it, and the margin over."""
+        local_n, d = xl.shape
+        n_valid, _, pad, _ = table(xl, spec)
+        ranks = spec[1:]
+        shards = mr.shard_count(axes)
+        if local_n <= SAMPLE_ROWS:
+            sample, sample_pad = xl, pad
+        else:
+            run = SAMPLE_ROWS // SAMPLE_RUNS
+            starts = [(local_n - run) * i // (SAMPLE_RUNS - 1)
+                      for i in range(SAMPLE_RUNS)]
+            sample = jnp.concatenate([xl[a:a + run] for a in starts])
+            sample_pad = 0
+        s_rows = sample.shape[0] * shards - sample_pad
+        q = ranks.astype(jnp.float32) / jnp.maximum(n_valid - 1, 1)
+        margin = jnp.where(s_rows >= n_valid, 0.0,
+                           SAFETY * jnp.sqrt(s_rows * q * (1 - q)) + 1)
+        side = jnp.asarray([-1.0, 0.0, 1.0])[:, None]
+        s_ranks = jnp.clip(jnp.round(q * (s_rows - 1) + side * margin),
+                           0, s_rows - 1).astype(jnp.int32)       # (3, m)
+
+        def halve(_, bracket):
+            lo, hi = bracket
+            mid = lo + (hi - lo) // u32(2)
+            ok = count(sample, mid, sample_pad) >= s_ranks[:, :, None] + 1
+            return jnp.where(ok, lo, mid + u32(1)), jnp.where(ok, mid, hi)
+
+        zeros = jnp.zeros((PIVOTS, m, d), u32)
+        _, first = jax.lax.fori_loop(0, 32, halve, (zeros, zeros + full))
+        # counted at key - 1, the lower guess proves itself the lower end
+        # where the column is tied there
+        return first.at[0].set(first[0] - (first[0] > 0).astype(u32))
+
+    def place(lo, hi, c_lo, c_hi, slow, target):
+        """The next pass's three pivots ``(3, m, d)`` in ``[lo, hi - 1]``:
+        by the wanted rank between the two proven counts, with the safety
+        margin either side, or, where ``slow``, at the bracket's
+        quarters."""
+        width = hi - lo
+        last = width - jnp.minimum(width, u32(1))
+        quarter = width >> u32(2)
+        even = jnp.stack([quarter, width >> u32(1),
+                          jnp.maximum(width >> u32(1), last - quarter)])
+        k = jnp.maximum(c_hi - c_lo, 1).astype(jnp.float32)
+        f = jnp.clip((target - c_lo).astype(jnp.float32) - 0.5,
+                     0.5, k - 0.5) / k
+        # (a small bracket loses little by a miss: a narrower margin.) The
+        # margin is the score interval's, not f +- z sd: a rank near an end
+        # of its bracket (where a one-sided bracket's usually lies) has a
+        # count as skewed as a Poisson's, and f +- z sd missed 30 times as
+        # often as z says (0.4 % of brackets at K 570: two extra passes in
+        # every second fit of 300)
+        z = jnp.minimum(SAFETY, 1 + jnp.log10(k))
+        centre = (f + z * z / (2 * k)) / (1 + z * z / k)
+        reach = (z * jnp.sqrt(f * (1 - f) / k + z * z / (4 * k * k))
+                 / (1 + z * z / k))
+        v_lo, v_hi = _key_to_float(lo), _key_to_float(hi)
+        at = jnp.clip(jnp.stack([centre - reach, f, centre + reach]),
+                      0.0, 1.0)
+        guess = _float_to_key(v_lo * (1 - at) + v_hi * at)
+        # in order, inside the bracket and at least a key apart, whatever
+        # rounding made of them; an end that is no number (a bracket not
+        # yet proven on that side) has nothing to interpolate
+        slow = slow | ~(jnp.isfinite(v_lo) & jnp.isfinite(v_hi))
+        # and the last EVEN_KEYS keys of a bracket of a few elements lying
+        # dense in them are cut faster at its quarters (a factor of 4 a
+        # pass, four passes) than by their ranks (some 2 sqrt(K) / margin);
+        # a little wider, one more pass by ranks first ends in as many
+        held = jnp.maximum(c_hi - c_lo, 1).astype(u32)
+        slow = slow | ((held <= int(4 * SAFETY * SAFETY))
+                       & (width <= EVEN_KEYS)
+                       & (width // u32(SNAP_KEYS) <= held))
+        low, high = (jnp.minimum(guess[0], guess[1]),
+                     jnp.maximum(guess[0], guess[1]))
+        guess = jnp.clip(jnp.stack([
+            jnp.minimum(low, guess[2]),
+            jnp.maximum(low, jnp.minimum(high, guess[2])),
+            jnp.maximum(high, guess[2])]), lo, lo + last)
+        guess = jnp.stack([
+            guess[1] - jnp.maximum(guess[1] - guess[0],
+                                   jnp.minimum(guess[1] - lo, u32(1))),
+            guess[1],
+            guess[1] + jnp.maximum(
+                guess[2] - guess[1],
+                jnp.minimum(lo + last - guess[1], u32(1)))])
+        return jnp.where(slow, lo + even, guess)
+
+    @functools.partial(jax.jit, static_argnames="with_ends")
+    def a_pass(xl, spec, state, with_ends: bool):
+        """One read of the table: the counts at the state's pivots prove a
+        narrower bracket (with ``with_ends`` its ends are also pulled in
+        to its outermost elements: nothing lies between them and the old
+        ends, so the two counts stand), and the next pivots are placed."""
+        _, target, pad, local_valid = table(xl, spec)
+        lo, hi, c_lo, c_hi, piv, _, was_slow = state
+        c = count(xl, piv, pad)
+        ok = c >= target
+        new_hi = jnp.minimum(hi, jnp.min(jnp.where(ok, piv, full), axis=0))
+        new_c_hi = jnp.minimum(c_hi, jnp.min(
+            jnp.where(ok, c, jnp.int32(2**31 - 1)), axis=0))
+        new_lo = jnp.maximum(lo, jnp.max(
+            jnp.where(ok, u32(0), piv + u32(1)), axis=0))
+        new_c_lo = jnp.maximum(c_lo, jnp.max(jnp.where(ok, 0, c), axis=0))
+        if with_ends:
+            a, b = _ends_within(xl, lo, hi, local_valid)
+            # (the smallest over the shards is the complement of the
+            # largest complement: no negation to overflow)
+            new_lo = jnp.maximum(new_lo, _unsigned(~mr.reduce_max(~a, axes)))
+            new_hi = jnp.minimum(new_hi, _unsigned(mr.reduce_max(b, axes)))
+        slow = (new_hi - new_lo) > ((hi - lo) >> u32(2))
+        stuck = (new_c_hi - new_c_lo) == (c_hi - c_lo)
+        # (one slow pass is a count outside its margin as often as a
+        # column that has no smooth ranks: the second gives them up)
+        return (new_lo, new_hi, new_c_lo, new_c_hi,
+                place(new_lo, new_hi, new_c_lo, new_c_hi, slow & was_slow,
+                      target), stuck, slow)
+
+    def report(state, passes: int):
+        lo, hi, c_lo, c_hi, _, stuck, _ = state
+        # a run of keys far wider than the elements in it, which are a
+        # handful or among which the last pass's counts all fell between
+        # two: the next pass looks for the elements
+        sparse = _wants_ends((c_hi - c_lo).astype(u32), hi - lo, stuck)
+        answers = jax.lax.bitcast_convert_type(_key_to_float(hi), jnp.int32)
+        return jnp.concatenate([answers.reshape(-1), jnp.stack([
+            jnp.any(hi > lo).astype(jnp.int32),
+            jnp.any(sparse).astype(jnp.int32), jnp.int32(passes)])])
+
+    def select_head(xl, spec):
+        d = xl.shape[1]
+        state = (jnp.zeros((m, d), u32), jnp.zeros((m, d), u32) + full,
+                 jnp.zeros((m, d), jnp.int32),
+                 jnp.zeros((m, d), jnp.int32) + spec[0],
+                 first_guess(xl, spec), jnp.zeros((m, d), bool),
+                 jnp.zeros((m, d), bool))
+        # (a table that is its own sample guessed its answers exactly:
+        # the one pass that proves them)
+        passes = 1 if xl.shape[0] <= SAMPLE_ROWS else HEAD_PASSES
+        for _ in range(passes):
+            state = a_pass(xl, spec, state, with_ends=False)
+        return _pack(*state), report(state, passes)
+
+    def select_step(xl, spec, packed):
+        state = a_pass(xl, spec, _unpack(packed), with_ends=False)
+        return _pack(*state), report(state, 1)
+
+    def select_step_ends(xl, spec, packed):
+        state = a_pass(xl, spec, _unpack(packed), with_ends=True)
+        return _pack(*state), report(state, 1)
+
+    rows = P(data_pspec(mesh), None)
+    return (mr.map_shards(select_head, mesh, in_specs=(rows, P()),
+                          out_specs=(P(), P())),
+            mr.map_shards(select_step, mesh, in_specs=(rows, P(), P()),
+                          out_specs=(P(), P())),
+            mr.map_shards(select_step_ends, mesh, in_specs=(rows, P(), P()),
+                          out_specs=(P(), P())))
+
+
+def read_report(report: np.ndarray, m: int):
+    """A program's report on the host: ``(elements (m, d) float32 at the
+    brackets' upper ends, any bracket open, the next pass wants the ends,
+    passes the program made)``."""
+    return (report[:-3].view(np.float32).reshape(m, -1), bool(report[-3]),
+            bool(report[-2]), int(report[-1]))
+
+
+def select_ranks(probs: Sequence[float], n: int) -> np.ndarray:
+    """0-based rank ``floor(q (n - 1))`` of each probability: numpy's
+    ``method='lower'`` (docs/deviations.md)."""
+    return np.floor(np.asarray(probs, np.float64) * (n - 1)).astype(np.int32)
+
+
+@functools.lru_cache(maxsize=32)
+def _spec_on_mesh(mesh, n: int, probs: tuple):
+    """The replicated ``[n, ranks...]`` operand of the programs, placed
+    once a mesh, row count and probabilities: a warm fit places nothing."""
+    from flink_ml_tpu.parallel.collective import replicate
+
+    return replicate(mesh, np.concatenate(
+        [[n], select_ranks(probs, n)]).astype(np.int32))
+
+
+def select_on_device(x, probs: Sequence[float]):
+    """Per-column order statistics of a DEVICE ``(n, d)`` float32 column →
+    ``((m, d) float32 on the host, passes)``: the element of 0-based rank
+    ``floor(q (n - 1))`` of every column, EXACT for every float32 input
+    (ties, negatives, infinities, denormals; NaN bit patterns sort outside
+    the finite band, negative payloads below -inf, positive above +inf, as
+    a sort puts them at the ends), with no sort and no second copy of the
+    table, and the whole reads of the table it took.
+
+    The one driver of :func:`select_programs`: the head, then one pass a
+    launch while a bracket is open, each program's report read through
+    ``read_boundary`` before the next is chosen. Spans
+    ``select.place_inputs``, ``select.build_program``, then a
+    ``select.launch`` (enqueue only) and a ``select.fetch`` (the blocking
+    read; ``passes``: the reads of the table it waited for) a program;
+    counter ``ml.select passes``."""
+    from flink_ml_tpu.common.metrics import ML_GROUP, metrics
+    from flink_ml_tpu.iteration.iteration import read_boundary
+    from flink_ml_tpu.parallel.collective import ensure_on_mesh
+    from flink_ml_tpu.parallel.mesh import data_axes, default_mesh
+
+    mesh = default_mesh()
+    d, m = x.shape[1], len(probs)
+    with tracer.span("select.place_inputs"):
+        xs, n = ensure_on_mesh(mesh, x, data_axes(mesh), np.float32)
+        spec = _spec_on_mesh(mesh, n, tuple(float(q) for q in probs))
+    with tracer.span("select.build_program"):
+        head, step, step_ends = select_programs(mesh, m)
+    with tracer.span("select.launch", path="select-device", rows=n, d=d,
+                     probs=list(probs)):
+        state, report = head(xs, spec)
+    passes = 0
+    while True:
+        with tracer.span("select.fetch") as sp:
+            found, more, ends, made = read_report(
+                read_boundary((report,))[0], m)
+            sp.set_attribute("passes", made)
+        passes += made
+        if not more:
+            break
+        with tracer.span("select.launch", ends=ends):
+            state, report = (step_ends if ends else step)(xs, spec, state)
+    metrics.group(ML_GROUP, "select").counter("passes", passes)
+    return found, passes

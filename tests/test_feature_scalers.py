@@ -321,13 +321,14 @@ def test_variance_selector_sparse_large_offset_stability(rng):
 
 
 def test_rank_select_device_exact_on_adversarial_columns(rng):
-    """The sort-free device rank-select must return the EXACT
-    method='lower' order statistic even when the value range is hostile:
-    huge outliers (RobustScaler's core use case), infinities, denormals,
-    signed zeros — integer bit-bisection is range-independent."""
+    """The sort-free device selection (``ops/quantile.select_on_device``,
+    the entry point since PR 36) must return the EXACT method='lower' order
+    statistic even when the value range is hostile: huge outliers
+    (RobustScaler's core use case), infinities, denormals, signed zeros —
+    counts over integer keys are range-independent."""
     import jax.numpy as jnp
 
-    from flink_ml_tpu.ops.quantile import rank_select_device
+    from flink_ml_tpu.ops.quantile import select_on_device
 
     cases = [
         (rng.normal(size=(5000, 4)) * [1, 10, 0.01, 1000]),
@@ -339,7 +340,8 @@ def test_rank_select_device_exact_on_adversarial_columns(rng):
     probs = [0.0, 0.25, 0.5, 0.75, 1.0]
     for x in cases:
         x32 = np.asarray(x, np.float32)
-        got = np.asarray(rank_select_device(jnp.asarray(x32), probs))
+        got, passes = select_on_device(jnp.asarray(x32), probs)
+        assert passes >= 1
         exp = np.quantile(x32.astype(np.float64), probs, axis=0,
                           method="lower").astype(np.float32)
         np.testing.assert_array_equal(got, exp)

@@ -8,8 +8,10 @@ compiler the installation brings: no chip is needed, and where there is no
 such compiler the cases are skipped. What Mosaic itself counts at a shape is
 ``python scripts/lloyd_vmem_bisect.py k,d,tile``. The counting kernel of the
 NaiveBayes fit (``category_counts``, gate ``counts_tile``) is held the same
-way, here and not in a file of its own: one file, one worker, one load of
-the TPU compiler.
+way, and so are the selection programs of the RobustScaler fit (no kernel:
+what is held is that, at the benchmark's 12M x 100, none keeps anything of
+the table's size beside it), here and not in files of their own: one file,
+one worker, one load of the TPU compiler.
 """
 
 import functools
@@ -131,3 +133,44 @@ def test_small_and_unaligned_counting_shapes_compile_for_the_chip(
         pytest.skip("no TPU compiler in this installation")
     assert pk.counts_tile(d, labels, values)
     compile_counts(d, labels, values, 20_000)
+
+
+# -- the selection programs of the order statistics (RobustScaler fit) ---------
+
+@pytest.mark.parametrize("program", ["head", "step", "step_ends"])
+def test_a_selection_program_keeps_nothing_of_the_table_s_size_on_the_chip(
+        program):
+    """Each program of a fit at the RobustScaler cell's shape, 12M x 100 on
+    a v5e: its temporaries do not grow with the table (the 32-round program
+    they replaced kept a 4.992 GB key image; the same passes inside a
+    ``while_loop`` or behind a ``cond`` make XLA copy the table row-major,
+    6.1 GB), and its one large argument is the table where it lies."""
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.ops import quantile
+    from flink_ml_tpu.parallel.mesh import create_mesh
+
+    mesh = create_mesh(devices=[chip()])
+
+    def of(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    n, d, m = 12_000_000, 100, 3
+    operands = [of((n, d), jnp.float32, P("data", None)),
+                of((m + 1,), jnp.int32)]
+    if program != "head":
+        operands.append(of((5 + quantile.PIVOTS, m, d), jnp.uint32))
+    quantile.select_programs.cache_clear()
+    try:
+        built = dict(zip(("head", "step", "step_ends"),
+                         quantile.select_programs(mesh, m)))[program]
+        compiled = built.lower(*operands).compile()
+    finally:
+        quantile.select_programs.cache_clear()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(4.992e9, rel=1e-3)
+    assert memory.temp_size_in_bytes < 0.02e9
+    assert "custom_call_target=\"tpu_custom_call\"" not in compiled.as_text()
