@@ -1,17 +1,18 @@
 """The forms of an exact per-column order statistic, timed on the chip at the
 RobustScaler cell's shape (12M x 100 float32, lower / median / upper): what
 a process's FIRST fit costs in each, phase by phase, and what a steady fit
-does. PERF.md section 6 (PR 36) holds what it read; this file is the record
-of what was timed.
+does. PERF.md section 6 (PR 36, PR 37) holds what it read; this file is the
+record of what was timed.
 
-    python scripts/select_forms.py [--rows 12000000] [--dim 100] [--heads 1,2,3,4,5]
+    python scripts/select_forms.py [--rows 12000000] [--dim 100] [--heads 3,4] [--blocks 8192] [--slices 8]
 
-The forms are one program text with another number of straight-line passes
-in its head (``ops/quantile.HEAD_PASSES``): 1 is the pass-by-pass form (a
-host trip a pass), 2-5 hold the passes every table needs in one program and
-go pass by pass for what is left. This process never touches JAX (a chip
-belongs to one process at a time): every measurement is a child, each a
-fresh process, each printing one JSON line.
+The forms are one program text with another number of straight-line counting
+passes in its head (``ops/quantile.HEAD_PASSES``), after which the head
+finishes its brackets where they are few-element brackets (the finishing
+pass, behind a branch) and the driver goes pass by pass for what is left.
+This process never touches JAX (a chip belongs to one process at a time):
+every measurement is a child, each a fresh process, each printing one JSON
+line.
 
 - ``first[K]`` (``--runs`` children a form; the first of them fills the
   compile cache where it was cold and says so by its ``backend_compiles``):
@@ -22,10 +23,10 @@ fresh process, each printing one JSON line.
   load, the first execution of every program the fit runs), its compile
   requests, the programs it made, whether ``jax.experimental.pallas`` was
   imported; then ``--repeats`` warm calls: the steady fit, its passes;
-- ``phases[K]``: the same set-up, then the head and the step program through
-  the staged API, each step timed alone: ``trace``, ``lower`` (and the
-  lowered text's length), ``compile`` (with the cache warm: its load), the
-  first execution, a second;
+- ``phases[K]``: the same set-up, then the head, the step and the finishing
+  program through the staged API, each step timed alone: ``trace``,
+  ``lower`` (and the lowered text's length), ``compile`` (with the cache
+  warm: its load), the first execution, a second;
 - ``pallas_import``: ``import jax.experimental.pallas`` and ``.tpu`` in a
   fresh process that has imported jax: the floor of any form that keeps a
   kernel (form (c) of ISSUE 36), before one line of it is traced;
@@ -34,11 +35,19 @@ fresh process, each printing one JSON line.
   rounds), ``xla_pass[9]`` (one counting pass at nine pivots a column,
   alone) and ``xla_pass[9 | 3 | 0 + ends]`` (the ends of three brackets
   beside nine counts, three, none: what the pass that pulls a bracket in to
-  its elements costs, and what of it is the ends'),
+  its elements costs, and what of it is the ends'), ``finish[blocks x
+  slices]`` (the finishing pass's read of the table alone, ``block_sums``
+  and ``block_elements`` on brackets as the cell's third pass leaves them,
+  for every ``--blocks`` a shard and ``--slices`` a turn of its loop),
   ``select[K]`` for every head on the cell's uniform table, answers held to
   ``bisect32``'s bit for bit, and ``tables[<distribution>][K]`` on tables of
-  OTHER distributions (``table_makers``), ``--tables`` seeds each: passes
-  and milliseconds (a pass count is the table's, not the shape's).
+  OTHER distributions (``table_makers``), ``--tables`` seeds each: passes,
+  the brackets the finishing pass closed and declined, and milliseconds (a
+  pass count is the table's, not the shape's). What PR 37 also timed and
+  did not keep (PERF.md section 6): count and sum in words of their own
+  (37.6 ms where the packed word takes 21.4), blocks of neighbouring rows
+  (29.3; a sorted table's bracket is then one block), the finishing
+  program launched by the driver after a report (52.8 ms a fit for 51.3).
 
 - ``seeds[<rule>]`` (``--seeds N``): the cell's uniform table on ``N`` more
   seeds under each of ``RULES`` (the program as kept; the pass that pulls a
@@ -133,6 +142,23 @@ def table_makers(n: int, d: int):
         ("sorted", sorted_rows))}
 
 
+def select_counts():
+    """``ml.select``'s counters, as they stand."""
+    from flink_ml_tpu.common.metrics import ML_GROUP, metrics
+
+    return dict(metrics.group(ML_GROUP, "select").snapshot()["counters"])
+
+
+def counted(call):
+    """``(call's result, the finishing pass's finished and declined
+    brackets inside it)``."""
+    before = select_counts()
+    out = call()
+    after = select_counts()
+    return out, [after.get(k, 0) - before.get(k, 0)
+                 for k in ("finished", "declined")]
+
+
 def timed(fn, repeats: int):
     """``(median seconds, last result)`` of ``repeats`` warm calls."""
     import jax
@@ -207,6 +233,7 @@ def child_phases(args, head: int) -> dict:
     quantile.HEAD_PASSES = head
     spec = quantile._spec_on_mesh(mesh, args.rows, tuple(PROBS))
     head_program, step_program, _ = quantile.select_programs(mesh, len(PROBS))
+    finish_program = quantile.finish_program(mesh, len(PROBS))
     out = {"form": f"phases[{head}]"}
 
     def staged(name, program, operands):
@@ -229,6 +256,7 @@ def child_phases(args, head: int) -> dict:
 
     state, _ = staged("head", head_program, (x, spec))
     staged("step", step_program, (x, spec, state))
+    staged("finish", finish_program, (x, spec, state))
     return out
 
 
@@ -266,20 +294,26 @@ def child_steady(args) -> list:
     def select_with(head):
         quantile.HEAD_PASSES = head
         quantile.select_programs.cache_clear()
+        quantile.finish_program.cache_clear()
 
     def over_seeds(make):
-        passes, walls = [], []
+        passes, finished, declined, walls = [], [], [], []
         for seed in range(args.seed + 1, args.seed + 1 + args.tables):
             x = jax.block_until_ready(make(jax.random.key(seed)))
             if not passes:
                 quantile.select_on_device(x, PROBS)        # builds
             t = time.perf_counter()
-            passes.append(quantile.select_on_device(x, PROBS)[1])
+            (_, made), closed = counted(
+                lambda: quantile.select_on_device(x, PROBS))
             walls.append((time.perf_counter() - t) * 1e3)
+            passes.append(made)
+            finished.append(closed[0])
+            declined.append(closed[1])
             del x
-        return passes, walls
+        return passes, finished, declined, walls
 
     x = jax.block_until_ready(makers["uniform"](jax.random.key(args.seed)))
+    shipped_slices = quantile.FINISH_SLICES
     want = None
     if not args.skip_bisect32:
         old_form = jax.jit(bisect32)
@@ -309,24 +343,44 @@ def child_steady(args) -> list:
         s, _ = timed(lambda: run(x, piv), args.repeats)
         say(f"xla_pass[{pivots} + ends]", ms=s * 1e3,
             GBps=table_bytes / s / 1e9)
+    # brackets as the cell's third pass leaves them: some 70-140 elements
+    # in 200 keys around each quartile
+    lo = jnp.asarray(np.repeat(np.asarray(quantile._float_to_key(
+        jnp.asarray(PROBS, jnp.float32)))[:, None], d, axis=1) - 100)
+    width = jnp.full((len(PROBS), d), 200, jnp.uint32)
+    finish = jax.jit(lambda x, lo, width, blocks: quantile.block_elements(
+        quantile.block_sums(x, lo, width, blocks), width), static_argnums=3)
+    for blocks in args.blocks:
+        for slices in args.slices:
+            quantile.FINISH_SLICES = slices
+            finish.clear_cache()
+            s, out = timed(lambda: finish(x, lo, width, blocks),
+                           args.repeats)
+            say(f"finish[{blocks} x {slices}]", ms=s * 1e3,
+                GBps=table_bytes / s / 1e9,
+                crowded=int(np.asarray(out[1]).sum()))
+    quantile.FINISH_SLICES = shipped_slices
     for head in args.heads:
         select_with(head)
         quantile.select_on_device(x, PROBS)                # builds
         walls = []
         for _ in range(args.repeats):
             t = time.perf_counter()
-            got, passes = quantile.select_on_device(x, PROBS)
+            (got, passes), closed = counted(
+                lambda: quantile.select_on_device(x, PROBS))
             walls.append((time.perf_counter() - t) * 1e3)
         say(f"select[{head}]", ms=statistics.median(walls), passes=passes,
+            finished=closed[0], declined=closed[1],
             equals_bisect32=None if want is None else bool(
                 np.array_equal(got.view(np.uint32), want.view(np.uint32))))
     del x
     for head in args.heads:
         select_with(head)
         for name, make in makers.items():
-            passes, walls = over_seeds(make)
-            say(f"tables[{name}][{head}]", passes=passes,
-                ms_median=statistics.median(walls), ms_max=max(walls))
+            passes, finished, declined, walls = over_seeds(make)
+            say(f"tables[{name}][{head}]", passes=passes, finished=finished,
+                declined=declined, ms_median=statistics.median(walls),
+                ms_max=max(walls))
     return lines
 
 
@@ -353,7 +407,8 @@ def child_seeds(args) -> list:
     tracer.keep_recent = True
     kept = (quantile._wants_ends, quantile.SAMPLE_ROWS)
     lines = []
-    for rule, change in RULES.items():
+    for rule in args.rules:
+        change = RULES[rule]
         keys = change.get("ends_keys", quantile.ENDS_KEYS)
 
         def wants(held, width, stuck, keys=keys):
@@ -365,20 +420,24 @@ def child_seeds(args) -> list:
         quantile._wants_ends = wants
         quantile.SAMPLE_ROWS = change.get("sample", kept[1])
         quantile.select_programs.cache_clear()
-        passes, ends, walls = [], [], []
+        quantile.finish_program.cache_clear()
+        passes, ends, declined, walls = [], [], [], []
         for seed in range(args.seed + 100, args.seed + 100 + args.seeds):
             x = jax.block_until_ready(make(jax.random.key(seed)))
             quantile.select_on_device(x, PROBS)            # builds
             tracer.recent.clear()
             t = time.perf_counter()
-            passes.append(quantile.select_on_device(x, PROBS)[1])
+            (_, made), closed = counted(
+                lambda: quantile.select_on_device(x, PROBS))
             walls.append(round((time.perf_counter() - t) * 1e3, 2))
+            passes.append(made)
+            declined.append(closed[1])
             ends.append(sum(1 for r in tracer.recent
                             if r["name"] == "select.launch"
                             and r["attrs"].get("ends")))
             del x
         lines.append({"form": f"seeds[{rule}]", "passes": passes,
-                      "ends_passes": ends, "ms": walls,
+                      "ends_passes": ends, "declined": declined, "ms": walls,
                       "ms_median": statistics.median(walls),
                       "slow_seeds": sum(w > 1.05 * statistics.median(walls)
                                         for w in walls)})
@@ -415,10 +474,18 @@ def main(argv=None) -> int:
     parser.add_argument("--runs", type=int, default=3,
                         help="first-fit children a form")
     parser.add_argument("--tables", type=int, default=3)
-    parser.add_argument("--heads", default=[1, 2, 3, 4, 5],
+    parser.add_argument("--blocks", default=[8192],
+                        type=lambda v: [int(k) for k in v.split(",")],
+                        help="blocks a shard for the finish[...] forms")
+    parser.add_argument("--slices", default=[8],
+                        type=lambda v: [int(k) for k in v.split(",")],
+                        help="slices a turn of the finishing pass's loop")
+    parser.add_argument("--heads", default=[3, 4],
                         type=lambda v: [int(k) for k in v.split(",")])
     parser.add_argument("--seeds", type=int, default=0,
                         help="uniform tables a rule of RULES (0: skip)")
+    parser.add_argument("--rules", default=list(RULES),
+                        type=lambda v: v.split(","))
     parser.add_argument("--skip-bisect32", action="store_true")
     parser.add_argument("--skip-steady", action="store_true")
     parser.add_argument("--skip-first", action="store_true")

@@ -28,14 +28,19 @@ WIDTHS = (6, 100, 128, 512, 1000, 2048)
 
 
 @functools.lru_cache(maxsize=None)
-def chip():
-    """One device of a v5e as the compiler sees it, or None."""
+def four_chips():
+    """The devices of a v5e host as the compiler sees them, or None."""
     try:
         from jax.experimental import topologies
         return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices[0]
+            platform="tpu", topology_name="v5e:2x2").devices
     except Exception:  # noqa: BLE001 — no TPU compiler here
         return None
+
+
+def chip():
+    """One device of a v5e as the compiler sees it, or None."""
+    return four_chips()[0] if four_chips() else None
 
 
 def compile_both(k, d, rows):
@@ -137,40 +142,58 @@ def test_small_and_unaligned_counting_shapes_compile_for_the_chip(
 
 # -- the selection programs of the order statistics (RobustScaler fit) ---------
 
-@pytest.mark.parametrize("program", ["head", "step", "step_ends"])
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("program", ["head", "step", "step_ends", "finish"])
 def test_a_selection_program_keeps_nothing_of_the_table_s_size_on_the_chip(
-        program):
+        program, chips):
     """Each program of a fit at the RobustScaler cell's shape, 12M x 100 on
-    a v5e: its temporaries do not grow with the table (the 32-round program
-    they replaced kept a 4.992 GB key image; the same passes inside a
-    ``while_loop`` or behind a ``cond`` make XLA copy the table row-major,
-    6.1 GB), and its one large argument is the table where it lies."""
+    a v5e, and at four times the rows over four: its temporaries do not
+    grow with the table (the 32-round program they replaced kept a 4.992 GB
+    key image; the same passes inside a ``while_loop`` or behind a ``cond``
+    make XLA copy the table row-major, 6.1 GB), its one large argument is
+    the table where it lies, and its text holds no array of the table's
+    shape but the table. The finishing pass (its own program, and the
+    head's last step behind a branch) loops over slices of the table: any
+    way of writing it as a reduction within blocks of rows made XLA write
+    the keys out first, 4.6-11.1 GB (PERF.md section 6, PR 37)."""
     if chip() is None:
         pytest.skip("no TPU compiler in this installation")
+    import re
+
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from flink_ml_tpu.ops import quantile
     from flink_ml_tpu.parallel.mesh import create_mesh
 
-    mesh = create_mesh(devices=[chip()])
+    mesh = create_mesh(devices=four_chips()[:chips])
 
     def of(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    n, d, m = 12_000_000, 100, 3
-    operands = [of((n, d), jnp.float32, P("data", None)),
+    local, d, m = 12_000_000, 100, 3
+    operands = [of((local * chips, d), jnp.float32, P("data", None)),
                 of((m + 1,), jnp.int32)]
     if program != "head":
         operands.append(of((5 + quantile.PIVOTS, m, d), jnp.uint32))
     quantile.select_programs.cache_clear()
+    quantile.finish_program.cache_clear()
     try:
         built = dict(zip(("head", "step", "step_ends"),
-                         quantile.select_programs(mesh, m)))[program]
+                         quantile.select_programs(mesh, m)),
+                     finish=quantile.finish_program(mesh, m))[program]
         compiled = built.lower(*operands).compile()
     finally:
         quantile.select_programs.cache_clear()
+        quantile.finish_program.cache_clear()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(4.992e9, rel=1e-3)
     assert memory.temp_size_in_bytes < 0.02e9
-    assert "custom_call_target=\"tpu_custom_call\"" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "custom_call_target=\"tpu_custom_call\"" not in text
+    if program == "finish":
+        # nothing of the table's shape is made: not its keys, not a mask
+        made = set(re.findall(rf"([a-z0-9]+)\[(?:{local},{d}|{d},{local})\]",
+                              text))
+        assert made == {"f32"}, made
+        assert " while(" in text

@@ -7,8 +7,11 @@ them, heavy ties, negatives, infinities, NaN, denormals and signed zeros,
 columns of one value, of two, of NaN alone, a sorted and a reverse-sorted
 table (the first guess comes from a few runs of rows: it must fall back, not
 err), one device and four (equal bit for bit), every number of passes in the
-head; a bound on the passes a distribution; the traced programs hold no loop
-or branch around a read of the table and nothing larger than it; a warm fit
+head; the finishing pass (``finish_program``: the few elements left in a
+narrow bracket taken out, not counted once more) against numpy, bracket by
+bracket: what it closes, what it declines and leaves exactly as it was; a
+bound on the passes a distribution; the traced programs hold no loop or
+branch around a reduction over the table and nothing larger than it; a warm fit
 builds nothing; a process's first fit stays inside its budget of programs
 and lowered text and imports no Pallas; the spans and counters of a fit; and
 the host path gives the same model.
@@ -44,6 +47,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 def drop_programs():
     quantile.select_programs.cache_clear()
+    quantile.finish_program.cache_clear()
     quantile._spec_on_mesh.cache_clear()
 
 
@@ -75,6 +79,8 @@ def column(name, rng):
         return rng.standard_normal((2403, 1)).astype(np.float32)
     if name == "thirteen-columns":
         return (rng.standard_normal((2500, 13)) * 100).astype(np.float32)
+    if name == "long":
+        return rng.random((40_000, 5), dtype=np.float32)
     if name == "hundred-columns":
         return rng.random((1203, 100), dtype=np.float32)
     if name == "arity-2":
@@ -107,16 +113,18 @@ def column(name, rng):
 #: count is the table's, so a rule of the program that stopped working
 #: shows here; a bisection takes 32 on every one of them, and without the
 #: rule that pulls a bracket of one value in to it the tied columns take a
-#: dozen and more). A table longer than its sample takes the head's four
-#: at the least, and a tied or a sorted one a pass or two more than it
-#: would pass by pass: the head's passes only count, and the pass that
-#: pulls a bracket in to its elements costs two and a half of them, so it
-#: is asked for only where counts alone would take more than three
-#: (``ENDS_KEYS``; PERF.md section 6, PR 36)
-COLUMNS = {"ragged": 7, "one-column": 8, "thirteen-columns": 11,
-           "hundred-columns": 10, "arity-2": 5, "arity-20": 8,
-           "zero-inflated": 10, "hostile": 12, "one-value": 4, "all-nan": 4,
-           "sorted": 13, "reverse-sorted": 19}
+#: dozen and more). A table longer than its sample takes the head's three
+#: at the least, and a tied one a pass or two more than it would pass by
+#: pass: the head's passes only count, and the pass that pulls a bracket
+#: in to its elements costs two and a half of them, so it is asked for
+#: only where counts alone would take more than three (``ENDS_KEYS``;
+#: PERF.md section 6, PR 36). Since PR 37 brackets of a few dozen elements
+#: are finished in one read (nine probabilities: three), which took two to
+#: six passes off ten of these twelve
+COLUMNS = {"ragged": 7, "one-column": 7, "thirteen-columns": 7,
+           "hundred-columns": 7, "arity-2": 4, "arity-20": 6,
+           "zero-inflated": 6, "hostile": 9, "one-value": 3, "all-nan": 3,
+           "sorted": 8, "reverse-sorted": 13}
 PROBS = {1: [0.5], 3: [0.25, 0.5, 0.75],
          9: [0.0, 0.01, 0.1, 0.25, 0.5, 0.75, 0.9, 0.99, 1.0]}
 
@@ -196,9 +204,10 @@ def select_forms():
 #: distribution (``scripts/select_forms.table_makers``: the tables the chip
 #: timed at 12M x 100, PERF.md section 6) -> the most passes 1M x 3 rows of
 #: seed 0 may take under the sample the program ships with, on one device
-#: and four; what they take today, or one more
-TABLES = {"uniform": 8, "normal": 8, "zero_inflated": 9, "integer_coded": 7,
-          "sorted": 6}
+#: and four; what they take today and one more (until PR 37, without the
+#: finishing pass: 8, 8, 9, 7, 6)
+TABLES = {"uniform": 5, "normal": 6, "zero_inflated": 5, "integer_coded": 7,
+          "sorted": 5}
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -233,13 +242,16 @@ def test_a_tied_column_has_its_brackets_pulled_in_to_their_elements(
     one value many times over, and the pass that looks for its elements
     (``select_step_ends``) ends it at once."""
     monkeypatch.setattr(tracer, "keep_recent", True)
-    x = column("arity-20", np.random.default_rng(3))
+    # (a thousand rows a value: too many for the finishing pass to take)
+    x = np.floor(np.random.default_rng(3).random((20_000, 3)) * 20).astype(
+        np.float32) - 7
     got, passes = select(x, PROBS[3], 1)
     np.testing.assert_array_equal(bits(got), bits(sorted_at(x, PROBS[3])))
     launches = [r["attrs"] for r in tracer.recent
                 if r["name"] == "select.launch"]
     assert launches[0]["path"] == "select-device"
     assert [a["ends"] for a in launches[1:]].count(True) >= 1
+    assert not any(a["finish"] for a in launches[1:])    # never so few
     assert passes == quantile.HEAD_PASSES + len(launches) - 1
 
 
@@ -281,12 +293,295 @@ def test_a_pass_counts_and_finds_ends_as_numpy_does(n, d, valid):
         .clip(min=t) for t, u in zip(lo, hi)]))
 
 
+# -- the finishing pass ----------------------------------------------------------
+
+def ukeys(x):
+    return np.asarray(quantile._float_to_key(jnp.asarray(x, jnp.float32)))
+
+
+def brackets_around(x, ranks, below, above, wider=(0, 0)):
+    """Proven brackets ``(lo, hi, c_lo, c_hi)``, each ``(m, d)``, around the
+    elements of 0-based ``ranks`` of every column: from the element
+    ``below`` ranks under the wanted one to the one ``above`` over it, made
+    ``wider`` by so many keys either side, with the two counts that prove
+    them (keys under ``lo``, keys at or under ``hi``)."""
+    keys, n = ukeys(x), len(x)
+    by_rank = np.sort(keys, axis=0)
+    lo = np.stack([by_rank[max(r - below, 0)] for r in ranks]) - np.uint32(
+        wider[0])
+    hi = np.stack([by_rank[min(r + above, n - 1)] for r in ranks]) + np.uint32(
+        wider[1])
+    c_lo = (keys[None] < lo[:, None]).sum(1).astype(np.int32)
+    c_hi = (keys[None] <= hi[:, None]).sum(1).astype(np.int32)
+    return lo, hi, c_lo, c_hi
+
+
+def crowded(x, lo, hi, devices, blocks):
+    """Which brackets have a block that holds more than three of their
+    elements: the rows, zero-padded to a multiple of ``devices``, split
+    evenly over the shards, a shard's row ``i`` in its block ``i %
+    blocks``."""
+    keys = ukeys(x)
+    pad = (-len(keys)) % devices
+    keys = np.concatenate([keys, np.full((pad, keys.shape[1]),
+                                         quantile._TOP, np.uint32)])
+    local = len(keys) // devices
+    row = np.arange(len(keys))
+    block = row % local % blocks + blocks * (row // local)
+    out = np.zeros(lo.shape, bool)
+    for i, c in np.ndindex(*lo.shape):
+        inside = (keys[:, c] >= lo[i, c]) & (keys[:, c] <= hi[i, c])
+        out[i, c] = inside.any() and np.bincount(block[inside]).max() > 3
+    return out
+
+
+def takes(held, width, pad):
+    """The gate of the finishing pass, as its docstring states it: at most
+    ``FINISH_HELD`` elements in at most ``FINISH_KEYS`` keys, and four
+    thirds of the elements (and the rows of padding) under what the count
+    of a block's packed word has room for beside three offsets."""
+    bits = np.vectorize(lambda w: max(int(3 * w).bit_length(), 1))(
+        np.minimum(width.astype(np.int64), quantile.FINISH_KEYS))
+    return ((held <= quantile.FINISH_HELD) & (width <= quantile.FINISH_KEYS)
+            & (4 * (held.astype(np.int64) + pad) < 3 * 2.0 ** (32 - bits)))
+
+
+def finish(x, ranks, brackets, devices, gave_up=None):
+    """The finishing program on ``brackets`` of ``x`` → ``(the state it
+    leaves, its report)``."""
+    from flink_ml_tpu.parallel.collective import ensure_on_mesh, replicate
+    from flink_ml_tpu.parallel.mesh import data_axes
+
+    mesh = on_mesh(devices)
+    xs, n = ensure_on_mesh(mesh, jnp.asarray(x, jnp.float32),
+                           data_axes(mesh), np.float32)
+    spec = replicate(mesh, np.asarray([n, *ranks], np.int32))
+    lo, hi, c_lo, c_hi = map(jnp.asarray, brackets)
+    no = jnp.zeros(lo.shape, bool)
+    state = quantile._pack(lo, hi, c_lo, c_hi, jnp.stack([lo, lo, hi]), no,
+                           no, no if gave_up is None else jnp.asarray(gave_up))
+    packed, report = quantile.finish_program(mesh, len(ranks))(
+        xs, spec, state)
+    return ([np.asarray(v) for v in quantile._unpack(packed)],
+            quantile.read_report(np.asarray(report), len(ranks)))
+
+
+def check_finish(x, ranks, brackets, devices):
+    """The finishing pass against numpy: a bracket it takes is closed on
+    the element a sort puts at the rank, with the counts that prove it; one
+    it does not take (too many elements, too many keys, four of them in one
+    block) is left exactly as it was, and marked. Returns which it
+    closed."""
+    state, seen = finish(x, ranks, brackets, devices)
+    lo, hi, c_lo, c_hi = brackets
+    keys = ukeys(x)
+    want = np.sort(keys, axis=0)[np.asarray(ranks)]
+    is_open = hi > lo
+    offered = is_open & takes(c_hi - c_lo, hi - lo, (-len(x)) % devices)
+    done = offered & ~crowded(x, lo, hi, devices, quantile.FINISH_BLOCKS)
+    np.testing.assert_array_equal(state[0], np.where(done, want, lo))
+    np.testing.assert_array_equal(state[1], np.where(done, want, hi))
+    np.testing.assert_array_equal(state[2], np.where(
+        done, (keys[None] < want[:, None]).sum(1), c_lo))
+    np.testing.assert_array_equal(state[3], np.where(
+        done, (keys[None] <= want[:, None]).sum(1), c_hi))
+    np.testing.assert_array_equal(state[7], is_open & ~done)
+    assert (seen.finished, seen.declined) == (done.sum(),
+                                              (is_open & ~done).sum())
+    # a read of the table a group of brackets that holds an offered one
+    # (a lone group is read whatever it holds)
+    group = quantile.FINISH_GROUP
+    assert seen.passes == sum(
+        offered[g:g + group].any() or len(ranks) <= group
+        for g in range(0, len(ranks), group))
+    assert seen.more == (is_open & ~done).any() and not seen.finish
+    np.testing.assert_array_equal(
+        bits(seen.found), bits(np.asarray(quantile._key_to_float(
+            jnp.asarray(state[1])))))
+    return done
+
+
+def middle_of_three(rng, middle, spread=0.1):
+    """A column of 2,000 rows whose ``middle`` central elements lie in
+    ``[1, 1 + spread)``, the others far outside."""
+    x = np.concatenate([np.full(1000 - middle // 2, -1e30),
+                        1 + rng.random(middle) * spread,
+                        np.full(1000 - (middle + 1) // 2, 1e30)])
+    return rng.permutation(x).astype(np.float32)[:, None]
+
+
+def finishing_case(name, rng):
+    """``(x, ranks, brackets, blocks, closes)``: a table, the ranks
+    wanted, proven brackets around them, the blocks a shard, and whether
+    the pass must close every bracket on one device (None: the oracle
+    says)."""
+    if name in ("smooth-1", "smooth-3", "smooth-9"):
+        x = rng.standard_normal((3001, 5)).astype(np.float32)
+        ranks = quantile.select_ranks(PROBS[int(name[-1])], len(x))
+        return x, ranks, brackets_around(x, ranks, 3, 4), 64, None
+    if name == "ties":
+        x = np.floor(rng.random((3000, 4)) * 400).astype(np.float32) + 1000
+        ranks = quantile.select_ranks(PROBS[3], len(x))
+        return x, ranks, brackets_around(x, ranks, 8, 8), 512, True
+    if name in ("rank-first", "rank-last"):
+        x = rng.random((2500, 3), dtype=np.float32)
+        ranks = quantile.select_ranks(PROBS[3], len(x))
+        around = (0, 30) if name == "rank-first" else (30, 0)
+        return x, ranks, brackets_around(x, ranks, *around), 4096, True
+    if name in ("held-at-the-gate", "held-one-over"):
+        x = rng.random((40_000, 2), dtype=np.float32)
+        ranks = quantile.select_ranks(PROBS[3], len(x))
+        above = quantile.FINISH_HELD - 200 - (name == "held-at-the-gate")
+        found = brackets_around(x, ranks, 200, above)
+        assert (found[3] - found[2] == quantile.FINISH_HELD
+                + (name == "held-one-over")).all()
+        return x, ranks, found, 32768, name == "held-at-the-gate"
+    if name in ("keys-at-the-bound", "keys-one-over", "room-at-the-edge",
+                "room-one-over"):
+        # the most keys a bracket may span (eight elements in them: the
+        # count beside a sum of 28 bits has four), and the most elements
+        # 2,000,000 keys leave room for (a sum of 23 bits, a count of
+        # nine: four thirds of 383 are under 512)
+        keys, middle, spread = {
+            "keys-at-the-bound": (quantile.FINISH_KEYS, 8, 500.0),
+            "keys-one-over": (quantile.FINISH_KEYS + 1, 8, 500.0),
+            "room-at-the-edge": (2_000_000, 383, 0.23),
+            "room-one-over": (2_000_000, 384, 0.23)}[name]
+        x = middle_of_three(rng, middle, spread)
+        ranks = np.asarray([1000 - middle // 4, 999, 1000 + middle // 4],
+                           np.int32)
+        lo = np.full((3, 1), ukeys(np.float32(1.0)), np.uint32)
+        hi = lo + np.uint32(keys)
+        at = ukeys(x)
+        found = (lo, hi, (at[None] < lo[:, None]).sum(1).astype(np.int32),
+                 (at[None] <= hi[:, None]).sum(1).astype(np.int32))
+        assert (found[3] - found[2] == middle).all()
+        return x, ranks, found, 32768, name in ("keys-at-the-bound",
+                                                "room-at-the-edge")
+    if name == "one-block":
+        # the elements of every bracket 64 rows apart: all in one block
+        x = middle_of_three(rng, 0)
+        x[5:5 + 64 * 5:64, 0] = 1 + np.arange(5, dtype=np.float32) / 8
+        ranks = np.asarray([np.sort(x[:, 0]).tolist().index(1.25)], np.int32)
+        return x, ranks, brackets_around(x, ranks, 2, 2), 64, False
+    tiny = np.arange(1, 1001, dtype=np.uint32)       # denormals: keys apart
+    if name == "signed-zeros":
+        x = np.concatenate([tiny | np.uint32(quantile._TOP),
+                            [quantile._TOP] * 6, [0] * 5, tiny])
+        x = rng.permutation(x.astype(np.uint32)).view(np.float32)[:, None]
+        ranks = np.asarray([999, 1005, 1006, 1010, 1011], np.int32)
+        return x, ranks, brackets_around(x, ranks, 4, 4), 4096, True
+    if name == "nan-payloads":
+        # positive payloads sort over +inf, negative ones under -inf
+        x = np.concatenate([
+            rng.standard_normal(900).astype(np.float32).view(np.uint32),
+            np.float32([np.inf, -np.inf]).view(np.uint32),
+            0x7FC00000 + np.asarray([0, 1, 2, 5, 9, 9, 17, 40]),
+            0xFFC00000 + np.asarray([0, 3, 3, 4, 11, 30])])
+        x = rng.permutation(x.astype(np.uint32)).view(np.float32)[:, None]
+        ranks = np.asarray([1, 2, 4, 910, 912, 914], np.int32)
+        return x, ranks, brackets_around(x, ranks, 1, 1), 4096, True
+    if name == "padding":
+        # 1,001 rows over four shards: three rows of zero padding, and
+        # brackets that hold +0.0
+        x = np.concatenate([tiny[:498] | np.uint32(quantile._TOP), [0] * 4,
+                            tiny[:499]])
+        x = rng.permutation(x.astype(np.uint32)).view(np.float32)[:, None]
+        ranks = np.asarray([497, 500, 502], np.int32)
+        return x, ranks, brackets_around(x, ranks, 3, 3), 4096, True
+    raise KeyError(name)
+
+
+FINISHING = ["smooth-1", "smooth-3", "smooth-9", "ties", "rank-first",
+             "rank-last", "held-at-the-gate", "held-one-over",
+             "keys-at-the-bound", "keys-one-over", "room-at-the-edge",
+             "room-one-over", "one-block",
+             "signed-zeros", "nan-payloads", "padding"]
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("name", FINISHING)
+def test_the_finishing_pass_takes_out_what_a_sort_finds(name, devices,
+                                                        monkeypatch):
+    x, ranks, brackets, blocks, closes = finishing_case(
+        name, np.random.default_rng(len(name)))
+    monkeypatch.setattr(quantile, "FINISH_BLOCKS", blocks)
+    monkeypatch.setattr(quantile, "FINISH_SLICES", 3)
+    drop_programs()
+    done = check_finish(x, ranks, brackets, devices)
+    if closes is not None and (devices == 1 or name != "one-block"):
+        assert done.all() if closes else not done.any()
+
+
+def test_one_device_and_four_finish_the_same_brackets_bit_for_bit(
+        monkeypatch):
+    x, ranks, brackets, _, _ = finishing_case(
+        "signed-zeros", np.random.default_rng(2))
+    monkeypatch.setattr(quantile, "FINISH_SLICES", 2)
+    drop_programs()
+    one, four = (finish(x, ranks, brackets, devices)
+                 for devices in (1, 4))
+    for a, b in zip(one[0], four[0]):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(bits(one[1].found), bits(four[1].found))
+    assert one[1][1:] == four[1][1:]
+
+
+def test_a_bracket_the_finishing_pass_left_is_not_offered_again():
+    """A mark in the state, so that the driver's loop ends: the report of
+    a program that holds a declined bracket does not ask for the pass."""
+    x, ranks, brackets, _, _ = finishing_case(
+        "rank-first", np.random.default_rng(1))
+    gave_up = np.zeros(brackets[0].shape, bool)
+    gave_up[1] = True
+    state, seen = finish(x, ranks, brackets, 1, gave_up)
+    np.testing.assert_array_equal(state[0][1], brackets[0][1])
+    np.testing.assert_array_equal(state[1][1], brackets[1][1])
+    assert state[7][1].all() and not state[7][[0, 2]].any()
+    assert seen.more and not seen.finish
+    assert seen.declined == gave_up.sum()
+
+
+def test_a_table_whose_neighbours_share_a_block_is_declined_and_still_exact(
+        monkeypatch):
+    """Neighbouring ranks ``FINISH_BLOCKS`` rows apart: every narrow
+    bracket has its elements in one block, the finishing pass declines it,
+    and the counting passes go on from where they stood, to what a sort
+    finds."""
+    monkeypatch.setattr(quantile, "FINISH_BLOCKS", 64)
+    monkeypatch.setattr(tracer, "keep_recent", True)
+    drop_programs()
+    rng = np.random.default_rng(8)
+    by_rank = np.sort(rng.random((64 * 200, 2), dtype=np.float32), axis=0)
+    x = by_rank.reshape(64, 200, 2).transpose(1, 0, 2).reshape(-1, 2)
+    before = metrics.group(ML_GROUP, "select").snapshot()["counters"]
+    got, passes = select(x, PROBS[3], 1)
+    np.testing.assert_array_equal(bits(got), bits(sorted_at(x, PROBS[3])))
+    after = metrics.group(ML_GROUP, "select").snapshot()["counters"]
+    fetches = [r["attrs"] for r in tracer.recent
+               if r["name"] == "select.fetch"]
+    launches = [r["attrs"] for r in tracer.recent
+                if r["name"] == "select.launch"]
+    # made once (by the head, behind its branch), never offered again
+    made = [a for a in fetches if "declined" in a]
+    assert len(made) == 1 and made[0] is fetches[0]
+    assert not any(a.get("finish") for a in launches)
+    asked = made[0]
+    assert asked["declined"] >= 1
+    assert asked["passes"] == quantile.HEAD_PASSES + 1
+    assert after["declined"] - before.get("declined", 0) == asked["declined"]
+    assert after["finished"] - before.get("finished", 0) == asked["finished"]
+    # the counting passes went on after it
+    assert len(launches) > 1
+    assert passes == sum(a["passes"] for a in fetches)
+
+
 def test_the_state_and_the_report_cross_whole():
     rng = np.random.default_rng(5)
     m, d = 3, 7
     u = lambda: jnp.asarray(rng.integers(0, 2**32, (m, d), dtype=np.uint32))
     c = lambda: jnp.asarray(rng.integers(-5, 2**31 - 1, (m, d)), jnp.int32)
-    marks = [jnp.asarray(rng.random((m, d)) < 0.5) for _ in range(2)]
+    marks = [jnp.asarray(rng.random((m, d)) < 0.5) for _ in range(3)]
     state = (u(), u(), c(), c(), jnp.stack([u(), u(), u()]), *marks)
     packed = quantile._pack(*state)
     assert packed.shape == (5 + quantile.PIVOTS, m, d)
@@ -295,11 +590,13 @@ def test_the_state_and_the_report_cross_whole():
         np.testing.assert_array_equal(got, want)
     answers = rng.standard_normal((m, d)).astype(np.float32)
     answers[0, 0], answers[1, 1] = np.nan, -0.0
-    report = np.concatenate([answers.view(np.int32).ravel(), [1, 0, 4]])
-    found, more, ends, made = quantile.read_report(
-        report.astype(np.int32), m)
-    np.testing.assert_array_equal(bits(found), bits(answers))
-    assert (more, ends, made) == (True, False, 4)
+    report = np.concatenate([answers.view(np.int32).ravel(),
+                             [1, 0, 1, 17, 2, 4]])
+    seen = quantile.read_report(report.astype(np.int32), m)
+    np.testing.assert_array_equal(bits(seen.found), bits(answers))
+    assert seen[1:] == (True, False, True, 17, 2, 4)
+    assert (seen.more, seen.ends, seen.finish) == (True, False, True)
+    assert (seen.finished, seen.declined, seen.passes) == (17, 2, 4)
 
 
 def test_ranks_are_numpy_s_lower():
@@ -329,19 +626,27 @@ def inside(eqn):
 
 
 @pytest.mark.parametrize("devices", [1, 4])
-@pytest.mark.parametrize("program", ["head", "step", "step_ends"])
+@pytest.mark.parametrize("program", ["head", "step", "step_ends", "finish"])
 def test_no_loop_or_branch_surrounds_a_read_of_the_table(program, devices):
     """What makes XLA copy the table before it reduces over its rows is
     control flow around the reduction (6.1 GB at 12M x 100: PERF.md section
     6), so every array as long as a shard lies outside every loop and
     branch (the sample's 32 small rounds are the one loop, over the
     sample); there is no sort, and nothing in a program is larger than the
-    table itself (a ``(rows, pivots, d)`` compare would be)."""
+    table itself (a ``(rows, pivots, d)`` compare would be). The finishing
+    pass (its own program, and the head's last step behind a branch) is
+    the one loop over the table, and it is over SLICES of it, which the
+    compiler takes where they lie (``test_lloyd_gate_compiles`` holds the
+    temporaries): the table enters a loop or a branch as the operand of
+    ``dynamic_slice`` alone, no slice is longer than ``FINISH_BLOCKS``
+    rows, nothing of a shard's length is made inside, and no reduction
+    over an array as long as a shard stands inside one."""
     n, d, m = 400_000 * devices, 100, 3
     mesh = create_mesh(devices=jax.devices()[:devices])
     assert n // devices > quantile.SAMPLE_ROWS     # the head's full form
     built = dict(zip(("head", "step", "step_ends"),
-                     quantile.select_programs(mesh, m)))[program]
+                     quantile.select_programs(mesh, m)),
+                 finish=quantile.finish_program(mesh, m))[program]
     operands = [jax.ShapeDtypeStruct((n, d), jnp.float32),
                 jax.ShapeDtypeStruct((m + 1,), jnp.int32)]
     if program != "head":
@@ -349,7 +654,7 @@ def test_no_loop_or_branch_surrounds_a_read_of_the_table(program, devices):
             (5 + quantile.PIVOTS, m, d), jnp.uint32))
     traced = jax.make_jaxpr(built)(*operands)
     local = n // devices
-    reads = 0
+    reads = slices = 0
     for eqn in eqns(traced.jaxpr):
         assert eqn.primitive.name != "sort"
         for var in eqn.outvars:
@@ -358,12 +663,31 @@ def test_no_loop_or_branch_surrounds_a_read_of_the_table(program, devices):
                 eqn.primitive, shape)
         reads += (eqn.primitive.name == "reduce_sum"
                   and local in eqn.invars[0].aval.shape)
-        if eqn.primitive.name in LOOPS:
-            for held in inside(eqn):
-                for var in list(held.invars) + list(held.outvars):
-                    assert local not in getattr(var.aval, "shape", ()), (
-                        eqn.primitive, held.primitive, var.aval)
-    passes = quantile.HEAD_PASSES if program == "head" else 1
+        if eqn.primitive.name not in LOOPS:
+            continue
+        for held in inside(eqn):
+            # (a loop or a branch inside hands the table on: what it holds
+            # is looked at in its turn)
+            taken = held.primitive.name in ("dynamic_slice", "slice")
+            handed = len(held.invars) if held.primitive.name in LOOPS else (
+                int(taken))
+            slices += (held.primitive.name == "dynamic_slice"
+                       and eqn.primitive.name != "cond"
+                       and local in held.invars[0].aval.shape)
+            for var in list(held.invars[handed:]) + list(held.outvars):
+                shape = getattr(var.aval, "shape", ())
+                assert local not in shape, (eqn.primitive, held.primitive,
+                                            var.aval)
+                assert not taken or max(shape, default=0) <= max(
+                    quantile.FINISH_BLOCKS, d), (held.primitive, var.aval)
+            assert not (held.primitive.name.startswith("reduce")
+                        and local in held.invars[0].aval.shape)
+    # the finishing pass, in its own program and behind the head's branch:
+    # its slices a group of brackets, and no reduction over the table
+    finishes = program in ("head", "finish")
+    assert slices == finishes * quantile.FINISH_SLICES * -(
+        -m // quantile.FINISH_GROUP)
+    passes = {"head": quantile.HEAD_PASSES, "finish": 0}.get(program, 1)
     assert reads == passes * quantile.PIVOTS * m    # one sum a pivot
 
 
@@ -392,15 +716,20 @@ def test_host_path_and_device_path_give_the_same_model(name, devices):
 
 
 @pytest.mark.parametrize("devices", [1, 4])
-@pytest.mark.parametrize("name", ["ragged", "arity-20"])
+@pytest.mark.parametrize("name", ["ragged", "arity-20", "long"])
 def test_a_warm_fit_builds_nothing(name, devices, monkeypatch):
     on_mesh(devices)
     watch = Watch(monkeypatch, module=quantile, events=BUILDS)
     table = device_table(column(name, np.random.default_rng(5)))
+    closed = lambda: metrics.group(ML_GROUP, "select").snapshot()[
+        "counters"].get("finished", 0)
     first = RobustScaler().fit(table)
+    before = closed()
     with watch():
         again = RobustScaler().fit(table)
     watch.armed = False
+    # (the fit that was watched ran the finishing program too)
+    assert name != "long" or closed() > before
     assert watch.jits == [] and watch.requests == 0
     # nothing is placed but the input (a ragged table is padded by a cached
     # program in place of a put): the ranks and the row count were placed
@@ -411,12 +740,15 @@ def test_a_warm_fit_builds_nothing(name, devices, monkeypatch):
     np.testing.assert_array_equal(again.ranges, first.ranges)
 
 
-#: what a process's first device-path fit may build (today: the head and
-#: the step, 61,000 + 41,000 characters of lowered text at d 7 with the
-#: head's passes at 4: 153,000 + 41,000; PR 35's one program was 166,000
-#: and its kernel's lowering took 1.6 s of every process's first fit)
-FIRST_FIT_PROGRAMS = 4
-FIRST_FIT_LOWERED_CHARS = 260_000
+#: what a process's first device-path fit may build (today five ``jit``s:
+#: the pass, the head, the step, the step with the ends, the finishing
+#: program; head, step and finishing program lower to 173,000 + 47,000 +
+#: 82,000 characters of text at d 7 (the head: three counting passes and
+#: the finishing pass behind its branch; 61,000 with one pass and no
+#: finishing pass, 153,000 with four); PR 35's one program was 166,000 and
+#: its kernel's lowering took 1.6 s of every process's first fit)
+FIRST_FIT_PROGRAMS = 5
+FIRST_FIT_LOWERED_CHARS = 330_000
 
 FIRST_FIT = """
 import json, sys
@@ -443,11 +775,16 @@ est = RobustScaler()
 model = est.fit(Table.from_columns(input=x))
 fit_made = made[before:]
 head, step, _ = quantile.select_programs(mesh, 3)
+finish = quantile.finish_program(mesh, 3)
 spec = quantile._spec_on_mesh(mesh, n, (0.25, 0.5, 0.75))
 state, _ = head(x, spec)
 chars = (len(head.lower(x, spec).as_text())
-         + len(step.lower(x, spec, state).as_text()))
+         + len(step.lower(x, spec, state).as_text())
+         + len(finish.lower(x, spec, state).as_text()))
+from flink_ml_tpu.common.metrics import ML_GROUP, metrics
 print(json.dumps({
+    "finished": metrics.group(ML_GROUP, "select").snapshot()["counters"][
+        "finished"],
     "path": est.last_execution_path,
     "pallas": "jax.experimental.pallas" in sys.modules,
     "jits": len(fit_made), "chars": chars,
@@ -471,6 +808,7 @@ def test_a_process_s_first_fit_stays_inside_its_budget():
     assert done.returncode == 0, done.stderr[-2000:]
     seen = json.loads(done.stdout.strip().splitlines()[-1])
     assert seen["path"] == "select-device" and seen["exact"]
+    assert seen["finished"] > 0         # the finishing program ran in it
     assert seen["pallas"] is False
     assert 1 <= seen["jits"] <= FIRST_FIT_PROGRAMS
     assert 50_000 < seen["chars"] <= FIRST_FIT_LOWERED_CHARS
@@ -525,12 +863,20 @@ def test_the_spans_of_a_fit_are_one_tree_under_its_root(devices, path,
         "select.launch", "select.fetch"] * len(launches)
     assert launches[0]["attrs"] == {"path": "select-device", "rows": 1001,
                                     "d": 7, "probs": [0.1, 0.5, 0.9]}
-    assert all(set(r["attrs"]) == {"ends"} for r in launches[1:])
-    assert all(set(r["attrs"]) == {"passes"} for r in fetches)
+    assert all(set(r["attrs"]) == {"ends", "finish"} for r in launches[1:])
+    # every read says the passes it waited for; the read of a program
+    # that made a finishing pass (the head may, behind its branch) also
+    # what that closed and what it left open
+    finishing = [bool(r["attrs"].get("finished", 0)
+                      + r["attrs"].get("declined", 0)) for r in fetches]
+    assert [set(r["attrs"]) for r in fetches] == [
+        {"passes", "finished", "declined"} if f else {"passes"}
+        for f in finishing]
+    assert finishing[1:] == [r["attrs"]["finish"] for r in launches[1:]]
     made = [r["attrs"]["passes"] for r in fetches]
     # (over four devices a shard of these 1,001 rows is its own sample)
-    assert made == [quantile.HEAD_PASSES if devices == 1 else 1] + [1] * (
-        len(fetches) - 1)
+    assert made == [quantile.HEAD_PASSES + finishing[0] if devices == 1
+                    else 1] + [1] * (len(fetches) - 1)
     after = {g: metrics.group(ML_GROUP, g).snapshot()["counters"]
              for g in groups}
     moved = {g: {k: v - before[g].get(k, 0) for k, v in after[g].items()}
@@ -539,6 +885,10 @@ def test_the_spans_of_a_fit_are_one_tree_under_its_root(devices, path,
     assert [moved["iteration"][name] for name in
             ("boundaryFetches", "boundaryWaits")] == [len(fetches)] * 2
     assert moved["select"]["passes"] == sum(made) <= 10
+    for count in ("finished", "declined"):
+        assert moved["select"][count] == sum(
+            r["attrs"].get(count, 0) for r in fetches)
+    assert moved["select"]["finished"] <= 3 * 7
     state = metrics.group(ML_GROUP, "update").snapshot()["gauges"]
     assert any("RobustScaler" in key and value == 3 * 7 * 4
                for key, value in state.items()), state
