@@ -29,15 +29,26 @@ Layers (bottom-up, see SURVEY.md §7):
 
 __version__ = "0.1.0"
 
-from flink_ml_tpu.api import (  # noqa: F401
-    AlgoOperator,
-    Estimator,
-    Model,
-    Stage,
-    Transformer,
-)
-from flink_ml_tpu.common.table import Table  # noqa: F401
-from flink_ml_tpu.common.functions import (  # noqa: F401
-    array_to_vector,
-    vector_to_array,
-)
+# a process's cold start, accounted from inside (docs/observability.md
+# "Cold spans"): the package's import and its two heavy third parties,
+# stamped before a tracer can exist and adopted by it when it is built
+from flink_ml_tpu._cold import importing as _importing
+
+with _importing("flink_ml_tpu"):
+    with _importing("numpy"):
+        import numpy  # noqa: F401
+    with _importing("jax"):
+        import jax  # noqa: F401
+
+    from flink_ml_tpu.api import (  # noqa: F401
+        AlgoOperator,
+        Estimator,
+        Model,
+        Stage,
+        Transformer,
+    )
+    from flink_ml_tpu.common.table import Table  # noqa: F401
+    from flink_ml_tpu.common.functions import (  # noqa: F401
+        array_to_vector,
+        vector_to_array,
+    )

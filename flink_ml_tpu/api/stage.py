@@ -24,6 +24,28 @@ from flink_ml_tpu.params.param import WithParams
 from flink_ml_tpu.utils import io as rw
 
 
+#: the stage classes whose first fit of this process has begun: the one
+#: look-up a warm fit pays for the cold spans
+_FIRST_FITS: set = set()
+
+
+def _first_fit(wrapper, stage, args, kwargs):
+    """The first ``fit`` of a stage class in this process, under the cold
+    root ``first_fit`` (observability/tracing.py: recorded whether or not
+    anybody is looking): the imports and program builds that fall inside
+    it are its children, and what jax traced, lowered and compiled
+    meanwhile lands in its attributes through ``compilestats``' listener,
+    subscribed here and not at package import."""
+    from flink_ml_tpu.observability import compilestats, tracing
+
+    cls = type(stage)
+    _FIRST_FITS.add(cls)
+    compilestats.watch_cold()
+    with tracing.tracer.cold_span("first_fit", kind="first_fit",
+                                  stage=cls.__name__):
+        return wrapper(stage, *args, **kwargs)
+
+
 def _profiled(method, kind: str):
     """Wrap a fit/transform implementation with the observability hooks
     (SURVEY.md §5: run visibility is the reference's gap we close).
@@ -43,10 +65,17 @@ def _profiled(method, kind: str):
     stage call, and a device-memory watermark sampled as the ROOT span
     closes (no-op on CPU) — so peak HBM per fit is on the root span
     itself. Under a capture or the ring alone none of these run: such a
-    fit is perturbed by its spans only."""
+    fit is perturbed by its spans only.
+
+    Whatever is armed, a class's first ``fit`` of the process runs under
+    the cold root ``first_fit`` (:func:`_first_fit`); every later one
+    pays one set look-up for it and nothing else."""
+    is_fit = kind == "fit"
 
     @functools.wraps(method)
     def wrapper(self, *args, **kwargs):
+        if is_fit and type(self) not in _FIRST_FITS:
+            return _first_fit(wrapper, self, args, kwargs)
         from flink_ml_tpu.common.metrics import PROFILE_DIR_ENV, profile
         from flink_ml_tpu.observability import (
             compilestats,

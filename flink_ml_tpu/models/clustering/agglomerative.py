@@ -13,7 +13,11 @@ from __future__ import annotations
 from typing import Tuple
 
 import numpy as np
-from scipy.cluster import hierarchy
+
+from flink_ml_tpu._cold import importing
+
+with importing("scipy.cluster"):  # most of a second of a cold start
+    from scipy.cluster import hierarchy
 
 from flink_ml_tpu.api.stage import AlgoOperator
 from flink_ml_tpu.common.table import Table

@@ -53,7 +53,7 @@ from flink_ml_tpu.params.shared import (
     HasSeed,
 )
 from flink_ml_tpu.models.common import IterationRuntimeMixin
-from flink_ml_tpu.observability.tracing import tracer
+from flink_ml_tpu.observability.tracing import cold_build, tracer
 from flink_ml_tpu.utils import io as rw
 
 
@@ -163,6 +163,7 @@ def _lloyd_round_math(measure, axes, partials_fn=None,
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("lloyd")
 def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
                          unroll: bool = False, use_kernel: bool = False,
                          health: bool = False, sharded: bool = False):
@@ -242,6 +243,7 @@ def _build_lloyd_program(mesh, measure_name: str, max_iter: int,
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("init_rows")
 def _build_init_rows_program(mesh, k: int):
     """``rows(xs, index) -> (k, d)`` replicated: the chosen rows of the
     row-sharded column, each taken by the shard that holds it and summed
@@ -451,11 +453,8 @@ class KMeans(Estimator, KMeansParams, IterationRuntimeMixin):
                 ("pallas-lloyd" if use_kernel else "xla-lloyd")
                 + ("-segments" if seg else ""))
 
-        # what the kernels' float32 products are made of (pallas_kernels.
-        # _split3): said where the path is said
-        products = {"products": "split3"} if use_kernel else {}
         with tracer.span("lloyd.init", rounds=self.max_iter, k=k,
-                         path=path, **products):
+                         path=path):
             # init: k distinct random input points (ref
             # selectRandomCentroids); fewer points than clusters repeat
             # cyclically. The indices, the zero counts and the row count

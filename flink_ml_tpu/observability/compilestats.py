@@ -77,6 +77,20 @@ def _channel_tail(channel: str) -> str:
     return tail
 
 
+#: what jax did while a cold span was open (docs/observability.md "Cold
+#: spans"): monitoring phase -> (seconds attribute, count attribute) on
+#: the innermost cold span of the thread the channel fired on.
+#: ``cache_load_s`` lies inside ``compile_s`` (jax times a compile request
+#: around the persistent cache's look-up): beside it, never added to it
+_COLD_PHASES = {
+    "jaxpr_trace": ("trace_s", "traces"),
+    "jaxpr_to_mlir_module": ("lower_s", None),
+    "backend_compile": ("compile_s", "compiles"),
+    "cache_retrieval_time_sec": ("cache_load_s", None),
+}
+_COLD_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
 def _backend_ready() -> bool:
     """True when jax is imported AND a backend is already live — the
     guard that keeps telemetry from *initializing* a backend (a chip
@@ -112,27 +126,43 @@ class CompileStats:
         self._window_depth = 0
         self._storm_fired: Set[str] = set()
         self._memory_unavailable = False
+        # per thread: the (start, seconds) regions the cold listener has
+        # counted, outermost last (see _note_cold)
+        self._cold_tls = threading.local()
 
     # -- jax.monitoring subscription -----------------------------------------
     def install(self) -> bool:
         """Subscribe to the jax.monitoring compile channels (idempotent —
-        every traced fit calls this). Returns True when the channels are
-        available and subscribed; False on jax builds without them (the
-        per-function instrumentation still works there)."""
+        every traced fit calls this) and record them in the registry.
+        Returns True when the channels are available and subscribed;
+        False on jax builds without them (the per-function
+        instrumentation still works there)."""
         with self._lock:
             self._enabled = True
-            if self._installed:
-                return True
-            try:
-                from jax import monitoring
-                register_dur = monitoring.register_event_duration_secs_listener
-                register_ev = monitoring.register_event_listener
-            except (ImportError, AttributeError):
-                return False
-            register_dur(self._on_duration)
-            register_ev(self._on_event)
-            self._installed = True
+            return self._subscribe()
+
+    def watch_cold(self) -> bool:
+        """Subscribe the same ONE listener without arming the registry:
+        what the channels report while a cold span is open lands in that
+        span's attributes (:data:`_COLD_PHASES`) and nowhere else. Called
+        as a process's first ``first_fit`` opens (api/stage.py); a warm
+        fit fires no channel, so the listener then costs nothing."""
+        with self._lock:
+            return self._subscribe()
+
+    def _subscribe(self) -> bool:
+        if self._installed:
             return True
+        try:
+            from jax import monitoring
+            register_dur = monitoring.register_event_duration_secs_listener
+            register_ev = monitoring.register_event_listener
+        except (ImportError, AttributeError):
+            return False
+        register_dur(self._on_duration)
+        register_ev(self._on_event)
+        self._installed = True
+        return True
 
     def uninstall(self) -> None:
         """Disarm the monitoring listeners. jax has no public
@@ -141,6 +171,9 @@ class CompileStats:
             self._enabled = False
 
     def _on_duration(self, event: str, duration_secs: float, **kw) -> None:
+        cold = tracing.tracer.cold_current()
+        if cold is not None:
+            self._note_cold(cold.attrs, event, float(duration_secs))
         if not self._enabled:  # jaxlint: disable=unguarded-shared-state -- lock-free bool fast path on the per-compile listener; a stale read delays disarm by one event
             return
         try:
@@ -155,7 +188,37 @@ class CompileStats:
         except Exception:  # a telemetry listener must never sink a compile
             pass
 
+    def _note_cold(self, attrs: dict, event: str, secs: float) -> None:
+        """Add one monitoring duration to the open cold span's attributes
+        (:data:`_COLD_PHASES`). jax reports a trace inside a trace (a
+        jitted function called while another is traced) once alone and
+        once within the outer one's seconds; the listener is called as a
+        region ends, so the region is ``[now - secs, now]``, and what
+        earlier regions of this thread already cover of it is taken off:
+        ``trace_s + lower_s + compile_s`` over a span tree is time that
+        passed once, never more than the tree's root."""
+        named = _COLD_PHASES.get(_channel_tail(event))
+        if named is None:
+            return
+        if named[0] != "cache_load_s":
+            start = time.perf_counter() - secs
+            cover = getattr(self._cold_tls, "cover", None)
+            if cover is None:
+                cover = self._cold_tls.cover = []
+            inner = 0.0
+            while cover and cover[-1][0] >= start:
+                inner += cover.pop()[1]
+            cover.append((start, secs))
+            secs = max(0.0, secs - inner)
+        attrs[named[0]] = attrs.get(named[0], 0.0) + secs
+        if named[1] is not None:
+            attrs[named[1]] = attrs.get(named[1], 0) + 1
+
     def _on_event(self, event: str, **kw) -> None:
+        if event == _COLD_CACHE_HIT:
+            cold = tracing.tracer.cold_current()
+            if cold is not None:
+                cold.attrs["cache_hits"] = cold.attrs.get("cache_hits", 0) + 1
         if not self._enabled:  # jaxlint: disable=unguarded-shared-state -- lock-free bool fast path on the per-compile listener; a stale read delays disarm by one event
             return
         try:
@@ -239,6 +302,11 @@ compile_stats = CompileStats()
 def install() -> bool:
     """Module-level convenience: :meth:`CompileStats.install`."""
     return compile_stats.install()
+
+
+def watch_cold() -> bool:
+    """Module-level convenience: :meth:`CompileStats.watch_cold`."""
+    return compile_stats.watch_cold()
 
 
 def uninstall() -> None:

@@ -55,7 +55,10 @@ route             serves                                      response with no d
                   closed spans (tracing.RECENT_SPANS;
                   arming the endpoint flips
                   ``tracer.keep_recent`` so request-scoped
-                  spans exist even without a trace dir)
+                  spans exist even without a trace dir),
+                  and under ``"cold"`` the process's cold
+                  spans (``tracer.cold``: imports, first
+                  fits, program builds), always recorded
 ``/fleet``        the live fleet report                        200 ``{"fleet": null}`` —
                   (observability/fleet.py): membership with    no fleet dir resolves, or
                   alive/stale/dead classification, bin-exact   no member wrote a beacon
@@ -357,8 +360,12 @@ class _Handler(BaseHTTPRequestHandler):
                 break
             except RuntimeError:
                 continue
-        self._send(200, json.dumps({"spans": spans},
-                                   default=str), _JSON_CTYPE)
+        # the cold spans beside them (tracing.Tracer.cold: a plain list
+        # that only ever grows, so a copy is safe): the process's cold
+        # start, whenever the endpoint was armed
+        self._send(200, json.dumps(
+            {"spans": spans, "cold": list(tracing.tracer.cold)},
+            default=str), _JSON_CTYPE)
 
     def _route_fleet(self) -> None:
         from flink_ml_tpu.observability import fleet

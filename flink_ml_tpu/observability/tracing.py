@@ -49,11 +49,26 @@ With none of the three, ``span`` returns a shared no-op context manager
 — one profiler-flag read and one environment look-up — so the
 instrumentation stays compiled into production paths, same policy as
 resilience.faults.
+
+A third kind of arming: cold. :meth:`Tracer.cold_span` opens a span that
+is recorded whether or not anybody is looking, because it sits only where
+code runs once a process — an import, the first fit of a stage class, a
+program builder's body behind its ``lru_cache`` — so a process's cold
+start is accounted from inside. A cold span is a :class:`Span` like any
+other (same record, ids, parent links and ``TraceAnnotation``) kept in
+the bounded list :attr:`Tracer.cold`; it reaches the ring only when the
+tracer is active anyway and the span file only where a trace dir is set.
+Cold spans nest among themselves, on a stack of their own: an armed
+fit's tree is what it was, and ``tracer.cold`` resolves every parent it
+names. What ran before this module could be imported (``jax`` itself) is
+stamped by the stdlib-only ``flink_ml_tpu/_cold.py`` and adopted here
+when the tracer is built.
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import json
 import os
@@ -62,6 +77,8 @@ import time
 from typing import Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
+
+from flink_ml_tpu import _cold
 
 #: env var holding a directory; when set, instrumented seams emit spans
 #: as ``spans-<pid>.jsonl`` files there (docs/observability.md)
@@ -85,6 +102,13 @@ RECENT_SPANS = 2048
 #: evidence, more resident memory); read once per Tracer construction /
 #: ``reseed_child``
 RING_ENV = "FLINK_ML_TPU_TRACE_RING"
+
+
+#: capacity of the cold-span list (:attr:`Tracer.cold`): a process's
+#: imports, first fits and program builds are a few dozen records; past
+#: the bound a record is counted (:attr:`Tracer.cold_dropped`) and let go,
+#: so the list keeps the oldest — set-up's
+COLD_SPANS = 256
 
 
 def ring_capacity() -> int:
@@ -254,13 +278,15 @@ class _ActiveSpan:
     the ``.xplane.pb`` of whatever capture is running (free when none
     is)."""
 
-    __slots__ = ("_tracer", "span", "_stack", "_annotation")
+    __slots__ = ("_tracer", "span", "_stack", "_annotation", "_cold")
 
-    def __init__(self, tracer: "Tracer", span: Span, stack: List[Span]):
+    def __init__(self, tracer: "Tracer", span: Span, stack: List[Span],
+                 cold: bool = False):
         self._tracer = tracer
         self.span = span
         self._stack = stack  # the opening thread's: where the span sits
         self._annotation = TraceAnnotation(span.name)
+        self._cold = cold
 
     def __enter__(self):
         self._annotation.__enter__()
@@ -270,7 +296,7 @@ class _ActiveSpan:
         if exc_type is not None:
             self.span.set_attribute("error", exc_type.__name__)
         self._annotation.__exit__(exc_type, exc, tb)
-        self._tracer._end(self.span, self._stack)
+        self._tracer._end(self.span, self._stack, self._cold)
         return False
 
 
@@ -302,6 +328,15 @@ class Tracer:
         #: per span)
         self.dropped_spans = 0
         self._drop_mirrored = 0
+        #: the cold spans' records, oldest first (see the module doc):
+        #: bounded by :data:`COLD_SPANS`, the overflow counted and never
+        #: raised; what ``benchmarks/harness/cold_spans.py`` reads
+        self.cold: List[dict] = []
+        self.cold_dropped = 0
+        # adopted stamps owed to the span file: written with the next
+        # record (the sink's imports cannot run while this module is
+        # itself being imported)
+        self._cold_unwritten: List[dict] = []
 
     # -- arming --------------------------------------------------------------
     @property
@@ -415,6 +450,54 @@ class Tracer:
         stack.append(sp)
         return _ActiveSpan(self, sp, stack)
 
+    # -- cold spans ----------------------------------------------------------
+    def _cold_stack(self) -> List[Span]:
+        stack = getattr(self._tls, "cold_stack", None)
+        if stack is None:
+            stack = self._tls.cold_stack = []
+        return stack
+
+    def cold_current(self) -> Optional[Span]:
+        """The innermost cold span open on this thread, or None — where
+        ``compilestats``' listener adds what jax did meanwhile."""
+        stack = self._cold_stack()
+        return stack[-1] if stack else None
+
+    def cold_span(self, name: str, **attrs):
+        """Open a span that is recorded whether or not the tracer is
+        active (see the module doc) — ONLY at a site that runs once a
+        process. Its parent is the innermost open cold span of this
+        thread (or the import stamp still open above it), else it is a
+        root as :meth:`span`'s are; its times hang off ``_cold``'s one
+        clock anchor, so a child lies inside its parent to the
+        microsecond."""
+        stack = self._cold_stack()
+        above = ((stack[-1].trace_id, stack[-1].span_id) if stack
+                 else _cold.innermost_open())
+        if above is None:
+            remote = self._remote_parent or self._env_parent()
+            above = ((remote.trace_id, remote.span_id) if remote is not None
+                     else (_new_id(), None))
+        sp = Span(name, above[0], _new_id(), above[1], attrs)
+        sp.ts_us = _cold.wall_us(sp._t0)
+        stack.append(sp)
+        return _ActiveSpan(self, sp, stack, cold=True)
+
+    def adopt_cold(self, records: List[dict]) -> None:
+        """Take finished import stamps (``_cold.importing``: what ran
+        before this tracer existed) as cold records."""
+        for record in records:
+            self._keep_cold(record)
+        if self.trace_dir:
+            with self._sink_lock:
+                self._cold_unwritten.extend(records)
+
+    def _keep_cold(self, record: dict) -> None:
+        if len(self.cold) < COLD_SPANS:
+            self.cold.append(record)
+        else:
+            self.cold_dropped += 1
+
     def event(self, name: str, **attrs) -> None:
         """Record an instant event on the current span; with no span
         open, emit a standalone zero-duration span carrying it — the
@@ -429,8 +512,11 @@ class Tracer:
         with self.span(f"event:{name}") as sp:
             sp.add_event(name, **attrs)
 
-    def _end(self, sp: Span, stack: List[Span]) -> None:
-        sp.finish()
+    def _end(self, sp: Span, stack: List[Span], cold: bool = False) -> None:
+        if cold:
+            sp.dur_us = _cold.wall_us(time.perf_counter_ns()) - sp.ts_us
+        else:
+            sp.finish()
         if stack and stack[-1] is sp:
             stack.pop()
         else:  # out-of-order exit: drop it from wherever it sits
@@ -439,6 +525,10 @@ class Tracer:
             except ValueError:
                 pass
         record = sp.to_record(os.getpid(), threading.get_ident())
+        if cold:
+            self._keep_cold(record)
+            if not self.active:  # nobody looking: the cold list alone
+                return
         # the ring fills whenever spans are recorded at all (not just
         # under keep_recent): it is the flight recorder's evidence of
         # "what ran before the incident", which must exist BEFORE the
@@ -500,6 +590,12 @@ class Tracer:
             record["process"] = proc
         line = json.dumps(record, default=str) + "\n"
         with self._sink_lock:
+            if self._cold_unwritten:
+                owed, self._cold_unwritten = self._cold_unwritten, []
+                if proc is not None:
+                    owed = [dict(r, process=proc) for r in owed]
+                line = "".join(json.dumps(r, default=str) + "\n"
+                               for r in owed) + line
             if self._sink is not None and self._sink_pid != os.getpid():
                 # forked child inherited the parent's handle: abandon it
                 # (closing could flush into the parent's file)
@@ -550,10 +646,15 @@ class Tracer:
         self.recent = collections.deque(maxlen=ring_capacity())
         self.dropped_spans = 0
         self._drop_mirrored = 0
+        # the cold start was the parent's, and is in the parent's list
+        self.cold = []
+        self.cold_dropped = 0
+        self._cold_unwritten = []  # jaxlint: disable=unguarded-shared-state -- single-threaded post-fork; the guard itself is stale and replaced above
 
 
-#: default process-wide tracer
+#: default process-wide tracer; what ran before it could exist is adopted
 tracer = Tracer()
+tracer.adopt_cold(_cold.take_pending())
 
 
 def span(name: str, **attrs):
@@ -564,6 +665,21 @@ def span(name: str, **attrs):
 def event(name: str, **attrs) -> None:
     """Module-level convenience: ``tracer.event`` on the default tracer."""
     tracer.event(name, **attrs)
+
+
+def cold_build(program: str):
+    """Decorator for a program builder UNDER its ``lru_cache``: the body
+    runs as the cold span ``build:<program>``, and a cache hit, which
+    never enters the body, opens nothing."""
+    def decorate(builder):
+        @functools.wraps(builder)
+        def build(*args, **kwargs):
+            with tracer.cold_span(f"build:{program}", kind="build"):
+                return builder(*args, **kwargs)
+
+        return build
+
+    return decorate
 
 
 def current_context() -> Optional[TraceContext]:

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from flink_ml_tpu.observability.tracing import cold_build
 from flink_ml_tpu.parallel import mapreduce as mr
 from flink_ml_tpu.parallel.mesh import data_axes, data_pspec
 
@@ -77,6 +78,7 @@ def category_counts_xla(x, y, n_valid, labels: int, values: int,
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("nb_look")
 def look_program(mesh, rows: int = None):
     """``look(xs, ys) -> (4,)`` replicated float32: ``[smallest entry of
     either, largest value, largest label, 1 where every entry is a whole
@@ -103,6 +105,7 @@ def look_program(mesh, rows: int = None):
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("nb_counts")
 def counts_program(mesh, labels: int, values: int, use_kernel: bool):
     """``counts(xs, ys, n_valid) -> (values, labels, d)`` replicated int32
     over the rows ``[0, n_valid)`` of the row-sharded table: each shard

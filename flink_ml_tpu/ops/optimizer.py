@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from flink_ml_tpu.observability import health as _health
-from flink_ml_tpu.observability.tracing import tracer
+from flink_ml_tpu.observability.tracing import cold_build, tracer
 from flink_ml_tpu.ops.losses import LossFunc
 from flink_ml_tpu.ops.regularization import regularize
 from flink_ml_tpu.parallel.mesh import (
@@ -294,6 +294,7 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
 
 
 @functools.lru_cache(maxsize=128)
+@cold_build("sgd_segment")
 def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                health: bool = False,
                                sharded: bool = False,

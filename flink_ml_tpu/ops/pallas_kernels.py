@@ -21,7 +21,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from flink_ml_tpu._cold import importing
+
+# the cold span ``import:pallas``: over a second inside the first fit of a
+# process that takes a kernel, Mosaic's front end with it
+with importing("pallas"):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
 #: row tiles the Lloyd and assign kernels may take, widest first: a fit
 #: at 12M x 100, k 10 on a v5e took 99.6 / 82.1 / 74.5 ms at 1024 / 2048 /
@@ -114,8 +121,6 @@ def _row_tiles(x, centroids, out_specs, prefetch: int = 0):
     lane-dense; ``(tile, d)`` row blocks instead cost a relayout copy of
     the whole table in every fit (6.1 GB at 12M rows). The last tile is
     ragged: nothing is padded."""
-    from jax.experimental.pallas import tpu as pltpu
-
     n, d = x.shape
     k = centroids.shape[0]
     tile = lloyd_tile(k, d) or TILES_N[-1]
@@ -393,8 +398,6 @@ def _counts_kernel(nv_ref, xt_ref, y_ref, out_ref):
 @functools.partial(jax.jit,
                    static_argnames=("labels", "values", "interpret"))
 def _counts_tiles(x, y, n_valid, labels, values, interpret=False):
-    from jax.experimental.pallas import tpu as pltpu
-
     n, d = x.shape
     tile = counts_tile(d, labels, values) or COUNTS_TILES_N[-1]
     slots = values + values % 2

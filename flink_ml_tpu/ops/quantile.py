@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flink_ml_tpu.observability.tracing import tracer
+from flink_ml_tpu.observability.tracing import cold_build, tracer
 
 
 @dataclasses.dataclass
@@ -561,6 +561,7 @@ def _report(state, pad, passes, finished=0, declined=0):
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("select")
 def select_programs(mesh, m: int):
     """``(head, step, step_ends)``: the three programs of a selection of
     ``m`` order statistics a column over ``mesh``, each held (a dictionary
@@ -784,6 +785,7 @@ def select_programs(mesh, m: int):
 
 
 @functools.lru_cache(maxsize=32)
+@cold_build("select_finish")
 def finish_program(mesh, m: int):
     """``finish(xs, spec, state) -> (state, report)``: the finishing pass
     of a selection of ``m`` order statistics a column over ``mesh``, held

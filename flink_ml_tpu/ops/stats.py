@@ -14,7 +14,11 @@ from __future__ import annotations
 from typing import Tuple
 
 import numpy as np
-from scipy import stats as sstats
+
+from flink_ml_tpu._cold import importing
+
+with importing("scipy.stats"):  # a second of a cold start: a cold span
+    from scipy import stats as sstats
 
 Arrays = Tuple[np.ndarray, np.ndarray, np.ndarray]
 

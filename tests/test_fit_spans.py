@@ -95,9 +95,15 @@ def estimator(cls=LogisticRegression):
 
 def one_trace(root_name):
     """The ring's records as ``{name: [record]}``, asserted to be one
-    trace under one root of that name."""
-    records = list(tracer.recent)
+    trace under one root of that name. Where the fit was its class's
+    first of the process its cold spans are in the ring beside it (an
+    active tracer rings them too), a tree of their own in a trace of
+    their own: set aside, the fit's tree is what it was."""
+    cold = {r["id"] for r in tracer.cold}
+    records = [r for r in tracer.recent if r["id"] not in cold]
     assert len({r["trace"] for r in records}) == 1
+    assert all(r["trace"] != records[0]["trace"] for r in tracer.recent
+               if r["id"] in cold)
     roots = [r for r in records if r["parent"] is None]
     assert [r["name"] for r in roots] == [root_name]
     assert roots[0]["attrs"]["kind"] == "fit"
