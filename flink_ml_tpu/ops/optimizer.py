@@ -300,7 +300,8 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                sharded: bool = False,
                                fused: bool = False,
                                weighted: bool = True,
-                               n_valid: Optional[int] = None):
+                               n_valid: Optional[int] = None,
+                               fresh: bool = False):
     """A K-round slice of the training loop as ONE compiled SPMD program:
     ``segment(xs, ys, ws, coeffs, offsets, opt, epoch0, limit, hist,
     fin) -> (coeffs, offsets, opt, mean_loss, epoch, stop, hist, fin)``.
@@ -318,8 +319,17 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     (arXiv:2004.13336 — the 1/N optimizer memory).
 
     The plain (uncheckpointed) fit is the degenerate call
-    ``segment(..., epoch0=0, limit=max_iter)`` — ONE program serves both,
-    so the two paths cannot drift numerically.
+    ``segment(..., epoch0=0, limit=max_iter)`` from a carry of zeros, and
+    with ``fresh`` the program makes that start itself:
+    ``segment(xs, ys, ws, coeffs)``, same outputs. The offsets, the
+    moments (zeros of the local shape their ``opt_specs`` say), adam's
+    step and the two bounds are built inside, before the same ``run``, so
+    the caller hands over the one thing that carries information — the
+    coefficients, as the host array they are — and places nothing. A
+    carry that crosses the host (a checkpointed fit's, a restore's) keeps
+    the form above; both forms wrap ONE loop, so the two paths cannot
+    drift numerically. ``fresh`` is for health-off builds: a health-armed
+    fit keeps its ``hist``/``fin`` operands.
 
     Without ``weighted`` the fit has no weight column: ``ws`` is ``None``,
     the program takes no weight operand and a row's weight is its
@@ -409,11 +419,28 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
         extra_in, extra_out = (), ()
         donate = (3, 4, 5)
 
+    carry_in = (P(spec0), opt_specs, P(), P()) + extra_in
+    if fresh:
+        if health:
+            raise ValueError("a health-armed fit starts from its carry")
+        from_carry = sgd_segment
+
+        def sgd_segment(xl, yl, wl, coeffs):  # noqa: F811 — the one name
+            local = coeffs.shape[0] // p if sharded else coeffs.shape[0]
+            opt = tuple(jnp.zeros((local,), coeffs.dtype)
+                        for _ in range(_OPT_VECTORS[prm.method]))
+            if prm.method == "adam":
+                opt = opt + (jnp.zeros((), coeffs.dtype),)
+            return from_carry(xl, yl, wl, coeffs, jnp.zeros((1,), jnp.int32),
+                              opt, jnp.int32(0), jnp.int32(prm.max_iter))
+
+        carry_in, donate = (), (3,)
+
     scalar_out = (P(),) if fused else (P(), P())
     return mr.map_shards(
         sgd_segment, mesh,
-        in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
-                  P(spec0), opt_specs, P(), P()) + extra_in,
+        in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec)
+        + carry_in,
         out_specs=(wspec, P(spec0), opt_specs, P()) + scalar_out
         + extra_out,
         donate_argnums=donate,
@@ -649,7 +676,17 @@ class SGD:
         the host crosses (docs/observability.md, span catalogue):
         ``sgd.place_inputs``, ``sgd.init_carry``, ``sgd.build_program``,
         ``sgd.launch`` (the enqueue, never a wait), ``sgd.fetch`` (the
-        blocking device→host reads) and ``sgd.health``."""
+        blocking device→host reads) and ``sgd.health``.
+
+        A plain fit (no ``config``/``listeners`` hook, health off) starts
+        on the device: ``init_coeffs``, cast and padded, is the one host
+        operand its program takes beside the table, and the zero carry
+        and the epoch bounds are made inside
+        (``_build_sgd_segment_program(fresh=True)``); ``sgd.launch`` says
+        ``start="fresh"``. A carry that crosses the host — checkpointed
+        segments, a restore, host rounds — is placed by one
+        ``jax.device_put`` under ``sgd.init_carry`` (``start="carry"``).
+        The two answer bit for bit."""
         mesh = mesh or default_mesh()
         with tracer.span("sgd.optimize", rounds=self.params.max_iter,
                          shards=data_shard_count(mesh),
@@ -720,13 +757,11 @@ class SGD:
                     if pad or rem:
                         features = np.pad(features, ((0, rem), (0, pad)))
                 xs = jax.device_put(features, x_sharding)
-                w_sharding = NamedSharding(mesh, P(MODEL_AXIS))
             else:
                 # device-resident features/labels (device datagen or a
                 # previous device stage) stay on device end-to-end — no
                 # host round-trip
                 xs, _ = ensure_on_mesh(mesh, features, axes, jnp.float32)
-                w_sharding = NamedSharding(mesh, P())
             ys, _ = ensure_on_mesh(mesh, labels, axes, jnp.float32)
             # no weight column: none is built. The programs take no
             # weight operand and a row weighs 1 where its round's batch
@@ -740,9 +775,32 @@ class SGD:
         from flink_ml_tpu.iteration.iteration import (
             device_checkpoint_segment, needs_host_loop, run_segmented)
 
-        # carry leaves must live on the full mesh (replicated or
-        # model-sharded coeffs, per-task offsets, moment vectors sharded
-        # 1/N under the sharded update) — both for the mapped
+        seg_k = device_checkpoint_segment(config, listeners)
+        plain = not needs_host_loop(config, listeners)
+        # a plain fit with health off starts on the device: its program
+        # makes the zero carry and the bounds itself, and the coefficients
+        # go in as the call's host operand
+        fresh = plain and not health_on
+
+        def record_state(w0, opt):
+            # per-replica update-state accounting (benchmark provenance):
+            # measured from the carry's real buffers — SGD's coefficients
+            # all-gather back to replicated every round, so this honestly
+            # reports full size even under the sharded update; the moment
+            # vectors are the state that genuinely shrinks 1/N (their
+            # slices never all-gather), recorded both folded into the algo
+            # total and as a standalone ".moments" record so the multihost
+            # bench can gate on the moment bytes alone
+            opt_leaves = list(jax.tree_util.tree_leaves(opt))
+            if opt_leaves:
+                _upd.record_state_bytes(f"{algo}.moments", opt_leaves, p,
+                                        sharded)
+            _upd.record_state_bytes(algo, [w0] + opt_leaves, p, sharded)
+
+        # a carry that crosses the host (checkpoints, restores, host
+        # rounds) has leaves that must live on the full mesh (replicated
+        # or model-sharded coeffs, per-task offsets, moment vectors
+        # sharded 1/N under the sharded update) — both for the mapped
         # round/segment and so that checkpoint restore re-places leaves
         # onto the right shardings (a sharded-adam resume puts each
         # moment slice back on its owning replica). The opt tuple rides
@@ -752,38 +810,29 @@ class SGD:
         # transfer of its own, once a device.
         with tracer.span("sgd.init_carry"):
             _check_method(self.params)
-            row_sharding = NamedSharding(mesh, P(spec0))
-            scalar = NamedSharding(mesh, P())
-            moments = _OPT_VECTORS[self.params.method]
-            step = self.params.method == "adam"
-            init = jax.device_put(
-                (np.asarray(init_coeffs, dtype), np.zeros((p,), np.int32),
-                 np.asarray(np.inf, dtype),
-                 (np.zeros(init_coeffs.shape[0], dtype),) * moments
-                 + (np.zeros((), dtype),) * step),
-                (w_sharding, row_sharding, scalar,
-                 (row_sharding if sharded else w_sharding,) * moments
-                 + (scalar,) * step))
-            w0 = init[0]
-            # per-replica update-state accounting (benchmark provenance):
-            # measured from the carry's real buffers — SGD's coefficients
-            # all-gather back to replicated every round, so this honestly
-            # reports full size even under the sharded update; the moment
-            # vectors are the state that genuinely shrinks 1/N (their
-            # slices never all-gather), recorded both folded into the algo
-            # total and as a standalone ".moments" record so the multihost
-            # bench can gate on the moment bytes alone
-            opt_leaves = list(jax.tree_util.tree_leaves(init[3]))
-            if opt_leaves:
-                _upd.record_state_bytes(f"{algo}.moments", opt_leaves, p,
-                                        sharded)
-            _upd.record_state_bytes(algo, [w0] + opt_leaves, p, sharded)
+            w0 = np.asarray(init_coeffs, dtype)
+            if not fresh:
+                w_sharding = NamedSharding(mesh, P(MODEL_AXIS) if tp
+                                           else P())
+                row_sharding = NamedSharding(mesh, P(spec0))
+                scalar = NamedSharding(mesh, P())
+                moments = _OPT_VECTORS[self.params.method]
+                step = self.params.method == "adam"
+                init = jax.device_put(
+                    (w0, np.zeros((p,), np.int32),
+                     np.asarray(np.inf, dtype),
+                     (np.zeros(w0.shape[0], dtype),) * moments
+                     + (np.zeros((), dtype),) * step),
+                    (w_sharding, row_sharding, scalar,
+                     (row_sharding if sharded else w_sharding,) * moments
+                     + (scalar,) * step))
+                w0 = init[0]
+                record_state(w0, init[3])
 
-        seg_k = device_checkpoint_segment(config, listeners)
-        if seg_k or not needs_host_loop(config, listeners):
+        if seg_k or plain:
             # the compiled fast path: a plain fit is one max_iter segment;
             # a checkpointed fit runs K-round segments with the carry
-            # snapshotted between them (same single program either way)
+            # snapshotted between them (one loop either way)
             from flink_ml_tpu.iteration.iteration import (
                 read_boundary, segment_fusion_enabled)
             fused = segment_fusion_enabled()
@@ -794,7 +843,8 @@ class SGD:
                                                       sharded=sharded,
                                                       fused=fused,
                                                       weighted=weighted,
-                                                      n_valid=n_valid)
+                                                      n_valid=n_valid,
+                                                      fresh=fresh)
                 # health carry lives OUTSIDE the checkpointed carry so the
                 # snapshot format is identical with telemetry on or off; a
                 # restore simply resumes the series at its epoch (earlier
@@ -814,7 +864,7 @@ class SGD:
                     hstate["first"] = int(epoch0)
                 health_in = ((hstate["hist"], np.bool_(hstate["fin"]))
                              if health_on else ())
-                with tracer.span("sgd.launch"):
+                with tracer.span("sgd.launch", start="carry"):
                     coeffs, offsets, opt, mean_loss, *tail = seg_prog(
                         xs, ys, ws, coeffs, offsets, opt,
                         np.int32(epoch0), np.int32(limit), *health_in)
@@ -865,8 +915,17 @@ class SGD:
             else:
                 # a plain fit is one segment, and everything it ends in
                 # crosses to the host under one wait
-                (coeffs, _, mean_loss, _), boundary = launch_segment(
-                    init, 0, self.params.max_iter)
+                if fresh:
+                    # jit's own argument path places the coefficients:
+                    # the one transfer a device this start costs
+                    with tracer.span("sgd.launch", start="fresh"):
+                        coeffs, _, opt, mean_loss, *boundary = seg_prog(
+                            xs, ys, ws, w0)
+                    # the returned leaves' metadata: nothing is waited on
+                    record_state(coeffs, opt)
+                else:
+                    (coeffs, _, mean_loss, _), boundary = launch_segment(
+                        init, 0, self.params.max_iter)
                 out, mean_loss, vals = self._fetch_result(
                     coeffs, d, mean_loss, boundary)
                 crossed(vals)

@@ -254,6 +254,11 @@ def test_the_tree_is_the_same_on_every_execution_path(
         assert len(by_name["sgd.fetch"]) == 1
     assert_tree(by_name, by_id, tree)
     assert by_name["sgd.optimize"][0]["attrs"]["path"] == path
+    # which entry of the segment program each launch took: a plain fit's
+    # start is made on the device, a checkpointed fit's carry is placed
+    assert {r.get("attrs", {}).get("start")
+            for r in by_name["sgd.launch"]} == {
+        {"xla-while": "fresh", "xla-while-segments": "carry"}.get(path)}
 
 
 @pytest.mark.parametrize("cls", [LinearSVC, LinearRegression])
@@ -302,13 +307,19 @@ def _program_args(mesh, n=1600, d=6):
     (lambda mesh, prm: opt_mod._build_sgd_segment_program(
         BinaryLogisticLoss, mesh, prm), "jit_sgd_segment",
      (jnp.int32(0), jnp.int32(4))),
+    (lambda mesh, prm: opt_mod._build_sgd_segment_program(
+        BinaryLogisticLoss, mesh, prm, fresh=True), "jit_sgd_segment",
+     None),
     (lambda mesh, prm: jax.jit(opt_mod._build_sgd_round_program(
         BinaryLogisticLoss, mesh, prm)), "jit_sgd_round", ()),
-])
+], ids=["segment", "segment-fresh", "round"])
 def test_programs_lower_under_stable_names_with_the_round_scoped(
         mesh8, build, module, extra):
     prm = SGDParams(max_iter=4, global_batch_size=160)
-    lowered = build(mesh8, prm).lower(*_program_args(mesh8), *extra)
+    args = _program_args(mesh8)
+    if extra is None:  # the fresh form: the table and the coefficients
+        args, extra = args[:4], ()
+    lowered = build(mesh8, prm).lower(*args, *extra)
     text = lowered.as_text(debug_info=True)
     assert f"module @{module} " in text
     for scope in ("sgd.round", "sgd.margins", "sgd.gradient",

@@ -1,8 +1,11 @@
 """A warm dense SGD fit builds and places nothing it built before
 (docs/performance.md; PERF.md section 5): no ``jax.jit`` object made, no
-compile request issued, the carry placed by ONE ``jax.device_put`` of host
-arrays, the epoch bounds host scalars — on every dense execution path, for
-every linear estimator's loss, SGD and Adam, one device and eight. The
+compile request issued, a carry that crosses the host placed by ONE
+``jax.device_put`` of host arrays with the epoch bounds host scalars, a
+plain fit's start made inside its program from the one host operand that
+carries information, the coefficients (PR 39) — on every dense execution
+path, for every linear estimator's loss, SGD and Adam, one device and
+eight. The
 answers are the parent tree's to the last bit
 (``tests/fixtures/optimizer_warm_fit/golden.json``, written from commit
 6708c0f by running this file as a script there; the four cases under its
@@ -120,12 +123,22 @@ def fit(case, ckpt_dir, manager=None, weights=None):
     return coeffs, loss, sgd.last_execution_path
 
 
-def short_fit(method: str, mesh) -> None:
-    """Two logistic rounds under ``method``: long enough to place a carry."""
+def short_fit(method: str, mesh, config=None, rounds=2):
+    """``rounds`` logistic rounds under ``method``: long enough to place a
+    carry, where ``config`` asks for one -> (coefficients, loss)."""
     x, y = make_data("lr")
-    SGD(SGDParams(max_iter=2, global_batch_size=BATCH,
-                  method=method)).optimize(
-        LogisticRegression.loss, np.zeros(D), x, y, None, mesh=mesh)
+    return SGD(SGDParams(max_iter=rounds, global_batch_size=BATCH,
+                         method=method)).optimize(
+        LogisticRegression.loss, np.zeros(D), x, y, None, mesh=mesh,
+        config=config)
+
+
+def one_segment(ckpt_dir, rounds=2):
+    """The checkpointed fit whose one segment is the whole fit: the plain
+    fit's rounds, from a carry that crossed the host."""
+    return IterationConfig(checkpoint_interval=rounds,
+                           checkpoint_manager=CheckpointManager(
+                               str(ckpt_dir)))
 
 
 def health_series(case, ckpt_dir):
@@ -176,6 +189,7 @@ class Watch:
     def __init__(self, monkeypatch, module=opt_mod, events=(REQUEST,)):
         self.events = events
         self.jits, self.requests, self.puts = [], 0, []
+        self.opened = []  # (span name, attributes), as they opened
         self.armed = False
         self._stack = []
         real_jit, real_put = jax.jit, jax.device_put
@@ -198,6 +212,8 @@ class Watch:
 
             @contextlib.contextmanager
             def span(self, name, **attrs):
+                if watch.armed:
+                    watch.opened.append((name, attrs))
                 watch._stack.append(name)
                 try:
                     yield _NoSpan()
@@ -243,10 +259,12 @@ def test_a_warm_fit_builds_nothing_and_answers_as_the_parent_did(
         again, loss_again, _ = fit(case, tmp_path)
     assert watch.jits == []
     assert watch.requests == 0
-    # the carry goes up in one call, and nothing else is placed between
-    # the inputs and the fetch
+    # a carry that crosses the host goes up in one call, a plain fit
+    # places none, and nothing else is placed between the inputs and the
+    # fetch
     assert [span for span, _ in watch.puts
-            if span != "sgd.place_inputs"] == ["sgd.init_carry"]
+            if span != "sgd.place_inputs"] == (
+        [] if case[3] == "xla-while" else ["sgd.init_carry"])
     assert loss_again == loss
     for got_c, got_l in ((coeffs, loss), (again, loss_again)):
         assert got_c.dtype == np.float64
@@ -307,17 +325,26 @@ LAYOUTS = {
 }
 
 
+def layout_mesh(layout, monkeypatch):
+    """``layout``'s mesh, with the sharded update armed where it is one."""
+    shape, names = LAYOUTS[layout][:2]
+    if layout == "sharded-update":
+        monkeypatch.setenv(update_sharding.ENV, "1")
+    return create_mesh(shape, names)
+
+
 @pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("method", CARRY_LEAVES)
 def test_the_carry_is_one_put_of_the_same_leaves(method, layout, watch,
-                                                 monkeypatch):
-    shape, names, wspec, mspec, d = LAYOUTS[layout]
-    if layout == "sharded-update":
-        monkeypatch.setenv(update_sharding.ENV, "1")
-    mesh = create_mesh(shape, names)
+                                                 monkeypatch, tmp_path):
+    """A checkpointed fit's carry crosses the host, so it is placed."""
+    shape, _, wspec, mspec, d = LAYOUTS[layout]
+    mesh = layout_mesh(layout, monkeypatch)
     with watch():
-        short_fit(method, mesh)
+        short_fit(method, mesh, one_segment(tmp_path))
     carry, = watch.carry_puts()
+    assert [attrs for name, attrs in watch.opened
+            if name == "sgd.launch"] == [{"start": "carry"}]
     specs = {"w": wspec, "m": mspec, "data": P("data"), None: P()}
     sizes = {"d": (d,), "p": (shape[0],), (): ()}
     coeffs, offsets, loss, opt = carry
@@ -328,6 +355,58 @@ def test_the_carry_is_one_put_of_the_same_leaves(method, layout, watch,
                    for dtype, spec, size in CARRY_LEAVES[method]]
     assert all(leaf.sharding.mesh == mesh
                for leaf in jax.tree_util.tree_leaves(carry))
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("method", CARRY_LEAVES)
+def test_a_plain_fit_places_no_carry_and_hands_over_one_host_operand(
+        method, layout, watch, monkeypatch):
+    """The program makes the zero carry and the bounds itself: beside the
+    resident table the call takes the coefficients, a host array that
+    jit's own argument path places, and nothing else."""
+    d = LAYOUTS[layout][4]
+    mesh = layout_mesh(layout, monkeypatch)
+    calls = []
+    build = opt_mod._build_sgd_segment_program
+
+    def recording(*a, **k):
+        prog = build(*a, **k)
+        assert k["fresh"] is True
+        return lambda *operands: (calls.append(operands), prog(*operands))[1]
+
+    monkeypatch.setattr(opt_mod, "_build_sgd_segment_program", recording)
+    with watch():
+        short_fit(method, mesh)
+    assert [span for span, _ in watch.puts
+            if span != "sgd.place_inputs"] == []
+    (xs, ys, ws, coeffs), = calls
+    assert isinstance(xs, jax.Array) and isinstance(ys, jax.Array)
+    assert ws is None
+    assert type(coeffs) is np.ndarray
+    assert (coeffs.dtype, coeffs.shape) == (np.float32, (d,))
+    assert [attrs for name, attrs in watch.opened
+            if name == "sgd.launch"] == [{"start": "fresh"}]
+    assert [name for name, _ in watch.opened].count("sgd.init_carry") == 1
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("method", CARRY_LEAVES)
+def test_a_plain_fit_answers_as_the_one_segment_checkpointed_fit(
+        method, layout, tmp_path, golden, monkeypatch):
+    """Both forms of the program wrap one loop: a start made on the device
+    and the same start placed from the host answer bit for bit, and on the
+    layout the golden answers were written on, as the parent did."""
+    mesh = layout_mesh(layout, monkeypatch)
+    plain, plain_loss = short_fit(method, mesh, rounds=ROUNDS)
+    carried, carried_loss = short_fit(
+        method, mesh, one_segment(tmp_path, ROUNDS), rounds=ROUNDS)
+    assert plain.dtype == carried.dtype == np.float64
+    assert plain.tolist() == carried.tolist()
+    assert plain_loss == carried_loss
+    want = golden["fits"].get(f"lr-{method}-8-xla-while")
+    if layout == "replicated" and want:
+        assert plain.tolist() == want["coefficients"]
+        assert plain_loss == pytest.approx(want["loss"], rel=1e-6, abs=0)
 
 
 @pytest.mark.parametrize("case", HEALTH_CASES, ids=case_id)

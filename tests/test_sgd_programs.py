@@ -332,34 +332,42 @@ LOWERED = {
 }
 
 
+def _shape(mesh, dims, spec, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(dims, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+def _carry_shapes(mesh, method, sharded, n, d, weighted):
+    """A segment or round program's operands up to the carry, as shapes:
+    the table (its weight column only for a ``weighted`` fit), the
+    coefficients, the per-task offsets and the rule's moments."""
+    tp = "model" in mesh.axis_names
+    wspec = P("model") if tp else P()
+    mspec = P("data") if sharded else wspec
+    vec = _shape(mesh, (d,), mspec)
+    opt = {"sgd": (), "momentum": (vec,),
+           "adam": (vec, vec, _shape(mesh, (), P()))}[method]
+    rows = _shape(mesh, (n,), P("data"))
+    return [_shape(mesh, (n, d), P("data", "model") if tp else P("data")),
+            rows, rows if weighted else None, _shape(mesh, (d,), wspec),
+            _shape(mesh, (mesh.shape["data"],), P("data"), jnp.int32), opt]
+
+
 def weighted_lowered_text(program, mesh_name, method="sgd", n=400, d=8):
     """The lowered text of ``program`` for a fit that has a weight column,
     at ``n`` rows of ``d`` features on ``mesh_name``."""
     builder, keywords, trailing = LOWERED[program]
     mesh = _unit_mesh(mesh_name)
-    tp = "model" in mesh.axis_names
     prm = dataclasses.replace(UNIT_PRM, method=method)
-    f32 = jnp.float32
-    wspec = P("model") if tp else P()
-    mspec = P("data") if keywords.get("sharded") else wspec
-
-    def shape(dims, spec, dtype=f32):
-        return jax.ShapeDtypeStruct(dims, dtype,
-                                    sharding=NamedSharding(mesh, spec))
-
-    opt = {"sgd": (), "momentum": (shape((d,), mspec),),
-           "adam": (shape((d,), mspec),) * 2 + (shape((), P()),)}[method]
     operands = {"epoch0": np.int32(0), "limit": np.int32(5),
-                "hist": shape((5, 3), P()), "fin": np.bool_(True)}
+                "hist": _shape(mesh, (5, 3), P()), "fin": np.bool_(True)}
     prog = getattr(opt_mod, builder)(BinaryLogisticLoss, mesh, prm,
                                      **keywords)
     if builder == "_build_sgd_round_program":
         prog = jax.jit(prog)
     # the sharded build is an ``instrumented_jit``, which keeps its jit
     return getattr(prog, "_jitted", prog).lower(
-        shape((n, d), P("data", "model") if tp else P("data")),
-        shape((n,), P("data")), shape((n,), P("data")), shape((d,), wspec),
-        shape((mesh.shape["data"],), P("data"), jnp.int32), opt,
+        *_carry_shapes(mesh, method, keywords.get("sharded"), n, d, True),
         *(operands[name] for name in trailing)).as_text()
 
 
@@ -383,6 +391,60 @@ def test_a_weighted_fit_lowers_to_the_parents_text(program, mesh_name,
     text = weighted_lowered_text(program, mesh_name, method)
     assert len(text) == want["characters"]
     assert hashlib.sha256(text.encode()).hexdigest() == want["sha256"]
+
+
+# -- a plain fit's start is made inside the same program -----------------------
+
+def _loops(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _loops(sub)
+
+
+FRESH_CASES = [("one-device", "sgd", False), ("data-4", "sgd", False),
+               ("data-4", "adam", False), ("data-4", "momentum", True),
+               ("data-4", "adam", True), ("tensor-parallel", "momentum", False),
+               ("tensor-parallel", "adam", False)]
+
+
+@pytest.mark.parametrize("mesh_name,method,sharded", FRESH_CASES)
+def test_the_fresh_form_wraps_the_loop_the_carried_form_runs(
+        mesh_name, method, sharded):
+    """``fresh`` changes what crosses the program's boundary and nothing
+    inside the loop: one ``while`` in either form, its body and its test
+    the same text, so a round's arithmetic cannot differ; the fresh form
+    takes the table and the coefficients and no other operand, and returns
+    what the carried form returns."""
+    mesh = _unit_mesh(mesh_name)
+    prm = dataclasses.replace(UNIT_PRM, method=method)
+    traced = {}
+    for fresh in (False, True):
+        prog = opt_mod._build_sgd_segment_program(
+            BinaryLogisticLoss, mesh, prm, fused=True, sharded=sharded,
+            weighted=False, fresh=fresh)
+        # the fresh form takes the table and the coefficients alone
+        operands = _carry_shapes(mesh, method, sharded, 400, 8, False)
+        operands = operands[:4] if fresh else operands + [np.int32(0),
+                                                          np.int32(5)]
+        traced[fresh] = jax.make_jaxpr(getattr(prog, "_jitted", prog))(
+            *operands)
+    carried, = _loops(traced[False].jaxpr)
+    made, = _loops(traced[True].jaxpr)
+    for part in ("body_jaxpr", "cond_jaxpr"):
+        assert str(made.params[part]) == str(carried.params[part])
+    assert len(traced[True].jaxpr.invars) == 3
+    assert ([v.aval for v in traced[True].jaxpr.outvars]
+            == [v.aval for v in traced[False].jaxpr.outvars])
+
+
+def test_a_health_armed_program_has_no_fresh_form():
+    opt_mod._build_sgd_segment_program.cache_clear()
+    with pytest.raises(ValueError, match="carry"):
+        opt_mod._build_sgd_segment_program(
+            BinaryLogisticLoss, _unit_mesh("one-device"), UNIT_PRM,
+            health=True, fresh=True)
 
 
 def write_weighted_lowered(path, commit):
