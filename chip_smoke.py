@@ -492,8 +492,8 @@ def kernels_phase(shrink: int = 1) -> dict:
     require(rc == 0, f"tpu_kernel_check exited {rc} (2 = wrong results, "
             f"3 = a kernel did not lower, compile or run)")
     return {"kernels": ["assign_nearest", "lloyd_partial_sums",
-                        "category_counts", "segment_reduce_sum",
-                        "knn_topk_indices"],
+                        "category_counts", "grouped_moments",
+                        "segment_reduce_sum", "knn_topk_indices"],
             "rc": rc}
 
 

@@ -12,6 +12,7 @@ import numpy as np
 
 from flink_ml_tpu.api.stage import Estimator, Model
 from flink_ml_tpu.common.table import Table
+from flink_ml_tpu.observability.tracing import tracer
 from flink_ml_tpu.ops.stats import anova_f_test, chi_square_test, f_value_test
 from flink_ml_tpu.params.param import (
     FloatParam,
@@ -124,8 +125,55 @@ class UnivariateFeatureSelectorParams(UnivariateFeatureSelectorModelParams,
 
 class UnivariateFeatureSelectorModel(_IndexSelectorModelBase,
                                      UnivariateFeatureSelectorModelParams):
+    """The selected ``indices`` and, where the model was fitted, the test
+    it selected by: ``f_values`` and ``p_values`` (length d, float64) and
+    ``degrees_of_freedom`` (length d, int64), handed out as a second
+    model-data table ``fValues`` / ``pValues`` / ``degreesOfFreedom``
+    (upstream's model data is ``indices`` alone: docs/deviations.md). A
+    selection of 50 from 100 cannot show whether the sums under it were
+    exact; the statistics can."""
+
+    _STATS = {"fValues": "f_values", "pValues": "p_values",
+              "degreesOfFreedom": "degrees_of_freedom"}
+
+    def __init__(self, indices=None, f_values=None, p_values=None,
+                 degrees_of_freedom=None, **kwargs):
+        super().__init__(indices=indices, **kwargs)
+        self.f_values = f_values
+        self.p_values = p_values
+        self.degrees_of_freedom = degrees_of_freedom
+
     _in_col = property(lambda self: self.features_col)
     _out_col = property(lambda self: self.output_col)
+
+    def set_model_data(self, model_data: Table, statistics: Table = None):
+        super().set_model_data(model_data)
+        for column, attr in self._STATS.items():
+            setattr(self, attr, None if statistics is None else np.asarray(
+                statistics.column(column),
+                np.int64 if attr == "degrees_of_freedom" else np.float64))
+        return self
+
+    def get_model_data(self) -> Tuple[Table, ...]:
+        tables = super().get_model_data()
+        if self.p_values is None:
+            return tables
+        return tables + (Table.from_columns(**{
+            column: getattr(self, attr)
+            for column, attr in self._STATS.items()}),)
+
+    def _save_extra(self, path: str) -> None:
+        arrays = {"indices": self.indices}
+        if self.p_values is not None:
+            arrays.update({attr: getattr(self, attr)
+                           for attr in self._STATS.values()})
+        rw.save_model_arrays(path, "model", arrays)
+
+    def _load_extra(self, path: str, meta: dict) -> None:
+        arrays = rw.load_model_arrays(path, "model")
+        self.indices = arrays["indices"]
+        for attr in self._STATS.values():
+            setattr(self, attr, arrays.get(attr))
 
 
 class UnivariateFeatureSelector(Estimator, UnivariateFeatureSelectorParams):
@@ -147,20 +195,41 @@ class UnivariateFeatureSelector(Estimator, UnivariateFeatureSelectorParams):
             x, _ = columnar.fit_vectors(table, self.features_col)
         else:  # chi2 contingency counting is host-side
             x = table.vectors(self.features_col, np.float64)
-        y = np.asarray(table.column(self.label_col))
-        if ftype == self.CATEGORICAL and ltype == self.CATEGORICAL:
-            _, p_values, _ = chi_square_test(x, y)
-        elif ftype == self.CONTINUOUS and ltype == self.CATEGORICAL:
-            _, p_values, _ = anova_f_test(x, y)
-        elif ftype == self.CONTINUOUS and ltype == self.CONTINUOUS:
-            _, p_values, _ = f_value_test(x, y.astype(np.float64))
+        self.last_execution_path = None
+        if ftype == self.CONTINUOUS and ltype == self.CATEGORICAL:
+            # the label stays where it lies: a device column is read on the
+            # device (``ops/stats.moments_on_device``)
+            report = {}
+            f_values, p_values, dofs = anova_f_test(
+                x, table.column(self.label_col), report)
+            self.last_execution_path = report["path"]
         else:
-            raise ValueError(
-                f"unsupported featureType={ftype!r} labelType={ltype!r}")
+            y = np.asarray(table.column(self.label_col))
+            if ftype == self.CATEGORICAL and ltype == self.CATEGORICAL:
+                f_values, p_values, dofs = chi_square_test(x, y)
+            elif ftype == self.CONTINUOUS and ltype == self.CONTINUOUS:
+                f_values, p_values, dofs = f_value_test(
+                    x, y.astype(np.float64))
+            else:
+                raise ValueError(f"unsupported featureType={ftype!r} "
+                                 f"labelType={ltype!r}")
+        if self.last_execution_path:    # with F and p, the host's part
+            with tracer.span("anova.test"):
+                indices = self._select(p_values, x.shape[1])
+        else:
+            indices = self._select(p_values, x.shape[1])
+        with tracer.span("fit.model"):
+            model = UnivariateFeatureSelectorModel(
+                indices=indices, f_values=np.asarray(f_values, np.float64),
+                p_values=np.asarray(p_values, np.float64),
+                degrees_of_freedom=np.asarray(dofs, np.int64))
+            return self.copy_params_to(model)
 
+    def _select(self, p_values, d: int) -> np.ndarray:
+        """The indices the selection mode keeps; ties in p go to the lower
+        index (a stable sort)."""
         mode = self.selection_mode
         thr = self.selection_threshold
-        d = x.shape[1]
         order = np.argsort(p_values, kind="stable")
         if mode == self.NUM_TOP_FEATURES:
             k = int(thr) if thr is not None else 50
@@ -181,8 +250,7 @@ class UnivariateFeatureSelector(Estimator, UnivariateFeatureSelectorParams):
         else:  # FWE
             alpha = thr if thr is not None else 0.05
             indices = np.nonzero(p_values < alpha / d)[0]
-        model = UnivariateFeatureSelectorModel(indices=indices)
-        return self.copy_params_to(model)
+        return indices
 
 
 # ---------------------------------------------------------------------------

@@ -22,10 +22,18 @@ from flink_ml_tpu.params.shared import HasFeaturesCol, HasFlatten, HasLabelCol
 
 class _StatTestBase(AlgoOperator, HasFeaturesCol, HasLabelCol, HasFlatten):
     _test: Callable = None
+    #: the test reads a device-resident table where it lies
+    _on_device = False
 
     def transform(self, table: Table) -> Tuple[Table]:
-        x = table.vectors(self.features_col, np.float64)
-        y = np.asarray(table.column(self.label_col))
+        if self._on_device:
+            from flink_ml_tpu.ops import columnar
+
+            x, _ = columnar.fit_vectors(table, self.features_col)
+            y = table.column(self.label_col)
+        else:
+            x = table.vectors(self.features_col, np.float64)
+            y = np.asarray(table.column(self.label_col))
         statistics, p_values, dofs = type(self)._test(x, y)
         if self.flatten:
             d = len(p_values)
@@ -46,8 +54,11 @@ class ChiSqTest(_StatTestBase):
 
 
 class ANOVATest(_StatTestBase):
-    """One-way ANOVA F-test (ref: ANOVATest.java)."""
+    """One-way ANOVA F-test (ref: ANOVATest.java). A device-resident
+    table is read once on the device (``ops/stats.moments_on_device``): the
+    grouped moments cross to the host, the table does not."""
     _test = staticmethod(anova_f_test)
+    _on_device = True
 
 
 class FValueTest(_StatTestBase):

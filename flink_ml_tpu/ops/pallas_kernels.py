@@ -8,9 +8,10 @@ between the matmul and the argmin; this kernel keeps each (tile_n, k)
 distance block in VMEM and writes only the argmin — HBM traffic drops from
 O(n·k) to O(n·d + k·d + n).
 
-Five kernels: ``assign_nearest`` (KMeans predict), ``lloyd_partial_sums``
-(KMeans fit), ``category_counts`` (NaiveBayes fit), ``segment_reduce_sum``
-(scatter-add by segment id) and ``knn_topk_indices`` (KNN). Each runs on a real TPU backend where its shape
+Six kernels: ``assign_nearest`` (KMeans predict), ``lloyd_partial_sums``
+(KMeans fit), ``category_counts`` (NaiveBayes fit), ``grouped_moments``
+(the ANOVA F-test), ``segment_reduce_sum`` (scatter-add by segment id) and
+``knn_topk_indices`` (KNN). Each runs on a real TPU backend where its shape
 gate admits the input; elsewhere the plain XLA path runs. Tests exercise
 the kernels in interpreter mode on CPU.
 """
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from flink_ml_tpu._cold import importing
+from flink_ml_tpu.ops.fixedpoint import (
+    MOMENTS_DIGITS, add_units, moment_digits)
 
 # the cold span ``import:pallas``: over a second inside the first fit of a
 # process that takes a kernel, Mosaic's front end with it
@@ -438,6 +441,157 @@ def category_counts(x, y, n_valid, labels: int, values: int,
         return jnp.zeros((values, labels, x.shape[1]), jnp.int32)
     return _counts_tiles(x, y, jnp.asarray(n_valid, jnp.int32),
                          labels=labels, values=values, interpret=interpret)
+
+
+# -- grouped moments by exact fixed-point digits (ANOVA F-test) ---------------
+
+#: row tiles the grouped-moments kernel may take, widest first; none under
+#: 1024: the label column is read as it lies, a 1-D array in tiles of 1024
+MOMENTS_TILES_N = (4096, 2048, 1024)
+#: VMEM the kernel's working set may claim, by ``_moments_working_bytes``'
+#: count, under Mosaic's 16 MiB scoped limit
+MOMENTS_VMEM_BUDGET_BYTES = 12 << 20
+def _moments_working_bytes(d: int, labels: int, tile: int) -> int:
+    """Bytes of VMEM one grid step of the moments kernel is counted at. A
+    row of the tile: the double-buffered float32 ``(d, tile)`` block, the
+    scaled value, its square, one remainder and one digit in float32 and
+    the eight bfloat16 digits (38 d), the label block and the labels'
+    one-hot (12 L). Beside the tile: the ``(8, L, d)`` int32 ``lo`` and
+    ``hi``, double-buffered, and the per-lane counts and maxima."""
+    dp, lp = -(-d // 16) * 16, -(-labels // 16) * 16
+    digits = sum(MOMENTS_DIGITS)
+    out = lp * (-(-d // 128) * 128) * 4
+    return tile * (38 * dp + 12 * lp) + 4 * digits * out + 1024 * (dp + lp)
+
+
+def moments_tile(d: int, labels: int) -> int:
+    """The widest row tile whose working set fits the VMEM budget, 0 when
+    none does (callers run the XLA form) — the moments kernel's shape
+    gate."""
+    if d < 2:       # see ``lloyd_tile``: no product with one output column
+        return 0
+    for tile in MOMENTS_TILES_N:
+        if (_moments_working_bytes(d, labels, tile)
+                <= MOMENTS_VMEM_BUDGET_BYTES):
+            return tile
+    return 0
+
+
+def moments_kernel_fits(d: int, labels: int) -> bool:
+    """True when the moments kernel has a tile for these shapes — the gate
+    ``ops/stats.py`` applies."""
+    return moments_tile(d, labels) > 0
+
+
+def _moments_kernel(magic, nv_ref, xt_ref, y_ref, pivot_ref, inv_ref,
+                    lo_ref, hi_ref, counts_ref, top_ref):
+    """One row tile of the grouped moments, entirely in VMEM. An element
+    becomes ``w = (x - pivot) / scale`` (its column's, the scale a power of
+    two: ``inv`` is exact) and ``w * w`` in float32, and each of the two its
+    fixed-point digits (:func:`fixed_digits`); ``A = onehot(y)`` is ``(L,
+    tile)``; ``A @ digit.T`` on the MXU with float32 accumulation is the
+    tile's sum of that digit by label, exact (whole units, under 2**24),
+    and the units add up across tiles in int32 with a carry. What comes out
+    is the exact sum of every ``w`` and of every float32 ``w * w`` to the
+    digits' last place, whatever the order of the rows or the tiles: an
+    answer over four shards is the answer over one, bit for bit.
+
+    ``top`` is the largest ``|w|`` of every column, lane by lane: over 1, a
+    first digit was past 256 units and not whole in bfloat16, and the
+    caller runs the pass again with the scale that holds it. The rows
+    whose index is not under ``n_valid`` are masked out of ``A`` and of
+    ``w`` (what the ragged last tile reads past the array may be anything).
+    A label that is not a whole number in ``[0, L)`` matches nothing: the
+    counts add up to ``n_valid`` exactly when every label was in range."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        lo_ref[:] = jnp.zeros_like(lo_ref)
+        hi_ref[:] = jnp.zeros_like(hi_ref)
+        counts_ref[:] = jnp.zeros_like(counts_ref)
+        top_ref[:] = jnp.zeros_like(top_ref)
+
+    labels = counts_ref.shape[0]
+    tile = xt_ref.shape[1]
+    chunks = range(0, tile, 128)
+    valid = i * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (1, tile), 1) < nv_ref[0]
+    label = jax.lax.broadcasted_iota(
+        jnp.int32, (labels, 128), 0).astype(jnp.float32)
+    # the label column comes as it lies, a (tile,) block of a 1-D array (a
+    # (1, n) row would be a 48 MB copy a fit): 128 of it are the labels of
+    # 128 table rows, already in lanes
+    hit = jnp.concatenate(
+        [label == y_ref[pl.ds(lane, 128)].reshape(1, 128)
+         for lane in chunks], axis=1) & valid                # (L, tile)
+    a = hit.astype(jnp.bfloat16)
+    counted = hit.astype(jnp.int32)
+    counts_ref[:] += sum(counted[:, lane:lane + 128] for lane in chunks)
+    w = jnp.where(valid, (xt_ref[:] - pivot_ref[:]) * inv_ref[:], 0.0)
+    size = jnp.abs(w)
+    top = size[:, :128]
+    for lane in chunks[1:]:
+        top = jnp.maximum(top, size[:, lane:lane + 128])
+    top_ref[:] = jnp.maximum(top_ref[:], top)
+    for at, (k, part) in enumerate(moment_digits(w, magic)):
+        units = (_dot(a, part.astype(jnp.bfloat16), ((1,), (1,)))
+                 * jnp.float32(2.0 ** (8 * k))).astype(jnp.int32)  # (L, d)
+        lo_ref[at], hi_ref[at] = add_units(lo_ref[at], hi_ref[at], units)
+
+
+@functools.partial(jax.jit, static_argnames=("labels", "interpret"))
+def _moments_tiles(x, y, n_valid, pivot, inv, labels, interpret=False):
+    n, d = x.shape
+    tile = moments_tile(d, labels) or MOMENTS_TILES_N[-1]
+    digits = sum(MOMENTS_DIGITS)
+    column = pl.BlockSpec((d, 1), lambda i, s: (0, 0))
+    sums = pl.BlockSpec((digits, labels, d), lambda i, s: (0, 0, 0))
+    lo, hi, counts, top = pl.pallas_call(
+        # (interpreted, the body runs through XLA, whose simplifier undoes
+        # the magic rounding: ``fixed_digits``)
+        functools.partial(_moments_kernel, not interpret),
+        name="grouped_moments",
+        out_shape=(jax.ShapeDtypeStruct((digits, labels, d), jnp.int32),
+                   jax.ShapeDtypeStruct((digits, labels, d), jnp.int32),
+                   jax.ShapeDtypeStruct((labels, 128), jnp.int32),
+                   jax.ShapeDtypeStruct((d, 128), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n, tile),),
+            in_specs=[pl.BlockSpec((d, tile), lambda i, s: (0, i)),
+                      pl.BlockSpec((tile,), lambda i, s: (i,)),
+                      column, column],
+            out_specs=(sums, sums,
+                       pl.BlockSpec((labels, 128), lambda i, s: (0, 0)),
+                       pl.BlockSpec((d, 128), lambda i, s: (0, 0)))),
+        interpret=interpret,
+    )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), x.T,
+      y, pivot[:, None], inv[:, None])
+    return lo, hi, jnp.sum(counts, axis=1), jnp.max(top, axis=1)
+
+
+def grouped_moments(x, y, n_valid, pivot, inv, labels: int,
+                    interpret: bool = False):
+    """``(lo, hi, counts, top)`` over the rows ``[0, n_valid)``: for every
+    label ``l < labels`` and column ``j`` the exact sums of the fixed-point
+    digits of ``w = (x - pivot[j]) * inv[j]`` and of the float32 ``w * w``
+    (``lo + (hi << 20)`` units of digit ``at``, ``(digits, labels, d)``
+    int32 each: the digits of ``w`` first), the rows of every label
+    ``(labels,)`` int32, and every column's largest ``|w|`` ``(d,)`` — one
+    pass over ``x`` where it lies.
+
+    x: (n, d) float32; y: (n,) float32; pivot, inv: (d,) float32, ``inv`` a
+    power of two. Any n: the mask comes from ``n_valid`` and an iota inside
+    the kernel and the last tile is ragged, so nothing is padded or copied.
+    The sums are exact where ``top <= 1``. Callers add ``lo``, ``hi`` and
+    ``counts`` and take the largest ``top`` across data shards."""
+    x = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(y, jnp.float32)
+    return _moments_tiles(x, y, jnp.asarray(n_valid, jnp.int32),
+                          jnp.asarray(pivot, jnp.float32),
+                          jnp.asarray(inv, jnp.float32), labels=labels,
+                          interpret=interpret)
 
 
 # -- fused segment-reduce (scatter-add by segment id) ------------------------

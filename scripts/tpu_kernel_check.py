@@ -192,6 +192,43 @@ def main(shrink: int = 1, small_only: bool = False) -> int:
               lambda: pk.category_counts(xc, yc, nc - 5, lc, vc),
               counts_oracle(xc, yc, nc - 5, lc, vc), rtol=0, atol=0)
 
+    # grouped moments: exact. The kernel's digit sums against float64
+    # sums of the same float32 values, by class: a ragged last tile, rows
+    # past n_valid, a label out of range (added nowhere), a column about a
+    # far pivot; where every |w| <= 1 the sums of w are exact, the sums of
+    # the float32 w * w exact to the digits' last place (2**-33 a row)
+    from flink_ml_tpu.ops.fixedpoint import MOMENTS_DIGITS, digits_value
+
+    for nm, dm, lm in ((3 * pk.MOMENTS_TILES_N[0] + 77, 100, 10),
+                       (5000, 7, 3)):
+        xm = rng.random((nm, dm)).astype(np.float32)
+        xm[:, 1] += 1000.0
+        ym = np.floor(rng.random(nm) * lm).astype(np.float32)
+        ym[7], ym[8] = lm, 0.25
+        pivot = np.zeros(dm, np.float32)
+        pivot[1] = 1000.0
+        inv = np.full(dm, 0.5, np.float32)
+        live = (np.arange(nm) < nm - 5)[:, None]
+        w = ((xm - pivot) * inv).astype(np.float32)
+        hot = (ym[:, None] == np.arange(lm)) & live            # (n, L)
+        want_s = hot.T.astype(np.float64) @ w.astype(np.float64)
+        want_q = hot.T.astype(np.float64) @ (w * w).astype(np.float64)
+
+        def moments(xm=xm, ym=ym, nm=nm, lm=lm, pivot=pivot, inv=inv):
+            lo, hi, counts, top = (np.asarray(a) for a in pk.grouped_moments(
+                xm, ym, nm - 5, pivot, inv, lm))
+            at = MOMENTS_DIGITS[0]
+            return np.concatenate([
+                digits_value(lo[:at], hi[:at]).ravel(),
+                digits_value(lo[at:], hi[at:]).ravel(),
+                counts.astype(np.float64), top.astype(np.float64)])
+
+        check(f"grouped_moments(n {nm}, d {dm}, L {lm})", moments,
+              np.concatenate([want_s.ravel(), want_q.ravel(),
+                              hot.sum(axis=0).astype(np.float64),
+                              np.abs(np.where(live, w, 0)).max(axis=0)]),
+              rtol=0, atol=nm * 2.0 ** -33)
+
     # -- benchmark-scale phase: kernel path vs the XLA path at the shapes
     # the fits use, both ON CHIP. The small-shape phase above proves the
     # lowering against numpy; this phase bounds kernel-vs-XLA drift at

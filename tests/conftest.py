@@ -62,8 +62,9 @@ def interpreted_kernels(monkeypatch):
         contingency.counts_program.cache_clear()
 
     monkeypatch.setattr(pk, "pallas_supported", lambda: True)
-    for name in ("assign_nearest", "category_counts", "knn_topk_indices",
-                 "lloyd_partial_sums", "segment_reduce_sum"):
+    for name in ("assign_nearest", "category_counts", "grouped_moments",
+                 "knn_topk_indices", "lloyd_partial_sums",
+                 "segment_reduce_sum"):
         orig = getattr(pk, name)
         monkeypatch.setattr(
             pk, name,

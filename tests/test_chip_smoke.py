@@ -19,8 +19,8 @@ sys.path.insert(0, REPO)
 
 import chip_smoke  # noqa: E402
 
-KERNELS = ("assign_nearest", "category_counts", "knn_topk_indices",
-           "lloyd_partial_sums", "segment_reduce_sum")
+KERNELS = ("assign_nearest", "category_counts", "grouped_moments",
+           "knn_topk_indices", "lloyd_partial_sums", "segment_reduce_sum")
 
 
 def test_main_refuses_a_non_tpu_platform():

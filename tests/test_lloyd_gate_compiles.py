@@ -197,3 +197,85 @@ def test_a_selection_program_keeps_nothing_of_the_table_s_size_on_the_chip(
                               text))
         assert made == {"f32"}, made
         assert " while(" in text
+
+
+# -- the grouped moments of the ANOVA F-test (UnivariateFeatureSelector fit) ---
+
+def moment_edges(d):
+    """``[(most labels the gate gives this tile, tile)]`` at width ``d``."""
+    tiles = {}
+    for labels in range(1, 257):
+        tiles[pk.moments_tile(d, labels)] = labels
+    return [(labels, tile) for tile, labels in tiles.items() if tile]
+
+
+@pytest.mark.parametrize("d", [2, 6, 100, 128, 256], ids=lambda v: str(v))
+def test_the_most_labels_of_every_moments_tile_compile_for_the_chip(d):
+    """What ``moments_tile`` admits Mosaic has to take: the most labels of
+    every tile at this width, over a ragged last tile (at five hundred
+    features no tile of 1024 rows fits and the XLA form runs)."""
+    assert not pk.moments_kernel_fits(512, 10)
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    one = SingleDeviceSharding(chip())
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    found = moment_edges(d)
+    assert found and {tile for _, tile in found} <= set(pk.MOMENTS_TILES_N)
+    for labels, tile in found:
+        rows = 2 * tile + 77
+        pk._moments_tiles.lower(of((rows, d)), of((rows,)),
+                                of((), jnp.int32), of((d,)), of((d,)),
+                                labels=labels).compile()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("program", ["look", "pallas", "xla"])
+def test_a_moments_program_keeps_nothing_of_the_table_s_size_on_the_chip(
+        program, chips):
+    """Each program of a selector fit at the ANOVA cell's shape, 12M x 100
+    with ten classes on a v5e, and the same table over four: no ``(n, L)``
+    one-hot operand, no ``(n, d)`` intermediate, no copy of the label column
+    (a ``(1, n)`` row of it costs the counting kernel 0.048 GB: the moments
+    kernel reads it as it lies), so the temporaries do not grow with the
+    table: the parent's two ``columnar.apply_multi`` calls kept an ``(n,
+    c)`` one-hot and an ``(n, d)`` gather of the class means."""
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.ops import stats
+    from flink_ml_tpu.parallel.mesh import create_mesh
+
+    mesh = create_mesh(devices=four_chips()[:chips])
+
+    def of(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    n, d, labels = 12_000_000, 100, 10
+    table = [of((n, d), jnp.float32, P("data", None)),
+             of((n,), jnp.float32, P("data")), of((), jnp.int32)]
+    stats.moments_look_program.cache_clear()
+    stats.moments_program.cache_clear()
+    try:
+        if program == "look":
+            compiled = stats.moments_look_program(mesh).lower(
+                *table).compile()
+        else:
+            assert pk.moments_kernel_fits(d, labels)
+            compiled = stats.moments_program(
+                mesh, labels, program == "pallas").lower(
+                    *table, of((d,), jnp.float32),
+                    of((d,), jnp.float32)).compile()
+    finally:
+        stats.moments_look_program.cache_clear()
+        stats.moments_program.cache_clear()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(
+        5.040e9 / chips, rel=1e-3)
+    assert memory.temp_size_in_bytes < 0.02e9
+    assert ("custom_call_target=\"tpu_custom_call\"" in compiled.as_text()
+            ) == (program == "pallas")
