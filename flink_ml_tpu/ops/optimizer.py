@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from flink_ml_tpu.common.metrics import ML_GROUP, metrics
 from flink_ml_tpu.observability import health as _health
 from flink_ml_tpu.observability.tracing import cold_build, tracer
 from flink_ml_tpu.ops.losses import LossFunc
@@ -153,9 +154,12 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     regularization (SGD.java:231-243) — shared by the while-loop and
     host-driven programs so a change here propagates to every fit path.
 
-    Returns ``update(coeffs, opt, xb, yb, wb) -> (new_coeffs, new_opt,
-    mean_loss)``: the local [grad | weight | loss] partials of the
-    minibatch, their cross-shard reduction and the model update.
+    Returns ``update(coeffs, opt, xb, yb, wb, onchip=False) ->
+    (new_coeffs, new_opt, mean_loss)``: the local [grad | weight | loss]
+    partials of the minibatch, their cross-shard reduction and the model
+    update. ``xb`` is the batch ``(rows, d)``, or with ``onchip`` the
+    window made once on chip as the table lies, ``(d, rows)``
+    (:func:`_sgd_round_math`): the same products either way.
     ``opt`` is the stateful rule's moment tuple (:func:`_update_rule`):
     ``()`` for plain sgd, so the stateless programs carry nothing. Must
     be called inside a ``mapreduce.map_shards`` body over the mesh's
@@ -173,18 +177,20 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     to float reassociation in the reduction order."""
     rule = _update_rule(prm)
 
-    def update(coeffs, opt, xb, yb, wb):
+    def update(coeffs, opt, xb, yb, wb, onchip=False):
         # LossFunc.loss_and_gradient, spelled out so that the two
         # products carry their names into the device trace
         with jax.named_scope("sgd.margins"):
-            if model_axis is None:
-                d = xb.shape[1]  # == coeffs length unless sharded padding
-                dots = xb @ coeffs[:d]
-            else:
-                dots = mr.reduce_sum(xb @ coeffs, model_axis)
+            # d == coeffs length unless sharded padding
+            d = xb.shape[0 if onchip else 1]
+            w = coeffs if model_axis is not None else coeffs[:d]
+            dots = w @ xb if onchip else xb @ w
+            if model_axis is not None:
+                dots = mr.reduce_sum(dots, model_axis)
         loss_sum, multipliers = loss_func.terms(dots, yb, wb)
         with jax.named_scope("sgd.gradient"):
-            grad_sum = xb.T @ multipliers  # local feature shard under TP
+            # local feature shard under TP
+            grad_sum = xb @ multipliers if onchip else xb.T @ multipliers
         packed_local = jnp.concatenate([
             grad_sum, jnp.sum(wb)[None].astype(grad_sum.dtype),
             loss_sum[None]])
@@ -221,6 +227,31 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     return update
 
 
+#: the largest batch window, in padded bytes, that a round brings on chip
+#: once (:func:`_batch_onchip`). Read from the TPU compiler ahead of time
+#: for a v5e (``scripts/round_forms.py --gate``): XLA keeps the made window
+#: in on-chip memory (``S(1)``) up to 117.4 MB at d 8, 100 and 512 (282,240
+#: rows at d 100) and writes it to HBM past that; 64 MiB keeps 1.75x under
+ONCHIP_BATCH_BYTES = 64 << 20
+
+
+def _local_batch(prm: SGDParams, p: int, local_n: int) -> int:
+    """The rows of a task's batch window: its share of the global batch,
+    the first ``global % p`` tasks' share, at most the shard."""
+    gb = prm.global_batch_size
+    return min(gb // p + (1 if gb % p else 0), local_n)
+
+
+def _batch_onchip(rows: int, d: int) -> bool:
+    """Whether a batch window of ``rows`` x ``d`` (the local feature shard)
+    is read from HBM once a round: its padded float32 bytes, ``d`` to the
+    sublane tile of 8, are under :data:`ONCHIP_BATCH_BYTES`. Both forms
+    compute the same products; past the budget XLA would write the window
+    to HBM and read it back twice, so the two products read the table
+    instead."""
+    return rows * (-(-d // 8) * 8) * 4 <= ONCHIP_BATCH_BYTES
+
+
 def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
                     model_axis=None, sharded: bool = False,
                     weighted: bool = True, n_valid: Optional[int] = None):
@@ -255,7 +286,7 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
     @jax.named_scope("sgd.round")
     def round_step(xl, yl, wl, coeffs, opt, offset):
         local_n = xl.shape[0]  # static at trace time
-        lb_max = min(lb_base + (1 if lb_rem else 0), local_n)
+        lb_max = _local_batch(prm, p, local_n)
         task_id = mr.shard_index(axes)
         # ref SGD.java:206-213 — low task ids take the remainder
         lb = jnp.minimum(lb_base + (task_id < lb_rem).astype(jnp.int32),
@@ -271,7 +302,18 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
         # are both `weights * ...`) zero their loss and gradient exactly;
         # the batch values themselves need no masking.
         start = jnp.minimum(offset, local_n - lb_max)
-        xb = jax.lax.dynamic_slice_in_dim(xl, start, lb_max, axis=0)
+        onchip = _batch_onchip(lb_max, xl.shape[1])
+        if onchip:
+            # the window as the table lies, (d, lb_max) rows in lanes (a
+            # bitcast of the column-major table), materialised once: XLA
+            # places it on chip and both products read it there, so the
+            # batch crosses HBM once a round, not once a product. No
+            # control flow around it: inside a loop XLA carries the table
+            # row-major, a copy of its size
+            xb = jax.lax.optimization_barrier(
+                jax.lax.dynamic_slice_in_dim(xl.T, start, lb_max, axis=1))
+        else:
+            xb = jax.lax.dynamic_slice_in_dim(xl, start, lb_max, axis=0)
         yb = jax.lax.dynamic_slice_in_dim(yl, start, lb_max, axis=0)
         ws = (jax.lax.dynamic_slice_in_dim(wl, start, lb_max, axis=0)
               if weighted else None)
@@ -286,7 +328,7 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
                     valid, task_id * local_n + src < n_valid)
             wb = valid.astype(xl.dtype)
 
-        coeffs, opt, mean_loss = update(coeffs, opt, xb, yb, wb)
+        coeffs, opt, mean_loss = update(coeffs, opt, xb, yb, wb, onchip)
         new_offset = jnp.where(offset + lb >= local_n, 0, offset + lb)
         return coeffs, opt, new_offset, mean_loss
 
@@ -535,6 +577,25 @@ def _finish_fit_health(algo: str, health_on: bool, hist, fin, epochs,
         _health.guard_final_state(algo, coeffs_host, loss=mean_loss)
 
 
+def _batch_form(prm: SGDParams, mesh: Mesh, n: int, d: int) -> str:
+    """``"onchip"`` where a fit's rounds read their batch from HBM once
+    (:func:`_batch_onchip` of a task's window over its shard of ``n`` rows
+    and of ``d`` features), else ``"hbm"``: the ``batch`` attribute of
+    ``sgd.optimize`` and ``sgd.launch``."""
+    p = data_shard_count(mesh)
+    model_axis = model_axis_of(mesh)
+    tp = int(mesh.shape[model_axis]) if model_axis else 1
+    rows = _local_batch(prm, p, -(-n // p))
+    return "onchip" if _batch_onchip(rows, -(-d // tp)) else "hbm"
+
+
+def _count_batch_reads(batch: str, rounds: int) -> None:
+    """``ml.sgd batchReads``: the HBM reads of a round's batch a fit made,
+    one a round on chip, one a product (two) past the gate."""
+    metrics.group(ML_GROUP, "sgd").counter(
+        "batchReads", rounds * (1 if batch == "onchip" else 2))
+
+
 class SGD:
     """Ref: Optimizer/SGD — optimize(initModel, trainData) → fitted coeffs."""
 
@@ -688,13 +749,14 @@ class SGD:
         ``jax.device_put`` under ``sgd.init_carry`` (``start="carry"``).
         The two answer bit for bit."""
         mesh = mesh or default_mesh()
+        batch = _batch_form(self.params, mesh, *features.shape)
         with tracer.span("sgd.optimize", rounds=self.params.max_iter,
                          shards=data_shard_count(mesh),
                          weights="unit" if weights is None
-                         else "column") as sp:
+                         else "column", batch=batch) as sp:
             out = self._optimize(loss_func, init_coeffs, features, labels,
                                  weights, mesh, dtype, config, listeners,
-                                 tag)
+                                 tag, batch)
             sp.set_attribute("path", self.last_execution_path)
             return out
 
@@ -713,7 +775,7 @@ class SGD:
                     vals)
 
     def _optimize(self, loss_func, init_coeffs, features, labels, weights,
-                  mesh, dtype, config, listeners, tag):
+                  mesh, dtype, config, listeners, tag, batch):
         algo = _health_tag(loss_func, tag)
         health_on = _health.armed()
         n = features.shape[0]
@@ -864,7 +926,7 @@ class SGD:
                     hstate["first"] = int(epoch0)
                 health_in = ((hstate["hist"], np.bool_(hstate["fin"]))
                              if health_on else ())
-                with tracer.span("sgd.launch", start="carry"):
+                with tracer.span("sgd.launch", start="carry", batch=batch):
                     coeffs, offsets, opt, mean_loss, *tail = seg_prog(
                         xs, ys, ws, coeffs, offsets, opt,
                         np.int32(epoch0), np.int32(limit), *health_in)
@@ -885,13 +947,14 @@ class SGD:
                 if fused:
                     vals, = vals
                 epoch, stop = int(vals[0]), bool(vals[1])
+                hstate["epoch"] = epoch
                 if health_on:
                     # epoch-boundary health check: the boundary is this
                     # mode's host sync point, so reading the sentinel
                     # costs no extra round-trip (it rides the bundle) —
                     # and a NaN state fails the fit NOW instead of
                     # burning the remaining segments
-                    hstate["fin"], hstate["epoch"] = bool(vals[2]), epoch
+                    hstate["fin"] = bool(vals[2])
                     if not hstate["fin"]:
                         _finish_fit_health(
                             algo, True, hstate["hist"], False, epoch,
@@ -918,7 +981,8 @@ class SGD:
                 if fresh:
                     # jit's own argument path places the coefficients:
                     # the one transfer a device this start costs
-                    with tracer.span("sgd.launch", start="fresh"):
+                    with tracer.span("sgd.launch", start="fresh",
+                                     batch=batch):
                         coeffs, _, opt, mean_loss, *boundary = seg_prog(
                             xs, ys, ws, w0)
                     # the returned leaves' metadata: nothing is waited on
@@ -931,6 +995,8 @@ class SGD:
                 crossed(vals)
             self.last_execution_path = ("xla-while-segments" if seg_k
                                         else "xla-while")
+            _count_batch_reads(batch,
+                               hstate["epoch"] - (hstate["first"] or 0))
             with tracer.span("sgd.health"):
                 _finish_fit_health(
                     algo, health_on, hstate["hist"], hstate["fin"],
@@ -945,11 +1011,14 @@ class SGD:
                 type(loss_func), mesh, self.params, sharded=sharded,
                 weighted=weighted, n_valid=n_valid)
 
+        rounds = [0]
+
         def body(carry, epoch):
             coeffs, offsets, _, opt = carry
             coeffs, offsets, mean_loss, opt = round_fn(xs, ys, ws,
                                                        coeffs, offsets,
                                                        opt)
+            rounds[0] += 1
             return coeffs, offsets, mean_loss, opt
 
         if health_on:
@@ -963,13 +1032,14 @@ class SGD:
 
         # the rounds are enqueued (and their stop bits awaited) by the
         # iteration runtime, one ``epoch`` span each under this one
-        with tracer.span("sgd.launch"):
+        with tracer.span("sgd.launch", batch=batch):
             final = iterate_bounded(
                 init, body, max_iter=self.params.max_iter,
                 terminate=lambda carry, epoch: carry[2] < self.params.tol,
                 config=config, listeners=listeners, jit_round=False)
         coeffs, _, mean_loss, _ = final
         self.last_execution_path = "host-rounds"
+        _count_batch_reads(batch, rounds[0])
         out, mean_loss, _ = self._fetch_result(coeffs, d, mean_loss)
         with tracer.span("sgd.health"):
             if not health_on:

@@ -15,6 +15,7 @@ one worker, one load of the TPU compiler.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -279,3 +280,74 @@ def test_a_moments_program_keeps_nothing_of_the_table_s_size_on_the_chip(
     assert memory.temp_size_in_bytes < 0.02e9
     assert ("custom_call_target=\"tpu_custom_call\"" in compiled.as_text()
             ) == (program == "pallas")
+
+
+# -- a round of the SGD fit (LogisticRegression and its kin) -------------------
+
+def computations(text):
+    """``{name: [instruction lines]}`` of a compiled module's text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            found[name] = []
+        elif line == "}":
+            name = None
+        elif name:
+            found[name].append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_round_reads_its_batch_from_the_table_once_on_the_chip(chips):
+    """The plain fit's program at the LR cells' shapes, 12M x 100 a chip and
+    a batch of 100,000 rows (25,000 a task over four): the round's window
+    is made once, in on-chip memory (space 1), and it is the one fusion in
+    the loop's body that reads the table; both products read the window.
+    The same pieces with a loop around the batch make XLA carry the table
+    row-major through it, a 6.14 GB copy (PERF.md section 6, PR 41): the
+    temporaries stay under 0.02 GB."""
+    if chip() is None:
+        pytest.skip("no TPU compiler in this installation")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_ml_tpu.ops import optimizer
+    from flink_ml_tpu.ops.losses import BinaryLogisticLoss
+    from flink_ml_tpu.parallel.mesh import create_mesh
+
+    mesh = create_mesh(devices=four_chips()[:chips])
+
+    def of(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh, spec))
+
+    local, d, batch = 12_000_000, 100, 100_000
+    prm = optimizer.SGDParams(learning_rate=0.1, global_batch_size=batch,
+                              max_iter=20, tol=1e-6)
+    optimizer._build_sgd_segment_program.cache_clear()
+    try:
+        compiled = optimizer._build_sgd_segment_program(
+            BinaryLogisticLoss, mesh, prm, fused=True, weighted=False,
+            fresh=True).lower(of((local * chips, d), P("data")),
+                              of((local * chips,), P("data")), None,
+                              of((d,), P())).compile()
+    finally:
+        optimizer._build_sgd_segment_program.cache_clear()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+    text = compiled.as_text()
+    body = computations(text)[re.search(r"body=%([^,\s]+)", text).group(1)]
+    table = {m.group(1) for line in body for m in [re.match(
+        rf"%(\S+) = f32\[{local},{d}\]\{{[^}}]*\}} get-tuple-element",
+        line)] if m}
+    readers = [line for line in body if " fusion(" in line and any(
+        re.search(rf"%{re.escape(name)}[,)]", line) for name in table)]
+    assert len(table) == 1 and len(readers) == 1, readers
+    window = re.match(rf"%(\S+) = f32\[{d},{batch // chips}\]\{{([^}}]*)\}}",
+                      readers[0])
+    assert window and "S(1)" in window.group(2), readers[0]
+    products = [line for line in body if " fusion(" in line
+                and "dot_general" in line]
+    assert len(products) == 2 and all(
+        re.search(rf"%{re.escape(window.group(1))}[,)]", line)
+        for line in products), products

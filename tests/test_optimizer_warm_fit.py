@@ -249,16 +249,30 @@ def watch(monkeypatch):
     w.armed = False  # jax keeps the listener; it counts nothing from here
 
 
+def batch_reads() -> int:
+    """``ml.sgd batchReads`` so far in this process."""
+    from flink_ml_tpu.common.metrics import ML_GROUP, metrics
+
+    return metrics.group(ML_GROUP, "sgd").get_counter("batchReads")
+
+
 @pytest.mark.parametrize("case", CASES, ids=case_id)
 def test_a_warm_fit_builds_nothing_and_answers_as_the_parent_did(
         case, tmp_path, watch, golden):
     want = golden["fits"][case_id(device_twin(case))]
     coeffs, loss, path = fit(case, tmp_path)
     assert path == case[3]
+    reads = batch_reads()
     with watch():
         again, loss_again, _ = fit(case, tmp_path)
     assert watch.jits == []
     assert watch.requests == 0
+    # every round read its batch from HBM once, and the spans say so
+    assert batch_reads() - reads == ROUNDS
+    assert {attrs["batch"] for name, attrs in watch.opened
+            if name in ("sgd.optimize", "sgd.launch")} == {"onchip"}
+    assert [name for name, _ in watch.opened].count("sgd.launch") == (
+        ROUNDS // SEGMENT if case[3] == "xla-while-segments" else 1)
     # a carry that crosses the host goes up in one call, a plain fit
     # places none, and nothing else is placed between the inputs and the
     # fetch
@@ -344,7 +358,8 @@ def test_the_carry_is_one_put_of_the_same_leaves(method, layout, watch,
         short_fit(method, mesh, one_segment(tmp_path))
     carry, = watch.carry_puts()
     assert [attrs for name, attrs in watch.opened
-            if name == "sgd.launch"] == [{"start": "carry"}]
+            if name == "sgd.launch"] == [{"start": "carry",
+                                          "batch": "onchip"}]
     specs = {"w": wspec, "m": mspec, "data": P("data"), None: P()}
     sizes = {"d": (d,), "p": (shape[0],), (): ()}
     coeffs, offsets, loss, opt = carry
@@ -385,7 +400,8 @@ def test_a_plain_fit_places_no_carry_and_hands_over_one_host_operand(
     assert type(coeffs) is np.ndarray
     assert (coeffs.dtype, coeffs.shape) == (np.float32, (d,))
     assert [attrs for name, attrs in watch.opened
-            if name == "sgd.launch"] == [{"start": "fresh"}]
+            if name == "sgd.launch"] == [{"start": "fresh",
+                                          "batch": "onchip"}]
     assert [name for name, _ in watch.opened].count("sgd.init_carry") == 1
 
 

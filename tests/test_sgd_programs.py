@@ -381,16 +381,184 @@ LOWERED_CASES = [
 ]
 
 
+@pytest.fixture
+def two_reads(monkeypatch):
+    """Every batch past the on-chip gate: the programs built under it read
+    their batch from HBM once a product, as the parent's did. The builders'
+    caches are emptied on both sides, so no program of one form is found
+    under the other."""
+    def clear():
+        opt_mod._build_sgd_segment_program.cache_clear()
+        opt_mod._build_sgd_round_program.cache_clear()
+
+    clear()
+    monkeypatch.setattr(opt_mod, "ONCHIP_BATCH_BYTES", 0)
+    yield
+    clear()
+
+
 @pytest.mark.parametrize("program,mesh_name,method", LOWERED_CASES,
                          ids=map("-".join, LOWERED_CASES))
 def test_a_weighted_fit_lowers_to_the_parents_text(program, mesh_name,
-                                                   method):
+                                                   method, two_reads):
+    """Past the on-chip gate a round reads its batch where it lies, as the
+    parent did: the program is the parent's text."""
     with open(WEIGHTED_LOWERED) as f:
         want = json.load(f)["programs"]["-".join((program, mesh_name,
                                                   method))]
     text = weighted_lowered_text(program, mesh_name, method)
     assert len(text) == want["characters"]
     assert hashlib.sha256(text.encode()).hexdigest() == want["sha256"]
+
+
+# -- a round reads its batch from HBM once ------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Parity:
+    loss: type = BinaryLogisticLoss
+    rows: int = 1000
+    dim: int = 7
+    batch: int = 160
+    rounds: int = 9
+    method: str = "sgd"
+    weighted: bool = False
+    path: str = "xla-while"
+
+
+#: on four devices a shard is a quarter of the rows; on one, all of them
+PARITY = {
+    **{f"loss-{cls.NAME}": Parity(cls) for cls in (
+        BinaryLogisticLoss, HingeLoss, LeastSquareLoss)},
+    "column-weights": Parity(weighted=True),
+    "hinge-column-weights": Parity(HingeLoss, weighted=True),
+    # 397 rows: the last of four shards holds 97 and 3 of padding
+    "padded-rows": Parity(rows=397, batch=200, rounds=5),
+    # shards of 250 (or 1000) rows, windows of 90 (or 360): the third
+    # round's window is clamped to the shard's end, the fourth wraps to 0
+    "clip-at-end-and-wrap": Parity(batch=360, rounds=6),
+    # the window is the whole shard, every round
+    "whole-table": Parity(rows=300, batch=1200, rounds=4),
+    "momentum": Parity(method="momentum"),
+    "adam": Parity(LeastSquareLoss, method="adam", weighted=True),
+    "segments": Parity(method="adam", path="xla-while-segments"),
+    "host-rounds": Parity(weighted=True, path="host-rounds"),
+}
+
+
+def parity_fit(case, mesh, ckpt_dir):
+    """``(coefficients, loss, HBM reads of a batch)`` of one fit."""
+    from flink_ml_tpu.common.metrics import ML_GROUP, metrics
+
+    rng = np.random.default_rng(41)
+    x = rng.normal(size=(case.rows, case.dim)).astype(np.float32)
+    y = (x @ rng.normal(size=case.dim)).astype(np.float32)
+    if case.loss is not LeastSquareLoss:
+        y = (y > 0).astype(np.float32)
+    w = (jnp.asarray(rng.random(case.rows) + 0.5, jnp.float32)
+         if case.weighted else None)
+    config = {"xla-while": None,
+              "host-rounds": IterationConfig(mode="host"),
+              "xla-while-segments": IterationConfig(
+                  checkpoint_interval=2,
+                  checkpoint_manager=CheckpointManager(str(ckpt_dir)))
+              }[case.path]
+    prm = SGDParams(learning_rate=0.1, global_batch_size=case.batch,
+                    max_iter=case.rounds, tol=0.0, method=case.method)
+    counter = metrics.group(ML_GROUP, "sgd")
+    before = counter.get_counter("batchReads")
+    sgd = SGD(prm)
+    coeffs, loss = sgd.optimize(case.loss(), np.zeros(case.dim), x, y, w,
+                                mesh=mesh, config=config)
+    assert sgd.last_execution_path == case.path
+    return coeffs, loss, counter.get_counter("batchReads") - before
+
+
+def assert_reassociated(one, two):
+    """The same float32 products, summed in another order: on the CPU the
+    cases read 7.5e-9 of a coefficient and 9.2e-8 of the loss at most, most
+    of them 0."""
+    np.testing.assert_allclose(one[0], two[0], rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(one[1], two[1], rtol=1e-6)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("name", PARITY)
+def test_the_one_read_form_answers_as_the_two_read_form(
+        name, devices, tmp_path, request):
+    case = PARITY[name]
+    mesh = create_mesh(devices=jax.devices()[:devices])
+    one = parity_fit(case, mesh, tmp_path / "one")
+    assert one[2] == case.rounds  # a read a round
+    request.getfixturevalue("two_reads")
+    two = parity_fit(case, mesh, tmp_path / "two")
+    assert two[2] == 2 * case.rounds  # a read a product
+    assert_reassociated(one, two)
+
+
+@pytest.mark.parametrize("method", ["sgd", "adam"])
+def test_a_tensor_parallel_round_reads_its_feature_shard_once(
+        method, tmp_path, request):
+    """Under ``model=2`` a task's window is its ``(d / 2, rows)`` block: the
+    margins are partial products added over the model axis, the gradient
+    stays the shard's."""
+    mesh = _unit_mesh("tensor-parallel")
+    case = Parity(dim=10, method=method)
+    one = parity_fit(case, mesh, tmp_path / "one")
+    request.getfixturevalue("two_reads")
+    two = parity_fit(case, mesh, tmp_path / "two")
+    assert (one[2], two[2]) == (case.rounds, 2 * case.rounds)
+    assert_reassociated(one, two)
+
+
+def test_the_gate_reads_the_padded_window_a_task_holds():
+    """A window is its rows by its local features padded to 8, in float32;
+    at the budget it is read once, a row past it twice."""
+    budget = opt_mod.ONCHIP_BATCH_BYTES
+    rows = budget // (8 * 4)
+    assert opt_mod._batch_onchip(rows, 8)
+    assert opt_mod._batch_onchip(rows, 1)  # one feature is a tile of 8
+    assert not opt_mod._batch_onchip(rows + 1, 8)
+    assert not opt_mod._batch_onchip(rows, 9)
+    prm = SGDParams(global_batch_size=4 * rows + 3)
+    one, four = (create_mesh(devices=jax.devices()[:k]) for k in (1, 4))
+    # the first task of four takes the remainder's extra row: over the gate
+    assert opt_mod._batch_form(prm, four, 8 * rows, 8) == "hbm"
+    assert opt_mod._batch_form(
+        SGDParams(global_batch_size=4 * rows), four, 8 * rows, 8) == "onchip"
+    # a shard shorter than the share bounds the window
+    assert opt_mod._batch_form(prm, one, rows, 8) == "onchip"
+    if len(jax.devices()) >= 8:
+        tp = create_mesh((4, 2), ("data", "model"))
+        assert opt_mod._batch_form(
+            SGDParams(global_batch_size=4 * rows), tp, 8 * rows, 16) == (
+                "onchip")
+
+
+def test_a_batch_over_the_gate_reads_the_table_twice(monkeypatch):
+    """A window past the budget takes the two-read form and says so: 2.1M
+    rows of 8 features a round on one device, 64 MiB and a row."""
+    from flink_ml_tpu.observability import tracing
+
+    rows = opt_mod.ONCHIP_BATCH_BYTES // 32 + 1
+    x = np.ones((rows, 8), np.float32)
+    x[:, 0] = np.linspace(-1.0, 1.0, rows, dtype=np.float32)
+    y = (x[:, 0] > 0).astype(np.float32)
+    opened = []
+    real = tracing.tracer.span
+
+    def span(name, **attrs):
+        opened.append((name, attrs))
+        return real(name, **attrs)
+
+    monkeypatch.setattr(opt_mod.tracer, "span", span)
+    sgd = SGD(SGDParams(learning_rate=0.5, global_batch_size=rows,
+                        max_iter=2, tol=0.0))
+    coeffs, _ = sgd.optimize(BinaryLogisticLoss(), np.zeros(8), x, y,
+                             mesh=create_mesh(devices=jax.devices()[:1]))
+    assert {attrs.get("batch") for name, attrs in opened
+            if name in ("sgd.optimize", "sgd.launch")} == {"hbm"}
+    # the data pulls the first coefficient up, the rest stay level
+    assert coeffs[0] > 0.1 and np.ptp(coeffs[1:]) < 1e-6
 
 
 # -- a plain fit's start is made inside the same program -----------------------
