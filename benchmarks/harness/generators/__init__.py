@@ -7,6 +7,12 @@ A configuration names its generator by the upstream class name:
 {column: array}, {column: rank})``. The generator's own ``seed`` parameter,
 where the upstream file has one, is replaced by ``--seed``.
 
+A sparse vector column is ``{"ids": (n, k) int32, "values": (n, k)
+float32, "size": int32 scalar}``: ``k`` entries a row, a padding entry
+with value 0, ``size`` the vector's. Its ranks are ``{"ids": 2, "values":
+2, "size": 0}``: both arrays row-sharded as a dense rank-2 column is, the
+size replicated.
+
 (The program has generators of the same semantics in
 ``flink_ml_tpu/benchmark/datagen.py``; the yardstick does not call them.)
 """
@@ -28,8 +34,9 @@ def values(key, shape, arity: int):
 
 
 def make_columns(class_name: str, params: dict, seed: int, row_sharding):
-    """``{column: device array}``; ``row_sharding(ndim)`` gives the
-    sharding of a column of that rank."""
+    """``{column: device array}`` (a sparse column: ``{"ids", "values",
+    "size"}``); ``row_sharding(ndim)`` gives the sharding of a column of
+    that rank, replicated at rank 0."""
     import jax
 
     short = class_name.rsplit(".", 1)[-1]
@@ -39,6 +46,6 @@ def make_columns(class_name: str, params: dict, seed: int, row_sharding):
         raise KeyError(f"no generator {class_name!r} under "
                        f"harness/generators/") from None
     gen, ranks = module.build(params)
-    shardings = {name: row_sharding(rank) for name, rank in ranks.items()}
+    shardings = jax.tree.map(row_sharding, ranks)
     columns = jax.jit(gen, out_shardings=shardings)(jax.random.key(seed))
     return jax.block_until_ready(columns)

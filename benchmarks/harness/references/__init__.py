@@ -7,15 +7,13 @@ A reference module has ``run(columns, params, tasks, precision=..., fault=...)``
 returning ``{model-data column: array}`` (+ keys starting with ``_``), and
 ``compare(answer, reference) -> {number: value}``. ``precision="bfloat16"``
 is the control: the same semantics one precision below what the
-configuration states. ``fault`` plants one of the faults the tests and the
-limits are read against.
+configuration states. ``fault`` plants one of the module's ``FAULTS``, the
+faults the tests and the limits are read against.
 """
 
 from __future__ import annotations
 
 import importlib
-
-FAULTS = ("state_unchanged", "half_batch", "no_exchange")
 
 
 def load(name: str):

@@ -15,6 +15,8 @@ import numpy as np
 
 from . import device_precision, np_dtype, task_views, worst_gap
 
+FAULTS = ("state_unchanged", "half_batch", "no_exchange")
+
 
 @functools.lru_cache(maxsize=None)
 def _partials_program(lb: int, precision: str, weighted: bool):

@@ -96,6 +96,78 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 end_to_end=end_to_end, per_layer=per_layer)
 
 
+def _short(class_name: str) -> str:
+    """A class name as the program's registry resolves it: its last part."""
+    return class_name.rsplit(".", 1)[-1]
+
+
+def _same_class(ours: dict, theirs: dict) -> bool:
+    """Two ``stage`` or ``inputData`` blocks, key for key, class names
+    compared by their last part."""
+    def plain(block):
+        return dict(block, className=_short(block.get("className", "")))
+
+    return plain(ours) == plain(theirs)
+
+
+def _upstream(root: Path, path: str) -> dict:
+    """The one job of a vendored upstream benchmark file."""
+    (job,) = (v for k, v in _read_json(root / path).items()
+              if k != "version")
+    return job
+
+
+def check_source(config: dict, root: Path = ROOT) -> None:
+    """A configuration runs its source as published, but for what
+    ``scaled`` lists with the source's own number beside it.
+
+    Its source is upstream's benchmark file (``source_vendored``: the stage
+    and every generator key are the file's), or a public benchmark of the
+    field named in ``source``: then ``assumed`` lists every generator key
+    the source does not fix, ``from_source`` where the source states the
+    others, and ``stage_vendored``, where the stage is upstream's, names
+    the file it must equal."""
+    name = config.get("name")
+    data = dict(config["inputData"]["paramMap"])
+    scaled = config.get("scaled", {})
+    for key, change in scaled.items():
+        if change.get("source") is None:
+            raise SpecError(f"configuration {name!r} scales {key!r} "
+                            f"without the source's own value")
+        if data.get(key) != change.get("here"):
+            raise SpecError(f"configuration {name!r} runs {key!r} = "
+                            f"{data.get(key)!r}, its scaled says "
+                            f"{change.get('here')!r}")
+    if "source_vendored" in config:
+        job = _upstream(root, config["source_vendored"])
+        theirs = dict(job["inputData"]["paramMap"])
+        for key, change in scaled.items():
+            if theirs.pop(key, None) != change["source"]:
+                raise SpecError(f"configuration {name!r}: the source's "
+                                f"{key!r} is not {change['source']!r}")
+            data.pop(key)
+        if not (_same_class(config["stage"], job["stage"])
+                and _same_class(dict(config["inputData"], paramMap=data),
+                                dict(job["inputData"], paramMap=theirs))):
+            raise SpecError(f"configuration {name!r} is not its vendored "
+                            f"source {config['source_vendored']}")
+        return
+    if "stage_vendored" in config and not _same_class(
+            config["stage"], _upstream(root, config["stage_vendored"])[
+                "stage"]):
+        raise SpecError(f"configuration {name!r}: its stage is not the "
+                        f"one of {config['stage_vendored']}")
+    if not config.get("assumed"):
+        raise SpecError(f"configuration {name!r} names a public source and "
+                        f"assumes nothing: list in 'assumed' every "
+                        f"generator key the source does not fix")
+    unaccounted = set(data) - {"colNames"} - set(scaled) - set(
+        config.get("from_source", {})) - set(config["assumed"])
+    if unaccounted:
+        raise SpecError(f"configuration {name!r}: {sorted(unaccounted)} "
+                        f"neither come from the source nor are assumed")
+
+
 def layer_metric_file(metric_name: str, root: Path = ROOT) -> dict:
     """``layer_metrics/<name>.json``: the reader the metric uses, by name."""
     return _read_json(

@@ -1,8 +1,9 @@
 """The system under test, and nothing of the yardstick: the one module of
-the benchmark that imports ``flink_ml_tpu``. It builds the mesh, wraps the
-generated columns in the program's ``Table``, builds a stage from the class
-name and paramMap of a configuration, and runs one fit to model data on
-the host."""
+the harness that drives ``flink_ml_tpu`` (``program_spans.py`` and
+``cold_spans.py`` read its tracer's spans; ``tools/aot_memory*.py`` compile
+its builders). It builds the mesh, wraps the generated columns in the
+program's ``Table``, builds a stage from the class name and paramMap of a
+configuration, and runs one fit to model data on the host."""
 
 from __future__ import annotations
 
@@ -33,6 +34,8 @@ def row_sharding(mesh):
     from flink_ml_tpu.parallel.mesh import data_pspec
 
     def sharding(ndim: int):
+        if ndim == 0:
+            return NamedSharding(mesh, P())
         return NamedSharding(
             mesh, P(data_pspec(mesh), *([None] * (ndim - 1))))
 
@@ -40,8 +43,28 @@ def row_sharding(mesh):
 
 
 def make_table(columns: dict):
+    """The program's ``Table`` over the generated columns, as they lie on
+    the device. A sparse column (``{"ids", "values", "size"}``) goes
+    through the program's one entry for it,
+    ``flink_ml_tpu.linalg.sparse.device_sparse_column(ids, values, size)``,
+    as the two device arrays; a program without that entry cannot take
+    one, and says so at once."""
     from flink_ml_tpu.common.table import Table
 
+    sparse = {name: col for name, col in columns.items()
+              if isinstance(col, dict)}
+    if sparse:
+        from flink_ml_tpu.linalg import sparse as program_sparse
+
+        entry = getattr(program_sparse, "device_sparse_column", None)
+        if entry is None:
+            raise NotImplementedError(
+                "flink_ml_tpu.linalg.sparse.device_sparse_column is missing: "
+                f"this program has no device path for the sparse column "
+                f"{sorted(sparse)}")
+        columns = dict(columns, **{
+            name: entry(col["ids"], col["values"], int(col["size"]))
+            for name, col in sparse.items()})
     return Table.from_columns(**columns)
 
 

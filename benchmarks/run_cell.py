@@ -17,7 +17,6 @@ _PROCESS_START = time.perf_counter()
 import argparse  # noqa: E402
 import copy  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -43,13 +42,6 @@ def configure_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return system.configure_compile_cache()
-
-
-def apply_program_env(cell: spec.Cell) -> None:
-    """The options of the program that the configuration states it runs
-    under, set before the program's modules are imported: they read the
-    environment once, at import."""
-    os.environ.update(cell.config.get("program_env", {}))
 
 
 def _finite(value: float) -> float:
@@ -115,7 +107,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         overrides: dict = None, peaks: dict = None, on_trace=None,
         out=sys.stdout, err=sys.stderr) -> int:
     cell = spec.load_cell(workload, root)
-    apply_program_env(cell)
     if overrides:
         cell = _apply_overrides(cell, overrides)
     import jax
@@ -141,8 +132,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     t = time.perf_counter()
     columns, params = make_inputs(cell, seed, system, used)
     phases["datagen_s"] = time.perf_counter() - t
-    table = system.make_table(columns)
     data = cell.config["inputData"]
+    try:
+        table = system.make_table(columns)
+    except NotImplementedError as exc:
+        print(f"cannot run: {exc}", file=err, flush=True)
+        return 2
     stage = system.build_stage(cell.config["stage"]["className"], params)
     count = counts.per_fit(cell.config["counts"], params, data["paramMap"])
     least = counts.least_seconds(count, peaks, cell.chips)
@@ -212,7 +207,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     paths = sorted(set(map(str, win.pop("paths"))))
     walls = win["walls_s"]
     _info(out, cell=cell.name, seed=seed, mesh=f"data={cell.chips}",
-          program_env=cell.config.get("program_env", {}),
           execution_paths=paths, fits=win["attempted"],
           window_s=win["window_s"],
           fit_wall_median_ms=statistics.median(walls) * 1e3,
@@ -268,7 +262,7 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     try:
         # before JAX: a bad name costs nothing
-        apply_program_env(spec.load_cell(args.workload))
+        spec.load_cell(args.workload)
         import flink_ml_tpu  # noqa: F401 — the system under test is here
     except (spec.SpecError, ImportError) as exc:
         print(f"cannot run: {exc}", file=sys.stderr)

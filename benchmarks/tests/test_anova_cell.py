@@ -61,7 +61,6 @@ def test_the_configuration_keeps_every_shape_of_the_source():
         ours_name = cell.config[block]["className"]
         assert ours_name.startswith("org.apache.flink.ml.")
         assert ours_name.rsplit(".", 1)[1] == name
-    assert "program_env" not in cell.config
     assert cell.config["traffic_may_override"] == []
     assert (cell.chips, cell.config["mesh"]) == (1, {"data": 1})
 
@@ -281,7 +280,6 @@ def test_rehearsal_end_to_end(f_arity):
     cell = spec.load_cell(CELL)
     assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end} == {
         "fit_rows_per_s", "setup_s"}
-    assert info["program_env"] == {}
     assert info["execution_paths"] == ["grouped-moments"]
     assert info["rows_per_fit"] == 40_000
     assert info["window_compiles"]["requests"] == 0

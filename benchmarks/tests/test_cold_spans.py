@@ -167,9 +167,9 @@ def test_the_program_s_tracer_keeps_the_list_the_readers_read():
 
 def test_the_five_are_listed_for_every_cell_and_move_setup_s():
     bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
+    # found by name, in their order among themselves: later PRs append
     mine = [m for m in bench["per_layer"] if m["name"] in FIVE]
     assert [m["name"] for m in mine] == list(FIVE)
-    assert [m["name"] for m in bench["per_layer"][-5:]] == list(FIVE)
     for m in mine:
         assert m["moves"] == "setup_s" and m["unit"] == "s"
         assert m["source"] == "program_span" and m["better"] == "lower"

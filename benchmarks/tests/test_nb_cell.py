@@ -57,7 +57,6 @@ def test_the_configuration_keeps_every_shape_of_the_source():
         assert ours_name.startswith("org.apache.flink.ml.")
         assert ours_name.rsplit(".", 1)[1] == theirs_name.rsplit(
             ".", 1)[1] == name
-    assert "program_env" not in cell.config
     assert cell.config["traffic_may_override"] == []
     bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
     entry = next(c for c in bench["configs"]
@@ -255,7 +254,6 @@ def test_rehearsal_end_to_end():
     cell = spec.load_cell(CELL)
     assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end} == {
         "fit_rows_per_s", "setup_s"}
-    assert info["program_env"] == {}
     assert info["execution_paths"] == ["mxu-counts"]
     assert info["rows_per_fit"] == 20000
     assert info["window_compiles"]["requests"] == 0

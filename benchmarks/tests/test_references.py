@@ -116,7 +116,7 @@ def test_control_one_precision_down_is_not_correct(lr_case, tasks):
     assert same is True
 
 
-@pytest.mark.parametrize("fault", references.FAULTS)
+@pytest.mark.parametrize("fault", sgd_logistic.FAULTS)
 def test_planted_faults_read_far_from_the_reference(fault, lr_case):
     assert _gaps(sgd_logistic, *lr_case, 4, fault=fault)["coef_gap"] > 0.1
 

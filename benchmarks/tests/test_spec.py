@@ -90,23 +90,92 @@ def test_every_cell_resolves_by_name(bench):
 
 
 def test_the_published_stage_is_run_as_published(bench):
-    """Every key of the vendored upstream file is in the configuration's
-    file with the same value, but for what ``scaled`` lists."""
+    """Every configuration is its source but for what ``scaled`` lists:
+    each key of the vendored upstream file, or, for a public benchmark of
+    the field, every generator key from the source or assumed, and the
+    stage of ``stage_vendored``. Class names compare by their last part."""
     for listed in bench["configs"]:
-        config = json.loads((spec.ROOT / listed["file"]).read_text())
-        (upstream,) = (v for k, v in json.loads(
-            (spec.ROOT / config["source_vendored"]).read_text()).items()
-            if k != "version")
-        assert config["stage"] == upstream["stage"]
-        ours = dict(config["inputData"]["paramMap"])
-        theirs = dict(upstream["inputData"]["paramMap"])
-        for key, change in config["scaled"].items():
-            assert theirs.pop(key) == change["source"]
-            assert ours.pop(key) == change["here"]
-        assert ours == theirs
+        spec.check_source(json.loads((spec.ROOT / listed["file"]).read_text()))
     for work in bench["workloads"]:
         cell = spec.load_cell(work["name"])
         assert cell.stage_params() == cell.config["stage"]["paramMap"]
+
+
+def _lr_config():
+    return json.loads((spec.BENCH_DIR / "configs/lr-dense-100.json"
+                       ).read_text())
+
+
+def test_a_vendored_class_name_compares_by_its_last_part():
+    config = _lr_config()
+    config["stage"]["className"] = "flink_ml_tpu.models.LogisticRegression"
+    spec.check_source(config)
+    config["stage"]["className"] = "LinearSVC"
+    with pytest.raises(spec.SpecError, match="vendored source"):
+        spec.check_source(config)
+
+
+def public_config():
+    """A deployment sourced from a public benchmark of the field, with the
+    stage block of upstream's LR benchmark."""
+    stage = _lr_config()["stage"]
+    return {
+        "name": "criteo-hashed-lr",
+        "source": "Criteo Display Advertising Challenge (Kaggle 2014), "
+                  "train.txt",
+        "stage_vendored": "flink_ml_tpu/benchmark/configs/"
+                          "logisticregression-benchmark.json",
+        "stage": stage,
+        "inputData": {"className": "CriteoHashedGenerator",
+                      "paramMap": dict(SPARSE_INPUT)},
+        "scaled": {"numValues": {"source": 45840617, "here": 20000,
+                                 "why": "a test's size"}},
+        "from_source": {"numericFields": "I1-I13",
+                        "categoricalFields": "C1-C26",
+                        "numFeatures": "FeatureHasher's default"},
+        "assumed": {"cardinalities": "DLRM's list, recalled",
+                    "zipfExponent": "a power law of exponent 1.1"},
+    }
+
+
+SPARSE_INPUT = {
+    "colNames": [["features", "label"]], "numValues": 20000,
+    "numFeatures": 262144, "numericFields": 13, "categoricalFields": 26,
+    "cardinalities": [1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3,
+                      93145, 5683, 8351593, 3194, 27, 14992, 5461306, 10,
+                      5652, 2173, 4, 7046547, 18, 15, 286181, 105, 142572],
+    "zipfExponent": 1.1}
+
+
+def test_a_public_source_is_admitted():
+    spec.check_source(public_config())
+
+
+def _without(config, *path):
+    *parents, last = path
+    for key in parents:
+        config = config[key]
+    del config[last]
+
+
+@pytest.mark.parametrize("change,match", [
+    (lambda c: _without(c, "assumed"), "assumes nothing"),
+    (lambda c: _without(c, "scaled", "numValues", "source"),
+     "source's own value"),
+    (lambda c: c["stage"]["paramMap"].update(maxIter=120),
+     "logisticregression-benchmark.json"),
+    (lambda c: _without(c, "assumed", "zipfExponent"), "zipfExponent"),
+    (lambda c: c["scaled"]["numValues"].update(here=30000), "scaled says"),
+])
+def test_what_a_public_source_leaves_out_is_refused(change, match):
+    """No ``assumed``; a ``scaled`` key without the source's own number; a
+    stage that is not its ``stage_vendored`` file's; a generator key that
+    neither the source fixes nor ``assumed`` lists; a size that is not the
+    one ``scaled`` states."""
+    config = public_config()
+    change(config)
+    with pytest.raises(spec.SpecError, match=match):
+        spec.check_source(config)
 
 
 def test_collective_metric_only_in_the_four_chip_cell(bench):

@@ -4,9 +4,9 @@ its arguments. Used once, to size ``numValues`` (PERF.md section 4).
 
     JAX_PLATFORMS=cpu python benchmarks/tools/aot_memory.py
 
-The program picks its kernels by ``jax.default_backend()``, which is the
-CPU here, so this script hands the program's own builders the described
-devices and the kernel choice the chip would make. Nothing runs.
+The LR cells' one program, ``_build_sgd_segment_program`` as a plain fit
+with no weight column builds it, handed the described devices. Nothing
+runs.
 """
 
 from __future__ import annotations
@@ -26,13 +26,11 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmarks import run_cell
     from benchmarks.harness import spec
 
     names = [w["name"] for w in json.loads(
-        (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]]
-    for name in names:      # before the program's modules read them
-        run_cell.apply_program_env(spec.load_cell(name))
+        (spec.ROOT / "BENCHMARK.json").read_text())["workloads"]
+        if spec.load_cell(w["name"]).config["counts"] == "sgd_dense"]
     from flink_ml_tpu.ops import optimizer
     from flink_ml_tpu.ops.losses import BinaryLogisticLoss
     from flink_ml_tpu.parallel.mesh import create_mesh
@@ -51,25 +49,20 @@ def main() -> int:
         mesh = create_mesh(devices=topo.devices[:cell.chips])
         params, data = cell.stage_params(), cell.config["inputData"]["paramMap"]
         n, d = data["numValues"], data["vectorDim"]
-        f32, i32 = jnp.float32, jnp.int32
+        f32 = jnp.float32
         prm = optimizer.SGDParams(
             learning_rate=params["learningRate"],
             global_batch_size=params["globalBatchSize"],
             max_iter=params["maxIter"], tol=params["tol"])
-        table = [((n, d), f32, P("data", None)), ((n,), f32, P("data")),
-                 ((n,), f32, P("data")), ((d,), f32, P()),
-                 ((cell.chips,), i32, P("data"))]
-        if params["maxIter"] <= optimizer._UNROLL_MAX_ROUNDS:
-            prog = optimizer._build_sgd_unrolled_program(
-                BinaryLogisticLoss, mesh, prm, use_kernel=True)
-            args = shapes(mesh, *table) + [()]
-            path = "pallas-unrolled"
-        else:
-            prog = optimizer._build_sgd_segment_program(
-                BinaryLogisticLoss, mesh, prm, fused=True)
-            args = shapes(mesh, *table) + [()] + shapes(
-                mesh, ((), i32, P()), ((), i32, P()))
-            path = "xla-while"
+        # what a plain fit with no weight column runs: the program makes
+        # its own start and takes the coefficients as its one host operand
+        prog = optimizer._build_sgd_segment_program(
+            BinaryLogisticLoss, mesh, prm, fused=True, weighted=False,
+            fresh=True)
+        args = shapes(mesh, ((n, d), f32, P("data", None)),
+                      ((n,), f32, P("data"))) + [None] + shapes(
+            mesh, ((d,), f32, P()))
+        path = "xla-while"
         compiled = prog.lower(*args).compile()
         m = compiled.memory_analysis()
         print(f"{name}: path {path}, rows {n}, per device: arguments "

@@ -5,7 +5,8 @@
   reference, over a dozen seeds — a short window of the cell's own traffic
   through ``run_cell.run`` in this process;
 - the upper readings: the control (the reference one precision below the
-  configuration's) and the reference with each fault planted, each put in
+  configuration's) and the reference with each of its module's ``FAULTS``
+  planted, each put in
   the program's place as the window's one answer and taken through
   ``check.decide`` with the configuration's limits, on a few seeds:
   ``correct`` has to come out false for every one of them.
@@ -57,7 +58,7 @@ def upper_readings(cell: spec.Cell, seed: int) -> dict:
     reference = module.run(columns, params, cell.chips, **args)
     out = {"seed": seed, "reference_s": time.perf_counter() - t}
     variants = [("control_bfloat16", {"precision": "bfloat16"})]
-    variants += [(f"fault_{f}", {"fault": f}) for f in references.FAULTS
+    variants += [(f"fault_{f}", {"fault": f}) for f in module.FAULTS
                  if f != "no_exchange" or cell.chips > 1]
     for name, kw in variants:
         try:
@@ -78,20 +79,12 @@ def main(argv=None) -> int:
     parser.add_argument("--seeds", default="")
     parser.add_argument("--control-seeds", default="")
     parser.add_argument("--seconds", type=float, default=1.0)
-    parser.add_argument("--program-default", action="store_true",
-                        help="a look, not a reading: leave out the "
-                        "configuration's program_env and see what the "
-                        "program's default path returns")
     args = parser.parse_args(argv)
     cell = spec.load_cell(args.workload)
-    if args.program_default:
-        run_cell.apply_program_env = lambda cell: None
-    run_cell.apply_program_env(cell)
     run_cell.configure_compile_cache()
     out_dir = spec.ROOT / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     report = {"workload": args.workload,
-              "program_default": args.program_default,
               "lower": [], "upper": []}
     for seed in [int(s) for s in args.seeds.split(",") if s]:
         report["lower"].append(lower_reading(args.workload, seed,
@@ -111,8 +104,7 @@ def main(argv=None) -> int:
     print(json.dumps({"largest_lower": report["largest_lower"],
                       "every_control_and_fault_not_correct":
                       report["every_control_and_fault_not_correct"]}))
-    suffix = "_program_default" if args.program_default else ""
-    (out_dir / f"limits_{args.workload}{suffix}.json").write_text(
+    (out_dir / f"limits_{args.workload}.json").write_text(
         json.dumps(report, indent=1))
     return 0
 

@@ -1,9 +1,9 @@
-"""``tools/limits.py`` for a reference that names its own faults: the same
-lower readings (the program against the plain reference over the seeds,
-through ``run_cell.run``), and the upper readings with the control and
-every fault of the reference module's ``FAULTS`` (``references.FAULTS``
-where the module has none), each taken through ``check.decide`` with the
-configuration's limits, where ``correct`` has to come out false.
+"""``tools/limits.py`` with the smallest upper reading of each number: the
+same lower readings (the program against the plain reference over the
+seeds, through ``run_cell.run``), and the upper readings with the control
+and every fault of the reference module's ``FAULTS``, each taken through
+``check.decide`` with the configuration's limits, where ``correct`` has to
+come out false.
 
     python benchmarks/tools/limits_faults.py --workload <cell> \\
         --seeds 1,2,...,12 --control-seeds 1,2,3 [--seconds 1] [--twice]
@@ -23,7 +23,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from benchmarks import run_cell  # noqa: E402
-from benchmarks.harness import references, spec  # noqa: E402
+from benchmarks.harness import spec  # noqa: E402
 from benchmarks.tools.limits import lower_reading, upper_readings  # noqa: E402
 
 
@@ -36,11 +36,6 @@ def main(argv=None) -> int:
     parser.add_argument("--twice", action="store_true")
     args = parser.parse_args(argv)
     cell = spec.load_cell(args.workload)
-    # what ``limits.upper_readings`` plants: this reference's own faults
-    references.FAULTS = getattr(
-        references.load(cell.config["correct"]["reference"]), "FAULTS",
-        references.FAULTS)
-    run_cell.apply_program_env(cell)
     run_cell.configure_compile_cache()
     out_dir = spec.ROOT / "chiprun_out"
     out_dir.mkdir(exist_ok=True)

@@ -26,7 +26,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from benchmarks import run_cell  # noqa: E402
-from benchmarks.harness import references, spec  # noqa: E402
+from benchmarks.harness import spec  # noqa: E402
 from benchmarks.tools.limits import upper_readings  # noqa: E402
 
 
@@ -50,10 +50,6 @@ def main(argv=None) -> int:
                (item.split("=", 1) for item in args.input)}
     overrides = {"inputData": changed}
     cell = spec.load_cell(args.workload)
-    references.FAULTS = getattr(
-        references.load(cell.config["correct"]["reference"]), "FAULTS",
-        references.FAULTS)
-    run_cell.apply_program_env(cell)
     run_cell.configure_compile_cache()
     report = {"workload": args.workload, "overrides": overrides,
               "lower": [], "upper": []}

@@ -204,7 +204,6 @@ def run(workload: str, seed: int, seconds: float, capture_at: list,
         capture_s: float, require_tpu: bool = True, overrides: dict = None,
         out=sys.stdout) -> int:
     cell = spec.load_cell(workload)
-    run_cell.apply_program_env(cell)
     if overrides:
         cell = run_cell._apply_overrides(cell, overrides)
     import jax
@@ -283,7 +282,7 @@ def main(argv=None) -> int:
     parser.add_argument("--capture-s", type=float, default=2.0)
     args = parser.parse_args(argv)
     try:
-        run_cell.apply_program_env(spec.load_cell(args.workload))
+        spec.load_cell(args.workload)
     except spec.SpecError as exc:
         print(f"cannot run: {exc}", file=sys.stderr)
         return 2

@@ -52,7 +52,6 @@ def main(argv) -> int:
             (out_dir / f"fixture_{cell}.expected.json").write_text(
                 json.dumps(trace_reduce.reduce(small), indent=1))
 
-    run_cell.apply_program_env(spec.load_cell(cell))
     run_cell.configure_compile_cache()
     return run_cell.run(cell, 20250925, seconds, True, on_trace=keep)
 
