@@ -35,6 +35,20 @@ def _is_csr_column(values) -> bool:
     return getattr(values, "is_csr_vector_column", False)
 
 
+def _is_device_sparse_column(values) -> bool:
+    """A DeviceSparseColumn (ids and values of a sparse vector column as
+    they lie on the device — see flink_ml_tpu.linalg.sparse): kept as it
+    is, sliced on the device, and copied to the host only through its own
+    ``to_csr()``."""
+    return getattr(values, "is_device_sparse_column", False)
+
+
+def _is_sparse_vector_column(values) -> bool:
+    """A whole-column sparse form (host CSR or device), which converts to
+    rows, dense arrays and other columns by its own methods."""
+    return _is_csr_column(values) or _is_device_sparse_column(values)
+
+
 def _slice_rows(col, start: int, stop: int):
     """``col[start:stop]`` with device columns routed through ONE
     compiled dynamic-slice program per (shape, dtype, length): the start
@@ -53,7 +67,7 @@ def _as_column(values) -> np.ndarray:
     IS a vector column (row i = vector i); this is the fast path that avoids
     materializing n DenseVector objects for large tables."""
     if isinstance(values, np.ndarray) or _is_device_column(values) \
-            or _is_csr_column(values):
+            or _is_sparse_vector_column(values):
         return values
     values = list(values)
     if values and isinstance(values[0], (Vector,)):
@@ -169,7 +183,7 @@ class Table:
         import csv as _csv
         names = self.column_names
         for name in names:
-            if _is_csr_column(self._columns[name]):
+            if _is_sparse_vector_column(self._columns[name]):
                 # rejected without materializing 10M SparseVector rows
                 raise ValueError(
                     f"column {name!r} is not scalar; to_csv writes scalar "
@@ -225,7 +239,7 @@ class Table:
         statistics keep their float64 contract.
         """
         col = self.column(name)
-        if _is_csr_column(col):
+        if _is_sparse_vector_column(col):
             # dense off-ramp, same semantics as stacking SparseVectors
             return col.to_dense(dtype)
         if _is_device_column(col):
@@ -320,7 +334,7 @@ class Table:
     # -- row view (collect parity with table.execute().collect()) -----------
     def _host_column(self, name: str) -> np.ndarray:
         col = self._columns[name]
-        if _is_csr_column(col):
+        if _is_sparse_vector_column(col):
             return col.to_object_column()
         return np.asarray(col) if _is_device_column(col) else col
 

@@ -1,26 +1,36 @@
-"""CSR batching for sparse vector columns.
+"""Sparse vector columns: host CSR, and the fixed-width device column.
 
 Ref parity: the reference trains/predicts on `SparseVector` input without
 densifying — BLAS.hDot (flink-ml-servable-core/.../linalg/BLAS.java:78)
 and the sparse gradient branch of FTRL
 (OnlineLogisticRegression.java:364-388). A HashingTF/FeatureHasher column
 at the default 2^18 dims would blow up memory if stacked dense
-(10M rows × 262144 × 8B ≈ 20 TB); this module keeps such columns in host
-CSR form end-to-end: one matrix for the whole column, matvecs through
-scipy's C kernels, per-coordinate scatters via np.bincount.
+(10M rows × 262144 × 8B ≈ 20 TB), so neither form ever densifies:
 
-Device offload note: the FTRL/SGD math on CSR is host-side by design
-(SURVEY.md §7 "Ragged/sparse ETL ops") — XLA wants static shapes and these
-batches' nnz varies per round; the dense model-update vector (d ≤ a few
-hundred thousand) is cheap on host. docs/deviations.md is not affected:
-sparse semantics match the reference exactly.
+- ``CsrVectorColumn``: one scipy CSR matrix for the whole column, matvecs
+  through scipy's C kernels, per-coordinate scatters via np.bincount. What
+  ``HashingTF``/``FeatureHasher``/``CountVectorizer`` produce, and what the
+  host stages (FTRL, online LR, the feature stages) take, in float64.
+- ``DeviceSparseColumn`` (:func:`device_sparse_column`): ``k`` entries a
+  row as two ``(n, k)`` device arrays, ids and values, kept as they lie
+  (row-sharded, the TPU's column-major layout, no copy). The linear models'
+  SGD fit and their predict take it on the device
+  (``SGD.optimize_sparse``, ``models/common.py::predict_dots``); every host
+  consumer reaches it through its one off-ramp, :meth:`~DeviceSparseColumn.
+  to_csr`.
+
+docs/deviations.md is not affected: sparse semantics match the reference
+exactly (two entries of a row in one bucket add, as a CSR sum adds them).
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from flink_ml_tpu.linalg.vectors import SparseVector, Vector
+from flink_ml_tpu.observability.tracing import cold_build, tracer
 
 
 class CsrVectorColumn:
@@ -114,6 +124,142 @@ def is_csr_column(col) -> bool:
     return getattr(col, "is_csr_vector_column", False)
 
 
+class DeviceSparseColumn:
+    """A sparse vector column as it lies on the device: ``k`` entries a
+    row, ``ids`` ``(n, k)`` int32 and ``values`` ``(n, k)`` float32, two
+    ``jax.Array`` objects kept exactly as they were handed over (their
+    sharding, their layout, no copy). A padding entry has value 0 and any id in
+    ``[0, size)``; two entries of a row in one bucket add.
+
+    ``hot`` is the index :func:`device_sparse_column` made: ``((entry,
+    bucket), ...)`` for each entry position whose id is one bucket on every
+    row (a numeric field, which ``FeatureHasher`` hashes by its name). The
+    device fit sums those entries as columns instead of scattering them
+    (``ops/sparse_window.py``). A row subset keeps the index.
+
+    Row slices and takes stay on the device. Nothing here copies the
+    column to the host but :meth:`to_csr`, the explicit off-ramp: an
+    implicit ``np.asarray`` raises."""
+
+    #: duck-type marker (Table, is_sparse_column)
+    is_device_sparse_column = True
+
+    def __init__(self, ids, values, size: int, hot=()):
+        self.ids, self.values = ids, values
+        self.size = int(size)
+        self.hot = tuple(hot)
+
+    def __len__(self):
+        return self.ids.shape[0]
+
+    @property
+    def shape(self):
+        """``(rows, size)``: the matrix the column stands for."""
+        return (self.ids.shape[0], self.size)
+
+    @property
+    def entries(self) -> int:
+        """``k``, the entries a row."""
+        return self.ids.shape[1]
+
+    def __array__(self, *args, **kwargs):
+        raise TypeError("a device sparse column is not copied to the host "
+                        "implicitly: to_csr() is its off-ramp")
+
+    def _rows(self, take) -> "DeviceSparseColumn":
+        return DeviceSparseColumn(take(self.ids), take(self.values),
+                                  self.size, self.hot)
+
+    def __getitem__(self, key):
+        from flink_ml_tpu.ops import columnar
+
+        if isinstance(key, slice):
+            start, stop, step = key.indices(len(self))
+            if step == 1:
+                size = max(0, stop - start)
+                return self._rows(
+                    lambda a: columnar.dynamic_rows(a, start, size))
+            key = np.arange(start, stop, step)
+        rows = np.asarray(key)
+        return self._rows(lambda a: a[rows])
+
+    def to_csr(self):
+        """The column on the host as one canonical scipy CSR matrix,
+        float64: duplicate ids of a row summed, padding entries dropped."""
+        import scipy.sparse as sp
+
+        ids = np.array(self.ids)  # a copy: the CSR sorts it in place
+        n, k = ids.shape
+        m = sp.csr_matrix(
+            (np.asarray(self.values, np.float64).ravel(), ids.ravel(),
+             np.arange(0, n * k + 1, k)), shape=(n, self.size))
+        m.sum_duplicates()
+        m.eliminate_zeros()
+        return m
+
+    def to_object_column(self) -> np.ndarray:
+        return csr_to_column(self.to_csr())
+
+    def to_dense(self, dtype=np.float64) -> np.ndarray:
+        return self.to_csr().astype(dtype).toarray()
+
+    def __repr__(self):
+        return (f"DeviceSparseColumn({len(self)} rows, size={self.size}, "
+                f"entries={self.entries}, hot={len(self.hot)})")
+
+
+def is_device_sparse_column(col) -> bool:
+    return getattr(col, "is_device_sparse_column", False)
+
+
+@functools.lru_cache(maxsize=None)
+@cold_build("sparse_index")
+def _index_program():
+    """``index(ids) -> (min, max, same, first)``: the range of the ids and,
+    per entry position, whether every row holds the first row's id; one
+    read of the ids where they lie."""
+    import jax
+    import jax.numpy as jnp
+
+    def sparse_index(ids):
+        first = ids[0]
+        return (jnp.min(ids), jnp.max(ids),
+                jnp.all(ids == first[None, :], axis=0), first)
+
+    return jax.jit(sparse_index)
+
+
+def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
+    """The one entry for a sparse column made on the device: ``ids`` and
+    ``values``, ``(n, k)`` ``jax.Array`` objects of int32 and float32, and
+    the vectors' ``size``. The arrays are kept as they are; one program reads
+    the ids once, refuses an id outside ``[0, size)`` and finds the entry
+    positions that hold one bucket on every row (``DeviceSparseColumn.
+    hot``), a few numbers to the host."""
+    import jax
+
+    if not (isinstance(ids, jax.Array) and isinstance(values, jax.Array)):
+        raise TypeError("device_sparse_column takes two jax.Arrays; a host "
+                        "sparse column is a CsrVectorColumn")
+    if ids.ndim != 2 or ids.shape != values.shape or not ids.shape[1]:
+        raise ValueError(f"ids {ids.shape} and values {values.shape} must "
+                         f"be one (rows, entries) shape, entries > 0")
+    if ids.dtype != np.int32 or values.dtype != np.float32:
+        raise TypeError(f"ids must be int32 and values float32, not "
+                        f"{ids.dtype} and {values.dtype}")
+    size = int(size)
+    hot = ()
+    if ids.shape[0]:
+        with tracer.span("sparse.index", rows=ids.shape[0],
+                         entries=ids.shape[1]):
+            lo, hi, same, first = jax.device_get(_index_program()(ids))
+        if lo < 0 or hi >= size:
+            raise ValueError(f"ids lie in [{lo}, {hi}], outside the "
+                             f"column's [0, {size})")
+        hot = tuple((j, int(first[j])) for j in np.flatnonzero(same))
+    return DeviceSparseColumn(ids, values, size, hot)
+
+
 def column_moments(m):
     """Per-column (mean, centered sum of squares, stored-count) of a CSR
     matrix in O(nnz), TWO-PASS (cancellation-stable): implicit zeros
@@ -147,14 +293,14 @@ def build_csr_column(n: int, size: int, sorted_row_ids, col_idx,
 
 
 def is_sparse_column(col) -> bool:
-    """True for a CSR-backed column or an object column holding at least
-    one SparseVector row.
+    """True for a CSR-backed column, a device sparse column, or an object
+    column holding at least one SparseVector row.
 
     The reference dispatches per row (``instanceof SparseVector``,
     OnlineLogisticRegression.java:375); a column with any sparse row takes
     the CSR path here — the scan short-circuits at the first sparse row.
     """
-    if is_csr_column(col):
+    if is_csr_column(col) or is_device_sparse_column(col):
         return True
     return (getattr(col, "dtype", None) == object and len(col) > 0
             and isinstance(col[0], Vector)
@@ -183,7 +329,7 @@ def column_to_csr(col, dtype=np.float64):
     """
     import scipy.sparse as sp
 
-    if is_csr_column(col):
+    if is_csr_column(col) or is_device_sparse_column(col):
         m = col.to_csr()
         return m if m.dtype == dtype else m.astype(dtype)
 
@@ -222,15 +368,23 @@ def csr_to_column(matrix) -> np.ndarray:
     return out
 
 
-def features_matrix(table, col_name: str, dtype=np.float32):
+def features_matrix(table, col_name: str, dtype=np.float32,
+                    device_sparse: bool = False):
     """Table column → dense (n, d) array OR scipy CSR, preserving sparsity.
 
     The shared Table→trainer boundary for fits/predicts that support both
     representations (linear models, FTRL). ``dtype`` applies to the dense
     branch only; the CSR branch is always float64 — its math runs on host
     where float64 is free and matches the reference's double precision.
+
+    A :class:`DeviceSparseColumn` comes back as it lies where the caller
+    takes it on the device (``device_sparse``: the linear models' SGD fit
+    and predict), else through its host off-ramp as the CSR branch: it
+    never reaches a host trainer unconverted, nor a device one converted.
     """
     col = table.column(col_name)
+    if device_sparse and is_device_sparse_column(col):
+        return col
     if is_sparse_column(col):
         return column_to_csr(col, dtype=np.float64)
     return table.vectors(col_name, dtype)

@@ -18,6 +18,7 @@ from flink_ml_tpu.api.stage import Estimator, Model
 from flink_ml_tpu.common.table import Table, as_dense_vector_column
 from flink_ml_tpu.linalg.vectors import DenseVector
 from flink_ml_tpu.observability.tracing import tracer
+from flink_ml_tpu.ops import sparse_window
 from flink_ml_tpu.ops.losses import LossFunc
 from flink_ml_tpu.ops.optimizer import SGD, SGDParams
 from flink_ml_tpu.params.shared import (
@@ -39,10 +40,12 @@ from flink_ml_tpu.utils import io as rw
 
 def extract_labeled_points(stage, table: Table
                            ) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
-    """Table → (features (n,d) dense or CSR, labels (n,), weights (n,)|None)
-    — the reference's Table→LabeledPointWithWeight map
-    (LogisticRegression.java:72-99). A SparseVector column stays CSR so
-    wide hashed features (2^18 dims) never densify (ref BLAS.java:78)."""
+    """Table → (features (n,d) dense, CSR or a device sparse column,
+    labels (n,), weights (n,)|None) — the reference's
+    Table→LabeledPointWithWeight map (LogisticRegression.java:72-99). A
+    SparseVector column stays CSR and a device sparse column stays where it
+    lies, so wide hashed features (2^18 dims) never densify (ref
+    BLAS.java:78)."""
     from flink_ml_tpu.linalg import sparse
 
     def scalar_col(name):
@@ -51,7 +54,7 @@ def extract_labeled_points(stage, table: Table
         col = table.column(name)
         return col if isinstance(col, jax.Array) else table.scalars(name)
 
-    x = sparse.features_matrix(table, stage.features_col)
+    x = sparse.features_matrix(table, stage.features_col, device_sparse=True)
     y = scalar_col(stage.label_col)
     w = None
     if stage.weight_col is not None and stage.weight_col in table:
@@ -62,6 +65,12 @@ def extract_labeled_points(stage, table: Table
 @jax.jit
 def _dots(features, coeffs):
     return features @ coeffs
+
+
+@jax.jit
+def _sparse_dots(ids, values, coeffs):
+    # a row's entries' coefficient times value, summed: the fit's margins
+    return jnp.sum(sparse_window.gather(coeffs, ids) * values, axis=1)
 
 
 def prediction_dtype(xp):
@@ -77,7 +86,9 @@ def predict_dots(x, coefficients):
     columnar path (sharded rows, replicated coefficients — the ⚙ predict
     tier of SURVEY §2.1; ref LogisticRegressionModelServable.java:106 dot),
     returning a device array so derived prediction columns stay resident;
-    CSR input stays a host matvec (ref BLAS.hDot sparse path).
+    a device sparse column runs on device as the fit's margins do (a
+    gather of the coefficients and a sum over a row's entries), rows where
+    they lie; CSR input stays a host matvec (ref BLAS.hDot sparse path).
 
     Returns (dots, xp) where xp is the array namespace (jnp or np) the
     caller should derive its prediction columns with."""
@@ -85,6 +96,9 @@ def predict_dots(x, coefficients):
 
     if sparse.is_csr(x):
         return np.asarray(x @ np.asarray(coefficients, np.float64)), np
+    if sparse.is_device_sparse_column(x):
+        return _sparse_dots(x.ids, x.values,
+                            np.asarray(coefficients, np.float32)), jnp
     from flink_ml_tpu.ops import columnar
 
     xd = columnar.to_device(x)
@@ -122,7 +136,8 @@ class LinearModelBase(Model, LinearTrainParams):
         if self.coefficients is None:
             raise ValueError(f"{type(self).__name__} has no model data")
         from flink_ml_tpu.linalg import sparse
-        x = sparse.features_matrix(table, self.features_col)
+        x = sparse.features_matrix(table, self.features_col,
+                                   device_sparse=True)
         dots, xp = predict_dots(x, self.coefficients)
         return (table.with_columns(**self._predict_columns(dots, xp)),)
 
@@ -183,6 +198,18 @@ class IterationRuntimeMixin:
                               listeners=self._iteration_listeners)
 
 
+def _baseline_rows(x):
+    """The row-capped training sample a baseline capture reads
+    (``drift.sample_rows``); a device sparse column's comes to the host as
+    CSR, a few thousand rows of it, so no capture copies the column or
+    densifies it."""
+    from flink_ml_tpu.linalg import sparse
+    from flink_ml_tpu.observability import drift
+
+    xs = drift.sample_rows(x)
+    return xs.to_csr() if sparse.is_device_sparse_column(xs) else xs
+
+
 def _capture_drift_baseline(estimator, model, x, coeffs) -> None:
     """The traced-fit drift seam (observability/drift.py): sketch a
     row-capped sample of the training inputs per feature plus the final
@@ -198,7 +225,7 @@ def _capture_drift_baseline(estimator, model, x, coeffs) -> None:
 
         if not drift.capture_armed():
             return
-        xs = drift.sample_rows(x)
+        xs = _baseline_rows(x)
         dots, xp = predict_dots(xs, coeffs)
         pred = model._predict_columns(dots, xp).get(
             model.prediction_col)
@@ -221,11 +248,11 @@ def _capture_quality_baseline(estimator, model, x, y, coeffs) -> None:
     sketch nothing, so no baseline attaches. Armed like drift capture;
     a failure is logged and never fails the fit."""
     try:
-        from flink_ml_tpu.observability import drift, evaluation
+        from flink_ml_tpu.observability import evaluation
 
         if not evaluation.capture_armed():
             return
-        xs = drift.sample_rows(x)
+        xs = _baseline_rows(x)
         ys = np.asarray(y).ravel()[:xs.shape[0]]
         dots, xp = predict_dots(xs, coeffs)
         cols = model._predict_columns(dots, xp)
@@ -276,6 +303,12 @@ class LinearEstimatorBase(Estimator, LinearTrainParams,
         # observability/health.py) across every SGD execution path
         if sparse.is_csr(x):
             coeffs, _ = sgd.optimize_csr(
+                self.loss, init, x, y, w,
+                config=self._iteration_config,
+                listeners=self._iteration_listeners,
+                tag=type(self).__name__)
+        elif sparse.is_device_sparse_column(x):
+            coeffs, _ = sgd.optimize_sparse(
                 self.loss, init, x, y, w,
                 config=self._iteration_config,
                 listeners=self._iteration_listeners,

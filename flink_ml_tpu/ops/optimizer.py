@@ -42,6 +42,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from flink_ml_tpu.common.metrics import ML_GROUP, metrics
 from flink_ml_tpu.observability import health as _health
 from flink_ml_tpu.observability.tracing import cold_build, tracer
+from flink_ml_tpu.ops import sparse_window
 from flink_ml_tpu.ops.losses import LossFunc
 from flink_ml_tpu.ops.regularization import regularize
 from flink_ml_tpu.parallel.mesh import (
@@ -154,12 +155,14 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     regularization (SGD.java:231-243) — shared by the while-loop and
     host-driven programs so a change here propagates to every fit path.
 
-    Returns ``update(coeffs, opt, xb, yb, wb, onchip=False) ->
+    Returns ``update(coeffs, opt, products, yb, wb) ->
     (new_coeffs, new_opt, mean_loss)``: the local [grad | weight | loss]
     partials of the minibatch, their cross-shard reduction and the model
-    update. ``xb`` is the batch ``(rows, d)``, or with ``onchip`` the
-    window made once on chip as the table lies, ``(d, rows)``
-    (:func:`_sgd_round_math`): the same products either way.
+    update. ``products`` is the batch window's ``(margins, gradient)``:
+    ``margins(coeffs)`` the rows' dots and ``gradient(multipliers)`` the
+    local gradient sum, the one part a dense window
+    (:func:`_dense_products`) and a sparse one
+    (``sparse_window.products``) differ in (:func:`_sgd_round_math`).
     ``opt`` is the stateful rule's moment tuple (:func:`_update_rule`):
     ``()`` for plain sgd, so the stateless programs carry nothing. Must
     be called inside a ``mapreduce.map_shards`` body over the mesh's
@@ -177,20 +180,13 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     to float reassociation in the reduction order."""
     rule = _update_rule(prm)
 
-    def update(coeffs, opt, xb, yb, wb, onchip=False):
-        # LossFunc.loss_and_gradient, spelled out so that the two
-        # products carry their names into the device trace
-        with jax.named_scope("sgd.margins"):
-            # d == coeffs length unless sharded padding
-            d = xb.shape[0 if onchip else 1]
-            w = coeffs if model_axis is not None else coeffs[:d]
-            dots = w @ xb if onchip else xb @ w
-            if model_axis is not None:
-                dots = mr.reduce_sum(dots, model_axis)
+    def update(coeffs, opt, products, yb, wb):
+        # LossFunc.loss_and_gradient, spelled out so that the window's
+        # two products carry their names into the device trace
+        margins, gradient = products
+        dots = margins(coeffs)
         loss_sum, multipliers = loss_func.terms(dots, yb, wb)
-        with jax.named_scope("sgd.gradient"):
-            # local feature shard under TP
-            grad_sum = xb @ multipliers if onchip else xb.T @ multipliers
+        grad_sum = gradient(multipliers)
         packed_local = jnp.concatenate([
             grad_sum, jnp.sum(wb)[None].astype(grad_sum.dtype),
             loss_sum[None]])
@@ -227,6 +223,56 @@ def _sgd_update_math(loss_func, prm: SGDParams, axes, model_axis=None,
     return update
 
 
+def _dense_products(xl, start, rows: int, model_axis=None):
+    """The two products of a dense batch window, the task's ``rows`` at
+    ``start``: under the on-chip gate the window as the table lies,
+    ``(d, rows)`` rows in lanes (a bitcast of the column-major table),
+    materialised once, so XLA places it on chip and both products read it
+    there: the batch crosses HBM once a round, not once a product. No
+    control flow around it: inside a loop XLA carries the table row-major,
+    a copy of its size. Past the gate each product reads ``(rows, d)``
+    where it lies. Under TP ``d`` is the local feature shard: the margins
+    are partial dots added over the model axis, the gradient stays
+    local."""
+    onchip = _batch_onchip(rows, xl.shape[1])
+    if onchip:
+        xb = jax.lax.optimization_barrier(
+            jax.lax.dynamic_slice_in_dim(xl.T, start, rows, axis=1))
+    else:
+        xb = jax.lax.dynamic_slice_in_dim(xl, start, rows, axis=0)
+
+    def margins(coeffs):
+        with jax.named_scope("sgd.margins"):
+            # d == coeffs length unless sharded padding
+            d = xb.shape[0 if onchip else 1]
+            w = coeffs if model_axis is not None else coeffs[:d]
+            dots = w @ xb if onchip else xb @ w
+            if model_axis is not None:
+                dots = mr.reduce_sum(dots, model_axis)
+        return dots
+
+    def gradient(multipliers):
+        with jax.named_scope("sgd.gradient"):
+            # local feature shard under TP
+            return xb @ multipliers if onchip else xb.T @ multipliers
+
+    return margins, gradient
+
+
+def _sparse_products(xl, start, rows: int, sparse):
+    """The two products of a sparse batch window: ``xl`` is a task's
+    ``(ids, values)``, each ``(local_n, k)`` as the column lies, and the
+    window their ``(k, rows)`` slice at ``start`` (a bitcast of the
+    column-major arrays, as the dense window is). Under the on-chip gate
+    both slices are made once, as the dense window is, and both products
+    read them there."""
+    window = tuple(jax.lax.dynamic_slice_in_dim(a.T, start, rows, axis=1)
+                   for a in xl)
+    if _batch_onchip(2 * rows, xl[0].shape[1]):
+        window = jax.lax.optimization_barrier(window)
+    return sparse_window.products(*window, sparse.size, sparse.hot)
+
+
 #: the largest batch window, in padded bytes, that a round brings on chip
 #: once (:func:`_batch_onchip`). Read from the TPU compiler ahead of time
 #: for a v5e (``scripts/round_forms.py --gate``): XLA keeps the made window
@@ -254,7 +300,8 @@ def _batch_onchip(rows: int, d: int) -> bool:
 
 def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
                     model_axis=None, sharded: bool = False,
-                    weighted: bool = True, n_valid: Optional[int] = None):
+                    weighted: bool = True, n_valid: Optional[int] = None,
+                    sparse: Optional[sparse_window.Layout] = None):
     """The per-shard math of ONE training round — shared verbatim by the
     all-device while_loop program and the host-driven round program so the
     two modes stay numerically identical by construction.
@@ -277,7 +324,12 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
     axis: the per-sample margins are partial dots psum'd over the model
     axis (every loss here is margin-decomposable, LossFunc.terms), the
     gradient matvec and the coefficient update stay local to the feature
-    shard, and the loss/weight reduction crosses the data axes only."""
+    shard, and the loss/weight reduction crosses the data axes only.
+
+    With ``sparse`` (a ``DeviceSparseColumn``'s ``size`` and ``hot``
+    index) ``xl`` is the column's ``(ids, values)`` pair and the window's
+    products are the sparse ones (:func:`_sparse_products`); the schedule,
+    the validity mask, the update and the carry are this same code."""
     gb = prm.global_batch_size
     lb_base, lb_rem = gb // p, gb % p
     update = _sgd_update_math(loss_func, prm, axes, model_axis,
@@ -285,7 +337,8 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
 
     @jax.named_scope("sgd.round")
     def round_step(xl, yl, wl, coeffs, opt, offset):
-        local_n = xl.shape[0]  # static at trace time
+        values = xl if sparse is None else xl[1]
+        local_n = values.shape[0]  # static at trace time
         lb_max = _local_batch(prm, p, local_n)
         task_id = mr.shard_index(axes)
         # ref SGD.java:206-213 — low task ids take the remainder
@@ -302,37 +355,37 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
         # are both `weights * ...`) zero their loss and gradient exactly;
         # the batch values themselves need no masking.
         start = jnp.minimum(offset, local_n - lb_max)
-        onchip = _batch_onchip(lb_max, xl.shape[1])
-        if onchip:
-            # the window as the table lies, (d, lb_max) rows in lanes (a
-            # bitcast of the column-major table), materialised once: XLA
-            # places it on chip and both products read it there, so the
-            # batch crosses HBM once a round, not once a product. No
-            # control flow around it: inside a loop XLA carries the table
-            # row-major, a copy of its size
-            xb = jax.lax.optimization_barrier(
-                jax.lax.dynamic_slice_in_dim(xl.T, start, lb_max, axis=1))
-        else:
-            xb = jax.lax.dynamic_slice_in_dim(xl, start, lb_max, axis=0)
+        products = (_dense_products(xl, start, lb_max, model_axis)
+                    if sparse is None
+                    else _sparse_products(xl, start, lb_max, sparse))
         yb = jax.lax.dynamic_slice_in_dim(yl, start, lb_max, axis=0)
         ws = (jax.lax.dynamic_slice_in_dim(wl, start, lb_max, axis=0)
               if weighted else None)
         src = start + jnp.arange(lb_max)
         valid = jnp.logical_and(src >= offset, src < offset + lb)
         if weighted:
-            wb = ws * valid.astype(xl.dtype)
+            wb = ws * valid.astype(values.dtype)
         else:
             if n_valid is not None:
                 # the rows ensure_on_mesh padded on weigh nothing
                 valid = jnp.logical_and(
                     valid, task_id * local_n + src < n_valid)
-            wb = valid.astype(xl.dtype)
+            wb = valid.astype(values.dtype)
 
-        coeffs, opt, mean_loss = update(coeffs, opt, xb, yb, wb, onchip)
+        coeffs, opt, mean_loss = update(coeffs, opt, products, yb, wb)
         new_offset = jnp.where(offset + lb >= local_n, 0, offset + lb)
         return coeffs, opt, new_offset, mean_loss
 
     return round_step
+
+
+def _table_spec(spec0, model_axis, sparse):
+    """The table operand's spec: rows over the data axes (features over
+    the model axis under TP), or a sparse column's two arrays, rows over
+    the data axes."""
+    if sparse is None:
+        return P(spec0, model_axis)
+    return (P(spec0, None), P(spec0, None))
 
 
 @functools.lru_cache(maxsize=128)
@@ -343,7 +396,8 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
                                fused: bool = False,
                                weighted: bool = True,
                                n_valid: Optional[int] = None,
-                               fresh: bool = False):
+                               fresh: bool = False,
+                               sparse: Optional[sparse_window.Layout] = None):
     """A K-round slice of the training loop as ONE compiled SPMD program:
     ``segment(xs, ys, ws, coeffs, offsets, opt, epoch0, limit, hist,
     fin) -> (coeffs, offsets, opt, mean_loss, epoch, stop, hist, fin)``.
@@ -376,7 +430,10 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     Without ``weighted`` the fit has no weight column: ``ws`` is ``None``,
     the program takes no weight operand and a row's weight is its
     validity in the round's batch (:func:`_sgd_round_math`, where
-    ``n_valid`` is explained too).
+    ``n_valid`` is explained too). With ``sparse`` ``xs`` is a device
+    sparse column's ``(ids, values)`` pair, each row-sharded as a dense
+    table is, and every round's products are the sparse window's (the same
+    function): the carries, the fresh start and the outputs do not change.
 
     With ``health`` (observability/health.py), the signature grows two
     trailing carries and each round writes its ``(loss, update norm,
@@ -403,7 +460,7 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     wspec = P(model_axis) if model_axis else P()
     round_step = _sgd_round_math(loss_cls(), prm, p, axes, model_axis,
                                  sharded=sharded, weighted=weighted,
-                                 n_valid=n_valid)
+                                 n_valid=n_valid, sparse=sparse)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
     def run(xl, yl, wl, coeffs, offsets, opt, epoch0, limit, hist, fin):
@@ -481,8 +538,8 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     scalar_out = (P(),) if fused else (P(), P())
     return mr.map_shards(
         sgd_segment, mesh,
-        in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec)
-        + carry_in,
+        in_specs=(_table_spec(spec0, model_axis, sparse), P(spec0), P(spec0),
+                  wspec) + carry_in,
         out_specs=(wspec, P(spec0), opt_specs, P()) + scalar_out
         + extra_out,
         donate_argnums=donate,
@@ -493,12 +550,14 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
 def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
                              sharded: bool = False,
                              weighted: bool = True,
-                             n_valid: Optional[int] = None):
+                             n_valid: Optional[int] = None,
+                             sparse: Optional[sparse_window.Layout] = None):
     """ONE training round as a compiled mapped program — the building
     block of the checkpointable host loop (iterate_bounded calls it as it
     is: nothing is jitted per fit). Wraps the same _sgd_round_math as the
     all-device program, so device and host modes are numerically
-    identical by construction (``weighted`` and ``n_valid`` as there)."""
+    identical by construction (``weighted``, ``n_valid`` and ``sparse``
+    as there)."""
     axes = data_axes(mesh)
     spec0 = data_pspec(mesh)
     p = data_shard_count(mesh)
@@ -506,7 +565,7 @@ def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
     wspec = P(model_axis) if model_axis else P()
     round_step = _sgd_round_math(loss_cls(), prm, p, axes, model_axis,
                                  sharded=sharded, weighted=weighted,
-                                 n_valid=n_valid)
+                                 n_valid=n_valid, sparse=sparse)
     opt_specs = _opt_specs(prm, wspec, spec0, sharded)
 
     def sgd_round(xl, yl, wl, coeffs, offsets, opt):
@@ -516,8 +575,8 @@ def _build_sgd_round_program(loss_cls, mesh: Mesh, prm: SGDParams,
 
     return mr.map_shards(
         sgd_round, mesh,
-        in_specs=(P(spec0, model_axis), P(spec0), P(spec0), wspec,
-                  P(spec0), opt_specs),
+        in_specs=(_table_spec(spec0, model_axis, sparse), P(spec0),
+                  P(spec0), wspec, P(spec0), opt_specs),
         out_specs=(wspec, P(spec0), P(), opt_specs))
 
 
@@ -577,23 +636,37 @@ def _finish_fit_health(algo: str, health_on: bool, hist, fin, epochs,
         _health.guard_final_state(algo, coeffs_host, loss=mean_loss)
 
 
-def _batch_form(prm: SGDParams, mesh: Mesh, n: int, d: int) -> str:
+def _batch_form(prm: SGDParams, mesh: Mesh, n: int, d: int,
+                arrays: int = 1) -> str:
     """``"onchip"`` where a fit's rounds read their batch from HBM once
     (:func:`_batch_onchip` of a task's window over its shard of ``n`` rows
-    and of ``d`` features), else ``"hbm"``: the ``batch`` attribute of
-    ``sgd.optimize`` and ``sgd.launch``."""
+    and of ``d`` features, in each of ``arrays`` arrays: a sparse column's
+    window is its ids' and its values'), else ``"hbm"``: the ``batch``
+    attribute of ``sgd.optimize`` and ``sgd.launch``."""
     p = data_shard_count(mesh)
     model_axis = model_axis_of(mesh)
     tp = int(mesh.shape[model_axis]) if model_axis else 1
     rows = _local_batch(prm, p, -(-n // p))
-    return "onchip" if _batch_onchip(rows, -(-d // tp)) else "hbm"
+    return "onchip" if _batch_onchip(arrays * rows, -(-d // tp)) else "hbm"
 
 
-def _count_batch_reads(batch: str, rounds: int) -> None:
+def _count_batch_reads(batch: str, rounds: int, entries: int = 0) -> int:
     """``ml.sgd batchReads``: the HBM reads of a round's batch a fit made,
-    one a round on chip, one a product (two) past the gate."""
-    metrics.group(ML_GROUP, "sgd").counter(
-        "batchReads", rounds * (1 if batch == "onchip" else 2))
+    one a round on chip, one a product (two) past the gate, which it
+    returns; and for a sparse fit ``sparseEntries``: the ``entries`` its
+    rounds' windows hold, each gathered once and scattered once."""
+    group = metrics.group(ML_GROUP, "sgd")
+    reads = rounds * (1 if batch == "onchip" else 2)
+    group.counter("batchReads", reads)
+    if entries:
+        group.counter("sparseEntries", rounds * entries)
+    return reads
+
+
+#: ``last_execution_path`` of a sparse fit, by the dense path it shares
+_SPARSE_PATHS = {"xla-while": "sparse-device",
+                 "xla-while-segments": "sparse-device-segments",
+                 "host-rounds": "sparse-host-rounds"}
 
 
 class SGD:
@@ -711,6 +784,43 @@ class SGD:
                 _health.guard_final_state(algo, coeffs, loss=mean_loss)
         return coeffs, mean_loss
 
+    def optimize_sparse(self, loss_func: LossFunc, init_coeffs: np.ndarray,
+                        column, labels, weights=None,
+                        mesh: Optional[Mesh] = None, config=None,
+                        listeners=(), tag: Optional[str] = None):
+        """A fit over a ``DeviceSparseColumn`` (``linalg/sparse.py``) where
+        it lies: :meth:`optimize`'s schedule, programs, carries, paths and
+        spans, one program a plain fit started on the device, with the
+        sparse window's gather and scatter in each round in place of the
+        dense products (``ops/sparse_window.py``). Returns (coeffs
+        ``(size,)`` np.ndarray, final mean loss float).
+
+        ``sgd.optimize`` carries ``form``, the gradient's form
+        (``sparse_window.form``), and ``path``: ``sparse-device``,
+        ``sparse-device-segments`` or ``sparse-host-rounds``, and
+        ``batch_reads``, the HBM reads of a round's batch the fit made
+        (``ml.sgd batchReads``'s part). A data mesh only: under a model
+        axis the coefficients would be split, and a gather over them is
+        not."""
+        mesh = mesh or default_mesh()
+        if model_axis_of(mesh) is not None:
+            raise ValueError("a sparse device fit runs over a data mesh, "
+                             "not one with a model axis")
+        batch = _batch_form(self.params, mesh, len(column), column.entries,
+                            arrays=2)
+        with tracer.span("sgd.optimize", rounds=self.params.max_iter,
+                         shards=data_shard_count(mesh),
+                         weights="unit" if weights is None else "column",
+                         batch=batch,
+                         form=sparse_window.form(column.hot)) as sp:
+            out = self._optimize(
+                loss_func, init_coeffs, column, labels, weights, mesh,
+                jnp.float32, config, listeners, tag, batch,
+                sparse=sparse_window.Layout(column.size, column.hot))
+            sp.set_attribute("path", self.last_execution_path)
+            sp.set_attribute("batch_reads", self.last_batch_reads)
+            return out
+
     def optimize(self, loss_func: LossFunc, init_coeffs: np.ndarray,
                  features: np.ndarray, labels: np.ndarray,
                  weights: Optional[np.ndarray] = None,
@@ -775,11 +885,14 @@ class SGD:
                     vals)
 
     def _optimize(self, loss_func, init_coeffs, features, labels, weights,
-                  mesh, dtype, config, listeners, tag, batch):
+                  mesh, dtype, config, listeners, tag, batch, sparse=None):
         algo = _health_tag(loss_func, tag)
         health_on = _health.armed()
         n = features.shape[0]
         d = features.shape[1]
+
+        def path(name):
+            return name if sparse is None else _SPARSE_PATHS[name]
 
         axes = data_axes(mesh)
         p = data_shard_count(mesh)
@@ -823,7 +936,14 @@ class SGD:
                 # device-resident features/labels (device datagen or a
                 # previous device stage) stay on device end-to-end — no
                 # host round-trip
-                xs, _ = ensure_on_mesh(mesh, features, axes, jnp.float32)
+                if sparse is not None:
+                    xs = (ensure_on_mesh(mesh, features.ids, axes,
+                                         jnp.int32)[0],
+                          ensure_on_mesh(mesh, features.values, axes,
+                                         jnp.float32)[0])
+                else:
+                    xs, _ = ensure_on_mesh(mesh, features, axes,
+                                           jnp.float32)
             ys, _ = ensure_on_mesh(mesh, labels, axes, jnp.float32)
             # no weight column: none is built. The programs take no
             # weight operand and a row weighs 1 where its round's batch
@@ -834,6 +954,10 @@ class SGD:
                 ws, _ = ensure_on_mesh(mesh, weights, axes, jnp.float32)
             elif n % p:
                 n_valid = n
+        # the entries a round's windows hold: a sparse fit's count
+        entries = (0 if sparse is None else
+                   p * _local_batch(self.params, p, -(-n // p))
+                   * features.entries)
         from flink_ml_tpu.iteration.iteration import (
             device_checkpoint_segment, needs_host_loop, run_segmented)
 
@@ -906,7 +1030,8 @@ class SGD:
                                                       fused=fused,
                                                       weighted=weighted,
                                                       n_valid=n_valid,
-                                                      fresh=fresh)
+                                                      fresh=fresh,
+                                                      sparse=sparse)
                 # health carry lives OUTSIDE the checkpointed carry so the
                 # snapshot format is identical with telemetry on or off; a
                 # restore simply resumes the series at its epoch (earlier
@@ -993,10 +1118,10 @@ class SGD:
                 out, mean_loss, vals = self._fetch_result(
                     coeffs, d, mean_loss, boundary)
                 crossed(vals)
-            self.last_execution_path = ("xla-while-segments" if seg_k
-                                        else "xla-while")
-            _count_batch_reads(batch,
-                               hstate["epoch"] - (hstate["first"] or 0))
+            self.last_execution_path = path("xla-while-segments" if seg_k
+                                            else "xla-while")
+            self.last_batch_reads = _count_batch_reads(
+                batch, hstate["epoch"] - (hstate["first"] or 0), entries)
             with tracer.span("sgd.health"):
                 _finish_fit_health(
                     algo, health_on, hstate["hist"], hstate["fin"],
@@ -1009,7 +1134,7 @@ class SGD:
         with tracer.span("sgd.build_program"):
             round_fn = _build_sgd_round_program(
                 type(loss_func), mesh, self.params, sharded=sharded,
-                weighted=weighted, n_valid=n_valid)
+                weighted=weighted, n_valid=n_valid, sparse=sparse)
 
         rounds = [0]
 
@@ -1038,8 +1163,9 @@ class SGD:
                 terminate=lambda carry, epoch: carry[2] < self.params.tol,
                 config=config, listeners=listeners, jit_round=False)
         coeffs, _, mean_loss, _ = final
-        self.last_execution_path = "host-rounds"
-        _count_batch_reads(batch, rounds[0])
+        self.last_execution_path = path("host-rounds")
+        self.last_batch_reads = _count_batch_reads(batch, rounds[0],
+                                                   entries)
         out, mean_loss, _ = self._fetch_result(coeffs, d, mean_loss)
         with tracer.span("sgd.health"):
             if not health_on:
