@@ -131,11 +131,18 @@ class DeviceSparseColumn:
     sharding, their layout, no copy). A padding entry has value 0 and any id in
     ``[0, size)``; two entries of a row in one bucket add.
 
-    ``hot`` is the index :func:`device_sparse_column` made: ``((entry,
-    bucket), ...)`` for each entry position whose id is one bucket on every
-    row (a numeric field, which ``FeatureHasher`` hashes by its name). The
-    device fit sums those entries as columns instead of scattering them
-    (``ops/sparse_window.py``). A row subset keeps the index.
+    ``hot`` and ``narrow`` are the index :func:`device_sparse_column` made:
+    ``hot`` is ``((entry, bucket), ...)`` for each entry position whose id
+    is one bucket on every row (a numeric field, which ``FeatureHasher``
+    hashes by its name); ``narrow`` is ``((entry, slots), ...)`` for each
+    other position whose ids lie in at most ``sparse_window.NARROW_MAX``
+    buckets over the whole table, its dictionary the first ``slots`` of its
+    row of ``dicts``, a ``(k, NARROW_MAX)`` int32 device array (``NARROW_MAX``
+    up to a multiple of ``CHUNK``) of each position's buckets, padded with
+    -1. The device fit sums hot entries as
+    columns and takes narrow ones by their dictionaries instead of
+    gathering and scattering them (``ops/sparse_window.py``). A row subset
+    keeps the index: its ids are a subset.
 
     Row slices and takes stay on the device. Nothing here copies the
     column to the host but :meth:`to_csr`, the explicit off-ramp: an
@@ -144,10 +151,14 @@ class DeviceSparseColumn:
     #: duck-type marker (Table, is_sparse_column)
     is_device_sparse_column = True
 
-    def __init__(self, ids, values, size: int, hot=()):
+    def __init__(self, ids, values, size: int, hot=(), narrow=(),
+                 dicts=None):
+        if narrow and dicts is None:
+            raise ValueError("a narrow index comes with its dictionaries")
         self.ids, self.values = ids, values
         self.size = int(size)
         self.hot = tuple(hot)
+        self.narrow, self.dicts = tuple(narrow), dicts
 
     def __len__(self):
         return self.ids.shape[0]
@@ -168,7 +179,8 @@ class DeviceSparseColumn:
 
     def _rows(self, take) -> "DeviceSparseColumn":
         return DeviceSparseColumn(take(self.ids), take(self.values),
-                                  self.size, self.hot)
+                                  self.size, self.hot, self.narrow,
+                                  self.dicts)
 
     def __getitem__(self, key):
         from flink_ml_tpu.ops import columnar
@@ -205,26 +217,85 @@ class DeviceSparseColumn:
 
     def __repr__(self):
         return (f"DeviceSparseColumn({len(self)} rows, size={self.size}, "
-                f"entries={self.entries}, hot={len(self.hot)})")
+                f"entries={self.entries}, hot={len(self.hot)}, "
+                f"narrow={len(self.narrow)})")
 
 
 def is_device_sparse_column(col) -> bool:
     return getattr(col, "is_device_sparse_column", False)
 
 
+#: rows at the head of a column whose ids propose each position's
+#: dictionary; every row of the table is then checked against it
+SAMPLE_ROWS = 1 << 20
+#: dictionary slots compared with a position's ids at a time in the check
+CHUNK = 128
+
+
+def dict_slots(buckets: int) -> int:
+    """A dictionary of ``buckets`` as the round program takes it: to the
+    sublane tile of 8."""
+    return -(-buckets // 8) * 8
+
+
 @functools.lru_cache(maxsize=None)
 @cold_build("sparse_index")
 def _index_program():
-    """``index(ids) -> (min, max, same, first)``: the range of the ids and,
-    per entry position, whether every row holds the first row's id; one
-    read of the ids where they lie."""
+    """``index(ids) -> (min, max, same, first, narrow, buckets, dicts)``:
+    the range of the ids; per entry position whether every row holds the
+    first row's id (``same``, ``first``); and per position whether its ids
+    lie in at most ``NARROW_MAX`` buckets over the whole table
+    (``narrow``), how many buckets its sample holds (``buckets``) and those
+    buckets, ascending and padded with -1 (``dicts``, ``(k,
+    NARROW_MAX)``, the width up to a multiple of ``CHUNK``). A position's candidates are the distinct ids of the
+    column's first ``SAMPLE_ROWS`` rows (sorted on the device); it is
+    narrow only if every row's id is one of them, counted by comparing each
+    row with each candidate. Hot positions are not narrow."""
     import jax
     import jax.numpy as jnp
 
+    from flink_ml_tpu.ops.sparse_window import NARROW_MAX
+
+    width = -(-NARROW_MAX // CHUNK) * CHUNK
+
     def sparse_index(ids):
+        n, k = ids.shape
         first = ids[0]
-        return (jnp.min(ids), jnp.max(ids),
-                jnp.all(ids == first[None, :], axis=0), first)
+        same = jnp.all(ids == first[None, :], axis=0)
+        ordered = jax.lax.sort(ids[:min(n, SAMPLE_ROWS)].T, dimension=1)
+        new = jnp.concatenate([jnp.ones((k, 1), bool),
+                               ordered[:, 1:] != ordered[:, :-1]], axis=1)
+        buckets = jnp.sum(new, axis=1, dtype=jnp.int32)
+        # slot s holds the ids' (s + 1)-th distinct value: where the count
+        # of distinct values so far reaches s + 1
+        slot = jnp.arange(1, width + 1, dtype=jnp.int32)
+        at = jax.vmap(lambda seen: jnp.searchsorted(seen, slot))(
+            jnp.cumsum(new, axis=1, dtype=jnp.int32))
+        dicts = jnp.where(slot[None, :] <= buckets[:, None],
+                          jnp.take_along_axis(
+                              ordered, jnp.minimum(at, ordered.shape[1] - 1),
+                              axis=1), -1)
+        candidate = jnp.logical_and(buckets <= NARROW_MAX,
+                                    jnp.logical_not(same))
+        # the check, one chunk of one candidate's dictionary a step: step
+        # t takes chunk c of position j, read from the ids as they lie
+        trips = jnp.where(candidate, -(-buckets // CHUNK), 0)
+        ends = jnp.cumsum(trips)
+        lanes = ids.T
+
+        def chunk(t, seen):
+            j = jnp.sum(ends <= t)
+            c = t - (ends[j] - trips[j])
+            row = jax.lax.dynamic_index_in_dim(lanes, j, keepdims=False)
+            d = jax.lax.dynamic_slice(dicts, (j, c * CHUNK), (1, CHUNK))
+            hits = jnp.sum(row[None, :] == d[0][:, None], dtype=jnp.int32)
+            return seen + jnp.where(jnp.arange(k) == j, hits, 0)
+
+        matched = jax.lax.fori_loop(0, ends[-1], chunk,
+                                    jnp.zeros((k,), jnp.int32))
+        narrow = jnp.logical_and(candidate, matched == n)
+        return (jnp.min(ids), jnp.max(ids), same, first, narrow, buckets,
+                dicts)
 
     return jax.jit(sparse_index)
 
@@ -233,9 +304,19 @@ def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
     """The one entry for a sparse column made on the device: ``ids`` and
     ``values``, ``(n, k)`` ``jax.Array`` objects of int32 and float32, and
     the vectors' ``size``. The arrays are kept as they are; one program reads
-    the ids once, refuses an id outside ``[0, size)`` and finds the entry
+    the ids, refuses an id outside ``[0, size)`` and finds the entry
     positions that hold one bucket on every row (``DeviceSparseColumn.
-    hot``), a few numbers to the host."""
+    hot``) and those that hold few (``narrow``, with their dictionaries,
+    which stay on the device, replicated where the ids lie), a few numbers
+    to the host.
+
+    Both indexes key on entry positions, so they pay only where a column
+    keeps one field at each position of every row, as the click-through
+    benchmark's generator (``benchmarks/harness/generators/
+    CriteoHashedGenerator.py``) lays its hashed fields. A column whose entries are sorted by bucket and merged, as
+    ``FeatureHasher``'s CSR rows are, holds no position of one field: no
+    position is hot or narrow there, and every entry is gathered and
+    scattered."""
     import jax
 
     if not (isinstance(ids, jax.Array) and isinstance(values, jax.Array)):
@@ -248,16 +329,27 @@ def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
         raise TypeError(f"ids must be int32 and values float32, not "
                         f"{ids.dtype} and {values.dtype}")
     size = int(size)
-    hot = ()
+    hot, narrow, dicts = (), (), None
     if ids.shape[0]:
         with tracer.span("sparse.index", rows=ids.shape[0],
-                         entries=ids.shape[1]):
-            lo, hi, same, first = jax.device_get(_index_program()(ids))
+                         entries=ids.shape[1]) as sp:
+            *found, dicts = _index_program()(ids)
+            lo, hi, same, first, is_narrow, buckets = jax.device_get(found)
+            sp.set_attribute("narrow", int(np.sum(is_narrow)))
         if lo < 0 or hi >= size:
             raise ValueError(f"ids lie in [{lo}, {hi}], outside the "
                              f"column's [0, {size})")
-        hot = tuple((j, int(first[j])) for j in np.flatnonzero(same))
-    return DeviceSparseColumn(ids, values, size, hot)
+        hot = tuple((int(j), int(first[j])) for j in np.flatnonzero(same))
+        narrow = tuple((int(j), dict_slots(int(buckets[j])))
+                       for j in np.flatnonzero(is_narrow))
+        if not narrow:
+            dicts = None
+        elif isinstance(ids.sharding, jax.sharding.NamedSharding):
+            # whole on every device of the ids' mesh, as the fit takes them
+            from flink_ml_tpu.parallel.collective import replicate
+
+            dicts = replicate(ids.sharding.mesh, dicts)
+    return DeviceSparseColumn(ids, values, size, hot, narrow, dicts)
 
 
 def column_moments(m):

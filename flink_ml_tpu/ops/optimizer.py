@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -55,7 +56,7 @@ from flink_ml_tpu.parallel.mesh import (
 )
 from flink_ml_tpu.parallel import mapreduce as mr
 from flink_ml_tpu.parallel import update_sharding as _upd
-from flink_ml_tpu.parallel.collective import ensure_on_mesh
+from flink_ml_tpu.parallel.collective import ensure_on_mesh, replicate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,16 +262,18 @@ def _dense_products(xl, start, rows: int, model_axis=None):
 
 def _sparse_products(xl, start, rows: int, sparse):
     """The two products of a sparse batch window: ``xl`` is a task's
-    ``(ids, values)``, each ``(local_n, k)`` as the column lies, and the
+    ``(ids, values)``, each ``(local_n, k)`` as the column lies (and with a
+    narrow index the column's dictionaries, whole on every task), and the
     window their ``(k, rows)`` slice at ``start`` (a bitcast of the
     column-major arrays, as the dense window is). Under the on-chip gate
     both slices are made once, as the dense window is, and both products
     read them there."""
     window = tuple(jax.lax.dynamic_slice_in_dim(a.T, start, rows, axis=1)
-                   for a in xl)
+                   for a in xl[:2])
     if _batch_onchip(2 * rows, xl[0].shape[1]):
         window = jax.lax.optimization_barrier(window)
-    return sparse_window.products(*window, sparse.size, sparse.hot)
+    return sparse_window.products(*window, sparse.size, sparse.hot,
+                                  sparse.narrow, *xl[2:])
 
 
 #: the largest batch window, in padded bytes, that a round brings on chip
@@ -382,10 +385,12 @@ def _sgd_round_math(loss_func, prm: SGDParams, p: int, axes,
 def _table_spec(spec0, model_axis, sparse):
     """The table operand's spec: rows over the data axes (features over
     the model axis under TP), or a sparse column's two arrays, rows over
-    the data axes."""
+    the data axes, and with a narrow index its dictionaries, whole on every
+    task."""
     if sparse is None:
         return P(spec0, model_axis)
-    return (P(spec0, None), P(spec0, None))
+    return (P(spec0, None), P(spec0, None)) + ((P(),) if sparse.narrow
+                                               else ())
 
 
 @functools.lru_cache(maxsize=128)
@@ -432,8 +437,9 @@ def _build_sgd_segment_program(loss_cls, mesh: Mesh, prm: SGDParams,
     validity in the round's batch (:func:`_sgd_round_math`, where
     ``n_valid`` is explained too). With ``sparse`` ``xs`` is a device
     sparse column's ``(ids, values)`` pair, each row-sharded as a dense
-    table is, and every round's products are the sparse window's (the same
-    function): the carries, the fresh start and the outputs do not change.
+    table is (and ``dicts``, replicated, where ``sparse.narrow``), and every
+    round's products are the sparse window's (the same function): the
+    carries, the fresh start and the outputs do not change.
 
     With ``health`` (observability/health.py), the signature grows two
     trailing carries and each round writes its ``(loss, update norm,
@@ -650,17 +656,45 @@ def _batch_form(prm: SGDParams, mesh: Mesh, n: int, d: int,
     return "onchip" if _batch_onchip(arrays * rows, -(-d // tp)) else "hbm"
 
 
-def _count_batch_reads(batch: str, rounds: int, entries: int = 0) -> int:
+def _count_batch_reads(batch: str, rounds: int, entries: int = 0,
+                       dict_entries: int = 0) -> int:
     """``ml.sgd batchReads``: the HBM reads of a round's batch a fit made,
     one a round on chip, one a product (two) past the gate, which it
     returns; and for a sparse fit ``sparseEntries``: the ``entries`` its
-    rounds' windows hold, each gathered once and scattered once."""
+    rounds' windows hold, each gathered once and scattered once (or summed
+    as a column), and ``dictEntries``: the ``dict_entries`` of those the
+    dictionary form took."""
     group = metrics.group(ML_GROUP, "sgd")
     reads = rounds * (1 if batch == "onchip" else 2)
     group.counter("batchReads", reads)
     if entries:
         group.counter("sparseEntries", rounds * entries)
+        group.counter("dictEntries", rounds * dict_entries)
     return reads
+
+
+#: program -> {abstract signature: its gradient's operations}
+_GRADIENT_OPS = weakref.WeakKeyDictionary()
+
+
+def _note_gradient_ops(span, prog, sparse, *args) -> None:
+    """Name on a recording ``sgd.launch`` span of a sparse fit, as
+    ``gradient_ops``, the operations its compiled program runs for the
+    gradient (``sparse_window.gradient_ops``): read once a program and
+    signature from the executable the call dispatches (``lower`` and
+    ``compile`` of the call's own operands find it in jit's caches), and
+    never where no span records."""
+    if sparse is None or not tracer.active:
+        return
+    from flink_ml_tpu.observability.compilestats import abstract_signature
+
+    known = _GRADIENT_OPS.setdefault(prog, {})
+    sig = abstract_signature(args)
+    if sig not in known:
+        jitted = getattr(prog, "_jitted", prog)
+        known[sig] = sparse_window.gradient_ops(
+            jitted.lower(*args).compile().as_text())
+    span.set_attribute("gradient_ops", known[sig])
 
 
 #: ``last_execution_path`` of a sparse fit, by the dense path it shares
@@ -796,10 +830,16 @@ class SGD:
         ``(size,)`` np.ndarray, final mean loss float).
 
         ``sgd.optimize`` carries ``form``, the gradient's form
-        (``sparse_window.form``), and ``path``: ``sparse-device``,
-        ``sparse-device-segments`` or ``sparse-host-rounds``, and
+        (``sparse_window.form``), ``narrow``, the entry positions the
+        dictionary form takes, and ``path``: ``sparse-device``,
+        ``sparse-device-segments`` or ``sparse-host-rounds``;
         ``batch_reads``, the HBM reads of a round's batch the fit made
-        (``ml.sgd batchReads``'s part). A data mesh only: under a model
+        (``ml.sgd batchReads``'s part); ``entries``, the window entries
+        its rounds held (``sparseEntries``'), and ``dict_entries``, those
+        of them the dictionary form took (``dictEntries``'). A recording
+        ``sgd.launch`` of the compiled paths carries ``gradient_ops``, the
+        names of the operations the program runs for the gradient
+        (``sparse_window.gradient_ops``). A data mesh only: under a model
         axis the coefficients would be split, and a gather over them is
         not."""
         mesh = mesh or default_mesh()
@@ -812,13 +852,17 @@ class SGD:
                          shards=data_shard_count(mesh),
                          weights="unit" if weights is None else "column",
                          batch=batch,
-                         form=sparse_window.form(column.hot)) as sp:
+                         form=sparse_window.form(column.hot, column.narrow),
+                         narrow=len(column.narrow)) as sp:
             out = self._optimize(
                 loss_func, init_coeffs, column, labels, weights, mesh,
                 jnp.float32, config, listeners, tag, batch,
-                sparse=sparse_window.Layout(column.size, column.hot))
+                sparse=sparse_window.Layout(column.size, column.hot,
+                                            column.narrow))
             sp.set_attribute("path", self.last_execution_path)
             sp.set_attribute("batch_reads", self.last_batch_reads)
+            sp.set_attribute("entries", self.last_entries[0])
+            sp.set_attribute("dict_entries", self.last_entries[1])
             return out
 
     def optimize(self, loss_func: LossFunc, init_coeffs: np.ndarray,
@@ -941,6 +985,14 @@ class SGD:
                                          jnp.int32)[0],
                           ensure_on_mesh(mesh, features.values, axes,
                                          jnp.float32)[0])
+                    if sparse.narrow:
+                        # made whole on the column's mesh: moved only
+                        # where the fit runs on another
+                        dicts = features.dicts
+                        if not dicts.sharding.is_equivalent_to(
+                                NamedSharding(mesh, P()), dicts.ndim):
+                            dicts = replicate(mesh, dicts)
+                        xs += (dicts,)
                 else:
                     xs, _ = ensure_on_mesh(mesh, features, axes,
                                            jnp.float32)
@@ -954,10 +1006,12 @@ class SGD:
                 ws, _ = ensure_on_mesh(mesh, weights, axes, jnp.float32)
             elif n % p:
                 n_valid = n
-        # the entries a round's windows hold: a sparse fit's count
-        entries = (0 if sparse is None else
-                   p * _local_batch(self.params, p, -(-n // p))
-                   * features.entries)
+        # the entries a round's windows hold, and those the dictionary
+        # form takes: a sparse fit's counts
+        rows = (0 if sparse is None else
+                p * _local_batch(self.params, p, -(-n // p)))
+        entries = rows * (0 if sparse is None else features.entries)
+        dict_entries = rows * (0 if sparse is None else len(sparse.narrow))
         from flink_ml_tpu.iteration.iteration import (
             device_checkpoint_segment, needs_host_loop, run_segmented)
 
@@ -1051,10 +1105,13 @@ class SGD:
                     hstate["first"] = int(epoch0)
                 health_in = ((hstate["hist"], np.bool_(hstate["fin"]))
                              if health_on else ())
-                with tracer.span("sgd.launch", start="carry", batch=batch):
+                operands = (xs, ys, ws, coeffs, offsets, opt,
+                            np.int32(epoch0), np.int32(limit), *health_in)
+                with tracer.span("sgd.launch", start="carry",
+                                 batch=batch) as sp:
+                    _note_gradient_ops(sp, seg_prog, sparse, *operands)
                     coeffs, offsets, opt, mean_loss, *tail = seg_prog(
-                        xs, ys, ws, coeffs, offsets, opt,
-                        np.int32(epoch0), np.int32(limit), *health_in)
+                        *operands)
                 # fused, the boundary is ONE stacked [epoch, stop(, fin)]
                 # vector instead of a transfer a scalar
                 if not health_on:
@@ -1107,7 +1164,9 @@ class SGD:
                     # jit's own argument path places the coefficients:
                     # the one transfer a device this start costs
                     with tracer.span("sgd.launch", start="fresh",
-                                     batch=batch):
+                                     batch=batch) as sp:
+                        _note_gradient_ops(sp, seg_prog, sparse, xs, ys, ws,
+                                           w0)
                         coeffs, _, opt, mean_loss, *boundary = seg_prog(
                             xs, ys, ws, w0)
                     # the returned leaves' metadata: nothing is waited on
@@ -1120,8 +1179,10 @@ class SGD:
                 crossed(vals)
             self.last_execution_path = path("xla-while-segments" if seg_k
                                             else "xla-while")
+            done = hstate["epoch"] - (hstate["first"] or 0)
             self.last_batch_reads = _count_batch_reads(
-                batch, hstate["epoch"] - (hstate["first"] or 0), entries)
+                batch, done, entries, dict_entries)
+            self.last_entries = (done * entries, done * dict_entries)
             with tracer.span("sgd.health"):
                 _finish_fit_health(
                     algo, health_on, hstate["hist"], hstate["fin"],
@@ -1165,7 +1226,8 @@ class SGD:
         coeffs, _, mean_loss, _ = final
         self.last_execution_path = path("host-rounds")
         self.last_batch_reads = _count_batch_reads(batch, rounds[0],
-                                                   entries)
+                                                   entries, dict_entries)
+        self.last_entries = (rounds[0] * entries, rounds[0] * dict_entries)
         out, mean_loss, _ = self._fetch_result(coeffs, d, mean_loss)
         with tracer.span("sgd.health"):
             if not health_on:

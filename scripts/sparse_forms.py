@@ -24,7 +24,16 @@ scatter-add of 3.9M terms into 262,144 float32 buckets):
                      adds one entry at a time (ids and terms in SMEM)
 
 and of the margins (a gather of the coefficients at 3.9M ids and a sum a
-row): ``gather`` over every entry, ``split-gather`` the program's. Then,
+row): ``gather`` over every entry, ``split-gather`` the split form. The
+``dictionary`` form, the program's (gradient and margins): the narrow
+positions the column's index names (at most ``sparse_window.NARROW_MAX``
+buckets over the table) by their dictionaries' compare, select and sum, the
+hot ones as columns, the rest gathered and scattered; its two halves alone
+(``dict-part``: the narrow positions' compares, nothing else); and for each
+width of ``--dict-widths``, one position's ids against a dictionary of that
+many slots (compare, select and sum over the window's rows, both ways): a
+slot's cost a row, which with the serial cost of an entry (``split-scatter``
+over its entries) sets ``NARROW_MAX``. Then,
 for each width of ``--hot-sets``, each cold position's most frequent ids
 in the window taken by compare, select and sum and the rest scattered and
 gathered with those entries sent out of bounds (``gradient``,
@@ -33,10 +42,13 @@ gradient and margins form is held to float64 NumPy on the same window
 (``gap``: max abs difference over max abs), and a form that does not add
 every entry reads about 1. The program's round in place: the plain-fit
 program at 20 and 40 rounds with each gradient form it can build
-(``scatter``: no hot index; ``split-scatter``: the column's), a round the
-slope (``--no-in-program`` leaves it out).
+(``scatter``: no index; ``split-scatter``: the hot index alone;
+``split-dict-scatter``: the column's), a round the slope
+(``--no-in-program`` leaves it out). The first line also times the column's
+index program (``index_first_s`` with its compile, ``index_warm_s``).
 
     python scripts/sparse_forms.py [--calls 20] [--hot-sets 64,256,1024]
+                                   [--dict-widths 256,1024,2048,4096]
     python scripts/sparse_forms.py --rehearse --rows 200000 --batch 10000
 
 One JSON line a form; the lines also go to ``chiprun_out/sparse_forms.json``.
@@ -179,6 +191,45 @@ def margin_forms(size, hot):
     return {"gather": gather, "split-gather": split_gather}
 
 
+def dictionary_forms(column):
+    """``{name: (product, operand)}``: the program's two products with the
+    column's whole index (``dictionary``), and the narrow positions' part
+    of each alone (``dict-part``: an index with no hot entry and every
+    other position's ids and values left out)."""
+    size, hot, narrow = column.size, column.hot, column.narrow
+    at = np.asarray([j for j, _ in narrow])
+    only = tuple((i, slots) for i, (_, slots) in enumerate(narrow))
+
+    def full(which):
+        return lambda ids, vals, dicts, arg: sparse_window.products(
+            ids, vals, size, hot, narrow, dicts)[which](arg)
+
+    def part(which):
+        return lambda ids, vals, dicts, arg: sparse_window.products(
+            ids[at], vals[at], size, (), only, dicts[at])[which](arg)
+
+    return {("gradient", "dictionary"): full(1),
+            ("margins", "dictionary"): full(0),
+            ("gradient", "dict-part"): part(1),
+            ("margins", "dict-part"): part(0)}
+
+
+def width_forms(size, width):
+    """One position's window ids against a dictionary of ``width`` slots,
+    both ways, as ``sparse_window.products`` takes a narrow position."""
+    layout = ((0, width),)
+
+    def gradient(ids, vals, dicts, mult):
+        return sparse_window.products(ids, vals, size, (), layout,
+                                      dicts)[1](mult)
+
+    def margins(ids, vals, dicts, w):
+        return sparse_window.products(ids, vals, size, (), layout,
+                                      dicts)[0](w)
+
+    return gradient, margins
+
+
 def _pallas_scatter(ids, terms, size, block=2048):
     """The gradient as one ``(size / 128, 128)`` VMEM block over the whole
     grid; each step brings ``block`` ids and terms into SMEM and adds them
@@ -255,10 +306,14 @@ def in_place(column, label, mesh, batch, calls, out):
     """The plain-fit program with each form it builds, a round the slope
     between ``ROUNDS``."""
     w0 = np.zeros(column.size, np.float32)
-    xs = (column.ids, column.values)
     fits = {}
-    for name, hot in (("scatter", ()), ("split-scatter", column.hot)):
-        layout = sparse_window.Layout(column.size, hot)
+    layouts = {"scatter": sparse_window.Layout(column.size),
+               "split-scatter": sparse_window.Layout(column.size, column.hot),
+               "split-dict-scatter": sparse_window.Layout(
+                   column.size, column.hot, column.narrow)}
+    for name, layout in layouts.items():
+        xs = (column.ids, column.values) + ((column.dicts,) if layout.narrow
+                                            else ())
         for rounds in ROUNDS:
             prog = optimizer._build_sgd_segment_program(
                 BinaryLogisticLoss, mesh, optimizer.SGDParams(
@@ -268,7 +323,7 @@ def in_place(column, label, mesh, batch, calls, out):
             fits[name, rounds] = (timed(
                 lambda: prog(xs, label, None, w0), (), max(2, calls // 4)),
                 np.asarray(prog(xs, label, None, w0)[0]))
-    for name in ("scatter", "split-scatter"):
+    for name in layouts:
         (lo, _), (hi, _) = (fits[name, r][0] for r in ROUNDS)
         gap = np.abs(fits[name, ROUNDS[0]][1] - fits["scatter", ROUNDS[0]][1])
         out({"in_program": name, "fit_ms": {str(r): fits[name, r][0][0]
@@ -287,6 +342,10 @@ def main(argv=None):
                                                   v.split(",") if x],
                     default=[256, 1024],
                     help="widths of the per-position hot sets to time")
+    ap.add_argument("--dict-widths", type=lambda v: [int(x) for x in
+                                                     v.split(",") if x],
+                    default=[256, 1024, 2048, 4096],
+                    help="dictionary widths to time on one position")
     ap.add_argument("--no-in-program", dest="in_program",
                     action="store_false",
                     help="leave out the fit programs' round times")
@@ -307,6 +366,7 @@ def main(argv=None):
     k, size = column.entries, column.size
     out({"rows": args.rows, "batch": args.batch, "entries": k, "size": size,
          "hot": len(column.hot), "calls": args.calls,
+         "narrow": [list(p) for p in column.narrow],
          "device_kind": jax.devices()[0].device_kind, **made})
     window = jax.jit(lambda a: jax.lax.optimization_barrier(
         a.T[:, :args.batch]))
@@ -332,7 +392,41 @@ def main(argv=None):
         got = np.asarray(jax.jit(fn)(ids, vals, w), np.float64)
         out({"margins": name, "ms": ms, "sets_ms": sets,
              "gap": float(np.abs(got - dots).max() / np.abs(dots).max())})
+    for (product, name), fn in dictionary_forms(column).items():
+        arg = mult if product == "gradient" else w
+        try:
+            ms, sets = timed(jax.jit(fn), (ids, vals, column.dicts, arg),
+                             args.calls)
+            got = np.asarray(jax.jit(fn)(ids, vals, column.dicts, arg),
+                             np.float64)
+            line = {product: name, "ms": ms, "sets_ms": sets}
+            if name == "dictionary":
+                ref = want if product == "gradient" else dots
+                line["gap"] = float(np.abs(got - ref).max()
+                                    / np.abs(ref).max())
+            out(line)
+        except Exception as exc:  # noqa: BLE001 — a failure is a reading
+            out({product: name, "failed": repr(exc)[:300]})
     cold = _split(column.hot, k)[0]
+    for width in args.dict_widths:
+        # the first cold position's ids against ``width`` slots, its
+        # distinct ids first (a row matches one slot, as in the program)
+        found = np.unique(ids_h[cold[0]])[:width]
+        dicts = np.full((1, width), -1, np.int32)
+        dicts[0, :len(found)] = found
+        dicts = jnp.asarray(dicts)
+        one = (ids[cold[0]:cold[0] + 1], vals[cold[0]:cold[0] + 1])
+        for product, fn, arg in zip(("gradient", "margins"),
+                                    width_forms(size, width), (mult, w)):
+            try:
+                ms, sets = timed(jax.jit(fn), (*one, dicts, arg),
+                                 args.calls)
+                out({"dict_width": width, "product": product, "ms": ms,
+                     "sets_ms": sets,
+                     "ps_per_slot_row": ms * 1e9 / (width * args.batch)})
+            except Exception as exc:  # noqa: BLE001 — a failure is a reading
+                out({"dict_width": width, "product": product,
+                     "failed": repr(exc)[:300]})
     for width in args.hot_sets:
         sets = hot_sets(ids_h, cold, size, width)
         share = float(np.mean(np.isin(ids_h[cold], sets)))

@@ -208,7 +208,7 @@ class Watch:
             return out
 
         class Spans:
-            enabled = False
+            enabled = active = False
 
             @contextlib.contextmanager
             def span(self, name, **attrs):
