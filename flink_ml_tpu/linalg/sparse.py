@@ -135,14 +135,16 @@ class DeviceSparseColumn:
     ``hot`` is ``((entry, bucket), ...)`` for each entry position whose id
     is one bucket on every row (a numeric field, which ``FeatureHasher``
     hashes by its name); ``narrow`` is ``((entry, slots), ...)`` for each
-    other position whose ids lie in at most ``sparse_window.NARROW_MAX``
-    buckets over the whole table, its dictionary the first ``slots`` of its
-    row of ``dicts``, a ``(k, NARROW_MAX)`` int32 device array (``NARROW_MAX``
-    up to a multiple of ``CHUNK``) of each position's buckets, padded with
-    -1. The device fit sums hot entries as
-    columns and takes narrow ones by their dictionaries instead of
-    gathering and scattering them (``ops/sparse_window.py``). A row subset
-    keeps the index: its ids are a subset.
+    other position the index proposes to take by its dictionary: the
+    distinct ids of the column's first ``SAMPLE_ROWS`` rows there, at most
+    ``sparse_window.NARROW_MAX`` buckets, the first ``slots`` of its row of
+    ``dicts``, a ``(k, NARROW_MAX)`` int32 device array of each position's
+    buckets, ascending, padded with -1. A proposal is checked only over the
+    rows a fit reads (:meth:`narrow_through`): a position one of whose ids
+    there its dictionary lacks leaves ``narrow`` for good. The device fit
+    sums hot entries as columns and takes narrow ones by their dictionaries
+    instead of gathering and scattering them (``ops/sparse_window.py``). A
+    row subset keeps the proposals, and starts with no row checked.
 
     Row slices and takes stay on the device. Nothing here copies the
     column to the host but :meth:`to_csr`, the explicit off-ramp: an
@@ -159,6 +161,9 @@ class DeviceSparseColumn:
         self.size = int(size)
         self.hot = tuple(hot)
         self.narrow, self.dicts = tuple(narrow), dicts
+        #: rows of a shard, per shard length: every row under the bound
+        #: holds, at each ``narrow`` position, an id of its dictionary
+        self._checked = {}
 
     def __len__(self):
         return self.ids.shape[0]
@@ -195,6 +200,44 @@ class DeviceSparseColumn:
         rows = np.asarray(key)
         return self._rows(lambda a: a[rows])
 
+    def narrow_through(self, mesh, reach: int):
+        """``(narrow, rows, demoted)``: the ``narrow`` positions every id of
+        whose first ``reach`` rows of each shard is in its dictionary, the
+        column's rows split over ``mesh``'s data axes as a fit splits them
+        (shards of ``ceil(n / p)`` rows). Rows no earlier call checked at
+        that shard length are checked now, by one program over the ids as
+        they lie on ``mesh`` (each shard its own rows, the misses added over
+        the shards), under the cold span ``sparse:check``; a position with
+        a miss there leaves ``narrow`` for every later fit. ``rows`` is the
+        rows checked now, over every shard, and ``demoted`` the positions
+        that left: ``0`` and ``()`` where nothing was left to check."""
+        import jax
+
+        from flink_ml_tpu.parallel.collective import (
+            ensure_on_mesh, replicate)
+        from flink_ml_tpu.parallel.mesh import data_axes, data_shard_count
+
+        n = len(self)
+        local_n = -(-n // data_shard_count(mesh))
+        lo, hi = self._checked.get(local_n, 0), min(reach, local_n)
+        if not self.narrow or hi <= lo:
+            return self.narrow, 0, ()
+        shards = range(data_shard_count(mesh))
+        rows = sum(max(0, min(hi, n - s * local_n) - lo) for s in shards)
+        with tracer.cold_span("sparse:check", rows=rows,
+                              positions=len(self.narrow)) as sp:
+            ids, _ = ensure_on_mesh(mesh, self.ids, data_axes(mesh))
+            dicts = replicate(mesh, self.dicts)
+            misses = jax.device_get(_check_program(mesh, self.narrow,
+                                                   hi - lo)(
+                ids, dicts, np.int32(lo), np.int32(n)))
+            demoted = tuple(j for (j, _), m in zip(self.narrow, misses) if m)
+            sp.set_attribute("demoted", len(demoted))
+        self.narrow = tuple(at for at, m in zip(self.narrow, misses)
+                            if not m)
+        self._checked[local_n] = hi
+        return self.narrow, rows, demoted
+
     def to_csr(self):
         """The column on the host as one canonical scipy CSR matrix,
         float64: duplicate ids of a row summed, padding entries dropped."""
@@ -226,10 +269,8 @@ def is_device_sparse_column(col) -> bool:
 
 
 #: rows at the head of a column whose ids propose each position's
-#: dictionary; every row of the table is then checked against it
+#: dictionary; a fit checks the rows it reads against it
 SAMPLE_ROWS = 1 << 20
-#: dictionary slots compared with a position's ids at a time in the check
-CHUNK = 128
 
 
 def dict_slots(buckets: int) -> int:
@@ -241,74 +282,110 @@ def dict_slots(buckets: int) -> int:
 @functools.lru_cache(maxsize=None)
 @cold_build("sparse_index")
 def _index_program():
-    """``index(ids) -> (min, max, same, first, narrow, buckets, dicts)``:
-    the range of the ids; per entry position whether every row holds the
-    first row's id (``same``, ``first``); and per position whether its ids
-    lie in at most ``NARROW_MAX`` buckets over the whole table
-    (``narrow``), how many buckets its sample holds (``buckets``) and those
-    buckets, ascending and padded with -1 (``dicts``, ``(k,
-    NARROW_MAX)``, the width up to a multiple of ``CHUNK``). A position's candidates are the distinct ids of the
-    column's first ``SAMPLE_ROWS`` rows (sorted on the device); it is
-    narrow only if every row's id is one of them, counted by comparing each
-    row with each candidate. Hot positions are not narrow."""
+    """``index(ids) -> (min, max, same, first, buckets, dicts)``: the range
+    of the ids; per entry position whether every row holds the first row's
+    id (``same``, ``first``); and per position that is not so the distinct
+    ids of the column's first ``SAMPLE_ROWS`` rows, how many (``buckets``,
+    0 where ``same``) and the first ``NARROW_MAX`` of them, ascending and
+    padded with -1 (``dicts``, ``(k, NARROW_MAX)``). One position's sample
+    is sorted at a time, read from the ids as they lie, so what the program
+    keeps besides the table is one position's sample: no ``(k, n)`` or
+    ``(k, SAMPLE_ROWS)`` copy. Nothing here reads a row past the sample against a dictionary: a fit
+    checks the rows it reads (:meth:`DeviceSparseColumn.narrow_through`)."""
     import jax
     import jax.numpy as jnp
 
     from flink_ml_tpu.ops.sparse_window import NARROW_MAX
 
-    width = -(-NARROW_MAX // CHUNK) * CHUNK
-
     def sparse_index(ids):
         n, k = ids.shape
+        rows = min(n, SAMPLE_ROWS)
         first = ids[0]
         same = jnp.all(ids == first[None, :], axis=0)
-        ordered = jax.lax.sort(ids[:min(n, SAMPLE_ROWS)].T, dimension=1)
-        new = jnp.concatenate([jnp.ones((k, 1), bool),
-                               ordered[:, 1:] != ordered[:, :-1]], axis=1)
-        buckets = jnp.sum(new, axis=1, dtype=jnp.int32)
-        # slot s holds the ids' (s + 1)-th distinct value: where the count
-        # of distinct values so far reaches s + 1
-        slot = jnp.arange(1, width + 1, dtype=jnp.int32)
-        at = jax.vmap(lambda seen: jnp.searchsorted(seen, slot))(
-            jnp.cumsum(new, axis=1, dtype=jnp.int32))
-        dicts = jnp.where(slot[None, :] <= buckets[:, None],
-                          jnp.take_along_axis(
-                              ordered, jnp.minimum(at, ordered.shape[1] - 1),
-                              axis=1), -1)
-        candidate = jnp.logical_and(buckets <= NARROW_MAX,
-                                    jnp.logical_not(same))
-        # the check, one chunk of one candidate's dictionary a step: step
-        # t takes chunk c of position j, read from the ids as they lie
-        trips = jnp.where(candidate, -(-buckets // CHUNK), 0)
-        ends = jnp.cumsum(trips)
-        lanes = ids.T
+        # slot s holds the sample's (s + 1)-th distinct id: where the count
+        # of distinct ids so far reaches s + 1
+        slot = jnp.arange(1, NARROW_MAX + 1, dtype=jnp.int32)
 
-        def chunk(t, seen):
-            j = jnp.sum(ends <= t)
-            c = t - (ends[j] - trips[j])
-            row = jax.lax.dynamic_index_in_dim(lanes, j, keepdims=False)
-            d = jax.lax.dynamic_slice(dicts, (j, c * CHUNK), (1, CHUNK))
-            hits = jnp.sum(row[None, :] == d[0][:, None], dtype=jnp.int32)
-            return seen + jnp.where(jnp.arange(k) == j, hits, 0)
+        def propose(j, found):
+            buckets, dicts = found
+            ordered = jax.lax.sort(
+                jax.lax.dynamic_slice(ids, (0, j), (rows, 1))[:, 0])
+            new = jnp.concatenate([jnp.ones((1,), bool),
+                                   ordered[1:] != ordered[:-1]])
+            at = jnp.searchsorted(jnp.cumsum(new, dtype=jnp.int32), slot)
+            held = jnp.sum(new, dtype=jnp.int32)
+            row = jnp.where(slot <= held,
+                            ordered[jnp.minimum(at, rows - 1)], -1)
+            return (buckets.at[j].set(held),
+                    jax.lax.dynamic_update_slice(dicts, row[None], (j, 0)))
 
-        matched = jax.lax.fori_loop(0, ends[-1], chunk,
-                                    jnp.zeros((k,), jnp.int32))
-        narrow = jnp.logical_and(candidate, matched == n)
-        return (jnp.min(ids), jnp.max(ids), same, first, narrow, buckets,
-                dicts)
+        def position(j, found):
+            # a hot position is summed as a column: no sample to sort
+            return jax.lax.cond(same[j], lambda j, found: found, propose, j,
+                                found)
+
+        buckets, dicts = jax.lax.fori_loop(
+            0, k, position, (jnp.zeros((k,), jnp.int32),
+                             jnp.zeros((k, NARROW_MAX), jnp.int32)))
+        return jnp.min(ids), jnp.max(ids), same, first, buckets, dicts
 
     return jax.jit(sparse_index)
+
+
+@functools.lru_cache(maxsize=32)
+@cold_build("sparse_check")
+def _check_program(mesh, narrow: tuple, rows: int):
+    """``check(ids, dicts, lo, n) -> (len(narrow),)`` int32, replicated: for
+    each ``(entry, slots)`` of ``narrow``, the rows ``[lo, lo + rows)`` of
+    every shard of the row-sharded ``(n_padded, k)`` ids, those under the
+    column's ``n`` rows, whose id at ``entry`` is none of the first ``slots``
+    of its row of ``dicts``, added over the shards. Each position's rows are
+    compared with its dictionary as a round's dictionary form compares them:
+    a ``(slots, rows)`` compare that never leaves its reduction, the ids
+    read once where they lie (a slice of the column-major array's
+    transpose, a bitcast), counted in int32."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from flink_ml_tpu.parallel import mapreduce as mr
+    from flink_ml_tpu.parallel.mesh import data_axes, data_pspec
+
+    axes = data_axes(mesh)
+
+    def sparse_check(il, dicts, lo, n):
+        end = jnp.clip(n - mr.shard_index(axes) * il.shape[0], 0,
+                       il.shape[0])
+        mine = lo + jax.lax.iota(jnp.int32, rows)[None, :] < end
+        held = jnp.sum(mine, dtype=jnp.int32)
+        lanes = il.T
+        misses = []
+        # lax slices, not ``jnp`` indexing: the check is built in the first
+        # fit's set-up, where tracing a position's indexing costs more
+        # than its compare
+        for j, slots in narrow:
+            block = jax.lax.dynamic_slice(lanes, (j, lo), (1, rows))
+            d = jax.lax.slice(dicts, (j, 0), (j + 1, slots)).reshape(slots, 1)
+            found = jnp.sum(jnp.logical_and(block == d, mine), axis=1,
+                            dtype=jnp.int32)
+            misses.append(held - jnp.sum(found))
+        return mr.reduce_sum(jnp.stack(misses), axes)
+
+    spec0 = data_pspec(mesh)
+    return mr.map_shards(sparse_check, mesh,
+                         in_specs=(P(spec0, None), P(), P(), P()),
+                         out_specs=P())
 
 
 def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
     """The one entry for a sparse column made on the device: ``ids`` and
     ``values``, ``(n, k)`` ``jax.Array`` objects of int32 and float32, and
     the vectors' ``size``. The arrays are kept as they are; one program reads
-    the ids, refuses an id outside ``[0, size)`` and finds the entry
-    positions that hold one bucket on every row (``DeviceSparseColumn.
-    hot``) and those that hold few (``narrow``, with their dictionaries,
-    which stay on the device, replicated where the ids lie), a few numbers
-    to the host.
+    the ids, refuses an id outside ``[0, size)``, finds the entry positions
+    that hold one bucket on every row (``DeviceSparseColumn.hot``) and
+    proposes those whose sample holds few (``narrow``, with their
+    dictionaries, which stay on the device, replicated where the ids lie), a
+    few numbers to the host, under the cold span ``sparse:index``.
 
     Both indexes key on entry positions, so they pay only where a column
     keeps one field at each position of every row, as the click-through
@@ -318,6 +395,8 @@ def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
     position is hot or narrow there, and every entry is gathered and
     scattered."""
     import jax
+
+    from flink_ml_tpu.ops.sparse_window import NARROW_MAX
 
     if not (isinstance(ids, jax.Array) and isinstance(values, jax.Array)):
         raise TypeError("device_sparse_column takes two jax.Arrays; a host "
@@ -331,17 +410,19 @@ def device_sparse_column(ids, values, size: int) -> DeviceSparseColumn:
     size = int(size)
     hot, narrow, dicts = (), (), None
     if ids.shape[0]:
-        with tracer.span("sparse.index", rows=ids.shape[0],
-                         entries=ids.shape[1]) as sp:
+        with tracer.cold_span("sparse:index", rows=ids.shape[0],
+                              entries=ids.shape[1]) as sp:
             *found, dicts = _index_program()(ids)
-            lo, hi, same, first, is_narrow, buckets = jax.device_get(found)
-            sp.set_attribute("narrow", int(np.sum(is_narrow)))
+            lo, hi, same, first, buckets = jax.device_get(found)
+            proposed = np.logical_and(buckets <= NARROW_MAX,
+                                      np.logical_not(same))
+            sp.set_attribute("proposed", int(np.sum(proposed)))
         if lo < 0 or hi >= size:
             raise ValueError(f"ids lie in [{lo}, {hi}], outside the "
                              f"column's [0, {size})")
         hot = tuple((int(j), int(first[j])) for j in np.flatnonzero(same))
         narrow = tuple((int(j), dict_slots(int(buckets[j])))
-                       for j in np.flatnonzero(is_narrow))
+                       for j in np.flatnonzero(proposed))
         if not narrow:
             dicts = None
         elif isinstance(ids.sharding, jax.sharding.NamedSharding):

@@ -697,6 +697,24 @@ def _note_gradient_ops(span, prog, sparse, *args) -> None:
     span.set_attribute("gradient_ops", known[sig])
 
 
+def _rows_read(prm: SGDParams, mesh: Mesh, n: int, config,
+               listeners) -> int:
+    """The rows of each shard of ``n`` over ``mesh``'s data axes that a
+    fit's rounds can weight: a plain fit starts at every shard's first row
+    and moves a task's batch on a round, so its ``max_iter`` rounds reach
+    ``max_iter`` batches (the whole shard where they wrap); a carry that
+    crosses the host may be restored at any offset: the whole shard. The
+    rows a round reads past them weigh 0, so their ids move no margin and
+    no gradient."""
+    from flink_ml_tpu.iteration.iteration import needs_host_loop
+
+    p = data_shard_count(mesh)
+    local_n = -(-n // p)
+    if needs_host_loop(config, listeners):
+        return local_n
+    return min(local_n, prm.max_iter * _local_batch(prm, p, local_n))
+
+
 #: ``last_execution_path`` of a sparse fit, by the dense path it shares
 _SPARSE_PATHS = {"xla-while": "sparse-device",
                  "xla-while-segments": "sparse-device-segments",
@@ -841,7 +859,17 @@ class SGD:
         names of the operations the program runs for the gradient
         (``sparse_window.gradient_ops``). A data mesh only: under a model
         axis the coefficients would be split, and a gather over them is
-        not."""
+        not.
+
+        The narrow positions are the column's proposals that hold, in
+        every row the fit's rounds can weight (:func:`_rows_read`), an id
+        of their dictionary: rows no earlier fit checked are checked first
+        (``DeviceSparseColumn.narrow_through``), and a position with a miss
+        there is taken wide, by this fit and every later one. ``checked_rows``
+        and ``demoted`` on ``sgd.optimize`` (``last_check``) say what this
+        fit checked, 0 for a fit whose rows were checked before; the
+        counters ``ml.sgd dictRowsChecked`` (rows times positions) and
+        ``dictDemoted`` add them."""
         mesh = mesh or default_mesh()
         if model_axis_of(mesh) is not None:
             raise ValueError("a sparse device fit runs over a data mesh, "
@@ -851,14 +879,25 @@ class SGD:
         with tracer.span("sgd.optimize", rounds=self.params.max_iter,
                          shards=data_shard_count(mesh),
                          weights="unit" if weights is None else "column",
-                         batch=batch,
-                         form=sparse_window.form(column.hot, column.narrow),
-                         narrow=len(column.narrow)) as sp:
+                         batch=batch) as sp:
+            narrow, checked, demoted = column.narrow_through(
+                mesh, _rows_read(self.params, mesh, len(column), config,
+                                 listeners))
+            if checked:
+                group = metrics.group(ML_GROUP, "sgd")
+                group.counter("dictRowsChecked",
+                              checked * (len(narrow) + len(demoted)))
+                group.counter("dictDemoted", len(demoted))
+            self.last_check = (checked, demoted)
+            sp.set_attribute("form", sparse_window.form(column.hot, narrow))
+            sp.set_attribute("narrow", len(narrow))
+            sp.set_attribute("checked_rows", checked)
+            sp.set_attribute("demoted", len(demoted))
             out = self._optimize(
                 loss_func, init_coeffs, column, labels, weights, mesh,
                 jnp.float32, config, listeners, tag, batch,
                 sparse=sparse_window.Layout(column.size, column.hot,
-                                            column.narrow))
+                                            narrow))
             sp.set_attribute("path", self.last_execution_path)
             sp.set_attribute("batch_reads", self.last_batch_reads)
             sp.set_attribute("entries", self.last_entries[0])

@@ -21,7 +21,8 @@ off them, by what every row of the table holds there:
   field) are summed as columns: the margins take ``coeffs[bucket] *
   values`` and the gradient adds one row sum a bucket;
 - ``narrow`` (``DeviceSparseColumn.narrow``: at most :data:`NARROW_MAX`
-  buckets over the whole table) take the dictionary form: with ``D`` the
+  buckets in the column's sample, and every id of the rows the fit reads
+  among them) take the dictionary form: with ``D`` the
   position's buckets (a row of the column's ``dicts``, padded with -1, a
   bucket no id holds), the margins take ``sum_s where(ids == D[s],
   coeffs[D[s]], 0) * values`` (one slot matches, so it is the gather's
@@ -49,18 +50,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-#: the most buckets a position may hold over the whole table and still take
-#: the dictionary form. On one TPU v5e (``scripts/sparse_forms.py``, the
+#: the most buckets a position's sample may hold and still take the
+#: dictionary form. On one TPU v5e (``scripts/sparse_forms.py``, the
 #: click-through cell's window of 100,000 rows) the serial gather and
-#: scatter take some 6.7 ns an entry each way, 0.67 ms a position; one
-#: position's dictionary costs some 0.2 ms each way up to 2,048 slots and
-#: 0.9 ps a slot a row past that, so a round alone gains up to some 7,000
-#: slots. The cap sits lower for the index: it compares every row of the
-#: table with each proposed dictionary, once a column, in set-up, so each
-#: 128 slots more is one more pass of compares over the table's rows (at
-#: 1024 the click-through cell's 11 narrow fields hold 1,776 slots and its
-#: index takes 0.39 s at 23M rows).
-NARROW_MAX = 1024
+#: scatter take some 6.7 and 7.4 ns an entry, 0.67 ms a position each way;
+#: one position's dictionary costs some 0.2 ms each way up to 2,048 slots
+#: and 0.9 ps a slot a row past that, so a round gains from it up to some
+#: 7,000 slots. 6,144, a multiple of the 128 lanes, holds the cell's
+#: fields of up to 5,683 buckets (16 of its 26) and leaves its 12,517 wide.
+#: The dictionaries are checked only over the rows a fit reads
+#: (``DeviceSparseColumn.narrow_through``), in the round's compare form:
+#: the cost of the cap is the fit's, not the table's.
+NARROW_MAX = 6144
 
 #: the scope every operation of a round's gradient is made under
 GRADIENT_SCOPE = "sgd.sparse_gradient"

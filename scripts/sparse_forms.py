@@ -33,7 +33,16 @@ hot ones as columns, the rest gathered and scattered; its two halves alone
 width of ``--dict-widths``, one position's ids against a dictionary of that
 many slots (compare, select and sum over the window's rows, both ways): a
 slot's cost a row, which with the serial cost of an entry (``split-scatter``
-over its entries) sets ``NARROW_MAX``. Then,
+over its entries) sets ``NARROW_MAX``. Before all of them the ``index``
+forms, what a column's dictionaries cost in set-up: the column's index
+program alone (``proposal``: the ids' range, the hot positions, each
+position's dictionary proposed from its sorted sample), the check a fit
+makes of the rows it reads (``check``: the published 20 rounds of
+``--batch`` rows against the proposed dictionaries, the program
+``DeviceSparseColumn.narrow_through`` runs), and the check of every row of
+the table an index made before that (``whole-table``: each proposed
+dictionary of at most 1,024 and of at most 6,144 buckets, 128 slots a
+step); ``--index-only`` stops after them. Then,
 for each width of ``--hot-sets``, each cold position's most frequent ids
 in the window taken by compare, select and sum and the rest scattered and
 gathered with those entries sent out of bounds (``gradient``,
@@ -49,6 +58,7 @@ index program (``index_first_s`` with its compile, ``index_warm_s``).
 
     python scripts/sparse_forms.py [--calls 20] [--hot-sets 64,256,1024]
                                    [--dict-widths 256,1024,2048,4096]
+                                   [--index-only]
     python scripts/sparse_forms.py --rehearse --rows 200000 --batch 10000
 
 One JSON line a form; the lines also go to ``chiprun_out/sparse_forms.json``.
@@ -71,6 +81,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from flink_ml_tpu.linalg import sparse  # noqa: E402
 from flink_ml_tpu.linalg.sparse import device_sparse_column  # noqa: E402
 from flink_ml_tpu.ops import optimizer, sparse_window  # noqa: E402
 from flink_ml_tpu.ops.losses import BinaryLogisticLoss  # noqa: E402
@@ -78,6 +89,8 @@ from flink_ml_tpu.parallel.mesh import create_mesh  # noqa: E402
 
 CONFIG = os.path.join(ROOT, "benchmarks", "configs", "criteo-hashed-lr.json")
 ROUNDS = (20, 40)
+#: the caps of the whole-table check timed: the one it ran at, and 6,144
+WHOLE_TABLE_CAPS = (1024, 6144)
 
 
 def _sorted(ids, terms):
@@ -230,6 +243,67 @@ def width_forms(size, width):
     return gradient, margins
 
 
+def whole_table_check(cap, chunk=128):
+    """``check(ids, dicts, buckets, same) -> (k,)`` bool: the check an index
+    made of every row of the table before a fit checked the rows it reads.
+    Each position whose sample holds at most ``cap`` buckets and that is not
+    hot is narrow only if every row's id is one of its dictionary's, counted
+    by comparing the ids with ``chunk`` slots of one dictionary a step, read
+    from the ids' transpose."""
+    def check(ids, dicts, buckets, same):
+        n, k = ids.shape
+        candidate = jnp.logical_and(buckets <= cap, jnp.logical_not(same))
+        trips = jnp.where(candidate, -(-buckets // chunk), 0)
+        ends = jnp.cumsum(trips)
+        lanes = ids.T
+
+        def step(t, seen):
+            j = jnp.sum(ends <= t)
+            c = t - (ends[j] - trips[j])
+            row = jax.lax.dynamic_index_in_dim(lanes, j, keepdims=False)
+            d = jax.lax.dynamic_slice(dicts, (j, c * chunk), (1, chunk))
+            hits = jnp.sum(row[None, :] == d[0][:, None], dtype=jnp.int32)
+            return seen + jnp.where(jnp.arange(k) == j, hits, 0)
+
+        matched = jax.lax.fori_loop(0, ends[-1], step,
+                                    jnp.zeros((k,), jnp.int32))
+        return jnp.logical_and(candidate, matched == n)
+
+    return jax.jit(check)
+
+
+def index_forms(column, mesh, rows, calls, out):
+    """The index program alone, the check of a fit's ``rows`` against the
+    proposed dictionaries, and the whole-table check at each cap of
+    :data:`WHOLE_TABLE_CAPS`: one line each, with the slots compared."""
+    index = sparse._index_program()
+    ms, sets = timed(index, (column.ids,), calls)
+    out({"index": "proposal", "ms": ms, "sets_ms": sets,
+         "rows": len(column)})
+    _, _, same, _, buckets, dicts = index(column.ids)
+    narrow = column.narrow
+    check = sparse._check_program(mesh, narrow, rows)
+    args = (column.ids, column.dicts, np.int32(0), np.int32(len(column)))
+    ms, sets = timed(check, args, calls)
+    slots = sum(s for _, s in narrow)
+    out({"index": "check", "ms": ms, "sets_ms": sets, "rows": rows,
+         "positions": len(narrow), "slots": slots,
+         "ps_per_slot_row": ms * 1e9 / (slots * rows),
+         "misses": np.asarray(check(*args)).tolist()})
+    host_buckets, host_same = np.asarray(buckets), np.asarray(same)
+    for cap in WHOLE_TABLE_CAPS:
+        taken = np.logical_and(host_buckets <= cap, ~host_same)
+        slots = int(sum(sparse.dict_slots(int(b))
+                        for b in host_buckets[taken]))
+        fn = whole_table_check(cap)
+        ms, sets = timed(fn, (column.ids, dicts, buckets, same),
+                         max(1, calls // 5))
+        out({"index": "whole-table", "cap": cap, "ms": ms, "sets_ms": sets,
+             "rows": len(column), "positions": int(taken.sum()),
+             "slots": slots,
+             "ps_per_slot_row": ms * 1e9 / (max(slots, 1) * len(column))})
+
+
 def _pallas_scatter(ids, terms, size, block=2048):
     """The gradient as one ``(size / 128, 128)`` VMEM block over the whole
     grid; each step brings ``block`` ids and terms into SMEM and adds them
@@ -349,6 +423,8 @@ def main(argv=None):
     ap.add_argument("--no-in-program", dest="in_program",
                     action="store_false",
                     help="leave out the fit programs' round times")
+    ap.add_argument("--index-only", action="store_true",
+                    help="time the index forms alone")
     ap.add_argument("--rehearse", action="store_true",
                     help="run off the chip too: the times mean nothing")
     args = ap.parse_args(argv)
@@ -364,10 +440,21 @@ def main(argv=None):
     mesh = create_mesh(devices=jax.devices()[:1])
     column, label, made = table(args.rows, mesh)
     k, size = column.entries, column.size
+    proposed = [list(p) for p in column.narrow]
+    index_forms(column, mesh, min(args.rows, ROUNDS[0] * args.batch),
+                args.calls, out)
+    # the rows the longest fit below reads, checked as a fit checks them
+    t = time.perf_counter()
+    _, checked, demoted = column.narrow_through(mesh,
+                                                ROUNDS[-1] * args.batch)
     out({"rows": args.rows, "batch": args.batch, "entries": k, "size": size,
-         "hot": len(column.hot), "calls": args.calls,
+         "hot": len(column.hot), "calls": args.calls, "proposed": proposed,
          "narrow": [list(p) for p in column.narrow],
+         "checked_rows": checked, "demoted": list(demoted),
+         "narrow_through_s": time.perf_counter() - t,
          "device_kind": jax.devices()[0].device_kind, **made})
+    if args.index_only:
+        return save(lines)
     window = jax.jit(lambda a: jax.lax.optimization_barrier(
         a.T[:, :args.batch]))
     ids, vals = window(column.ids), window(column.values)
@@ -448,6 +535,10 @@ def main(argv=None):
                      "failed": repr(exc)[:300]})
     if args.in_program:
         in_place(column, label, mesh, args.batch, args.calls, out)
+    return save(lines)
+
+
+def save(lines):
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "sparse_forms.json"),
               "w") as f:

@@ -105,11 +105,12 @@ CASES = {
     # hot, narrow and wide positions in one window (``mixed_table``)
     "hot-narrow-and-wide": Case(size=1 << 18, table="mixed"),
 }
-#: at 1,024 buckets the index takes every position that is not hot to the
+#: the Zipf table's 20,000 rows hold under ``NARROW_MAX`` ids at every
+#: position, so the index takes every position that is not hot to the
 #: dictionary form: each such case again with them gathered and scattered
 CASES.update({f"{name}-wide": dataclasses.replace(case, wide=True)
               for name, case in list(CASES.items())
-              if case.size <= sparse_window.NARROW_MAX})
+              if case.table == "zipf"})
 
 
 @pytest.fixture(autouse=True)
@@ -287,10 +288,9 @@ def test_the_device_fit_is_the_references_fit(name, devices):
     sgd, coeffs, _ = device_fit(prm, mesh, ids, vals, y, case.size,
                                 wide=case.wide)
     assert sgd.last_execution_path == "sparse-device"
-    # the dictionary form takes entries where the index found narrow ones
-    assert (sgd.last_entries[1] > 0) == (
-        not case.wide and case.size <= sparse_window.NARROW_MAX
-        or case.table == "mixed")
+    # the dictionary form takes entries where the index found narrow ones:
+    # every table has some
+    assert (sgd.last_entries[1] > 0) == (not case.wide)
     want, rounds = reference_fit(prm, ids, vals, y, devices, case.size)
     assert (rounds < case.rounds) == name.startswith("tol-stop")
     assert gap(coeffs, want) < case.tolerance
@@ -421,22 +421,70 @@ def small_sample(monkeypatch):
     sparse._index_program.cache_clear()
 
 
-def test_an_id_the_sample_missed_keeps_its_position_wide(small_sample):
-    """A bucket first held after the sample: the check finds it, the
-    position stays wide, and the fit scatters its entries."""
+#: a bucket the sample missed: the devices, the row that first holds it, the
+#: rounds of each fit over the column (a task's batch 2,000 / devices rows),
+#: and whether its position is wide after each
+MISSED = {
+    # 5 rounds read rows [0, 10,000)
+    "inside-the-rows-read": (1, 5_000, (5,), (True,)),
+    # ... and not row 15,000, which 12 rounds (the whole table) read
+    "past-the-rows-read": (1, 15_000, (5, 12), (False, True)),
+    # shards of 5,000 rows, 5 rounds of 500 on each: row 11,000 is the
+    # third shard's 1,000th
+    "on-one-shard-of-four": (4, 11_000, (5,), (True,)),
+}
+
+
+@pytest.mark.parametrize("where", MISSED)
+def test_an_id_the_sample_missed_keeps_its_position_wide(where,
+                                                         small_sample):
+    """A bucket first held after the sample: a fit that weights the row
+    holding it finds it and takes its position wide, for that fit and
+    every later one (on a mesh, on every shard); a fit that does not keeps
+    the position narrow; every fit is the reference's."""
+    devices, row, rounds, wide_after = MISSED[where]
     size = 1 << 18
     ids, vals, y = mixed_table(size)
     late = HOT + 1                       # 4 buckets in the first rows
-    ids[15_000, late] = 12_345
+    ids[row, late] = 12_345
     assert 12_345 not in ids[:1_000, late]
-    mesh = on_mesh(1)
+    mesh = on_mesh(devices)
     col = column_on(mesh, ids, vals, size)
-    narrow_at = [j for j, _ in col.narrow]
-    assert late not in narrow_at and HOT in narrow_at
-    prm = CASES["zipf-hot-and-duplicates"].params()
-    _, coeffs, _ = device_fit(prm, mesh, ids, vals, y, size)
-    want, _ = reference_fit(prm, ids, vals, y, 1, size)
-    assert gap(coeffs, want) < TOL
+    assert {HOT, late} <= {j for j, _ in col.narrow}    # proposed
+    for fit_rounds, wide in zip(rounds, wide_after):
+        prm = dataclasses.replace(CASES["zipf-hot-and-duplicates"],
+                                  rounds=fit_rounds).params()
+        sgd = SGD(prm)
+        coeffs, _ = sgd.optimize_sparse(BinaryLogisticLoss(), np.zeros(size),
+                                        col, labels_on(mesh, y), mesh=mesh)
+        narrow_at = [j for j, _ in col.narrow]
+        assert (late in sgd.last_check[1]) == wide == (late not in narrow_at)
+        assert HOT in narrow_at
+        want, _ = reference_fit(prm, ids, vals, y, devices, size)
+        assert gap(coeffs, want) < TOL
+
+
+def test_a_row_subset_keeps_the_proposals_and_checks_its_own_rows():
+    """A fit checks the rows it reads once; a row subset of the column
+    keeps its proposals and dictionaries and starts with no row checked."""
+    mesh = on_mesh(1)
+    ids, vals, y = sparse_table(1 << 10)
+    col, labels = column_on(mesh, ids, vals, 1 << 10), labels_on(mesh, y)
+    sgd = SGD(CASES["l2"].params())         # 12 rounds of 2,000: every row
+    group = metrics.group(ML_GROUP, "sgd")
+    for checked in (N, 0):
+        before = group.get_counter("dictRowsChecked")
+        sgd.optimize_sparse(BinaryLogisticLoss(), np.zeros(1 << 10), col,
+                            labels, mesh=mesh)
+        assert sgd.last_check == (checked, ())
+        # rows times the positions checked: the 36 that are not hot
+        assert group.get_counter("dictRowsChecked") - before == \
+            checked * (K - HOT)
+    part = col[4_000:14_000]
+    assert part.narrow == col.narrow and part.dicts is col.dicts
+    sgd.optimize_sparse(BinaryLogisticLoss(), np.zeros(1 << 10), part,
+                        labels_on(mesh, y[4_000:14_000]), mesh=mesh)
+    assert sgd.last_check == (10_000, ())
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -637,12 +685,14 @@ def test_a_warm_fit_builds_nothing(monkeypatch):
     sgd = SGD(CASES["l2"].params())
     first, _ = sgd.optimize_sparse(BinaryLogisticLoss(), np.zeros(1 << 10),
                                    column, labels, mesh=mesh)
+    assert sgd.last_check == (N, ())        # the first fit checks its rows
     watch = Watch(monkeypatch, events=BUILDS)
     with watch():
         again, _ = sgd.optimize_sparse(BinaryLogisticLoss(),
                                        np.zeros(1 << 10), column, labels,
                                        mesh=mesh)
     assert watch.jits == [] and watch.requests == 0
+    assert sgd.last_check == (0, ())       # ... and the second none
     # the ids, the values and the label are where they are: puts that move
     # nothing, under placement alone
     assert {span for span, _ in watch.puts} <= {"sgd.place_inputs"}
@@ -679,6 +729,34 @@ def test_a_traced_fit_compiles_nothing_more(monkeypatch):
     assert first == again and first
 
 
+def test_the_index_and_a_fit_s_check_are_cold_spans(monkeypatch):
+    """Recorded with nothing armed: ``sparse:index`` as the column is made,
+    ``sparse:check`` as its first fit checks the rows it reads, and nothing
+    as a second fit reads the same rows; the ring stays empty."""
+    monkeypatch.setattr(tracer, "cold", [])
+    assert not tracer.active
+    mesh = on_mesh(4)
+    ids, vals, y = sparse_table(1 << 10)
+    col, labels = column_on(mesh, ids, vals, 1 << 10), labels_on(mesh, y)
+    def attrs(record, *names):
+        # a span inside which a program compiles also carries its compile
+        # times: whether one does depends on what the process built before
+        return {name: record["attrs"][name] for name in names}
+
+    index, = [r for r in tracer.cold if r["name"] == "sparse:index"]
+    assert attrs(index, "rows", "entries", "proposed") == {
+        "rows": N, "entries": K, "proposed": K - HOT}
+    sgd = SGD(dataclasses.replace(CASES["l2"], rounds=2).params())
+    for _ in range(2):
+        sgd.optimize_sparse(BinaryLogisticLoss(), np.zeros(1 << 10), col,
+                            labels, mesh=mesh)
+    check, = [r for r in tracer.cold if r["name"] == "sparse:check"]
+    # two rounds of 500 rows on each of four shards
+    assert attrs(check, "rows", "positions", "demoted") == {
+        "rows": 4_000, "positions": K - HOT, "demoted": 0}
+    assert len(tracer.recent) == 0
+
+
 @pytest.mark.parametrize("devices", [1, 4])
 def test_the_spans_and_counters_of_a_fit(devices, monkeypatch):
     mesh = on_mesh(devices)
@@ -689,7 +767,9 @@ def test_the_spans_and_counters_of_a_fit(devices, monkeypatch):
     group = metrics.group(ML_GROUP, "sgd")
     before = {k: group.get_counter(k) for k in ("batchReads",
                                                 "sparseEntries",
-                                                "dictEntries")}
+                                                "dictEntries",
+                                                "dictRowsChecked",
+                                                "dictDemoted")}
     monkeypatch.setattr(tracer, "keep_recent", True)
     lr.fit(table)
     records = list(tracer.recent)
@@ -701,12 +781,15 @@ def test_the_spans_and_counters_of_a_fit(devices, monkeypatch):
     under = sorted(r["name"] for r in records if r["parent"] == opt["id"])
     assert under == ["sgd.build_program", "sgd.fetch", "sgd.health",
                      "sgd.init_carry", "sgd.launch", "sgd.place_inputs"]
-    # 1,024 buckets: the 36 positions that are not hot are narrow
+    # 1,024 buckets: the 36 positions that are not hot are narrow, and the
+    # warm fit checked the rows this one reads
     assert opt["attrs"] == {
         "rounds": 8, "shards": devices, "weights": "unit", "batch": "onchip",
         "form": "split-dict-scatter", "narrow": K - HOT,
+        "checked_rows": 0, "demoted": 0,
         "path": "sparse-device", "batch_reads": 8,
         "entries": 8 * 2_000 * K, "dict_entries": 8 * 2_000 * (K - HOT)}
+    assert not any(r["name"].startswith("sparse:") for r in records)
     launch, = [r for r in records if r["name"] == "sgd.launch"]
     # the compiled program's operations under the gradient's scope, its
     # scatter-adds among them
@@ -716,7 +799,8 @@ def test_the_spans_and_counters_of_a_fit(devices, monkeypatch):
     moved = {k: group.get_counter(k) - v for k, v in before.items()}
     # a task's window is 2000 / devices rows of 39 entries, every round
     assert moved == {"batchReads": 8, "sparseEntries": 8 * 2_000 * K,
-                     "dictEntries": 8 * 2_000 * (K - HOT)}
+                     "dictEntries": 8 * 2_000 * (K - HOT),
+                     "dictRowsChecked": 0, "dictDemoted": 0}
 
 
 # -- the dense programs keep their text ---------------------------------------
@@ -769,17 +853,21 @@ def v5e():
         pytest.skip(f"no v5e can be described here: {exc}")
 
 
-#: the click-through column's narrow index as ``device_sparse_column`` finds
-#: it in the cell's table (``CriteoHashedGenerator``, 2^18 buckets): C2, C5,
-#: C6, C8, C9, C14, C17, C20, C22, C23 and C25, their buckets to 8
-CELL_NARROW = ((14, 584), (17, 304), (18, 24), (20, 632), (21, 8), (26, 32),
-               (29, 16), (32, 8), (34, 24), (35, 16), (37, 112))
+#: the click-through column's narrow index as ``device_sparse_column``
+#: proposes it in the cell's table (``CriteoHashedGenerator``, 2^18
+#: buckets): C1, C2, C5, C6, C8, C9, C11, C13, C14, C17, C18, C19, C20, C22,
+#: C23 and C25, each field's cardinality to 8 (its sample holds at most as
+#: many buckets)
+CELL_NARROW = ((13, 1464), (14, 584), (17, 304), (18, 24), (20, 632),
+               (21, 8), (23, 5688), (25, 3200), (26, 32), (29, 16),
+               (30, 5656), (31, 2176), (32, 8), (34, 24), (35, 16),
+               (37, 112))
 
 
 def test_the_cell_s_fit_keeps_its_temporaries_small_on_a_v5e(v5e):
     """23M rows of 39 entries in 2^18 buckets, the published 20 rounds of
     100,000 rows: the table stays where it lies (no ``(n, k)`` copy, 3.68 GB
-    an array), the window's 15 wide positions are made on chip, the narrow
+    an array), the window's 10 wide positions are made on chip, the narrow
     ones' ``(slots, rows)`` compares stay inside their reductions, and the
     program's temporaries are under 0.5 GB."""
     n, size = 23_000_000, 1 << 18
@@ -802,7 +890,7 @@ def test_the_cell_s_fit_keeps_its_temporaries_small_on_a_v5e(v5e):
     text = compiled.as_text()
     for dtype in ("s32", "f32"):
         assert f"{dtype}[{n},39]{{0,1:T(8,128)}}" in text
-    assert "[15,100000]{1,0:T(8,128)S(1)}" in text  # the wide window on chip
+    assert "[10,100000]{1,0:T(8,128)S(1)}" in text  # the wide window on chip
     # ``benchmarks/harness/readers/sparse_gradient_device_ms.py`` times the
     # operations the program names under the gradient's scope: among them
     # the wide entries' scatter-add and the hot and dictionary sums' add,
@@ -822,6 +910,25 @@ def test_the_cell_s_fit_keeps_its_temporaries_small_on_a_v5e(v5e):
         for line in (ln for ln in text.splitlines() if f" %{op} = " in ln):
             assert line.split(" = ")[1].startswith("f32[262144]")
             assert "sgd.sparse_gradient/scatter-add" in line
+
+
+def test_the_cell_s_index_and_check_keep_their_temporaries_small_on_a_v5e(
+        v5e):
+    """The index over the cell's 23M rows keeps one position's sample, not a
+    ``(k, n)`` or ``(k, SAMPLE_ROWS)`` copy; the first fit's check of the 2M
+    rows the published 20 rounds of 100,000 read, against the 16
+    dictionaries, keeps its compares inside their reductions. Each under
+    0.05 GB of temporaries beside the 3.68 GB ids."""
+    n = 23_000_000
+    mesh = create_mesh(devices=[v5e])
+    ids = _shape(mesh, (n, K), P("data", None), jnp.int32)
+    index = sparse._index_program().lower(ids).compile()
+    assert index.memory_analysis().temp_size_in_bytes < 0.05e9
+    check = sparse._check_program(mesh, CELL_NARROW, 2_000_000).lower(
+        ids, _shape(mesh, (K, sparse_window.NARROW_MAX), P(), jnp.int32),
+        np.int32(0), np.int32(n)).compile()
+    assert check.memory_analysis().temp_size_in_bytes < 0.05e9
+    assert f"s32[{n},39]{{0,1:T(8,128)}}" in check.as_text()
 
 
 def write_sparse_lowered(path, commit):
